@@ -91,7 +91,7 @@ def export_chromosome(store: VariantStore, code: int, out_dir: str,
 def main(argv=None) -> int:
     from annotatedvdb_tpu.utils.runtime import pin_platform
 
-    # host-only CLI: pin CPU outright (no accelerator probe needed)
+    # host-only CLI: pin CPU outright
     pin_platform("cpu")
 
     ap = argparse.ArgumentParser(description=__doc__)

@@ -59,7 +59,7 @@ def emit_rows(chr_map: dict, out) -> int:
 def main(argv=None) -> int:
     from annotatedvdb_tpu.utils.runtime import pin_platform
 
-    # host-only CLI: pin CPU outright (no accelerator probe needed)
+    # host-only CLI: pin CPU outright
     pin_platform("cpu")
 
     ap = argparse.ArgumentParser(description=__doc__)

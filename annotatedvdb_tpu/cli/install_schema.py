@@ -27,7 +27,7 @@ from annotatedvdb_tpu.sql.schema import full_schema
 def main(argv=None) -> int:
     from annotatedvdb_tpu.utils.runtime import pin_platform
 
-    # host-only CLI: pin CPU outright (no accelerator probe needed)
+    # host-only CLI: pin CPU outright
     pin_platform("cpu")
 
     ap = argparse.ArgumentParser(description=__doc__)

@@ -22,7 +22,7 @@ from annotatedvdb_tpu.store import AlgorithmLedger, VariantStore
 def main(argv=None) -> int:
     from annotatedvdb_tpu.utils.runtime import pin_platform
 
-    # environment-robust platform pin (probe accelerator, CPU fallback)
+    # honor an explicit cpu pin, place the compile cache
     pin_platform("auto")
 
     ap = argparse.ArgumentParser(description=__doc__)

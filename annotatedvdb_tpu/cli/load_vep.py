@@ -17,9 +17,8 @@ from annotatedvdb_tpu.store import AlgorithmLedger, VariantStore
 
 
 def main(argv=None):
-    # platform pinning happens in runtime.apply() AFTER argparse — an
-    # early pin_platform("auto") here would cache its probe verdict in
-    # AVDB_JAX_PLATFORM and silently override a user's --platform flag
+    # platform pinning happens in runtime.apply() AFTER argparse, so the
+    # --platform flag is known
     parser = argparse.ArgumentParser(description="load VEP JSON results")
     parser.add_argument("--fileName", required=True)
     parser.add_argument("--storeDir", required=True)

@@ -306,7 +306,22 @@ def _run_single(args, log) -> int:
     from annotatedvdb_tpu.serve.residency import ResidencyManager
     from annotatedvdb_tpu.serve.snapshot import SnapshotManager
     from annotatedvdb_tpu.utils import faults
+    from annotatedvdb_tpu.utils.runtime import device_summary, pin_platform
 
+    # honor an explicit cpu pin, place the compile cache (the supervisor
+    # of a fleet never gets here: it stays off JAX)
+    pin_platform()
+    try:
+        # take the device NOW: a process that cannot have one (the chip is
+        # held by another process) must say so in one line at start-up,
+        # not in a traceback at its first request
+        device_summary()
+    except RuntimeError as err:
+        from annotatedvdb_tpu.serve.fleet import NO_DEVICE_RC
+
+        print("serve: cannot start: JAX found no usable device "
+              f"({str(err).splitlines()[0][:300]})", file=sys.stderr)
+        return NO_DEVICE_RC
     tracer = Tracer(process_name="avdb-serve") if args.traceOut else None
     registry = MetricsRegistry()
     try:

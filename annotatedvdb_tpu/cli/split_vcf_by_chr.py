@@ -61,7 +61,7 @@ def split_file(path: str, out_dir: str, chrm_map: dict | None = None,
 def main(argv=None) -> int:
     from annotatedvdb_tpu.utils.runtime import pin_platform
 
-    # host-only CLI: pin CPU outright (no accelerator probe needed)
+    # host-only CLI: pin CPU outright
     pin_platform("cpu")
 
     ap = argparse.ArgumentParser(description=__doc__)

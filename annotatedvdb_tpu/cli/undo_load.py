@@ -17,7 +17,7 @@ from annotatedvdb_tpu.store import AlgorithmLedger, VariantStore
 def main(argv=None):
     from annotatedvdb_tpu.utils.runtime import pin_platform
 
-    # host-only CLI: pin CPU outright (no accelerator probe needed)
+    # host-only CLI: pin CPU outright
     pin_platform("cpu")
 
     parser = argparse.ArgumentParser(description="undo a variant load")

@@ -871,9 +871,9 @@ class TpuVcfLoader:
             enc = None  # reader's scan already found exotic bytes
         else:
             enc = encode_alleles_nibble(*pad_alleles(width))
-        # uploads ride the bounded-retry wrapper: a transient tunnel/
-        # runtime blip on a remote-attached device re-sends the buffer
-        # instead of killing a multi-hour load (utils.retry)
+        # uploads ride the bounded-retry wrapper: a transient runtime
+        # error re-sends the buffer instead of killing a multi-hour load
+        # (utils.retry)
         from annotatedvdb_tpu.utils.retry import device_put as _dput
 
         if enc is not None:
@@ -917,10 +917,10 @@ class TpuVcfLoader:
         h_dev = allele_hash_jit(dev[2], dev[3], dev[4], dev[5])
         handles = {"ann_p": ann_p, "h_dev": h_dev}
         if will_pack:
-            # remote-attached TPUs pay a fixed round trip PER materialized
-            # array; pack the six per-row outputs on device so process time
-            # fetches once (_will_pack already probed the transport's
-            # bit-exactness on this backend).
+            # every materialized array is its own transfer; pack the six
+            # per-row outputs on device so process time fetches once
+            # (_will_pack already probed the transport's bit-exactness on
+            # this backend).
             import jax.numpy as jnp
 
             from annotatedvdb_tpu.ops.pack import pack_outputs_jit
@@ -1066,8 +1066,7 @@ class TpuVcfLoader:
                 )
         # ---- force the dispatched device results (annotate + bin + hash +
         # in-batch dedup).  Only the fields the host path consumes are
-        # fetched back — host<->device bytes are the load's bottleneck on
-        # remote-attached TPUs.
+        # fetched back.
         with self.timer.stage("annotate", items=batch.n):
             n = batch.n
             ann_p = handles["ann_p"]

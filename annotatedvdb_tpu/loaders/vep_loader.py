@@ -454,10 +454,10 @@ class TpuVepLoader:
 
     def _batch_identity(self, batch: VariantBatch):
         """(hash, prefix_len, host_fallback) for one per-alt batch — the
-        three identity outputs the update path consumes.  Device kernels on
-        fast links (packed single-fetch transport), bit-exact numpy twins on
-        slow remote-attached links (see ops/hashing.allele_hash_np,
-        ops/annotate.vep_identity_np)."""
+        three identity outputs the update path consumes.  Device kernels
+        where the measured upload rate clears ``DEVICE_MIN_BANDWIDTH``
+        (packed single-fetch transport), bit-exact numpy twins below it
+        (see ops/hashing.allele_hash_np, ops/annotate.vep_identity_np)."""
         from annotatedvdb_tpu.loaders.vcf_loader import _pad_batch
         from annotatedvdb_tpu.store.variant_store import _transfer_fast
         from annotatedvdb_tpu.utils.arrays import next_pow2

@@ -123,3 +123,11 @@ def load():
 
 def available() -> bool:
     return load() is not None
+
+
+def status() -> dict:
+    """What :func:`load` found so far, without building anything:
+    ``{"loaded": bool, "error": str | None}`` — load summaries print it,
+    so a load that took the Python tokenizer because the build failed says
+    so instead of only being slower."""
+    return {"loaded": _lib is not None, "error": _lib_error}

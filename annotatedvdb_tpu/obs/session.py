@@ -51,11 +51,36 @@ def config_hash(params: dict) -> str:
     return hashlib.sha256(blob.encode()).hexdigest()[:12]
 
 
+def execution_facts(probe_base: dict | None = None) -> dict:
+    """Where and how this process ran its load: the device as JAX reports
+    it, the annotate kernel that was selected, the transport verdicts, the
+    native tokenizer's state and the device membership probes since
+    ``probe_base`` — the run record's ``execution`` block, so a reader can
+    tell a chip run from a CPU run, see which device paths a load
+    reached, and take compile seconds apart from the load itself.
+    Reports only: nothing here selects, probes or builds."""
+    from annotatedvdb_tpu import native
+    from annotatedvdb_tpu.models.pipeline import selected_kernel
+    from annotatedvdb_tpu.ops.pack import transport_state
+    from annotatedvdb_tpu.store.variant_store import device_lookup_state
+    from annotatedvdb_tpu.utils.runtime import compile_summary, device_summary
+
+    return {
+        "device": device_summary(),
+        "compile": compile_summary(),
+        "kernel": selected_kernel(resolve=False),
+        "pack_transport": transport_state(),
+        "native_ingest": native.status(),
+        "device_lookup": device_lookup_state(probe_base),
+    }
+
+
 def run_record(script: str, input_path: str | None, params: dict,
                counters: dict, wall_seconds: float,
                stages: dict | None = None,
                queue_stalls: dict | None = None,
-               error: BaseException | None = None) -> dict:
+               error: BaseException | None = None,
+               execution: dict | None = None) -> dict:
     """Build one run-ledger record (the ``type: "run"`` JSONL payload)."""
     rec = {
         "script": script,
@@ -73,6 +98,8 @@ def run_record(script: str, input_path: str | None, params: dict,
         rec["stages"] = stages
     if queue_stalls:
         rec["queue_stalls"] = queue_stalls
+    if execution:
+        rec["execution"] = execution
     if error is not None:
         rec["error_class"] = type(error).__name__
         rec["error"] = str(error)[:500]
@@ -181,6 +208,9 @@ class ObsSession:
 
         self._faults_base = _faults.fired()
         self._retry_base = dict(_retry.stats)
+        from annotatedvdb_tpu.store.variant_store import probe_stats
+
+        self._probe_base = dict(probe_stats)
 
     @classmethod
     def from_args(cls, script: str, args, params: dict) -> "ObsSession":
@@ -274,6 +304,10 @@ class ObsSession:
                 ledger.run(run_record(
                     self.script, self.input_path, self.params, counters,
                     wall, stages=stages, queue_stalls=stalls, error=error,
+                    # an aborted load may have died OF the backend: its
+                    # record must still land, without the block
+                    execution=(execution_facts(self._probe_base)
+                               if error is None else None),
                 ))
         except Exception as err:
             print(f"obs: run-ledger append failed ({err})", file=sys.stderr)

@@ -260,8 +260,9 @@ def vep_identity_np(ref, alt, ref_len, alt_len):
     The path's third input, the allele hash, comes from
     ``ops.hashing.allele_hash_np``.
 
-    On slow remote-attached links the device round trip costs more than
-    this numpy scan; see ``loaders/vep_loader.py``."""
+    Where the measured upload rate is below the store's
+    ``DEVICE_MIN_BANDWIDTH`` the update loader runs this instead of the
+    device round trip; see ``loaders/vep_loader.py``."""
     import numpy as _np
 
     ref = _np.asarray(ref, _np.uint8)

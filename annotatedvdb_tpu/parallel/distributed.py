@@ -29,17 +29,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.6: top-level export, varying-manual-axes API (check_vma)
-    from jax import shard_map
-except ImportError:  # jax 0.4/0.5: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _experimental_shard_map(f, **kw)
 
 from annotatedvdb_tpu.models.pipeline import annotate_pipeline
 from annotatedvdb_tpu.parallel.mesh import SHARD_AXIS

@@ -64,6 +64,13 @@ from annotatedvdb_tpu.obs import reqtrace
 HB_SLOT = struct.Struct("<ddii")
 
 
+#: a worker's exit code when JAX could give it no device at start-up
+#: (``cli/serve._run_single``).  An accelerator belongs to one process at a
+#: time, so a second worker on the same chip can never start: the
+#: supervisor refuses the fleet on this code instead of respawning into it
+NO_DEVICE_RC = 3
+
+
 def wedge_timeout_from_env() -> float:
     """``AVDB_SERVE_WEDGE_TIMEOUT_S`` (default 10; 0 disables the
     watchdog) — how stale a worker's heartbeat may grow before the
@@ -249,6 +256,9 @@ class ServeFleet:
     #: failure, not an indefinite respawn loop
     MAX_RAPID_DEATHS = 5
 
+    #: how long the drain waits for a SIGKILLed straggler to be reaped
+    KILL_WAIT_S = 30.0
+
     # -- worker lifecycle ---------------------------------------------------
 
     def _worker_cmd(self, index: int) -> list[str]:
@@ -383,6 +393,18 @@ class ServeFleet:
                     self._wedged.discard(i)
                     self._harvest_flight(i, reason)
                     self._harvest_history(i, reason)
+                    if rc == NO_DEVICE_RC:
+                        self.log(
+                            f"worker {i}: JAX found no usable device — an "
+                            "accelerator belongs to one process at a time, "
+                            f"so {self.workers} workers cannot share it; "
+                            "refusing to start (run --workers 1 per "
+                            "accelerator host, or pin the CPU with "
+                            "AVDB_JAX_PLATFORM=cpu)"
+                        )
+                        failed = True
+                        self._stopping = True
+                        break
                     lived = time.monotonic() - self._spawn_time.get(i, 0.0)
                     if lived >= self.HEALTHY_RUN_S:
                         self._respawns[i] = 0  # streak broken: healthy run
@@ -560,7 +582,17 @@ class ServeFleet:
                 self.log(f"worker {i}: did not drain; killing")
                 with contextlib.suppress(OSError):
                     proc.kill()
-                proc.wait(timeout=5)
+                try:
+                    proc.wait(timeout=self.KILL_WAIT_S)
+                except subprocess.TimeoutExpired:
+                    # SIGKILL cannot be refused, only delayed: a process
+                    # inside the accelerator driver's initialization sits
+                    # in uninterruptible sleep until that returns (seen
+                    # on a v5e: > 5 s).  The supervisor's exit must not
+                    # hang on it, and must not die of it either
+                    self.log(f"worker {i}: still exiting "
+                             f"{self.KILL_WAIT_S:.0f}s after SIGKILL "
+                             "(uninterruptible); not waiting for it")
                 clean = False
         self.log("fleet: stopped")
         return 0 if clean else 1

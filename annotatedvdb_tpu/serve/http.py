@@ -128,12 +128,18 @@ def readyz_payload(ctx) -> tuple[int, str]:
 
 def stats_payload(ctx) -> str:
     """The ``/stats`` body — shared like :func:`healthz_payload`."""
+    from annotatedvdb_tpu.store.variant_store import device_lookup_state
+    from annotatedvdb_tpu.utils.runtime import compile_summary
+
     snap = ctx.manager.current()
     stats = {
         "generation": snap.generation,
         "rows": snap.store.n,
         "snapshot_swaps": ctx.manager.swaps,
         "batcher": ctx.batcher.drain_stats(),
+        "device": ctx.device,
+        "compile": compile_summary(),
+        "device_lookup": device_lookup_state(),
     }
     if ctx.engine.residency is not None:
         stats["residency"] = ctx.engine.residency.stats()
@@ -695,6 +701,11 @@ class ServeContext:
         self.health_tick_inline = True
         self.worker_index = int(worker_index)
         self.started_t = time.time()
+        #: the device this process serves from, as JAX reports it
+        #: (``/stats``); resolved once
+        from annotatedvdb_tpu.utils.runtime import device_summary
+
+        self.device = device_summary()
         self.debug_trace_enabled = chaos_enabled_from_env()
         #: flight-recorder flush cadence: request summaries buffer (the
         #: hot path never touches the mmap) and drain every FLUSH_S.  On

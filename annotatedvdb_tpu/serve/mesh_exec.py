@@ -99,12 +99,9 @@ def serve_mesh_on():
     if mesh is None:
         return None
     if mode == "auto":
-        try:
-            import jax
+        import jax
 
-            if jax.default_backend() in ("cpu",):
-                return None
-        except Exception:
+        if jax.default_backend() == "cpu":
             return None
     return mesh
 
@@ -196,10 +193,13 @@ class MeshExecutor:
         self._bulk: _BulkState | None = None
         #: guarded by self._lock
         self._spans: _SpanState | None = None
-        #: guarded by self._lock — monotonic stamp of the last started
-        #: build per state kind, the rebuild rate limiter's input (per
-        #: kind: a fresh generation builds BOTH states back to back)
-        self._last_build = {"bulk": 0.0, "spans": 0.0}
+        #: monotonic stamp of the last started build per state kind, the
+        #: rebuild rate limiter's input (per kind: a fresh generation
+        #: builds BOTH states back to back).  -inf, not 0.0: monotonic
+        #: time starts near zero on a freshly booted machine, and the
+        #: FIRST build must never be declined.
+        #: guarded by self._lock
+        self._last_build = {"bulk": float("-inf"), "spans": float("-inf")}
         if registry is not None:
             self._m_devices = registry.gauge(
                 "avdb_mesh_devices",
@@ -440,7 +440,9 @@ class MeshExecutor:
             self._spans = None
             # the breaker's cooldown is the retry gate after a failure —
             # the rebuild rate limiter must not ALSO delay the recovery
-            self._last_build = {"bulk": 0.0, "spans": 0.0}
+            self._last_build = {
+                "bulk": float("-inf"), "spans": float("-inf"),
+            }
         self._note_resident()
 
     # -- dispatch policy ----------------------------------------------------
@@ -604,6 +606,19 @@ class MeshExecutor:
             bulk = self._bulk
             spans = self._spans
         placed = groups_per_device(self.placement, self.placement.keys())
+        # what each device really holds, from the committed arrays' own
+        # shards — state that all landed on the first device would show
+        # here, whatever the host-side byte count says
+        per_device = {str(d.id): 0 for d in self.mesh.devices.flat}
+        arrays = []
+        if bulk is not None and bulk.store is not None:
+            arrays += [getattr(bulk.store, f) for f in bulk.store._fields
+                       if f != "n_rows"]
+        if spans is not None and spans.pos_stack is not None:
+            arrays.append(spans.pos_stack)
+        for arr in arrays:
+            for shard in arr.addressable_shards:
+                per_device[str(shard.device.id)] += int(shard.data.nbytes)
         return {
             "devices": self.n_devices,
             "bulk_min": self.bulk_min,
@@ -612,6 +627,7 @@ class MeshExecutor:
                 (bulk.nbytes if bulk is not None else 0)
                 + (spans.nbytes if spans is not None else 0)
             ),
+            "per_device_bytes": per_device,
             "generation": bulk.generation if bulk is not None else None,
             "groups_per_device": {
                 str(dev): len(codes) for dev, codes in placed.items()

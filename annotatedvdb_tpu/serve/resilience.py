@@ -358,8 +358,8 @@ _tls = threading.local()
 
 def _probe_failure_hook(exc: BaseException) -> bool:
     """The one store-side hook: route a device-probe failure to the
-    breaker observing on this thread (True = owned, suppress the store's
-    process-wide latch); outside any window keep legacy behavior."""
+    breaker observing on this thread (True = owned: the probe answers
+    from the numpy path); outside any window the store raises it."""
     owner = getattr(_tls, "owner", None)
     if owner is None:
         return False
@@ -375,12 +375,13 @@ class DeviceBreaker:
     States per group: ``closed`` (device allowed), ``open`` (host path
     only, until ``reopen_at``), ``half_open`` (exactly one trial probe in
     flight — success closes, failure re-opens with doubled cooldown).
-    The store's probe ALREADY falls back to numpy on any device error;
-    what the breaker adds is policy: stop paying the failing-device
-    attempt per probe (open), and recover automatically when the device
-    heals (half-open) instead of latching host-only for the process
-    lifetime (the pre-breaker ``_DEVICE_LOOKUP_OK`` behavior, which the
-    installed hook suppresses).
+    Inside an observing window the store's probe answers a device error
+    from the byte-identical numpy path and reports it here; what the
+    breaker adds is policy: stop paying the failing-device attempt per
+    probe (open), and recover automatically when the device heals
+    (half-open).  Every trip is counted
+    (``avdb_serve_breaker_trips_total``) — the fallback is a guarantee of
+    the server, never a silent one.
     """
 
     FAILURE_THRESHOLD = 3
@@ -427,9 +428,9 @@ class DeviceBreaker:
     def install(self) -> None:
         """Register the module-level dispatcher as the store's
         device-probe failure observer: a REAL device error inside
-        ``Segment.probe`` (which falls back to numpy internally) reports
-        to the breaker observing on that thread instead of latching
-        device lookups off process-wide.  Idempotent across breakers."""
+        ``Segment.probe`` reports to the breaker observing on that thread
+        (and the probe answers from numpy) instead of propagating.
+        Idempotent across breakers."""
         from annotatedvdb_tpu.store import variant_store
 
         variant_store.set_device_probe_failure_hook(_probe_failure_hook)

@@ -109,8 +109,8 @@ DEVICE_SEGMENT_MIN = 1 << 18
 DEVICE_QUERY_MIN = 1 << 12
 
 # The device probe must first UPLOAD the segment's identity columns
-# (~110B/row); on remote-attached accelerators that transfer dwarfs a numpy
-# searchsorted unless it amortizes.  Ski-rental rule: each segment counts
+# (~110B/row); that transfer has to amortize before the kernel beats a
+# numpy searchsorted.  Ski-rental rule: each segment counts
 # the query volume its numpy probes have served, and uploads once
 # cumulative volume reaches 1/AMORTIZE of the segment size — by then the
 # forgone device work would have paid for the transfer, so total cost is
@@ -222,49 +222,66 @@ def _device_lookup_mode() -> str:
 
 
 # Minimum measured host->device bandwidth for 'auto' device lookups: every
-# probe call must also UPLOAD its query identity columns (~110B/row), so on
-# slow links (remote-attached/tunneled devices, ~tens of MB/s) the query
-# transfer alone dwarfs a numpy searchsorted no matter how the segment
-# cache amortizes.  Locally-attached accelerators (~10GB/s PCIe/ICI) clear
-# this easily.
+# probe call must also UPLOAD its query identity columns (~110B/row), so
+# below this rate the query transfer alone costs more than a numpy
+# searchsorted no matter how the segment cache amortizes.
 DEVICE_MIN_BANDWIDTH = 1e9  # bytes/sec
 _TRANSFER_FAST: bool | None = None
 
 
 def _transfer_fast() -> bool:
-    """One-time 1MB upload timing; latched per process."""
+    """One-time 1MB upload timing; latched per process.  A device error
+    propagates to the first caller — it is never read as a slow link."""
     global _TRANSFER_FAST
     if _TRANSFER_FAST is None:
-        try:
-            import time
+        import time
 
-            import jax
+        import jax
 
-            buf = np.zeros(1 << 20, np.uint8)
-            dev = jax.device_put(buf)          # warm the path once
-            dev.block_until_ready()
-            t0 = time.perf_counter()
-            dev = jax.device_put(buf)
-            dev.block_until_ready()
-            dt = max(time.perf_counter() - t0, 1e-9)
-            _TRANSFER_FAST = (len(buf) / dt) >= DEVICE_MIN_BANDWIDTH
-        except Exception:
-            _TRANSFER_FAST = False
+        buf = np.zeros(1 << 20, np.uint8)
+        dev = jax.device_put(buf)          # warm the path once
+        dev.block_until_ready()
+        t0 = time.perf_counter()
+        dev = jax.device_put(buf)
+        dev.block_until_ready()
+        dt = max(time.perf_counter() - t0, 1e-9)
+        _TRANSFER_FAST = (len(buf) / dt) >= DEVICE_MIN_BANDWIDTH
     return _TRANSFER_FAST
 
-# Latch: None = not yet probed; flips False on a CPU-only backend (numpy
-# searchsorted beats per-shape XLA compiles there) or on the first
-# device-lookup failure, so a missing/broken backend costs one attempt per
-# process, not one per membership check.
+# Latch: None = not yet asked; False on a CPU backend (numpy searchsorted
+# beats per-shape XLA compiles there).  Decided by the backend's NAME only:
+# a backend that fails to initialize raises at first use, and a failing
+# device probe never flips it (see Segment.probe).
 _DEVICE_LOOKUP_OK = None
 
 # Serve-side device-probe failure observer (serve/resilience.DeviceBreaker):
-# a device error inside Segment.probe falls back to numpy EITHER way; the
-# hook decides the recovery policy.  Returning True means the observer owns
-# it (per-group breaker state, half-open re-probes) and the process-wide
-# latch above stays untouched; None/False keeps the legacy latch — one
-# failure turns device lookups off for the process lifetime.
+# the server guarantees an answer, so with an observer installed a device
+# error inside Segment.probe is handed to it (per-group breaker state,
+# half-open re-probes, counted in avdb_serve_breaker_trips_total) and the
+# probe answers from the byte-identical numpy path.  Without an observer
+# that owns the failure — every loader — the error propagates.
 _DEVICE_PROBE_FAILURE_HOOK = None
+
+#: cumulative device membership probes of this process (the
+#: ``utils.retry.stats`` pattern): load summaries report their session's
+#: delta, so a reader can tell whether a load reached the device lookup
+#: path at all
+probe_stats = {"device_probes": 0, "device_queries": 0}
+
+
+def device_lookup_state(base: dict | None = None) -> dict:
+    """Where the device-lookup policy stands in this process — reported by
+    load summaries and the server's ``/stats``; asks nothing of the
+    backend.  ``enabled``/``transfer_fast`` are the two latches (None =
+    never asked); the probe counts are taken relative to ``base`` (an
+    earlier copy of :data:`probe_stats`)."""
+    base = base or {}
+    return {
+        "mode": _device_lookup_mode(),
+        "enabled": _DEVICE_LOOKUP_OK,
+        "transfer_fast": _TRANSFER_FAST,
+        **{k: v - base.get(k, 0) for k, v in probe_stats.items()},
+    }
 
 
 def set_device_probe_failure_hook(hook) -> None:
@@ -278,12 +295,9 @@ def _device_lookup_enabled() -> bool:
     if _device_lookup_mode() == "off":
         return False
     if _DEVICE_LOOKUP_OK is None:
-        try:
-            import jax
+        import jax
 
-            _DEVICE_LOOKUP_OK = jax.default_backend() not in ("cpu",)
-        except Exception:
-            _DEVICE_LOOKUP_OK = False
+        _DEVICE_LOOKUP_OK = jax.default_backend() != "cpu"
     return _DEVICE_LOOKUP_OK
 
 
@@ -596,7 +610,6 @@ class Segment:
         ``host_only=True`` skips the device branch outright — the serving
         circuit breaker's open-state path (byte-identical answers, no
         failing-device attempt paid per probe)."""
-        global _DEVICE_LOOKUP_OK
         if self.n == 0:
             return np.zeros(pos.shape, np.bool_), np.full(pos.shape, -1, np.int32)
         nq = pos.shape[0]
@@ -626,17 +639,20 @@ class Segment:
                                   and (self._numpy_query_volume + nq)
                                   * DEVICE_UPLOAD_AMORTIZE >= self.n))))):
             try:
-                return self._probe_device(pos, h, ref, alt, ref_len,
-                                          alt_len, dev=dev)
+                out = self._probe_device(pos, h, ref, alt, ref_len,
+                                         alt_len, dev=dev)
             except Exception as exc:
-                # device unusable (no backend / OOM): numpy is always
-                # correct.  An installed failure observer (the serving
-                # circuit breaker) owns the recovery policy — per-group
-                # trip + half-open re-probe; otherwise latch so the hot
-                # path doesn't retry per lookup
+                # only an installed failure observer (the serving circuit
+                # breaker: per-group trip + half-open re-probe, counted)
+                # may turn a device error into a numpy answer; a loader
+                # sees the error
                 hook = _DEVICE_PROBE_FAILURE_HOOK
                 if hook is None or not hook(exc):
-                    _DEVICE_LOOKUP_OK = False
+                    raise
+            else:
+                probe_stats["device_probes"] += 1
+                probe_stats["device_queries"] += nq
+                return out
         self._numpy_query_volume += nq
         lo = np.searchsorted(self.key, qkey, side="left")
         found = np.zeros(nq, np.bool_)
@@ -935,8 +951,7 @@ class ChromosomeShard:
         one-time identity-column upload amortizes across many query
         batches; inserts invalidate the cache (merges replace segments), so
         the insert path never calls this.  Returns the number of segments
-        pinned; a failed/unavailable backend pins none (lookups keep the
-        numpy path)."""
+        pinned; a CPU backend pins none (lookups keep the numpy path)."""
         if not _device_lookup_enabled():
             return 0
         pinned = 0
@@ -1233,11 +1248,11 @@ class VariantStore:
 
     def pin_for_updates(self) -> int:
         """Upload every shard's membership cache to HBM when that pays:
-        update loads (VEP/CADD/QC) probe a STATIC store many times, so on
-        fast locally-attached links the one-time identity-column upload
-        amortizes across the whole file.  No-op on slow links (probing a
-        remote tunnel costs more in query transfers than numpy saves) and
-        on CPU backends.  Returns segments pinned."""
+        update loads (VEP/CADD/QC) probe a STATIC store many times, so the
+        one-time identity-column upload amortizes across the whole file.
+        No-op where the measured upload rate is below
+        ``DEVICE_MIN_BANDWIDTH`` (query transfers would cost more than
+        numpy saves) and on CPU backends.  Returns segments pinned."""
         if not (_device_lookup_enabled() and _transfer_fast()):
             return 0
         return sum(s.pin_device_lookup() for s in self.shards.values())

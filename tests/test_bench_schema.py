@@ -653,7 +653,7 @@ def test_multichip_block_inside_full_record_validates():
 
 
 def test_multichip_dryrun_wrappers_validate(tmp_path):
-    # the historic MULTICHIP_r01–r05 shape stays loadable
+    # the historic MULTICHIP_r02–r05 shape stays loadable
     wrapper = {"n_devices": 8, "rc": 0, "ok": True, "skipped": False,
                "tail": "dryrun_multichip(8): ok\n"}
     p = tmp_path / "MULTICHIP_r99.json"
@@ -666,7 +666,7 @@ def test_multichip_dryrun_wrappers_validate(tmp_path):
 
 def test_checker_cli_covers_committed_multichip_records():
     paths = sorted(glob.glob(os.path.join(ROOT, "MULTICHIP_*.json")))
-    assert len(paths) >= 5  # r01–r05 are committed history
+    assert len(paths) >= 4  # r02–r05 are committed history
     for path in paths:
         assert validate_file(path) == [], path
 

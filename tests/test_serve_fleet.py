@@ -7,7 +7,9 @@ point ``serve.worker``)."""
 from __future__ import annotations
 
 import json
+import os
 import re
+import shutil
 import signal
 import subprocess
 import sys
@@ -117,6 +119,83 @@ def test_fleet_gives_up_on_instant_death_workers(fleet_store, monkeypatch):
     rc = fleet.run()
     assert rc == 1
     assert any("giving up" in ln for ln in lines), lines
+
+
+def test_worker_without_a_device_exits_with_one_line(tmp_path, capsys,
+                                                     monkeypatch):
+    """A serving process that JAX can give no device (on a chip: a second
+    worker, the first holds it) says so in one line and exits with the
+    code the supervisor refuses on — no traceback, no lazy failure at the
+    first request."""
+    from annotatedvdb_tpu.cli import serve
+    from annotatedvdb_tpu.serve.fleet import NO_DEVICE_RC
+    from annotatedvdb_tpu.utils import runtime
+
+    def held(*a, **kw):
+        raise RuntimeError("Unable to initialize backend 'tpu': ABORTED: "
+                           "libtpu lockfile\nsecond line")
+
+    monkeypatch.setattr(runtime, "device_summary", held)
+    rc = serve.main(["--storeDir", str(tmp_path / "unused")])
+    err = capsys.readouterr().err
+    assert rc == NO_DEVICE_RC
+    assert err.count("\n") == 1
+    assert "cannot start: JAX found no usable device" in err
+    assert "second line" not in err
+
+
+def test_fleet_refuses_when_a_worker_finds_no_device(fleet_store):
+    """What `serve --workers 2` met on one chip was five respawns of the
+    second worker and then exit 1; now the first such death ends the
+    fleet at once, with the reason."""
+    from annotatedvdb_tpu.serve.fleet import NO_DEVICE_RC, ServeFleet
+
+    store_dir, _truth = fleet_store
+    lines: list[str] = []
+    fleet = ServeFleet(store_dir, workers=2, restart_backoff_s=0.01,
+                       drain_s=2.0, log=lines.append)
+    fleet._worker_cmd = lambda index: [
+        sys.executable, "-c", f"raise SystemExit({NO_DEVICE_RC})"
+    ]
+    t0 = time.monotonic()
+    assert fleet.run() == 1
+    assert time.monotonic() - t0 < 20
+    assert any("refusing to start" in ln for ln in lines), lines
+    assert not any("restart #" in ln for ln in lines), lines
+
+
+def test_drain_survives_a_worker_that_outlives_sigkill(fleet_store):
+    """Met on a v5e: a worker SIGKILLed inside the accelerator driver's
+    initialization was not reaped within the wait, and the supervisor died
+    of TimeoutExpired mid-drain.  The drain reports it and returns."""
+    from annotatedvdb_tpu.serve.fleet import ServeFleet
+
+    class Unreapable:
+        def poll(self):
+            return None
+
+        def send_signal(self, _sig):
+            pass
+
+        def kill(self):
+            pass
+
+        def wait(self, timeout=None):
+            raise subprocess.TimeoutExpired("worker", timeout)
+
+    store_dir, _truth = fleet_store
+    lines: list[str] = []
+    fleet = ServeFleet(store_dir, workers=1, drain_s=0.05, log=lines.append)
+    fleet.KILL_WAIT_S = 0.05
+    fleet._procs[0] = Unreapable()
+    try:
+        assert fleet._drain() == 1
+    finally:  # what run()'s own finally would release
+        fleet._reserve.close()
+        fleet._hb_mm.close()
+        os.unlink(fleet._hb_path)
+        shutil.rmtree(fleet._telemetry_dir, ignore_errors=True)
+    assert any("not waiting for it" in ln for ln in lines), lines
 
 
 def test_fleet_splits_hbm_budget_across_workers(monkeypatch):

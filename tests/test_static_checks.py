@@ -19,7 +19,7 @@ import sys
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SCAN = ["annotatedvdb_tpu", "tools", "tests", "bench.py"]
+SCAN = ["annotatedvdb_tpu", "tools", "tests", "bench.py", "chip_smoke.py"]
 
 
 def test_tree_is_clean_and_fast():

@@ -11,8 +11,8 @@ suppression policy (``# avdb: noqa[CODE] -- reason``).
 Usage:
     python tools/avdb_check.py [--json] [--diff REV] [paths...]
 
-Default paths: ``annotatedvdb_tpu tools tests bench.py`` relative to the
-repo root.  ``--diff REV`` analyzes only the ``.py`` files changed since
+Default paths: ``annotatedvdb_tpu tools tests bench.py chip_smoke.py``
+relative to the repo root.  ``--diff REV`` analyzes only the ``.py`` files changed since
 ``REV`` (tracked changes + untracked files, fixture data excluded) — the
 fast pre-commit mode; project-audit codes that need the full tree gate
 themselves off automatically, and the tier-1 gate stays the full-tree
@@ -29,7 +29,8 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-DEFAULT_PATHS = ("annotatedvdb_tpu", "tools", "tests", "bench.py")
+DEFAULT_PATHS = ("annotatedvdb_tpu", "tools", "tests", "bench.py",
+                 "chip_smoke.py")
 
 
 def _changed_files(root: str, rev: str) -> list:

@@ -36,8 +36,7 @@ import urllib.error
 import urllib.request
 
 # pin a 4-virtual-device CPU platform before anything imports jax (the
-# smoke must never hang on an accelerator probe, and the mesh needs its
-# devices before backend init)
+# mesh needs its devices before backend init)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("AVDB_JAX_PLATFORM", "cpu")
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -47,24 +46,18 @@ if "xla_force_host_platform_device_count" not in _flags:
     ).strip()
 os.environ["AVDB_MESH_SHAPE"] = "4"
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
 # persistent XLA compilation cache, shared by this process AND the fleet
 # workers (they inherit the environment): the sharded serve programs cost
 # ~10s of compile each, and without the cache BOTH workers pay it on
 # their first request — with it, the warmup request below compiles once
 # and every later first-touch (second worker, smoke re-runs) loads from
 # disk.  Content-keyed, so a stale entry can never serve wrong code.
-import tempfile as _tempfile
+from annotatedvdb_tpu.utils.runtime import ensure_compile_cache  # noqa: E402
 
-_uid = getattr(os, "getuid", lambda: "u")()
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(_tempfile.gettempdir(), f"avdb_mesh_smoke_xla.{_uid}"),
-)
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+ensure_compile_cache()
 
 
 def log(msg: str) -> None:

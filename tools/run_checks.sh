@@ -72,6 +72,7 @@ rc=0
 echo "== avdb_check ==" >&2
 python "$root/tools/avdb_check.py" \
     "$root/annotatedvdb_tpu" "$root/tools" "$root/tests" "$root/bench.py" \
+    "$root/chip_smoke.py" \
     || rc=1
 
 echo "== ruff ==" >&2
