@@ -29,7 +29,7 @@ from annotatedvdb_tpu.config import (
 )
 from annotatedvdb_tpu.io.vcf import read_chromosome_map
 from annotatedvdb_tpu.loaders import TpuVcfLoader
-from annotatedvdb_tpu.utils.profiling import device_trace
+from annotatedvdb_tpu.utils.profiling import device_trace, startup_phase
 
 
 def main(argv=None):
@@ -52,8 +52,10 @@ def main(argv=None):
                              "(--no-skipExisting disables, the reference's "
                              "unchecked fast path)")
     parser.add_argument("--profile", default=None, metavar="DIR",
-                        help="capture a jax.profiler (XLA) trace of the load "
-                             "into DIR (view in TensorBoard/Perfetto)")
+                        help="capture a jax.profiler trace of the load into "
+                             "DIR: the load's stages and waits (avdb.*) on "
+                             "host lines and the device's operations, one "
+                             ".xplane.pb (view in TensorBoard/Perfetto)")
     from annotatedvdb_tpu.obs import add_obs_args
 
     add_obs_args(parser)
@@ -124,7 +126,8 @@ def main(argv=None):
         # compile the device kernels (and probe the packed-output
         # transport) before streaming begins: a steady-state load should
         # not pay the first-compile cost mid-stream
-        loader.warmup()
+        with startup_phase("programs"):
+            loader.warmup()
         with device_trace(args.profile):
             counters = loader.load_file(
                 args.fileName,

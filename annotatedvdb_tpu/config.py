@@ -314,11 +314,14 @@ class RuntimeConfig:
             return None
         import jax
 
+        from annotatedvdb_tpu.utils.profiling import startup_phase
+
         # the loader's annotate fan-out uses THIS PROCESS's devices: under
         # multi-host each process loads its own inputs share-nothing (the
         # reference's worker model) and numpy batches stay addressable; the
         # global mesh is the device-resident/dryrun path, not the load path
-        devices = jax.local_devices()
+        with startup_phase("device"):  # the backend starts here
+            devices = jax.local_devices()
         # resolution goes through the ONE mesh authority: AVDB_MESH_SHAPE
         # bounds the fan-out (and a typo'd shape fails here, loudly),
         # --maxWorkers clamps it further, single device returns None

@@ -119,7 +119,7 @@ class ChunkPrefetcher:
             )
         self._stage = BoundedStage(
             self._schedule(iter(source), timer, stage),
-            depth=self.depth_limit, name=name,
+            depth=self.depth_limit, name=name, boundary=stage,
         )
 
     def _schedule(self, it, timer, stage_name):

@@ -462,6 +462,7 @@ class TpuVcfLoader:
             ),
             depth=depth,
             name="vcf-dispatch",
+            boundary="dispatch",
         )
         tracer = self.timer.tracer
         entries = Resequencer(dispatch)
@@ -1001,13 +1002,7 @@ class TpuVcfLoader:
         rec["items"] += 1
         rec["max_depth"] = max(rec["max_depth"], len(self._inflight))
         if len(self._inflight) > self.MAX_INFLIGHT_COMMITS:
-            t0 = time.perf_counter()
-            while len(self._inflight) > self.MAX_INFLIGHT_COMMITS:
-                self._inflight[0][0].result()
-                self._inflight.popleft()
-            rec["producer_block_s"] = round(
-                rec["producer_block_s"] + (time.perf_counter() - t0), 4
-            )
+            self._wait_writer(self.MAX_INFLIGHT_COMMITS)
 
     def _prune_inflight(self) -> None:
         """Drop completed commits (surfacing writer exceptions promptly)."""
@@ -1015,10 +1010,29 @@ class TpuVcfLoader:
             fut, _ = self._inflight.popleft()
             fut.result()
 
+    def _wait_writer(self, keep: int) -> None:
+        """Block on the oldest queued commits until at most ``keep`` are
+        in flight: the caller's thread waiting on the store writer.  The
+        seconds go to the ``store-writer`` stall record and the episode is
+        an ``avdb.wait.store-writer`` annotation on this thread, so a
+        capture reads the gap as this wait, not as the writer's persist."""
+        from annotatedvdb_tpu.utils.profiling import annotation
+
+        rec = self._stall_rec("store-writer")
+        with annotation("avdb.wait.store-writer", side="producer"):
+            t0 = time.perf_counter()
+            try:
+                while len(self._inflight) > keep:
+                    fut, _ = self._inflight.popleft()
+                    fut.result()
+            finally:
+                rec["producer_block_s"] = round(
+                    rec["producer_block_s"] + (time.perf_counter() - t0), 4
+                )
+
     def _drain_inflight(self) -> None:
-        while self._inflight:
-            fut, _ = self._inflight.popleft()
-            fut.result()
+        if self._inflight:
+            self._wait_writer(0)
 
     def _prefetch(self):
         """Single-worker transfer thread (lazy: configurations that never

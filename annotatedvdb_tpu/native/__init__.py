@@ -92,8 +92,11 @@ def load():
     with _lock:
         if _lib is not None or _lib_error is not None:
             return _lib
+        from annotatedvdb_tpu.utils.profiling import startup_phase
+
         try:
-            lib = ctypes.CDLL(_build())
+            with startup_phase("native"):  # build (first run) + dlopen
+                lib = ctypes.CDLL(_build())
         except (OSError, RuntimeError, subprocess.CalledProcessError,
                 FileNotFoundError) as err:
             _lib_error = str(err)

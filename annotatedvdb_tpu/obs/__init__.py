@@ -7,17 +7,27 @@ Five layers, one import surface:
   fixed-bucket histograms with JSON-snapshot and Prometheus-textfile export
   (``--metricsOut``), plus the fleet snapshot merge (``?fleet=1``);
 - :mod:`~annotatedvdb_tpu.obs.trace` — Chrome trace-event host spans, one
-  track per pipeline thread, Perfetto-mergeable with the ``jax.profiler``
-  device trace (``--traceOut``);
-- :mod:`~annotatedvdb_tpu.obs.reqtrace` — request-scoped tracing: the
-  lock-free per-worker span ring, ``avdb_stage_seconds`` stage
-  histograms, the slow-request log, and the background-writer sink;
+  track per pipeline thread (``--traceOut``): the export that needs no
+  profiler, on its own clock;
+- :mod:`~annotatedvdb_tpu.obs.reqtrace` — request-scoped tracing: spans
+  with a start, an end and a parent into the lock-free per-worker span
+  ring, ``avdb_stage_seconds`` stage histograms, the slow-request log,
+  and the background-writer sink;
 - :mod:`~annotatedvdb_tpu.obs.flight` — the mmap'd crash flight recorder
   (last-N request summaries + lifecycle events, SIGKILL-durable,
   supervisor-harvested, ``doctor flight``);
 - :mod:`~annotatedvdb_tpu.obs.session` — the per-CLI lifecycle gluing
   metrics+trace to a load and appending the ``type: "run"`` ledger
   record.
+
+Every span these layers time — a loader stage
+(``utils.profiling.StageTimer``), a queue wait (``utils.pipeline``), a
+request stage (``reqtrace.stage``), a lookup sub-stage (``serve.engine``)
+— is also a ``jax.profiler.TraceAnnotation`` named ``avdb.*`` on the
+thread that does the work, so any ``jax.profiler`` capture (``--profile``)
+holds the program's stages and the device's operations in one file on one
+clock.  There is no second tracer for that: the annotation is entered where
+the span is already opened.
 
 Backpressure gauges live with the queues themselves
 (:class:`annotatedvdb_tpu.utils.pipeline.BoundedStage` ``.stats``) and are

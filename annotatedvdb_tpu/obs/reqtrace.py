@@ -7,9 +7,22 @@ carries a trace id (minted at admission or adopted from the client's
 ``traceparent``/``X-Request-Id`` — see ``serve.http.resolve_trace_id``),
 and the stages it passes through — admission wait, batcher queue wait,
 device execution, render, the WAL fsync of an upsert ack — each record
-one span against that id.
+one span against that id.  A span is what a span is: name, start, end
+(``perf_counter_ns``), the span that caused it (``parent``; None for a
+stage of the request itself) and, through the trace it sits on, the
+request's id.  Nothing records a bare duration.
 
-Three export surfaces, one recording path:
+A stage that a thread runs — ``device``, ``render``, ``wal_fsync``
+(:func:`stage`) and a background writer's unit of work
+(:func:`background_span`) — is also a ``jax.profiler.TraceAnnotation``
+``avdb.serve.<stage>`` on that thread, with the request's ``trace_id`` and
+``kind`` as arguments, so a profiler capture of the serving process holds
+the request stages on the host lines of the same file as the device's
+operations.  ``admission`` and ``queue`` are waits measured across threads
+(arrival -> executor slot, enqueue -> drain), not scopes any one thread
+sits in: they stay recorded spans, with a start and an end.
+
+Four export surfaces, one recording path:
 
 - **the span ring** — a fixed-size per-worker ring of finished-request
   records.  Writes are LOCK-FREE: one shared ``itertools.count`` reserves
@@ -26,12 +39,15 @@ Three export surfaces, one recording path:
   never hides the outlier: the threshold check runs on every finished
   trace that recorded).
 
+- **the profiler's capture** — the annotations above, when one runs.
+
 ``AVDB_TRACE_SAMPLE`` (default 1.0) is the recording probability; 0
 disarms span recording entirely (trace ids still mint and echo — the
 header contract is part of the route surface).  ``chrome_events`` renders
-the ring in the PR-2 tracer's Chrome trace-event format so
+the ring in the ``--traceOut`` tracer's Chrome trace-event format — every
+span at its recorded start, nested under its parent — so
 ``GET /debug/trace`` merges request spans, background spans, and the
-batcher tracer's drain spans into one Perfetto timeline.
+batcher tracer's drain spans into one timeline without a profiler.
 
 Background writers join the same plane through the module-level sink
 (:func:`set_background_sink` / :func:`background_span` /
@@ -50,6 +66,8 @@ import random
 import threading
 import time
 
+from annotatedvdb_tpu.utils.profiling import annotation
+
 #: the fixed stage vocabulary (`avdb_stage_seconds{stage=...}` series):
 #: admission = arrival -> handed to execution (preflight/body read/pool
 #: queue), queue = batcher queue wait, device = engine execution of the
@@ -66,6 +84,29 @@ STAGE_SECONDS_EDGES = (
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
     0.1, 0.25, 0.5, 1.0, 2.5, 10.0,
 )
+
+#: the ``device`` stage of a point/bulk lookup, split where the work
+#: happens (``serve.engine.lookup_many``): id parsing; grouping, allele
+#: encoding, hashing and the residency touch; the membership probe
+#: (upload, program, fetch, breaker); rendering the found rows.  Series of
+#: the same ``avdb_stage_seconds`` histogram, observed by the ENGINE once
+#: per ``lookup_many`` call (a microbatch is one call, however many
+#: requests share it) — sub-spans of ``device``, so never in a request's
+#: ``stages`` (the flight recorder's slot has no room for more names)
+LOOKUP_STAGES = ("lookup.parse", "lookup.hash", "lookup.probe",
+                 "lookup.rows")
+
+
+def stage_histograms(registry, stages) -> dict:
+    """{stage: its ``avdb_stage_seconds`` series} on ``registry``."""
+    return {
+        stage: registry.histogram(
+            "avdb_stage_seconds", STAGE_SECONDS_EDGES,
+            "per-request stage latency from the request tracer",
+            {"stage": stage},
+        )
+        for stage in stages
+    }
 
 
 def slow_ms_from_env() -> float:
@@ -89,66 +130,126 @@ class RequestTrace:
     concurrently); it becomes an immutable ring record at
     :meth:`TraceRecorder.finish`."""
 
-    __slots__ = ("trace_id", "kind", "t0_ns", "stages", "spans")
+    __slots__ = ("trace_id", "kind", "t0_ns", "spans", "_subspans")
 
     #: sub-span cap per request: a 4096-interval panel must not grow an
-    #: unbounded span list (the overflow is visible as a dropped count)
+    #: unbounded span list (stages — parent None — are never dropped)
     MAX_SPANS = 64
 
     def __init__(self, trace_id: str, kind: str):
         self.trace_id = trace_id
         self.kind = kind
         self.t0_ns = time.perf_counter_ns()
-        self.stages: list = []  # (stage_name, seconds)
-        self.spans: list = []   # (name, seconds) sub-spans (engine detail)
+        #: (name, start_ns, end_ns, parent) in recording order; parent
+        #: None = a stage of the request, else the enclosing stage's name
+        self.spans: list = []
+        self._subspans = 0
 
-    def add(self, stage: str, seconds: float) -> None:
-        self.stages.append((stage, seconds))
+    def record(self, name: str, start_ns: int, end_ns: int,
+               parent: str | None = None) -> None:
+        """THE span call: one finished span on the ``perf_counter_ns``
+        clock.  A stage (``parent`` None) feeds the stage histogram, the
+        flight summary and the slow log at :meth:`TraceRecorder.finish`;
+        a sub-span (per-chromosome-group engine work etc.) is ring/
+        trace-dump detail — unbounded name cardinality has no place in a
+        Prometheus export."""
+        if parent is not None:
+            if self._subspans >= self.MAX_SPANS:
+                return
+            self._subspans += 1
+        self.spans.append((name, int(start_ns), int(end_ns), parent))
 
-    @contextlib.contextmanager
-    def stage(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
+    def since(self, name: str, start_s: float) -> None:
+        """A stage that began at ``time.perf_counter()`` reading
+        ``start_s`` and ends now — for waits measured across threads
+        (``admission``: arrival -> handed to execution), whose start was
+        read before any trace existed."""
+        self.record(name, int(start_s * 1e9), time.perf_counter_ns())
 
-    def span(self, name: str, seconds: float) -> None:
-        """One named sub-span (per-chromosome-group engine work etc.) —
-        ring/trace-dump detail, not a histogram series (unbounded name
-        cardinality has no place in a Prometheus export)."""
-        if len(self.spans) < self.MAX_SPANS:
-            self.spans.append((name, seconds))
+    def adopt(self, shared: "RequestTrace") -> None:
+        """Take over the spans of a stage this request shared with others
+        (a microbatch's one engine call serves every co-batched request:
+        the continuous-batching reality)."""
+        for span in shared.spans:
+            self.record(*span)
+
+    @property
+    def stages(self) -> list:
+        """[(stage, seconds)] — the request's own stages in recording
+        order, as the histograms, the flight recorder's summary and the
+        slow-request log read them."""
+        return [(name, (end - start) / 1e9)
+                for name, start, end, parent in self.spans if parent is None]
 
 
-# -- thread-local active trace (engine sub-span attribution) ----------------
+# -- thread-local active stage (engine sub-span attribution) ----------------
 
 _active = threading.local()
 
 
 @contextlib.contextmanager
-def activate(trace: RequestTrace | None):
+def activate(trace: RequestTrace | None, parent: str = "device"):
     """Bind ``trace`` as THIS thread's active trace for the duration —
     the engine runs entirely on the calling thread (request thread,
-    executor worker, or batcher drain), so deep layers attribute spans
-    without threading a trace argument through every signature."""
+    executor worker, or batcher drain), so deep layers attribute
+    sub-spans (:func:`record_active`, parented to ``parent``) without
+    threading a trace argument through every signature."""
     if trace is None:
         yield
         return
-    prev = getattr(_active, "trace", None)
-    _active.trace = trace
+    prev = getattr(_active, "scope", None)
+    _active.scope = (trace, parent)
     try:
         yield
     finally:
-        _active.trace = prev
+        _active.scope = prev
 
 
-def span_active(name: str, seconds: float) -> None:
-    """Attach a sub-span to the calling thread's active trace (no-op
+@contextlib.contextmanager
+def stage(trace: RequestTrace | None, name: str):
+    """One request stage on the thread that runs it: a recorded span
+    (start, end, no parent) on ``trace``, the active scope of the engine's
+    sub-spans, and a profiler annotation ``avdb.serve.<name>`` carrying the
+    request's id.  ``trace`` None (unsampled) is transparent.  The span is
+    recorded however the block ends — a stage that failed still ran."""
+    if trace is None:
+        yield
+        return
+    with annotation(f"avdb.serve.{name}", trace_id=trace.trace_id,
+                    kind=trace.kind), activate(trace, name):
+        start_ns = time.perf_counter_ns()
+        try:
+            yield
+        finally:
+            trace.record(name, start_ns, time.perf_counter_ns())
+
+
+@contextlib.contextmanager
+def shared_stage(traces, name: str):
+    """A stage several requests share — a microbatch's ONE engine call on
+    the drain thread serves every co-batched request.  Runs as one
+    :func:`stage` (one annotation, one set of engine sub-spans); afterwards
+    every sampled trace of ``traces`` adopts the stage span and its
+    sub-spans.  Nothing sampled: transparent."""
+    traces = [t for t in traces if t is not None]
+    if not traces:
+        yield
+        return
+    shared = RequestTrace(f"batch:{len(traces)}", traces[0].kind)
+    try:
+        with stage(shared, name):
+            yield
+    finally:
+        for trace in traces:
+            trace.adopt(shared)
+
+
+def record_active(name: str, start_ns: int, end_ns: int) -> None:
+    """Attach a sub-span to the calling thread's active stage (no-op
     outside any request — the engine never needs to know)."""
-    trace = getattr(_active, "trace", None)
-    if trace is not None:
-        trace.span(name, seconds)
+    scope = getattr(_active, "scope", None)
+    if scope is not None:
+        scope[0].record(name, start_ns, end_ns, parent=scope[1])
 
 
 # -- background writers (store layer joins the plane without importing it) --
@@ -160,8 +261,8 @@ _BACKGROUND: tuple | None = None
 
 
 def set_background_sink(span_sink, event_sink) -> None:
-    """Install the process's background sinks: ``span_sink(name, seconds,
-    meta)`` records one background-track span, ``event_sink(name,
+    """Install the process's background sinks: ``span_sink(name, start_ns,
+    end_ns, meta)`` records one background-track span, ``event_sink(name,
     detail)`` one lifecycle event (flight recorder).  Either may be None;
     pass ``(None, None)`` to clear."""
     global _BACKGROUND
@@ -172,21 +273,24 @@ def set_background_sink(span_sink, event_sink) -> None:
 @contextlib.contextmanager
 def background_span(name: str, **meta):
     """Time one background-writer unit of work (a memtable flush, a
-    compaction group, a daemon pass) onto the ``background`` track.  The
-    sink must never take the writer down: failures are swallowed — losing
-    a span is always better than losing a flush."""
+    compaction group, a daemon pass) onto the ``background`` track, and
+    onto the profiler's clock as ``avdb.serve.background`` (the unit in its ``span``
+    arguments) on the writer's own thread.  The sink must never take the
+    writer down: failures are swallowed — losing a span is always better
+    than losing a flush."""
     sink = _BACKGROUND
-    if sink is None or sink[0] is None:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
+    with annotation("avdb.serve.background", span=name):
+        if sink is None or sink[0] is None:
+            yield
+            return
+        start_ns = time.perf_counter_ns()
         try:
-            sink[0](name, time.perf_counter() - t0, meta or None)
-        except Exception:  # avdb: noqa[AVDB602] -- observability must never take down the background writer it observes
-            pass
+            yield
+        finally:
+            try:
+                sink[0](name, start_ns, time.perf_counter_ns(), meta or None)
+            except Exception:  # avdb: noqa[AVDB602] -- observability must never take down the background writer it observes
+                pass
 
 
 def lifecycle_event(name: str, detail: str) -> None:
@@ -238,12 +342,7 @@ class TraceRecorder:
         self._hist = {}
         self._m_slow = None
         if registry is not None:
-            for stage in STAGES:
-                self._hist[stage] = registry.histogram(
-                    "avdb_stage_seconds", STAGE_SECONDS_EDGES,
-                    "per-request stage latency from the request tracer",
-                    {"stage": stage},
-                )
+            self._hist = stage_histograms(registry, STAGES)
             self._m_slow = registry.counter(
                 "avdb_trace_slow_requests_total",
                 "requests whose total latency exceeded AVDB_TRACE_SLOW_MS",
@@ -266,16 +365,17 @@ class TraceRecorder:
             return
         now_ns = time.perf_counter_ns()
         total = (now_ns - trace.t0_ns) / 1e9
+        stages = tuple(trace.stages)
         record = (
             trace.trace_id, trace.kind, int(status),
             trace.t0_ns, total,
-            tuple(trace.stages), tuple(trace.spans),
+            stages, tuple(trace.spans),
         )
         self._ring[next(self._seq) % self.slots] = record
         hist = self._hist
         if hist:
             hist["total"].observe(total)
-            for stage, seconds in trace.stages:
+            for stage, seconds in stages:
                 h = hist.get(stage)
                 if h is not None:
                     h.observe(seconds)
@@ -284,29 +384,38 @@ class TraceRecorder:
                 self._m_slow.inc()
             breakdown = " ".join(
                 f"{stage}={seconds * 1000:.2f}ms"
-                for stage, seconds in trace.stages
+                for stage, seconds in stages
             )
+            # sub-spans summed by name: which part of a stage was slow
+            subs: dict = {}
+            for name, start_ns, end_ns, parent in trace.spans:
+                if parent is not None:
+                    subs[name] = subs.get(name, 0) + end_ns - start_ns
+            detail = " ".join(f"{name}={ns / 1e6:.2f}ms"
+                              for name, ns in subs.items())
             self.log(
                 f"slow request trace={trace.trace_id} kind={trace.kind} "
                 f"status={status} total={total * 1000:.2f}ms {breakdown}"
-                + (f" spans={len(trace.spans)}" if trace.spans else "")
+                + (f" spans={len(trace.spans) - len(stages)} [{detail}]"
+                   if subs else "")
             )
         if self.flight is not None:
             try:
                 self.flight.request(
-                    trace.trace_id, trace.kind, int(status), total,
-                    trace.stages,
+                    trace.trace_id, trace.kind, int(status), total, stages,
                 )
             except Exception:  # avdb: noqa[AVDB602] -- the flight recorder must never fail the request it records
                 pass
 
-    def background(self, name: str, seconds: float, meta=None) -> None:
+    def background(self, name: str, start_ns: int, end_ns: int,
+                   meta=None) -> None:
         """One background-track span (the module sink's target): same
         ring, kind ``background``, plus the background stage histogram."""
-        t0_ns = time.perf_counter_ns() - int(seconds * 1e9)
-        record = ("-", "background", 0, t0_ns, float(seconds),
-                  (("background", float(seconds)),),
-                  ((name, float(seconds)),))
+        seconds = (end_ns - start_ns) / 1e9
+        record = ("-", "background", 0, int(start_ns), seconds,
+                  (("background", seconds),),
+                  (("background", int(start_ns), int(end_ns), None),
+                   (name, int(start_ns), int(end_ns), "background")))
         self._ring[next(self._seq) % self.slots] = record
         h = self._hist.get("background")
         if h is not None:
@@ -334,11 +443,15 @@ class TraceRecorder:
         )
 
     def chrome_events(self, base_ns: int | None = None) -> list[dict]:
-        """The ring as Chrome trace events in the PR-2 tracer's track
-        format: requests on one named track, background spans on another,
-        stages as nested complete (``X``) events — merge the list with a
+        """The ring as Chrome trace events in the ``--traceOut`` tracer's
+        track format: requests on one named track, background spans on
+        another, every span a complete (``X``) event at its RECORDED start
+        — a span opened 5 ms into a request is drawn 5 ms in — with its
+        parent in ``args`` (viewers nest by containment, which recorded
+        starts give).  Merge the list with a
         :class:`~annotatedvdb_tpu.obs.trace.Tracer`'s events (same
-        ``base_ns`` timebase) and Perfetto shows the whole worker."""
+        ``base_ns`` timebase: both clocks are ``perf_counter_ns``) and the
+        whole worker shows on one timeline."""
         base = self.t0_ns if base_ns is None else int(base_ns)
         pid = os.getpid()
         req_tid, bg_tid = 1, 2
@@ -348,27 +461,24 @@ class TraceRecorder:
             {"ph": "M", "name": "thread_name", "pid": pid, "tid": bg_tid,
              "ts": 0, "args": {"name": "background"}},
         ]
-        for trace_id, kind, status, t0_ns, total, stages, spans \
+        for trace_id, kind, status, t0_ns, total, _stages, spans \
                 in self.records():
             tid = bg_tid if kind == "background" else req_tid
-            ts = (t0_ns - base) / 1000.0
-            args = {"trace_id": trace_id, "status": status}
             events.append({
                 "ph": "X", "name": kind, "cat": "request", "pid": pid,
-                "tid": tid, "ts": ts, "dur": total * 1e6, "args": args,
+                "tid": tid, "ts": (t0_ns - base) / 1000.0,
+                "dur": total * 1e6,
+                "args": {"trace_id": trace_id, "status": status},
             })
-            at = ts
-            for stage, seconds in stages:
+            for name, start_ns, end_ns, parent in spans:
+                args = {"trace_id": trace_id}
+                if parent is not None:
+                    args["parent"] = parent
                 events.append({
-                    "ph": "X", "name": stage, "cat": "stage", "pid": pid,
-                    "tid": tid, "ts": at, "dur": seconds * 1e6,
-                    "args": {"trace_id": trace_id},
-                })
-                at += seconds * 1e6
-            for name, seconds in spans:
-                events.append({
-                    "ph": "X", "name": name, "cat": "span", "pid": pid,
-                    "tid": tid, "ts": ts, "dur": seconds * 1e6,
-                    "args": {"trace_id": trace_id},
+                    "ph": "X", "name": name,
+                    "cat": "stage" if parent is None else "span",
+                    "pid": pid, "tid": tid,
+                    "ts": (start_ns - base) / 1000.0,
+                    "dur": (end_ns - start_ns) / 1000.0, "args": args,
                 })
         return events

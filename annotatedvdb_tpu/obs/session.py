@@ -5,8 +5,8 @@ Every loader CLI builds one :class:`ObsSession` around its load:
 - ``attach(loader)`` hands the loader a chunk-granularity
   :class:`~annotatedvdb_tpu.obs.metrics.LoadObserver` and (when
   ``--traceOut`` was passed) points the loader's ``StageTimer`` at a
-  :class:`~annotatedvdb_tpu.obs.trace.Tracer`, so every stage span lands on
-  the host trace timeline under its pipeline thread's track;
+  :class:`~annotatedvdb_tpu.obs.trace.Tracer`, so every stage span also
+  lands on that no-profiler timeline under its pipeline thread's track;
 - ``finish``/``abort`` export the metrics textfile + JSON snapshot and the
   Chrome trace, then append ONE ``type: "run"`` record to the store's
   ``ledger.jsonl`` — input path, config hash, per-stage seconds, counters,
@@ -39,8 +39,9 @@ def add_obs_args(parser) -> None:
     parser.add_argument(
         "--traceOut", default=None, metavar="FILE",
         help="write a Chrome trace-event JSON of host pipeline spans "
-             "(one track per pipeline thread; open in Perfetto alongside "
-             "--profile's device trace)",
+             "(one track per pipeline thread, on its own clock; needs no "
+             "profiler -- --profile holds the same spans beside the "
+             "device's operations)",
     )
 
 
@@ -63,11 +64,19 @@ def execution_facts(probe_base: dict | None = None) -> dict:
     from annotatedvdb_tpu.models.pipeline import selected_kernel
     from annotatedvdb_tpu.ops.pack import transport_state
     from annotatedvdb_tpu.store.variant_store import device_lookup_state
+    from annotatedvdb_tpu.utils.profiling import STARTUP_SECONDS
     from annotatedvdb_tpu.utils.runtime import compile_summary, device_summary
 
     return {
         "device": device_summary(),
         "compile": compile_summary(),
+        # seconds per start-up phase of this PROCESS so far (cumulative: a
+        # second load in one process adds only what it paid again):
+        # ``device`` the backend's start, ``native`` the tokenizer's
+        # build + dlopen, ``transport_probe`` the two parity probes,
+        # ``programs`` the loader's warm-up — loading or compiling every
+        # program of the load's shape; it CONTAINS the probes
+        "startup": {k: round(v, 4) for k, v in STARTUP_SECONDS.items()},
         "kernel": selected_kernel(resolve=False),
         "pack_transport": transport_state(),
         "native_ingest": native.status(),

@@ -1,12 +1,15 @@
 """Host-side span tracer emitting Chrome trace-event JSON.
 
 The overlapped load executor runs on four threads (ingest / dispatch /
-process / store-writer).  ``jax.profiler`` (``--profile``) shows the DEVICE
-side of that pipeline; this tracer records the HOST side — every
-``StageTimer.stage`` span becomes one B/E event pair on the thread that ran
-it — as the Chrome trace-event format both chrome://tracing and Perfetto
-load natively.  Open the host trace and the XLA trace in the same Perfetto
-session and queue stalls line up against device steps on one timeline.
+process / store-writer).  This tracer is the NO-PROFILER export of their
+stages (``--traceOut``): every ``StageTimer.stage`` span becomes one B/E
+event pair on the thread that ran it, in the Chrome trace-event format both
+chrome://tracing and Perfetto load natively.  Its clock is its own
+(microseconds since the tracer was made), so it does not line up with a
+device trace.  The merged timeline is the profiler's capture
+(``--profile``): the same stages are ``jax.profiler.TraceAnnotation``s
+(``utils/profiling.py``) on the host lines of the ``.xplane.pb`` that holds
+the device's operations.
 
 Format (https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU):
 ``{"traceEvents": [...], "displayTimeUnit": "ms"}`` where each span is a
@@ -95,12 +98,6 @@ class Tracer:
             yield
         finally:
             self.end(name)
-
-    def instant(self, name: str, **args) -> None:
-        ev = {"ph": "i", "name": name, "ts": self._ts_us(), "s": "t"}
-        if args:
-            ev["args"] = args
-        self._emit(ev)
 
     def counter(self, name: str, **series) -> None:
         """One sample of a counter track (e.g. queue depth gauges)."""

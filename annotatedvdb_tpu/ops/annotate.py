@@ -25,6 +25,7 @@ from annotatedvdb_tpu.parallel.mesh import mesh_pjit
 from annotatedvdb_tpu.types import MAX_PK_SEQUENCE_LENGTH, VariantClass
 
 
+@jax.named_scope("avdb.annotate")
 def annotate_kernel(pos, ref, alt, ref_len, alt_len):
     """Annotate one batch.
 

@@ -239,6 +239,7 @@ def annotate_bin_pallas(pos, ref, alt, ref_len, alt_len,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((8, n_pad), jnp.int32),
         interpret=interpret,
+        name="avdb_annotate_bin",
     )(meta, refT, altT, rev)
 
     prefix = outs[_OUT_PREFIX, :n]

@@ -30,6 +30,7 @@ LEAF_SIZE = 15_625
 NUM_BIN_LEVELS = 13  # levels 1..13 below the whole-chromosome level 0
 
 
+@jax.named_scope("avdb.bin_index")
 def bin_index_kernel(start, end):
     """Deepest enclosing bin for [start, end] intervals (1-based, inclusive).
 

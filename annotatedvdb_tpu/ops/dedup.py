@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from annotatedvdb_tpu.parallel.mesh import mesh_pjit
 
 
+@jax.named_scope("avdb.dedup")
 def mark_batch_duplicates(pos, h, ref, alt, ref_len, alt_len):
     """Flag rows that duplicate an earlier row in the batch.
 
@@ -52,6 +53,7 @@ def mark_batch_duplicates(pos, h, ref, alt, ref_len, alt_len):
     return jnp.zeros((n,), jnp.bool_).at[idx_s].set(dup_sorted)
 
 
+@jax.named_scope("avdb.probe")
 def lookup_in_sorted(
     store_pos, store_h, store_ref, store_alt, store_rlen, store_alen,
     pos, h, ref, alt, ref_len, alt_len,
@@ -100,6 +102,7 @@ def lookup_in_sorted(
     return found, index
 
 
+@jax.named_scope("avdb.dedup_multi")
 def mark_batch_duplicates_multi(chrom, pos, h, ref, alt, ref_len, alt_len):
     """Chromosome-aware :func:`mark_batch_duplicates` for mesh shards that
     own SEVERAL chromosomes (``parallel.distributed.chromosome_owner`` packs
@@ -138,6 +141,7 @@ def mix_chrom_hash(h, chrom):
     return h ^ (chrom.astype(jnp.uint32) * jnp.uint32(CHROM_MIX))
 
 
+@jax.named_scope("avdb.probe_multi")
 def lookup_in_sorted_multi(
     store_chrom, store_pos, store_hm, store_ref, store_alt,
     store_rlen, store_alen,

@@ -28,6 +28,7 @@ def _fnv_step(h, byte):
     return (h ^ byte.astype(jnp.uint32)) * FNV_PRIME
 
 
+@jax.named_scope("avdb.hash")
 def allele_hash(ref, alt, ref_len, alt_len):
     """[N] uint32 hash of the allele identity (lengths + padded byte content).
 

@@ -57,6 +57,7 @@ from annotatedvdb_tpu.utils.arrays import POS_SENTINEL, pad_pow2
 MAX_QUERY_POS = int(POS_SENTINEL) - 16
 
 
+@jax.named_scope("avdb.bits_spans")
 def bits_spans_kernel(pos, starts, ends):
     """BITS spans + bin tokens for a batch of query intervals.
 
@@ -85,6 +86,7 @@ def bits_spans_kernel(pos, starts, ends):
 bits_spans_kernel_jit = jax.jit(bits_spans_kernel)
 
 
+@jax.named_scope("avdb.bits_spans_stacked")
 def bits_spans_stacked(pos, starts, ends):
     """BITS spans + bin tokens for a STACK of chromosome groups — the
     mesh-sharded panel kernel.

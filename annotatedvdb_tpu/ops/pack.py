@@ -20,6 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from annotatedvdb_tpu.utils.profiling import startup_phase
+
 #: packed row layout (little-endian byte order on both TPU and x86 hosts)
 _H = slice(0, 4)          # uint32 allele hash
 _LEAF = slice(4, 8)       # int32 leaf bin
@@ -28,6 +30,7 @@ _FLAGS = 9                # bit0 dup, bit1 needs_digest, bit2 host_fallback
 WIDTH = 10
 
 
+@jax.named_scope("avdb.pack_outputs")
 def pack_outputs(h, dup, bin_level, leaf_bin, needs_digest, host_fallback):
     """[n] device outputs -> [n, 10] uint8 (one transferable buffer)."""
     n = h.shape[0]
@@ -121,6 +124,7 @@ def _inflate_one(packed, width: int):
     return jnp.take(jnp.asarray(_DEC), codes, axis=0)[:, :width]
 
 
+@jax.named_scope("avdb.inflate_alleles")
 def inflate_alleles(ref_packed, alt_packed, width: int):
     """Device-side inverse of :func:`encode_alleles_nibble`."""
     return _inflate_one(ref_packed, width), _inflate_one(alt_packed, width)
@@ -197,11 +201,12 @@ def nibble_verified() -> bool:
         enc = encode_alleles_nibble(probe, probe[::-1].copy())
         # a backend that cannot compile/run the tiny kernel raises here,
         # at first use — only a WRONG answer selects raw uploads
-        r, a = inflate_alleles_jit(enc[0], enc[1], 7)
-        _NIBBLE_OK = bool(
-            (np.asarray(r) == probe).all()
-            and (np.asarray(a) == probe[::-1]).all()
-        )
+        with startup_phase("transport_probe"):
+            r, a = inflate_alleles_jit(enc[0], enc[1], 7)
+            _NIBBLE_OK = bool(
+                (np.asarray(r) == probe).all()
+                and (np.asarray(a) == probe[::-1]).all()
+            )
     return _NIBBLE_OK
 
 
@@ -211,6 +216,7 @@ def nibble_verified() -> bool:
 VEP_WIDTH = 6
 
 
+@jax.named_scope("avdb.pack_vep_outputs")
 def pack_vep_outputs(h, prefix_len, host_fallback):
     """[n] update-path device outputs -> [n, 6] uint8 (one fetch)."""
     n = h.shape[0]
@@ -268,9 +274,10 @@ def transport_verified() -> bool:
         t = np.array([True, False, True, False])
         # like nibble_verified: an error raises at first use, only a
         # byte-order mismatch selects per-field fetches
-        cols = unpack_outputs(
-            np.asarray(pack_outputs_jit(h, t, level, leaf, ~t, t))
-        )
+        with startup_phase("transport_probe"):
+            cols = unpack_outputs(
+                np.asarray(pack_outputs_jit(h, t, level, leaf, ~t, t))
+            )
         _TRANSPORT_OK = bool(
             (cols["h"] == h).all()
             and (cols["leaf_bin"] == leaf).all()

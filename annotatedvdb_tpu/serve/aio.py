@@ -307,7 +307,7 @@ class LoopBatcher:
         fut = self._loop.create_future()
         self._pending.append((
             fut, variant_id, parsed, deadline_t, trace,
-            time.perf_counter() if trace is not None else 0.0,
+            time.perf_counter_ns() if trace is not None else 0,
         ))
         depth = len(self._pending)
         if depth > self._max_depth:
@@ -362,7 +362,11 @@ class LoopBatcher:
         batch = live
         if not batch:
             return
-        t_exec = time.perf_counter()
+        t_exec = time.perf_counter_ns()
+        for _f, _q, _p, _d, trace, t_enq in batch:
+            if trace is not None:
+                # queue-wait = enqueue -> drain (a wait, not a scope)
+                trace.record("queue", t_enq, t_exec)
         try:
             # crash point: the microbatch is assembled, nothing executed —
             # a failure here must fail exactly this batch's callers and
@@ -372,7 +376,10 @@ class LoopBatcher:
                 self.tracer.span("serve.batch", n=len(batch))
                 if self.tracer is not None else contextlib.nullcontext()
             )
-            with span:
+            # device = the microbatch's engine time, shared (with its
+            # lookup.* sub-spans) by every co-batched request
+            with span, reqtrace_mod.shared_stage(
+                    [t for _f, _q, _p, _d, t, _e in batch], "device"):
                 results = self.engine.lookup_many(
                     [q for _f, q, _p, _d, _t, _e in batch],
                     parsed=[p for _f, _q, p, _d, _t, _e in batch],
@@ -382,13 +389,7 @@ class LoopBatcher:
                 if not fut.done():
                     fut.set_exception(exc)
             return
-        dt_device = time.perf_counter() - t_exec
-        for (fut, _q, _p, _d, trace, t_enq), result in zip(batch, results):
-            if trace is not None:
-                # queue-wait = enqueue -> drain; device = the microbatch's
-                # engine time, shared by every co-batched request
-                trace.add("queue", t_exec - t_enq)
-                trace.add("device", dt_device)
+        for (fut, _q, _p, _d, _t, _e), result in zip(batch, results):
             if not fut.done():
                 fut.set_result(result)
         self._batches += 1
@@ -1149,7 +1150,7 @@ class AioServer:
             return _add_trace(
                 _error(500, f"{type(err).__name__}: {err}"), tid
             )
-        t_render = time.perf_counter()
+        t_render = time.perf_counter_ns()
         ctx.remember_point(generation, vid, record)
         if record is None:
             ctx.observe("point", time.perf_counter() - t0)
@@ -1160,7 +1161,7 @@ class AioServer:
         resp = _resp(200, record)
         ctx.observe("point", time.perf_counter() - t0, rows=1)
         if trace is not None:
-            trace.add("render", time.perf_counter() - t_render)
+            trace.record("render", t_render, time.perf_counter_ns())
         ctx.reqtrace.finish(trace, 200)
         return _add_trace(resp, tid)
 
@@ -1476,7 +1477,7 @@ class AioServer:
             return _resp(200, payload)
         generation = payload
         if trace is not None:
-            trace.add("admission", time.perf_counter() - t0)
+            trace.since("admission", t0)
         try:
             if self._loop_batcher:
                 # loop-native coalescing: no cross-thread handoffs
@@ -1588,7 +1589,7 @@ class AioServer:
             if trace is not None:
                 # admission = arrival -> this executor slot (pool wait
                 # included: that IS where an overloaded worker queues)
-                trace.add("admission", time.perf_counter() - t0)
+                trace.since("admission", t0)
             try:
                 parsed = json.loads(body or b"{}")
                 ids = parsed["ids"]
@@ -1616,27 +1617,22 @@ class AioServer:
                     self.governor.charge, client, float(len(ids) - 1)
                 )
             try:
-                t_dev = time.perf_counter()
-                with reqtrace_mod.activate(trace):
+                with reqtrace_mod.stage(trace, "device"):
                     results = ctx.engine.lookup_many(ids)
-                if trace is not None:
-                    trace.add("device", time.perf_counter() - t_dev)
             except QueryError as err:
                 ctx.errored("bulk")
                 return _error(400, str(err))
             except Exception as err:
                 ctx.errored("bulk")
                 return _error(500, f"{type(err).__name__}: {err}")
-            t_render = time.perf_counter()
-            found = sum(1 for r in results if r is not None)
-            resp = _resp(200, (
-                f'{{"n":{len(results)},"found":{found},"results":['
-                + ",".join(r if r is not None else "null" for r in results)
-                + "]}"
-            ))
-            ctx.observe("bulk", time.perf_counter() - t0, rows=found)
-            if trace is not None:
-                trace.add("render", time.perf_counter() - t_render)
+            with reqtrace_mod.stage(trace, "render"):
+                found = sum(1 for r in results if r is not None)
+                resp = _resp(200, (
+                    f'{{"n":{len(results)},"found":{found},"results":['
+                    + ",".join(r if r is not None else "null" for r in results)
+                    + "]}"
+                ))
+                ctx.observe("bulk", time.perf_counter() - t0, rows=found)
             return resp
         finally:
             ctx.release()
@@ -1679,7 +1675,7 @@ class AioServer:
                 ctx.deadline_shed("execute")
                 return _error(504, MSG_DEADLINE_EXECUTE)
             if trace is not None:
-                trace.add("admission", time.perf_counter() - t0)
+                trace.since("admission", t0)
             status, text, rows = ctx.upsert_execute(body, max_rows=max_rows,
                                                     trace=trace)
             if client is not None and rows > 1 and status == 200:
@@ -1741,7 +1737,7 @@ class AioServer:
                 ctx.deadline_shed("execute")
                 return _error(504, MSG_DEADLINE_EXECUTE)
             if trace is not None:
-                trace.add("admission", time.perf_counter() - t0)
+                trace.since("admission", t0)
             try:
                 specs, min_cadd, max_rank, limit, tokenize = \
                     parse_regions_body(body)
@@ -1769,8 +1765,7 @@ class AioServer:
                 if cap is not None:
                     # brownout level >= 1: bound per-interval render work
                     limit = min(limit, cap)
-                t_dev = time.perf_counter()
-                with reqtrace_mod.activate(trace):
+                with reqtrace_mod.stage(trace, "device"):
                     result = ctx.engine.regions_serve(
                         specs,
                         min_cadd=min_cadd,
@@ -1778,8 +1773,6 @@ class AioServer:
                         limit=limit,
                         tokenize=tokenize,
                     )
-                if trace is not None:
-                    trace.add("device", time.perf_counter() - t_dev)
             except QueryError as err:
                 ctx.errored("regions")
                 return _error(400, str(err))
@@ -1789,12 +1782,10 @@ class AioServer:
             if http11 and result.returned > self.stream_threshold:
                 stream_holds_slot = True
                 return ("stream", result)  # the writer releases that slot
-            t_render = time.perf_counter()
-            resp = _resp(200, result.assemble())
-            ctx.observe("regions", time.perf_counter() - t0,
-                        rows=result.returned)
-            if trace is not None:
-                trace.add("render", time.perf_counter() - t_render)
+            with reqtrace_mod.stage(trace, "render"):
+                resp = _resp(200, result.assemble())
+                ctx.observe("regions", time.perf_counter() - t0,
+                            rows=result.returned)
             return resp
         finally:
             if not stream_holds_slot:
@@ -1834,7 +1825,7 @@ class AioServer:
                 ctx.deadline_shed("execute")
                 return _error(504, MSG_DEADLINE_EXECUTE)
             if trace is not None:
-                trace.add("admission", time.perf_counter() - t0)
+                trace.since("admission", t0)
             try:
                 specs, metrics, windows = parse_stats_body(body)
             except QueryError as err:
@@ -1857,25 +1848,20 @@ class AioServer:
                     self.governor.charge, client, float(len(specs) - 1)
                 )
             try:
-                t_dev = time.perf_counter()
-                with reqtrace_mod.activate(trace):
+                with reqtrace_mod.stage(trace, "device"):
                     result = ctx.engine.stats_serve(
                         specs, metrics=metrics, windows=windows,
                     )
-                if trace is not None:
-                    trace.add("device", time.perf_counter() - t_dev)
             except QueryError as err:
                 ctx.errored("stats")
                 return _error(400, str(err))
             except Exception as err:
                 ctx.errored("stats")
                 return _error(500, f"{type(err).__name__}: {err}")
-            t_render = time.perf_counter()
-            resp = _resp(200, result.assemble())
-            ctx.observe("stats", time.perf_counter() - t0,
-                        rows=result.returned)
-            if trace is not None:
-                trace.add("render", time.perf_counter() - t_render)
+            with reqtrace_mod.stage(trace, "render"):
+                resp = _resp(200, result.assemble())
+                ctx.observe("stats", time.perf_counter() - t0,
+                            rows=result.returned)
             return resp
         finally:
             ctx.release()
@@ -1911,18 +1897,15 @@ class AioServer:
                 ctx.deadline_shed("execute")
                 return _error(504, MSG_DEADLINE_EXECUTE)
             if trace is not None:
-                trace.add("admission", time.perf_counter() - t0)
+                trace.since("admission", t0)
             try:
                 params = parse_stream_query(query)
             except ValueError as err:  # QueryError subclasses ValueError
                 ctx.errored("export")
                 return _error(400, str(err))
             try:
-                t_dev = time.perf_counter()
-                with reqtrace_mod.activate(trace):
+                with reqtrace_mod.stage(trace, "device"):
                     body, n_valid = stream_payload(ctx.engine, params)
-                if trace is not None:
-                    trace.add("device", time.perf_counter() - t_dev)
             except QueryError as err:
                 ctx.errored("export")
                 return _error(400, str(err))
@@ -1970,7 +1953,7 @@ class AioServer:
                 ctx.deadline_shed("execute")
                 return _error(504, MSG_DEADLINE_EXECUTE)
             if trace is not None:
-                trace.add("admission", time.perf_counter() - t0)
+                trace.since("admission", t0)
             try:
                 min_cadd, max_rank, limit, cursor = \
                     parse_region_params(query)
@@ -1978,8 +1961,7 @@ class AioServer:
                 if cap is not None:
                     # brownout level >= 1: bound per-request render work
                     limit = min(limit, cap)
-                t_dev = time.perf_counter()
-                with reqtrace_mod.activate(trace):
+                with reqtrace_mod.stage(trace, "device"):
                     kind, payload = ctx.engine.region_serve(
                         spec,
                         min_cadd=min_cadd,
@@ -1990,8 +1972,6 @@ class AioServer:
                             self.stream_threshold if http11 else None
                         ),
                     )
-                if trace is not None:
-                    trace.add("device", time.perf_counter() - t_dev)
             except QueryError as err:
                 ctx.errored("region")
                 return _error(400, str(err))

@@ -38,6 +38,7 @@ import queue
 import threading
 import time
 
+from annotatedvdb_tpu.obs import reqtrace
 from annotatedvdb_tpu.serve.engine import parse_variant_id
 from annotatedvdb_tpu.serve.resilience import DeadlineExceeded
 from annotatedvdb_tpu.utils import faults
@@ -149,7 +150,7 @@ class _Pending:
         #: attributes queue-wait and device time to it; None when the
         #: request is unsampled (zero tracing work downstream)
         self.trace = trace
-        self.t_enq = time.perf_counter() if trace is not None else 0.0
+        self.t_enq = time.perf_counter_ns() if trace is not None else 0
 
     def finish(self) -> None:
         """Publish the filled result/error to the waiter."""
@@ -330,7 +331,12 @@ class QueryBatcher:
         batch = self._shed_expired(batch)
         if not batch:
             return
-        t_exec = time.perf_counter()
+        t_exec = time.perf_counter_ns()
+        for pending in batch:
+            if pending.trace is not None:
+                # queue-wait = enqueue -> drain execution (a wait across
+                # threads: a recorded span, not a scope)
+                pending.trace.record("queue", pending.t_enq, t_exec)
         try:
             # crash point: the microbatch is assembled, nothing executed —
             # a failure here must fail exactly this batch's callers and
@@ -340,7 +346,11 @@ class QueryBatcher:
                 self.tracer.span("serve.batch", n=len(batch))
                 if self.tracer is not None else contextlib.nullcontext()
             )
-            with span:
+            # device = the whole microbatch's engine time (co-batched
+            # requests share the span and its lookup.* sub-spans, the
+            # continuous-batching reality)
+            with span, reqtrace.shared_stage(
+                    [p.trace for p in batch], "device"):
                 results = self.engine.lookup_many(
                     [p.qid for p in batch],
                     parsed=[p.parsed for p in batch],
@@ -350,14 +360,7 @@ class QueryBatcher:
                 pending.error = exc
                 pending.finish()
             return
-        dt_device = time.perf_counter() - t_exec
         for pending, result in zip(batch, results):
-            if pending.trace is not None:
-                # queue-wait = enqueue -> drain execution; device = the
-                # whole microbatch's engine time (co-batched requests
-                # share the span, the continuous-batching reality)
-                pending.trace.add("queue", t_exec - pending.t_enq)
-                pending.trace.add("device", dt_device)
             pending.result = result
             pending.finish()
         with self._lock:

@@ -51,6 +51,7 @@ byte-identical numpy twin.
 from __future__ import annotations
 
 import base64
+import contextlib
 import functools
 import json
 import os
@@ -63,6 +64,7 @@ import numpy as np
 
 from annotatedvdb_tpu.loaders.lookup import identity_hashes
 from annotatedvdb_tpu.obs import reqtrace
+from annotatedvdb_tpu.utils.profiling import annotation
 from annotatedvdb_tpu.ops import intervals as interval_ops
 from annotatedvdb_tpu.ops import stats as stats_ops
 from annotatedvdb_tpu.ops.binindex import bin_index_kernel_jit
@@ -668,6 +670,36 @@ class RegionsResult:
         return self.prefix() + ",".join(self.rows()) + self.suffix()
 
 
+class _LookupClock:
+    """One ``lookup_many`` call's sub-stage seconds and render-cache
+    tallies (one call runs on one thread: plain integers, no lock).  :meth:`span` times one sub-stage of one chromosome group
+    where the work happens: nanoseconds summed per stage over the call, a
+    sub-span on the calling thread's active request stage (true start and
+    end, parent ``device``), and a profiler annotation ``avdb.<stage>`` —
+    about ten a call, never one per id."""
+
+    __slots__ = ("ns", "found", "misses")
+
+    def __init__(self):
+        self.ns = dict.fromkeys(reqtrace.LOOKUP_STAGES, 0)
+        #: ids found (each goes through the render cache once) and the
+        #: cache misses among them — the miss path counts itself, so the
+        #: hit path pays nothing for being counted
+        self.found = 0
+        self.misses = 0
+
+    @contextlib.contextmanager
+    def span(self, stage: str, **args):
+        with annotation(f"avdb.{stage}", **args):
+            start_ns = time.perf_counter_ns()
+            try:
+                yield
+            finally:
+                end_ns = time.perf_counter_ns()
+                self.ns[stage] += end_ns - start_ns
+                reqtrace.record_active(stage, start_ns, end_ns)
+
+
 class QueryEngine:
     """Point/bulk/region queries over a snapshot provider
     (:class:`~annotatedvdb_tpu.serve.snapshot.SnapshotManager` in a server,
@@ -781,6 +813,25 @@ class QueryEngine:
             )
         else:
             self._cache_hits = self._cache_misses = None
+        #: render-cache (point/bulk record LRU) outcomes, added once per
+        #: ``lookup_many`` call from the call's own tallies — ints under
+        #: the GIL, read by ``/stats`` (``render_cache``)
+        self.render_cache_hits = 0
+        self.render_cache_misses = 0
+        self._lookup_hist = None
+        self._m_render_hits = self._m_render_misses = None
+        if registry is not None:
+            self._lookup_hist = reqtrace.stage_histograms(
+                registry, reqtrace.LOOKUP_STAGES
+            )
+            self._m_render_hits = registry.counter(
+                "avdb_render_cache_hits_total",
+                "found ids answered from the rendered-record LRU",
+            )
+            self._m_render_misses = registry.counter(
+                "avdb_render_cache_misses_total",
+                "found ids rendered fresh (locate, render, decode)",
+            )
 
     # -- point / bulk -------------------------------------------------------
 
@@ -799,8 +850,16 @@ class QueryEngine:
         out: list = [None] * len(ids)
         if not ids:
             return out
+        clock = _LookupClock()
+        try:
+            return self._lookup_many(ids, parsed, out, clock)
+        finally:
+            self._lookup_done(clock)
+
+    def _lookup_many(self, ids: list, parsed, out: list, clock) -> list:
         if parsed is None:
-            parsed = [parse_variant_id(s) for s in ids]
+            with clock.span("lookup.parse", n=len(ids)):
+                parsed = [parse_variant_id(s) for s in ids]
         snap = self.snapshots.current()
         if self.residency is not None:
             self.residency.govern(snap)
@@ -808,39 +867,63 @@ class QueryEngine:
         width = store.width
         if self.mesh is not None and len(ids) >= self.mesh.bulk_min \
                 and self.mesh.would_dispatch(snap):
-            got = self._mesh_lookup_many(snap, parsed, out)
+            got = self._mesh_lookup_many(snap, parsed, out, clock)
             if got is not None:
                 return got
-        by_code: dict[int, list] = {}
-        for i, (code, _pos, _ref, _alt) in enumerate(parsed):
-            by_code.setdefault(code, []).append(i)
+        with clock.span("lookup.hash", n=len(ids)):
+            by_code: dict[int, list] = {}
+            for i, (code, _pos, _ref, _alt) in enumerate(parsed):
+                by_code.setdefault(code, []).append(i)
         for code, idxs in by_code.items():
             shard = store.shards.get(code)
             if shard is None:
                 continue  # chromosome not loaded: every id misses
-            refs = [parsed[i][2] for i in idxs]
-            alts = [parsed[i][3] for i in idxs]
-            ref, ref_len = encode_allele_array(refs, width)
-            alt, alt_len = encode_allele_array(alts, width)
-            pos = np.fromiter(
-                (parsed[i][1] for i in idxs), np.int32, count=len(idxs)
-            )
-            h = identity_hashes(width, ref, alt, ref_len, alt_len, refs, alts)
-            if self.residency is not None:
-                qkey = combined_key(pos, h)
-                self.residency.touch_window(
-                    shard, qkey.min(), qkey.max(), len(idxs)
+            with clock.span("lookup.hash", chrom=code, n=len(idxs)):
+                refs = [parsed[i][2] for i in idxs]
+                alts = [parsed[i][3] for i in idxs]
+                ref, ref_len = encode_allele_array(refs, width)
+                alt, alt_len = encode_allele_array(alts, width)
+                pos = np.fromiter(
+                    (parsed[i][1] for i in idxs), np.int32, count=len(idxs)
                 )
-            found, gid = self._probe_group(
-                shard, code, pos, h, ref, alt, ref_len, alt_len
-            )
-            generation = snap.generation
-            for k, i in enumerate(idxs):
-                if found[k]:
-                    out[i] = self._render_cached(
-                        shard, code, int(gid[k]), generation
+                h = identity_hashes(
+                    width, ref, alt, ref_len, alt_len, refs, alts
+                )
+                if self.residency is not None:
+                    qkey = combined_key(pos, h)
+                    self.residency.touch_window(
+                        shard, qkey.min(), qkey.max(), len(idxs)
                     )
+            with clock.span("lookup.probe", chrom=code, n=len(idxs)):
+                found, gid = self._probe_group(
+                    shard, code, pos, h, ref, alt, ref_len, alt_len
+                )
+            with clock.span("lookup.rows", chrom=code, n=len(idxs)):
+                generation = snap.generation
+                for k, i in enumerate(idxs):
+                    if found[k]:
+                        out[i] = self._render_cached(
+                            shard, code, int(gid[k]), generation, clock
+                        )
+                clock.found += int(np.count_nonzero(found))
         return out
+
+    def _lookup_done(self, clock: "_LookupClock") -> None:
+        """One ``lookup_many`` call's accounts: each sub-stage's seconds
+        (summed over the call's chromosome groups; 0 for a stage the call
+        never entered) observed ONCE, so the four means add up to the
+        ``device`` stage's; the render-cache tallies added once — no lock
+        and no metric call per id."""
+        hits = clock.found - clock.misses
+        self.render_cache_hits += hits
+        self.render_cache_misses += clock.misses
+        if self._lookup_hist is not None:
+            for stage, ns in clock.ns.items():
+                self._lookup_hist[stage].observe(ns / 1e9)
+            if hits:
+                self._m_render_hits.inc(hits)
+            if clock.misses:
+                self._m_render_misses.inc(clock.misses)
 
     def _probe_group(self, shard, code: int, pos, h, ref, alt,
                      ref_len, alt_len):
@@ -878,7 +961,7 @@ class QueryEngine:
             breaker.record_success(code)
         return out
 
-    def _mesh_lookup_many(self, snap, parsed, out):
+    def _mesh_lookup_many(self, snap, parsed, out, clock):
         """The mesh bulk path: every id of the batch — all chromosome
         groups at once — resolves through ONE sharded call
         (``serve.mesh_exec.MeshExecutor.bulk_lookup``), and hits render
@@ -888,17 +971,19 @@ class QueryEngine:
         answers are byte-identical."""
         store = snap.store
         width = store.width
-        refs = [p[2] for p in parsed]
-        alts = [p[3] for p in parsed]
-        ref, ref_len = encode_allele_array(refs, width)
-        alt, alt_len = encode_allele_array(alts, width)
         n = len(parsed)
-        pos = np.fromiter((p[1] for p in parsed), np.int32, count=n)
-        chrom = np.fromiter((p[0] for p in parsed), np.int8, count=n)
-        h = identity_hashes(width, ref, alt, ref_len, alt_len, refs, alts)
-        got = self.mesh.bulk_lookup(
-            snap, chrom, pos, h, ref, alt, ref_len, alt_len
-        )
+        with clock.span("lookup.hash", n=n):
+            refs = [p[2] for p in parsed]
+            alts = [p[3] for p in parsed]
+            ref, ref_len = encode_allele_array(refs, width)
+            alt, alt_len = encode_allele_array(alts, width)
+            pos = np.fromiter((p[1] for p in parsed), np.int32, count=n)
+            chrom = np.fromiter((p[0] for p in parsed), np.int8, count=n)
+            h = identity_hashes(width, ref, alt, ref_len, alt_len, refs, alts)
+        with clock.span("lookup.probe", n=n):
+            got = self.mesh.bulk_lookup(
+                snap, chrom, pos, h, ref, alt, ref_len, alt_len
+            )
         if got is None:
             return None
         found, gid = got
@@ -907,37 +992,42 @@ class QueryEngine:
             # the per-segment caches are what the single-device FALLBACK
             # serves from, and a decayed-to-zero plan would evict them
             # exactly when a tripped mesh needs them warm
-            qkey = combined_key(pos, h)
-            by_code: dict[int, list] = {}
-            for i, (code, _p, _r, _a) in enumerate(parsed):
-                by_code.setdefault(code, []).append(i)
-            for code, idxs in by_code.items():
-                shard = store.shards.get(code)
-                if shard is None:
-                    continue
-                k = qkey[idxs]
-                self.residency.touch_window(
-                    shard, k.min(), k.max(), len(idxs)
-                )
-        generation = snap.generation
-        for i, (code, _pos, _ref, _alt) in enumerate(parsed):
-            if found[i]:
-                out[i] = self._render_cached(
-                    store.shards[code], code, int(gid[i]), generation
-                )
+            with clock.span("lookup.hash", n=n):
+                qkey = combined_key(pos, h)
+                by_code: dict[int, list] = {}
+                for i, (code, _p, _r, _a) in enumerate(parsed):
+                    by_code.setdefault(code, []).append(i)
+                for code, idxs in by_code.items():
+                    shard = store.shards.get(code)
+                    if shard is None:
+                        continue
+                    k = qkey[idxs]
+                    self.residency.touch_window(
+                        shard, k.min(), k.max(), len(idxs)
+                    )
+        with clock.span("lookup.rows", n=n):
+            generation = snap.generation
+            for i, (code, _pos, _ref, _alt) in enumerate(parsed):
+                if found[i]:
+                    out[i] = self._render_cached(
+                        store.shards[code], code, int(gid[i]), generation,
+                        clock,
+                    )
+            clock.found += int(np.count_nonzero(found))
         return out
 
     def _render_cached(self, shard, code: int, gid: int,
-                       generation: int) -> str:
+                       generation: int, clock: "_LookupClock") -> str:
         """Point-record render through the generation-keyed LRU (stale
         generations age out with everything else; their keys can never be
-        probed again)."""
+        probed again).  A miss counts itself on the call's ``clock``."""
         key = (generation, code, gid)
         with self._render_lock:
             text = self._render_cache.get(key)
             if text is not None:
                 self._render_cache.move_to_end(key)
                 return text
+        clock.misses += 1
         text = render_variant(shard, code, gid)
         with self._render_lock:
             # two threads can race the same miss: replace, don't
@@ -1070,7 +1160,7 @@ class QueryEngine:
                 lambda code: self._interval_index(snap, code),
             )
         for code, idxs in by_code.items():
-            t_group = time.perf_counter()
+            t_group = time.perf_counter_ns()
             index = indexes[code] = self._interval_index(snap, code)
             if index is None:
                 level[idxs], leaf[idxs] = interval_ops.bin_tokens_host(
@@ -1093,9 +1183,9 @@ class QueryEngine:
             # an active trace): a panel's every interval shares the
             # request's trace id, and the group split is where device
             # time actually goes
-            reqtrace.span_active(
+            reqtrace.record_active(
                 f"regions.chr{chromosome_label(code)}",
-                time.perf_counter() - t_group,
+                t_group, time.perf_counter_ns(),
             )
         no_filters = min_cadd is None and max_conseq_rank is None
         pages = []
@@ -1206,7 +1296,7 @@ class QueryEngine:
             by_code.setdefault(code, []).append(i)
         entries: list = [None] * len(parsed)
         for code, idxs in by_code.items():
-            t_group = time.perf_counter()
+            t_group = time.perf_counter_ns()
             starts = [parsed[i][1] for i in idxs]
             ends = [parsed[i][2] for i in idxs]
             index = self._interval_index(snap, code)
@@ -1243,9 +1333,9 @@ class QueryEngine:
                 }
             # per-group sub-span onto the request's trace (no-op outside
             # an active trace) — the group split is where device time goes
-            reqtrace.span_active(
+            reqtrace.record_active(
                 f"stats.chr{chromosome_label(code)}",
-                time.perf_counter() - t_group,
+                t_group, time.perf_counter_ns(),
             )
         return StatsResult(snap.generation, metrics, entries)
 
@@ -1384,7 +1474,7 @@ class QueryEngine:
         label = chromosome_label(code)
         level, leaf = _region_bin(start, end)
         shard = snap.store.shards.get(code)
-        t_page = time.perf_counter()
+        t_page = time.perf_counter_ns()
         paged = cursor is not None
         wkey = hit = None
         if paged:
@@ -1458,8 +1548,8 @@ class QueryEngine:
                 next_token = encode_cursor(snap.generation, stop, ckey)
             # page sub-span: every page of a cursor walk attributes its
             # scan to the walking request's trace id (no-op untraced)
-            reqtrace.span_active(f"region.chr{label}",
-                                 time.perf_counter() - t_page)
+            reqtrace.record_active(f"region.chr{label}", t_page,
+                                   time.perf_counter_ns())
             return RegionPage(
                 shard, label, level, closed_form_path(label, level, leaf),
                 total, snap.generation, shown, f"{label}:{start}-{end}",
@@ -1467,8 +1557,8 @@ class QueryEngine:
             )
         stop = len(kept) if limit is None \
             else min(max(int(limit), 0), len(kept))
-        reqtrace.span_active(f"region.chr{label}",
-                             time.perf_counter() - t_page)
+        reqtrace.record_active(f"region.chr{label}", t_page,
+                               time.perf_counter_ns())
         return RegionPage(
             shard, label, level, closed_form_path(label, level, leaf),
             len(kept) if full_count is None else full_count,

@@ -140,6 +140,8 @@ def stats_payload(ctx) -> str:
         "device": ctx.device,
         "compile": compile_summary(),
         "device_lookup": device_lookup_state(),
+        "render_cache": {"hits": ctx.engine.render_cache_hits,
+                         "misses": ctx.engine.render_cache_misses},
     }
     if ctx.engine.residency is not None:
         stats["residency"] = ctx.engine.residency.stats()
@@ -1363,7 +1365,7 @@ class ServeHandler(BaseHTTPRequestHandler):
             return
         generation = payload
         if trace is not None:
-            trace.add("admission", time.perf_counter() - t0)
+            trace.since("admission", t0)
         try:
             record = ctx.batcher.submit(variant_id, deadline_t=deadline_t,
                                         trace=trace)
@@ -1387,7 +1389,7 @@ class ServeHandler(BaseHTTPRequestHandler):
             ctx.reqtrace.finish(trace, 500)
             self._error(500, f"{type(err).__name__}: {err}")
             return
-        t_render = time.perf_counter()
+        t_render = time.perf_counter_ns()
         ctx.remember_point(generation, variant_id, record)
         if record is None:
             ctx.observe("point", time.perf_counter() - t0)
@@ -1396,7 +1398,7 @@ class ServeHandler(BaseHTTPRequestHandler):
             return
         ctx.observe("point", time.perf_counter() - t0, rows=1)
         if trace is not None:
-            trace.add("render", time.perf_counter() - t_render)
+            trace.record("render", t_render, time.perf_counter_ns())
         ctx.reqtrace.finish(trace, 200)
         self._reply(200, record)
 
@@ -1435,13 +1437,10 @@ class ServeHandler(BaseHTTPRequestHandler):
                 return
             trace = ctx.reqtrace.begin(self._trace_id, "bulk")
             if trace is not None:
-                trace.add("admission", time.perf_counter() - t0)
+                trace.since("admission", t0)
             try:
-                t_dev = time.perf_counter()
-                with reqtrace_mod.activate(trace):
+                with reqtrace_mod.stage(trace, "device"):
                     results = ctx.engine.lookup_many(ids)
-                if trace is not None:
-                    trace.add("device", time.perf_counter() - t_dev)
             except QueryError as err:
                 ctx.errored("bulk")
                 ctx.reqtrace.finish(trace, 400)
@@ -1452,16 +1451,14 @@ class ServeHandler(BaseHTTPRequestHandler):
                 ctx.reqtrace.finish(trace, 500)
                 self._error(500, f"{type(err).__name__}: {err}")
                 return
-            t_render = time.perf_counter()
-            found = sum(1 for r in results if r is not None)
-            body = (
-                f'{{"n":{len(results)},"found":{found},"results":['
-                + ",".join(r if r is not None else "null" for r in results)
-                + "]}"
-            )
-            ctx.observe("bulk", time.perf_counter() - t0, rows=found)
-            if trace is not None:
-                trace.add("render", time.perf_counter() - t_render)
+            with reqtrace_mod.stage(trace, "render"):
+                found = sum(1 for r in results if r is not None)
+                body = (
+                    f'{{"n":{len(results)},"found":{found},"results":['
+                    + ",".join(r if r is not None else "null" for r in results)
+                    + "]}"
+                )
+                ctx.observe("bulk", time.perf_counter() - t0, rows=found)
             ctx.reqtrace.finish(trace, 200)
             self._reply(200, body)
         finally:
@@ -1544,14 +1541,13 @@ class ServeHandler(BaseHTTPRequestHandler):
                 return
             trace = ctx.reqtrace.begin(self._trace_id, "regions")
             if trace is not None:
-                trace.add("admission", time.perf_counter() - t0)
+                trace.since("admission", t0)
             try:
                 cap = ctx.governor.region_limit_cap()
                 if cap is not None:
                     # brownout level >= 1: bound per-interval render work
                     limit = min(limit, cap)
-                t_dev = time.perf_counter()
-                with reqtrace_mod.activate(trace):
+                with reqtrace_mod.stage(trace, "device"):
                     result = ctx.engine.regions_serve(
                         specs,
                         min_cadd=min_cadd,
@@ -1559,8 +1555,6 @@ class ServeHandler(BaseHTTPRequestHandler):
                         limit=limit,
                         tokenize=tokenize,
                     )
-                if trace is not None:
-                    trace.add("device", time.perf_counter() - t_dev)
             except QueryError as err:
                 ctx.errored("regions")
                 ctx.reqtrace.finish(trace, 400)
@@ -1571,12 +1565,10 @@ class ServeHandler(BaseHTTPRequestHandler):
                 ctx.reqtrace.finish(trace, 500)
                 self._error(500, f"{type(err).__name__}: {err}")
                 return
-            t_render = time.perf_counter()
-            body = result.assemble()
-            ctx.observe("regions", time.perf_counter() - t0,
-                        rows=result.returned)
-            if trace is not None:
-                trace.add("render", time.perf_counter() - t_render)
+            with reqtrace_mod.stage(trace, "render"):
+                body = result.assemble()
+                ctx.observe("regions", time.perf_counter() - t0,
+                            rows=result.returned)
             ctx.reqtrace.finish(trace, 200)
             self._reply(200, body)
         finally:
@@ -1620,15 +1612,12 @@ class ServeHandler(BaseHTTPRequestHandler):
                 return
             trace = ctx.reqtrace.begin(self._trace_id, "stats")
             if trace is not None:
-                trace.add("admission", time.perf_counter() - t0)
+                trace.since("admission", t0)
             try:
-                t_dev = time.perf_counter()
-                with reqtrace_mod.activate(trace):
+                with reqtrace_mod.stage(trace, "device"):
                     result = ctx.engine.stats_serve(
                         specs, metrics=metrics, windows=windows,
                     )
-                if trace is not None:
-                    trace.add("device", time.perf_counter() - t_dev)
             except QueryError as err:
                 ctx.errored("stats")
                 ctx.reqtrace.finish(trace, 400)
@@ -1639,12 +1628,10 @@ class ServeHandler(BaseHTTPRequestHandler):
                 ctx.reqtrace.finish(trace, 500)
                 self._error(500, f"{type(err).__name__}: {err}")
                 return
-            t_render = time.perf_counter()
-            body = result.assemble()
-            ctx.observe("stats", time.perf_counter() - t0,
-                        rows=result.returned)
-            if trace is not None:
-                trace.add("render", time.perf_counter() - t_render)
+            with reqtrace_mod.stage(trace, "render"):
+                body = result.assemble()
+                ctx.observe("stats", time.perf_counter() - t0,
+                            rows=result.returned)
             ctx.reqtrace.finish(trace, 200)
             self._reply(200, body)
         finally:
@@ -1680,13 +1667,10 @@ class ServeHandler(BaseHTTPRequestHandler):
                 return
             trace = ctx.reqtrace.begin(self._trace_id, "export")
             if trace is not None:
-                trace.add("admission", time.perf_counter() - t0)
+                trace.since("admission", t0)
             try:
-                t_dev = time.perf_counter()
-                with reqtrace_mod.activate(trace):
+                with reqtrace_mod.stage(trace, "device"):
                     body, n_valid = stream_payload(ctx.engine, params)
-                if trace is not None:
-                    trace.add("device", time.perf_counter() - t_dev)
             except QueryError as err:
                 ctx.errored("export")
                 ctx.reqtrace.finish(trace, 400)
@@ -1722,7 +1706,7 @@ class ServeHandler(BaseHTTPRequestHandler):
             ctx.refresh_snapshot()
             trace = ctx.reqtrace.begin(self._trace_id, "region")
             if trace is not None:
-                trace.add("admission", time.perf_counter() - t0)
+                trace.since("admission", t0)
             try:
                 min_cadd, max_rank, limit, cursor = \
                     parse_region_params(query)
@@ -1730,8 +1714,7 @@ class ServeHandler(BaseHTTPRequestHandler):
                 if cap is not None:
                     # brownout level >= 1: bound per-request render work
                     limit = min(limit, cap)
-                t_dev = time.perf_counter()
-                with reqtrace_mod.activate(trace):
+                with reqtrace_mod.stage(trace, "device"):
                     text = ctx.engine.region(
                         spec,
                         min_cadd=min_cadd,
@@ -1739,8 +1722,6 @@ class ServeHandler(BaseHTTPRequestHandler):
                         limit=limit,
                         cursor=cursor,
                     )
-                if trace is not None:
-                    trace.add("device", time.perf_counter() - t_dev)
             except QueryError as err:
                 ctx.errored("region")
                 ctx.reqtrace.finish(trace, 400)

@@ -335,13 +335,11 @@ class Memtable:
                 # the ack barrier: the WAL frame is fsync'd BEFORE the rows
                 # become visible — a raise here fails the request with the
                 # memtable untouched (nothing acknowledged, nothing lost)
+                # (the fsync is the acknowledging request's wal_fsync
+                # trace stage: the durable-ack barrier's cost)
                 wal_bytes = self.wal.append({
                     "rows": [parsed[i] for i in accepted_idx],
-                })
-                if trace is not None:
-                    # the durable-ack barrier's cost, attributed to the
-                    # acknowledging request (the wal_fsync trace stage)
-                    trace.add("wal_fsync", self.wal.last_fsync_s)
+                }, trace=trace)
                 if self._m_wal_bytes is not None:
                     self._m_wal_bytes.inc(wal_bytes)
             for code, (idxs, rows, ref, alt, ann_cols) in built.items():

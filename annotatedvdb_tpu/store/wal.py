@@ -40,7 +40,6 @@ import json
 import os
 import re
 import struct
-import time
 import zlib
 
 from annotatedvdb_tpu.obs import reqtrace
@@ -112,10 +111,6 @@ class WriteAheadLog:
         self.name = name
         self.log = log if log is not None else (lambda msg: None)
         self._lock = make_lock("store.wal")
-        #: duration of the most recent append's fsync — the ack barrier's
-        #: cost, read by the memtable (under its own lock) to attribute
-        #: the ``wal_fsync`` trace stage
-        self.last_fsync_s = 0.0
         #: guarded by self._lock
         self._f = None
         existing = self.pending_files()
@@ -158,8 +153,11 @@ class WriteAheadLog:
             tio.fsync(f)
         tio.replace(tmp, path)
 
-    def append(self, payload: dict) -> int:
+    def append(self, payload: dict, trace=None) -> int:
         """Write one CRC-framed record and fsync; returns frame bytes.
+        ``trace`` (the acknowledging request's
+        :class:`~annotatedvdb_tpu.obs.reqtrace.RequestTrace`, or None)
+        gets the fsync as its ``wal_fsync`` stage: the ack barrier's cost.
 
         Returning AT ALL is the durability promise the ack rides: the
         frame is on stable storage (as far as a process SIGKILL is
@@ -193,12 +191,8 @@ class WriteAheadLog:
             # or may not be durable, but the ack was never sent — replay
             # applies it in full or not at all, never a hybrid
             faults.fire("wal.fsync", f, tear_base=pre)
-            t_fsync = time.perf_counter()
-            tio.fsync(f)
-            # the ack barrier's cost, attributed to the acknowledging
-            # request's trace (single writer per worker: the caller reads
-            # it back under the memtable lock it already holds)
-            self.last_fsync_s = time.perf_counter() - t_fsync
+            with reqtrace.stage(trace, "wal_fsync"):
+                tio.fsync(f)
         return len(frame)
 
     # -- rotation / discard (the flush protocol's WAL half) ------------------

@@ -30,6 +30,8 @@ import queue
 import threading
 import time
 
+from annotatedvdb_tpu.utils.profiling import annotation
+
 _END = object()
 
 
@@ -48,6 +50,11 @@ class StageStats:
     the stage thread, ``consumer_wait_s`` only by the consuming thread.
     Reads from other threads (summaries after ``close()``) see a settled
     value; a mid-run read is a monotone snapshot, good enough for gauges.
+
+    Each blocked episode is also a profiler annotation
+    ``avdb.wait.<boundary>`` on the thread that sat blocked (``side``:
+    producer or consumer), so an idle gap in a capture reads as the wait
+    it was and not as whatever another thread was busy with.
     """
 
     __slots__ = ("name", "items", "producer_block_s", "consumer_wait_s",
@@ -106,7 +113,11 @@ class BoundedStage:
     unconsumed before the producer blocks.
     """
 
-    def __init__(self, source, fn=None, depth: int = 2, name: str = "stage"):
+    def __init__(self, source, fn=None, depth: int = 2, name: str = "stage",
+                 boundary: str | None = None):
+        #: the wait spans' name: the boundary as the ``queue_stalls`` table
+        #: calls it (``ingest``, ``dispatch``), the thread's name otherwise
+        self._wait_name = f"avdb.wait.{boundary or name}"
         self._q: queue.Queue = queue.Queue(maxsize=max(1, depth))
         self._stop = threading.Event()
         self._done = False
@@ -150,22 +161,23 @@ class BoundedStage:
             return True
         except queue.Full:
             pass
-        t0 = time.perf_counter()
-        try:
-            while not self._stop.is_set():
-                try:
-                    self._q.put(item, timeout=0.05)
-                    if is_data:
-                        stats.items += 1
-                        stats.max_depth = max(
-                            stats.max_depth, self._q.qsize()
-                        )
-                    return True
-                except queue.Full:
-                    continue
-            return False
-        finally:
-            stats.producer_block_s += time.perf_counter() - t0
+        with annotation(self._wait_name, side="producer"):
+            t0 = time.perf_counter()
+            try:
+                while not self._stop.is_set():
+                    try:
+                        self._q.put(item, timeout=0.05)
+                        if is_data:
+                            stats.items += 1
+                            stats.max_depth = max(
+                                stats.max_depth, self._q.qsize()
+                            )
+                        return True
+                    except queue.Full:
+                        continue
+                return False
+            finally:
+                stats.producer_block_s += time.perf_counter() - t0
 
     def _run(self, source, fn) -> None:
         try:
@@ -202,29 +214,8 @@ class BoundedStage:
         try:
             item = self._q.get_nowait()
         except queue.Empty:
-            t0 = time.perf_counter()
-            try:
-                while True:
-                    if self._done or self._stop.is_set():
-                        raise StopIteration
-                    try:
-                        item = self._q.get(timeout=0.05)
-                    except queue.Empty:
-                        if not self._thread.is_alive():
-                            # producer gone without _END: closed upstream —
-                            # or CRASHED with its error envelope dropped.
-                            # Silently stopping would truncate the stream
-                            # and report success; surface the root cause
-                            self._done = True
-                            with self._lock:
-                                err = self.error
-                            if err is not None:
-                                raise err
-                            raise StopIteration
-                        continue
-                    break
-            finally:
-                self.stats.consumer_wait_s += time.perf_counter() - t0
+            with annotation(self._wait_name, side="consumer"):
+                item = self._wait_get()
         if item is _END:
             self._done = True
             raise StopIteration
@@ -232,6 +223,31 @@ class BoundedStage:
             self._done = True
             raise item.exc
         return item
+
+    def _wait_get(self):
+        """The slow half of ``__next__``: poll the empty queue until an
+        item, the end, or a dead producer."""
+        t0 = time.perf_counter()
+        try:
+            while True:
+                if self._done or self._stop.is_set():
+                    raise StopIteration
+                try:
+                    return self._q.get(timeout=0.05)
+                except queue.Empty:
+                    if not self._thread.is_alive():
+                        # producer gone without _END: closed upstream — or
+                        # CRASHED with its error envelope dropped.
+                        # Silently stopping would truncate the stream and
+                        # report success; surface the root cause
+                        self._done = True
+                        with self._lock:
+                            err = self.error
+                        if err is not None:
+                            raise err
+                        raise StopIteration
+        finally:
+            self.stats.consumer_wait_s += time.perf_counter() - t0
 
     def close(self, timeout: float = 10.0) -> bool:
         """Stop the producer and reclaim the thread (idempotent).  Pending

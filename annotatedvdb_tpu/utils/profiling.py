@@ -5,9 +5,16 @@ pairs around buffer-build vs COPY in debug mode
 (``Load/bin/load_vcf_file.py:108-111,136-140,165-168``).  Here every loader
 carries a :class:`StageTimer` that attributes wall-clock to named pipeline
 stages (ingest / annotate / lookup / egress / append / flush) and can emit
-rate summaries at a log cadence; ``device_trace`` wraps ``jax.profiler`` so
-a ``--profile <dir>`` flag captures an XLA trace viewable in TensorBoard /
-Perfetto.
+rate summaries at a log cadence.
+
+Every stage is also a ``jax.profiler.TraceAnnotation`` (:func:`annotation`)
+on the thread that runs it, so under any ``jax.profiler`` capture — the
+``--profile <dir>`` flag (:func:`device_trace`) or one started around the
+program — the program's stages sit on the host lines of the same
+``.xplane.pb`` as the device's operations: one file, one clock.  With no
+capture running an annotation is a flag test (under a microsecond), which
+is why spans stay at stage granularity — a handful per chunk, never per
+row.
 """
 
 from __future__ import annotations
@@ -15,6 +22,46 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
+
+_TraceAnnotation = None
+
+
+def annotation(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``: a span on the
+    calling thread's host line of whatever profiler capture is running,
+    ``args`` shown as the event's stats.  The one place the program's
+    spans (load stages, queue waits, request stages, start-up phases)
+    reach the profiler's clock.  jax is imported on first use — modules
+    that only time things (``utils.pipeline``, ``obs.reqtrace``) stay
+    importable without it."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **args)
+
+
+#: seconds per start-up phase of this process (device start, native
+#: tokenizer, transport probe, compiled programs), cumulative: a second
+#: load in one process adds only what it paid again.  The run record's
+#: ``execution.startup``
+STARTUP_SECONDS: dict[str, float] = {}
+
+
+@contextlib.contextmanager
+def startup_phase(name: str):
+    """Time one start-up phase into :data:`STARTUP_SECONDS` and onto the
+    profiler's clock as ``avdb.startup.<name>``.  Records only: nothing is
+    reordered or skipped."""
+    with annotation(f"avdb.startup.{name}"):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            STARTUP_SECONDS[name] = (
+                STARTUP_SECONDS.get(name, 0.0) + time.perf_counter() - t0
+            )
 
 
 class StageTimer:
@@ -26,6 +73,12 @@ class StageTimer:
         with timer.wall():                      # once around the whole load
             with timer.stage("annotate", items=batch.n):
                 ...
+
+    One span, three sinks: the busy-seconds table (run record ``stages``,
+    ``avdb_stage_busy_seconds_total``), the optional ``--traceOut``
+    :class:`~annotatedvdb_tpu.obs.trace.Tracer`, and a profiler annotation
+    ``avdb.load.<stage>`` (``avdb.load`` for :meth:`wall`) on the thread
+    that does the work.
 
     Stages may run CONCURRENTLY on pipeline threads (overlapped executor:
     ingest / dispatch / process / store-writer), so accumulation is
@@ -49,7 +102,7 @@ class StageTimer:
         self.wall_seconds: float = 0.0
         #: optional :class:`annotatedvdb_tpu.obs.trace.Tracer`; when set,
         #: every stage span is mirrored as a B/E trace-event pair on the
-        #: thread that ran it — the host half of the Perfetto timeline
+        #: thread that ran it — the no-profiler export of the same spans
         self.tracer = None
 
     @contextlib.contextmanager
@@ -57,16 +110,17 @@ class StageTimer:
         tracer = self.tracer
         if tracer is not None:
             tracer.begin(name)
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            dt = self._clock() - t0
-            with self._lock:
-                self.seconds[name] = self.seconds.get(name, 0.0) + dt
-                self.items[name] = self.items.get(name, 0) + items
-            if tracer is not None:
-                tracer.end(name)
+        with annotation(f"avdb.load.{name}", items=items):
+            t0 = self._clock()
+            try:
+                yield
+            finally:
+                dt = self._clock() - t0
+                with self._lock:
+                    self.seconds[name] = self.seconds.get(name, 0.0) + dt
+                    self.items[name] = self.items.get(name, 0) + items
+                if tracer is not None:
+                    tracer.end(name)
 
     @contextlib.contextmanager
     def wall(self):
@@ -75,15 +129,16 @@ class StageTimer:
         tracer = self.tracer
         if tracer is not None:
             tracer.begin("load")
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            dt = self._clock() - t0
-            with self._lock:
-                self.wall_seconds += dt
-            if tracer is not None:
-                tracer.end("load")
+        with annotation("avdb.load"):
+            t0 = self._clock()
+            try:
+                yield
+            finally:
+                dt = self._clock() - t0
+                with self._lock:
+                    self.wall_seconds += dt
+                if tracer is not None:
+                    tracer.end("load")
 
     def total(self) -> float:
         with self._lock:
@@ -231,13 +286,21 @@ def stall_summary(queue_stalls: dict, wall_seconds: float | None = None) -> str:
 
 @contextlib.contextmanager
 def device_trace(trace_dir: str | None):
-    """jax.profiler capture when ``trace_dir`` is set; no-op otherwise."""
+    """``jax.profiler`` capture into ``trace_dir`` when set; no-op
+    otherwise.  The Python tracer is off (a Python-heavy load would be
+    millions of call events) and the host tracer is at level 2: the
+    capture holds XLA's own host spans, the program's ``avdb.*``
+    annotations and the device's operations — the same file the
+    benchmark's children capture."""
     if not trace_dir:
         yield
         return
     import jax
 
-    with jax.profiler.trace(trace_dir):
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    with jax.profiler.trace(trace_dir, profiler_options=options):
         yield
 
 

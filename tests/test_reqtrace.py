@@ -46,16 +46,18 @@ def test_ring_records_stages_and_wraps():
     rec = TraceRecorder(slots=4, sample=1.0)
     for i in range(10):
         t = rec.begin(f"id{i}", "point")
-        t.add("queue", 0.001 * i)
-        t.add("device", 0.002)
+        t.record("queue", t.t0_ns, t.t0_ns + 1_000 * i)
+        t.record("device", t.t0_ns + 1_000 * i, t.t0_ns + 1_000 * i + 2_000_000)
         rec.finish(t, 200)
     records = rec.records()
     assert len(records) == 4  # wrapped: only the last four survive
     ids = {r[0] for r in records}
     assert ids == {"id6", "id7", "id8", "id9"}
-    trace_id, kind, status, _t0, total, stages, _spans = records[-1]
+    trace_id, kind, status, t0, total, stages, spans = records[-1]
     assert kind == "point" and status == 200 and total >= 0
     assert dict(stages)["device"] == 0.002
+    # the spans keep what a span is: name, start, end, parent
+    assert spans[-1] == ("device", t0 + 9_000, t0 + 2_009_000, None)
 
 
 def test_ring_concurrent_writers_never_tear():
@@ -66,7 +68,7 @@ def test_ring_concurrent_writers_never_tear():
         try:
             for i in range(200):
                 t = rec.begin(f"w{wid}-{i}", "bulk")
-                t.add("device", 0.001)
+                t.record("device", t.t0_ns, t.t0_ns + 1_000_000)
                 rec.finish(t, 200)
         except Exception as err:  # pragma: no cover
             errors.append(err)
@@ -104,8 +106,8 @@ def test_stage_histograms_and_slow_log():
     t = rec.begin("fast", "point")
     rec.finish(t, 200)
     t = rec.begin("slowone", "region")
-    t.add("device", 0.02)
     t.t0_ns -= int(20e6)  # backdate 20ms: over the 5ms threshold
+    t.since("device", t.t0_ns / 1e9)
     rec.finish(t, 200)
     slow = [ln for ln in lines if "slow request" in ln]
     assert len(slow) == 1
@@ -119,9 +121,13 @@ def test_stage_histograms_and_slow_log():
 def test_span_cap_bounds_subspans():
     rec = TraceRecorder(sample=1.0)
     t = rec.begin("panel", "regions")
-    for i in range(200):
-        t.span(f"regions.chr{i}", 0.001)
+    with reqtrace.activate(t):
+        for i in range(200):
+            reqtrace.record_active(f"regions.chr{i}", t.t0_ns, t.t0_ns + 1000)
     assert len(t.spans) == t.MAX_SPANS
+    # the cap is on sub-spans: a stage recorded after it is never dropped
+    t.record("render", t.t0_ns, t.t0_ns + 5)
+    assert t.stages == [("render", 5e-9)]
 
 
 def test_chrome_events_merge_with_tracer_timebase():
@@ -130,7 +136,7 @@ def test_chrome_events_merge_with_tracer_timebase():
     tracer = Tracer(process_name="t")
     rec = TraceRecorder(sample=1.0)
     t = rec.begin("abc", "point")
-    t.add("queue", 0.001)
+    t.record("queue", t.t0_ns, t.t0_ns + 1_000_000)
     rec.finish(t, 200)
     with tracer.span("serve.batch", n=3):
         pass
@@ -151,14 +157,15 @@ def test_chrome_events_merge_with_tracer_timebase():
 def test_active_trace_attaches_engine_subspans():
     rec = TraceRecorder(sample=1.0)
     t = rec.begin("x", "regions")
-    reqtrace.span_active("orphan", 1.0)  # no active trace: no-op
+    reqtrace.record_active("orphan", 0, 1)  # no active trace: no-op
     with reqtrace.activate(t):
-        reqtrace.span_active("regions.chr8", 0.003)
-    reqtrace.span_active("late", 1.0)  # deactivated again
-    assert t.spans == [("regions.chr8", 0.003)]
+        reqtrace.record_active("regions.chr8", 10, 3_000_010)
+    reqtrace.record_active("late", 0, 1)  # deactivated again
+    assert t.spans == [("regions.chr8", 10, 3_000_010, "device")]
     with reqtrace.activate(None):  # None trace: transparent
-        reqtrace.span_active("nope", 1.0)
-    assert t.spans == [("regions.chr8", 0.003)]
+        reqtrace.record_active("nope", 0, 1)
+    assert t.spans == [("regions.chr8", 10, 3_000_010, "device")]
+    assert t.stages == []  # a sub-span is never a stage
 
 
 def test_background_sink_records_span_and_event():
@@ -175,7 +182,9 @@ def test_background_sink_records_span_and_event():
         reqtrace.set_background_sink(None, None)
     records = [r for r in rec.records() if r[1] == "background"]
     assert len(records) == 1
-    assert records[0][6][0][0] == "memtable.flush"
+    name, start_ns, end_ns, parent = records[0][6][-1]
+    assert name == "memtable.flush" and parent == "background"
+    assert records[0][3] == start_ns <= end_ns
     assert events == [("wal", "rotated")]
     # cleared sink: everything is a no-op again
     with reqtrace.background_span("x"):

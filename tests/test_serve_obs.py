@@ -186,8 +186,8 @@ def test_cursor_walk_pages_share_the_trace_id(store, pair):
     assert len(recs) == pages
     for r in recs:
         assert r[1] == "region"
-        assert any(name.startswith("region.chr8")
-                   for name, _s in r[6]), r[6]
+        assert any(name.startswith("region.chr8") and parent == "device"
+                   for name, _s, _e, parent in r[6]), r[6]
 
 
 def test_regions_panel_intervals_share_the_trace_id(store, pair):
@@ -202,7 +202,8 @@ def test_regions_panel_intervals_share_the_trace_id(store, pair):
         assert hdrs.get("X-Request-Id") == tid
         recs = _records_for(ctx, tid)
         assert len(recs) == 1 and recs[0][1] == "regions"
-        span_names = {name for name, _s in recs[0][6]}
+        span_names = {name for name, _s, _e, parent in recs[0][6]
+                      if parent == "device"}
         # every touched chromosome group's span hangs off the PANEL's id
         assert {"regions.chr8", "regions.chr1"} <= span_names
         stages = dict(recs[0][5])
