@@ -27,7 +27,10 @@ Records render as JSON **text** through the same codec the egress path uses
 (``store.variant_store.jsonb_dumps``): a ``RawJson`` annotation splices its
 stored text verbatim — zero parse/re-serialize on the hot read path — and
 rendering never mutates the snapshot (unlike ``get_ann``, which
-materializes parsed trees back into the column).
+materializes parsed trees back into the column).  ``_render_row`` is the
+scalar definition (region pages, rows that keep host strings, a lone
+miss); a point/bulk lookup renders a chromosome group's cache misses in
+one columnar pass (``render_rows``), byte for byte the same text.
 
 Rendered region responses sit in a small LRU keyed by store generation
 (``AVDB_SERVE_REGION_CACHE``), so a hot region costs one dict probe until
@@ -53,6 +56,7 @@ from __future__ import annotations
 import base64
 import contextlib
 import functools
+import itertools
 import json
 import os
 import re
@@ -295,6 +299,150 @@ def _render_row(seg, j: int, label: str, width: int) -> str:
             ann.append(f'"{c}":{jsonb_dumps(v)}')
     parts.append('"annotations":{' + ",".join(ann) + "}")
     return "{" + ",".join(parts) + "}"
+
+
+def decode_allele_rows(matrix: np.ndarray, lengths) -> list:
+    """Allele strings of a gathered ``[k, width]`` byte matrix: ONE ascii
+    decode of the whole block, then one slice per row by its stored length
+    (capped at the width) — ``decode_allele(row, length)`` for every row,
+    never the ``S<width>`` view's trailing-NUL rule (which is what
+    ``io.egress.decode_alleles`` applies to whole loader batches)."""
+    k, width = matrix.shape
+    text = matrix.tobytes().decode("ascii")
+    return [
+        text[o:o + n]
+        for o, n in zip(range(0, k * width, width),
+                        np.minimum(lengths, width).tolist())
+    ]
+
+
+def render_rows(shard, code: int, gids, clock=None) -> list:
+    """Many store rows (by global id, any order, repeats allowed) as JSON
+    text, in ``gids`` order — byte for byte ``[render_variant(shard, code,
+    g) for g in gids]``, which stays the scalar definition.
+
+    One columnar pass per touched segment: one vectorised locate for the
+    call, each numeric column gathered once by fancy index, alleles decoded
+    in bulk (:func:`decode_allele_rows`), each present annotation column
+    taken once, the quoted ``bin_index`` built once per distinct (level,
+    leaf), one format per row.  A row that keeps host strings — retained long
+    alleles, a digest PK — or whose stored length exceeds the width goes
+    through :func:`_render_row` (the retained strings first, else its
+    ``ValueError``).  ``clock`` (a :class:`_LookupClock`) is told how many
+    rows took each route, once per segment."""
+    gids = np.asarray(gids, np.int64)
+    n = int(gids.shape[0])
+    if n == 0:
+        return []
+    if n == 1:
+        # nothing to amortise: eight one-element gathers cost over twice
+        # the scalar renderer (PERF.md section 6, PR 27)
+        if clock is not None:
+            clock.scalar_rows += 1
+        return [render_variant(shard, code, int(gids[0]))]
+    label = chromosome_label(code)
+    width = shard.width
+    si, off = shard.locate_rows(gids)
+    first = int(si[0])
+    if bool((si == first).all()):
+        return _render_segment_rows(
+            shard.segments[first], off, label, width, clock
+        )
+    out: list = [None] * n
+    for s in np.unique(si).tolist():
+        at = np.flatnonzero(si == s)
+        texts = _render_segment_rows(
+            shard.segments[s], off[at], label, width, clock
+        )
+        for k, text in zip(at.tolist(), texts):
+            out[k] = text
+    return out
+
+
+def _render_segment_rows(seg, j: np.ndarray, label: str, width: int,
+                         clock) -> list:
+    """Rows ``j`` (local offsets, any order) of one segment as JSON text:
+    :func:`_render_row`'s bytes, assembled column by column."""
+    cols, obj = seg.cols, seg.obj
+    ref_len = cols["ref_len"][j]
+    alt_len = cols["alt_len"][j]
+    # rows the columnar pass does not assemble: retained host strings
+    # (long alleles, digest PK) and lengths the device bytes cannot hold
+    scalar = (ref_len > width) | (alt_len > width)
+    for name in (_LONG_ALLELES, _DIGEST_PK):
+        col = obj[name]
+        if col is not None:
+            scalar |= np.fromiter(
+                (v is not None for v in col[j].tolist()), np.bool_,
+                count=j.shape[0],
+            )
+    n_scalar = int(np.count_nonzero(scalar))
+    if clock is not None:
+        clock.batch_rows += j.shape[0] - n_scalar
+        clock.scalar_rows += n_scalar
+    if n_scalar:
+        out: list = [None] * j.shape[0]
+        for k in np.flatnonzero(scalar).tolist():
+            out[k] = _render_row(seg, int(j[k]), label, width)
+        keep = np.flatnonzero(~scalar)
+        if keep.shape[0]:
+            texts = _render_columnar(
+                seg, j[keep], ref_len[keep], alt_len[keep], label
+            )
+            for k, text in zip(keep.tolist(), texts):
+                out[k] = text
+        return out
+    return _render_columnar(seg, j, ref_len, alt_len, label)
+
+
+def _render_columnar(seg, j: np.ndarray, ref_len: np.ndarray,
+                     alt_len: np.ndarray, label: str) -> list:
+    """The columnar pass proper: every row of ``j`` has device-width
+    alleles and no retained host string."""
+    cols = seg.cols
+    refs = decode_allele_rows(seg.ref[j], ref_len)
+    alts = decode_allele_rows(seg.alt[j], alt_len)
+    # quoted bin_index once per distinct (level, leaf) of the call
+    bins = list(zip(cols["bin_level"][j].tolist(),
+                    cols["leaf_bin"][j].tolist()))
+    quoted = {
+        key: json.dumps(_bin_path(label, *key)) for key in set(bins)
+    }
+    # annotations: each present column taken once, JSONB_COLUMNS order
+    ann = None
+    for c in JSONB_COLUMNS:
+        col = seg.obj[c]
+        if col is None:
+            continue
+        if ann is None:
+            ann = [""] * j.shape[0]
+        for k, v in enumerate(col[j].tolist()):
+            if v is not None:
+                field = f'"{c}":{jsonb_dumps(v)}'
+                ann[k] = f"{ann[k]},{field}" if ann[k] else field
+    if ann is None:
+        ann = itertools.repeat("")
+    out = []
+    for pos, ref, alt, rs, multi, adsp, bin_index, fields in zip(
+        cols["pos"][j].tolist(), refs, alts, cols["ref_snp"][j].tolist(),
+        cols["is_multi_allelic"][j].tolist(),
+        cols["is_adsp_variant"][j].tolist(), map(quoted.get, bins), ann,
+    ):
+        metaseq = f"{label}:{pos}:{ref}:{alt}"
+        if rs >= 0:
+            pk, ref_snp = f"{metaseq}:rs{rs}", f'"rs{rs}"'
+        else:
+            pk, ref_snp = metaseq, "null"
+        out.append(
+            f'{{"primary_key":"{pk}","metaseq_id":"{metaseq}"'
+            f',"chromosome":"{label}","position":{pos}'
+            f',"ref":"{ref}","alt":"{alt}","ref_snp":{ref_snp}'
+            f',"is_multi_allelic":{"true" if multi else "false"}'
+            ',"is_adsp_variant":'
+            f'{"null" if adsp < 0 else ("true" if adsp else "false")}'
+            f',"bin_index":{bin_index},"annotations":{{{fields}}}}}'
+        )
+    return out
 
 
 def _ann_number(seg, j: int, column: str, field: str):
@@ -671,22 +819,28 @@ class RegionsResult:
 
 
 class _LookupClock:
-    """One ``lookup_many`` call's sub-stage seconds and render-cache
-    tallies (one call runs on one thread: plain integers, no lock).  :meth:`span` times one sub-stage of one chromosome group
-    where the work happens: nanoseconds summed per stage over the call, a
-    sub-span on the calling thread's active request stage (true start and
-    end, parent ``device``), and a profiler annotation ``avdb.<stage>`` —
-    about ten a call, never one per id."""
+    """One ``lookup_many`` call's sub-stage seconds and render tallies
+    (one call runs on one thread: plain integers, no lock).  :meth:`span`
+    times one sub-stage of one chromosome group where the work happens:
+    nanoseconds summed per stage over the call, a sub-span on the calling
+    thread's active request stage (true start and end, parent ``device``),
+    and a profiler annotation ``avdb.<stage>`` — about ten a call, never
+    one per id.  The tallies are added once per chromosome group (render
+    cache) and once per touched segment (renderer route)."""
 
-    __slots__ = ("ns", "found", "misses")
+    __slots__ = ("ns", "found", "misses", "batch_rows", "scalar_rows")
 
     def __init__(self):
         self.ns = dict.fromkeys(reqtrace.LOOKUP_STAGES, 0)
-        #: ids found (each goes through the render cache once) and the
-        #: cache misses among them — the miss path counts itself, so the
-        #: hit path pays nothing for being counted
+        #: ids found (each is answered through the render cache) and the
+        #: distinct rows among them that were not in it
         self.found = 0
         self.misses = 0
+        #: of those misses: rows the columnar pass assembled, and rows
+        #: that went through the scalar ``_render_row`` (retained host
+        #: strings, an over-width length, a group of one miss)
+        self.batch_rows = 0
+        self.scalar_rows = 0
 
     @contextlib.contextmanager
     def span(self, stage: str, **args):
@@ -710,8 +864,11 @@ class QueryEngine:
     #: rendered point-record LRU capacity (entries).  Keyed by
     #: (generation, chromosome, global id): a serving generation's rows
     #: are immutable, so a hot variant renders once per generation and
-    #: costs a dict probe afterwards — rendering is the dominant term of
-    #: a point drain (~half the microbatch budget).
+    #: costs a dict probe afterwards.  What it saves is the miss path of
+    #: ``_render_group``: a point drain's one or two misses a chromosome
+    #: render row by row (``_render_row``), a bulk call's thousands in
+    #: one columnar pass (``render_rows``) — uniform bulk draws over
+    #: millions of rows hardly ever hit it (PERF.md section 5).
     POINT_RENDER_CACHE = 1 << 16
     #: and a byte ceiling on the cached text: records carrying large
     #: spliced RawJson annotation blobs (tens of KB each) must not pin
@@ -818,8 +975,13 @@ class QueryEngine:
         #: the GIL, read by ``/stats`` (``render_cache``)
         self.render_cache_hits = 0
         self.render_cache_misses = 0
+        #: of the misses, by renderer route (``/stats`` ``render_batch``):
+        #: rows of the columnar pass / rows of the scalar ``_render_row``
+        self.render_batch_rows = 0
+        self.render_scalar_rows = 0
         self._lookup_hist = None
         self._m_render_hits = self._m_render_misses = None
+        self._m_batch_rows = self._m_scalar_rows = None
         if registry is not None:
             self._lookup_hist = reqtrace.stage_histograms(
                 registry, reqtrace.LOOKUP_STAGES
@@ -831,6 +993,14 @@ class QueryEngine:
             self._m_render_misses = registry.counter(
                 "avdb_render_cache_misses_total",
                 "found ids rendered fresh (locate, render, decode)",
+            )
+            self._m_batch_rows, self._m_scalar_rows = (
+                registry.counter(
+                    "avdb_render_batch_rows_total",
+                    "rows rendered fresh, by renderer route",
+                    labels={"path": path},
+                )
+                for path in ("columnar", "scalar")
             )
 
     # -- point / bulk -------------------------------------------------------
@@ -899,31 +1069,36 @@ class QueryEngine:
                     shard, code, pos, h, ref, alt, ref_len, alt_len
                 )
             with clock.span("lookup.rows", chrom=code, n=len(idxs)):
-                generation = snap.generation
-                for k, i in enumerate(idxs):
-                    if found[k]:
-                        out[i] = self._render_cached(
-                            shard, code, int(gid[k]), generation, clock
-                        )
-                clock.found += int(np.count_nonzero(found))
+                at = np.flatnonzero(found)
+                texts = self._render_group(
+                    shard, code, gid[at].tolist(), snap.generation, clock
+                )
+                for k, text in zip(at.tolist(), texts):
+                    out[idxs[k]] = text
         return out
 
     def _lookup_done(self, clock: "_LookupClock") -> None:
         """One ``lookup_many`` call's accounts: each sub-stage's seconds
         (summed over the call's chromosome groups; 0 for a stage the call
         never entered) observed ONCE, so the four means add up to the
-        ``device`` stage's; the render-cache tallies added once — no lock
-        and no metric call per id."""
+        ``device`` stage's; the render-cache and renderer-route tallies
+        added once — no lock and no metric call per id."""
         hits = clock.found - clock.misses
         self.render_cache_hits += hits
         self.render_cache_misses += clock.misses
+        self.render_batch_rows += clock.batch_rows
+        self.render_scalar_rows += clock.scalar_rows
         if self._lookup_hist is not None:
             for stage, ns in clock.ns.items():
                 self._lookup_hist[stage].observe(ns / 1e9)
-            if hits:
-                self._m_render_hits.inc(hits)
-            if clock.misses:
-                self._m_render_misses.inc(clock.misses)
+            for metric, n in (
+                (self._m_render_hits, hits),
+                (self._m_render_misses, clock.misses),
+                (self._m_batch_rows, clock.batch_rows),
+                (self._m_scalar_rows, clock.scalar_rows),
+            ):
+                if n:
+                    metric.inc(n)
 
     def _probe_group(self, shard, code: int, pos, h, ref, alt,
                      ref_len, alt_len):
@@ -1006,44 +1181,72 @@ class QueryEngine:
                         shard, k.min(), k.max(), len(idxs)
                     )
         with clock.span("lookup.rows", n=n):
-            generation = snap.generation
-            for i, (code, _pos, _ref, _alt) in enumerate(parsed):
-                if found[i]:
-                    out[i] = self._render_cached(
-                        store.shards[code], code, int(gid[i]), generation,
-                        clock,
-                    )
-            clock.found += int(np.count_nonzero(found))
+            at = np.flatnonzero(found)
+            codes = chrom[at]
+            for code in np.unique(codes).tolist():
+                sel = at[codes == code]
+                texts = self._render_group(
+                    store.shards[code], code, gid[sel].tolist(),
+                    snap.generation, clock,
+                )
+                for i, text in zip(sel.tolist(), texts):
+                    out[i] = text
         return out
 
-    def _render_cached(self, shard, code: int, gid: int,
-                       generation: int, clock: "_LookupClock") -> str:
-        """Point-record render through the generation-keyed LRU (stale
-        generations age out with everything else; their keys can never be
-        probed again).  A miss counts itself on the call's ``clock``."""
-        key = (generation, code, gid)
+    def _render_group(self, shard, code: int, gids: list, generation: int,
+                      clock: "_LookupClock") -> list:
+        """One chromosome group's found rows (global ids, request order,
+        repeats allowed) as JSON text through the generation-keyed LRU
+        (stale generations age out with everything else; their keys can
+        never be probed again).  Two lock holds a call, never one per id:
+        the first answers the hits and moves them to the end; the distinct
+        misses render in one :func:`render_rows` pass outside the lock;
+        the second inserts them and evicts to both ceilings.  A gid twice
+        in one call renders once and tallies one miss and one hit; tallies
+        go to the call's ``clock``."""
+        clock.found += len(gids)
+        texts = []
+        #: distinct missing key -> its place in the render; and per
+        #: unanswered id (its place in ``texts``, its key's place)
+        missing: dict = {}
+        fill = []
         with self._render_lock:
-            text = self._render_cache.get(key)
-            if text is not None:
-                self._render_cache.move_to_end(key)
-                return text
-        clock.misses += 1
-        text = render_variant(shard, code, gid)
+            cache = self._render_cache
+            for k, gid in enumerate(gids):
+                key = (generation, code, gid)
+                text = cache.get(key)
+                if text is None:
+                    fill.append((k, missing.setdefault(key, len(missing))))
+                else:
+                    cache.move_to_end(key)
+                texts.append(text)
+        if not fill:
+            return texts
+        clock.misses += len(missing)
+        fresh = render_rows(
+            shard, code, [key[2] for key in missing], clock
+        )
         with self._render_lock:
-            # two threads can race the same miss: replace, don't
-            # double-count
-            old = self._render_cache.pop(key, None)
-            if old is not None:
-                self._render_cache_bytes -= len(old)
-            self._render_cache[key] = text
-            self._render_cache_bytes += len(text)
-            while self._render_cache and (
-                len(self._render_cache) > self.POINT_RENDER_CACHE
-                or self._render_cache_bytes > self.POINT_RENDER_CACHE_BYTES
+            cache = self._render_cache
+            size = self._render_cache_bytes
+            for key, text in zip(missing, fresh):
+                # another thread can race the same miss: replace, don't
+                # double-count
+                old = cache.pop(key, None)
+                if old is not None:
+                    size -= len(old)
+                cache[key] = text
+                size += len(text)
+            while cache and (
+                len(cache) > self.POINT_RENDER_CACHE
+                or size > self.POINT_RENDER_CACHE_BYTES
             ):
-                _, old = self._render_cache.popitem(last=False)
-                self._render_cache_bytes -= len(old)
-        return text
+                _, old = cache.popitem(last=False)
+                size -= len(old)
+            self._render_cache_bytes = size
+        for k, at in fill:
+            texts[k] = fresh[at]
+        return texts
 
     # -- region -------------------------------------------------------------
 

@@ -142,6 +142,8 @@ def stats_payload(ctx) -> str:
         "device_lookup": device_lookup_state(),
         "render_cache": {"hits": ctx.engine.render_cache_hits,
                          "misses": ctx.engine.render_cache_misses},
+        "render_batch": {"rows": ctx.engine.render_batch_rows,
+                         "scalar_rows": ctx.engine.render_scalar_rows},
     }
     if ctx.engine.residency is not None:
         stats["residency"] = ctx.engine.residency.stats()

@@ -869,6 +869,13 @@ class ChromosomeShard:
         si = int(starts.searchsorted(gid, side="right")) - 1
         return self.segments[si], gid - int(starts[si])
 
+    def locate_rows(self, gids) -> tuple[np.ndarray, np.ndarray]:
+        """(segment index, local offset) int64 arrays for many global row
+        ids, in the ids' order — :meth:`locate_row` for a batch: one
+        searchsorted for the whole call (the serving path's columnar
+        renderer gathers each touched segment's columns through these)."""
+        return self._locate(gids)
+
     def get_col(self, name: str, ids):
         seg, off = self._locate(ids)
         out = np.empty(seg.shape, dtype=dict(_NUMERIC_COLUMNS)[name])
