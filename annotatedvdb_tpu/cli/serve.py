@@ -349,10 +349,12 @@ def _run_single(args, log) -> int:
                     budget // n_dev, registry=registry, log=log,
                     placement=chromosome_placement(n_dev),
                     devices=list(mesh.devices.flat),
+                    max_batch=args.maxBatch,
                 )
             else:
                 residency = ResidencyManager(budget, registry=registry,
-                                             log=log)
+                                             log=log,
+                                             max_batch=args.maxBatch)
         manager = SnapshotManager(
             args.storeDir, log=log,
             ttl_s=(args.snapshotTtlMs / 1000.0

@@ -70,7 +70,7 @@ from annotatedvdb_tpu.export.stream import (
 )
 from annotatedvdb_tpu.obs import reqtrace as reqtrace_mod
 from annotatedvdb_tpu.obs.metrics import MetricsRegistry
-from annotatedvdb_tpu.serve.batcher import QueueFull
+from annotatedvdb_tpu.serve.batcher import QueueFull, batch_annotation
 from annotatedvdb_tpu.serve.engine import (
     QueryEngine,
     QueryError,
@@ -359,9 +359,12 @@ class LoopBatcher:
                 live.append(item)
         if shed and self._m_deadline_shed is not None:
             self._m_deadline_shed.inc(shed)
-        batch = live
-        if not batch:
+        if not live:
             return
+        with batch_annotation([p for _f, _q, p, _d, _t, _e in live]):
+            self._execute(live)
+
+    def _execute(self, batch: list) -> None:
         t_exec = time.perf_counter_ns()
         for _f, _q, _p, _d, trace, t_enq in batch:
             if trace is not None:

@@ -44,6 +44,7 @@ from annotatedvdb_tpu.serve.resilience import DeadlineExceeded
 from annotatedvdb_tpu.utils import faults
 from annotatedvdb_tpu.utils.pipeline import StageStats
 from annotatedvdb_tpu.utils.locks import make_lock
+from annotatedvdb_tpu.utils.profiling import annotation
 
 #: batch-fill histogram edges (fraction of max_batch actually used)
 BATCH_FILL_EDGES = (0.05, 0.1, 0.25, 0.5, 0.75, 0.9, 1.0)
@@ -120,6 +121,16 @@ def resolve_stats_knobs(stats_max, device_min):
             os.environ.get("AVDB_SERVE_STATS_DEVICE_MIN", "") or 16
         )
     return max(int(stats_max), 1), max(int(device_min), 0)
+
+
+def batch_annotation(parsed: list):
+    """``avdb.serve.batch`` on the profiler's clock around one drain of
+    either batcher: ``n`` ids in ``groups`` chromosome groups (one probe
+    each).  A capture then shows the drains, what lies between them
+    (waiting for company, the sockets) and what each spent outside the
+    engine (shedding, handing results back)."""
+    return annotation("avdb.serve.batch", n=len(parsed),
+                      groups=len({p[0] for p in parsed}))
 
 
 class _Pending:
@@ -331,6 +342,10 @@ class QueryBatcher:
         batch = self._shed_expired(batch)
         if not batch:
             return
+        with batch_annotation([p.parsed for p in batch]):
+            self._execute(batch)
+
+    def _execute(self, batch: list) -> None:
         t_exec = time.perf_counter_ns()
         for pending in batch:
             if pending.trace is not None:

@@ -102,14 +102,17 @@ class _Entry:
     on a touch path would lazily materialize its full combined-key array
     under the manager lock."""
 
-    __slots__ = ("seg", "nbytes", "score", "resident", "key_min", "key_max",
-                 "device")
+    __slots__ = ("seg", "nbytes", "score", "resident", "pending",
+                 "key_min", "key_max", "device")
 
     def __init__(self, seg, nbytes: int, device: int | None = None):
         self.seg = seg
         self.nbytes = nbytes
         self.score = 0.0
         self.resident = False
+        #: planned resident, its upload (and probe-program warm) not landed
+        #: yet: counted by the budget, not reported resident
+        self.pending = False
         self.key_min, self.key_max = _key_bounds(seg)
         #: placement device index (None = default device / no placement)
         self.device = device
@@ -132,7 +135,8 @@ class ResidencyManager:
                  min_rows: int | None = None,
                  async_upload: bool | None = None,
                  plan_interval_s: float | None = None,
-                 placement: dict | None = None, devices=None):
+                 placement: dict | None = None, devices=None,
+                 max_batch: int | None = None):
         if budget_bytes is None:
             budget_bytes = budget_from_env() or 0
         self.budget = max(int(budget_bytes), 0)
@@ -153,6 +157,14 @@ class ResidencyManager:
         # event loop, and a multi-hundred-MB host->device transfer must
         # never stall it.  Tests pass async_upload=False for determinism.
         self._async_upload = True if async_upload is None else bool(async_upload)
+        # the most ids a point microbatch's probe can hold (the batcher's
+        # ``max_batch``, resolved where its env default lives): every query
+        # shape up to it is run once against an uploaded segment BEFORE the
+        # segment is installed and reported resident, so no reader's
+        # request pays a compile
+        from annotatedvdb_tpu.serve.batcher import resolve_batch_knobs
+
+        self._max_probe_queries = resolve_batch_knobs(max_batch, None, None)[0]
         self._uploader = None  # lazily-built single-thread executor
         if min_rows is None:
             from annotatedvdb_tpu.store.variant_store import DEVICE_SEGMENT_MIN
@@ -295,7 +307,7 @@ class ResidencyManager:
             # pin — all traffic serves from host)
             evict = [e for e in entries if e.resident]
             for e in evict:
-                e.resident = False
+                e.resident = e.pending = False
             return evict, []
         # greedy hottest-first pack into the budget; residents rank with a
         # HYSTERESIS bonus so a near-tied challenger never thrashes the
@@ -319,12 +331,17 @@ class ResidencyManager:
             want_ids.add(id(e))
             used[e.device] = spent + e.nbytes
         evict, upload = [], []
+        # planned resident and reported resident part here, in one lock
+        # hold: a segment whose upload is ahead of it is ``pending`` from
+        # the moment the budget counts it (_do_uploads clears it)
+        uploads = self._upload_enabled()
         for e in entries:
             if e.resident and id(e) not in want_ids:
-                e.resident = False
+                e.resident = e.pending = False
                 evict.append(e)
             elif not e.resident and id(e) in want_ids:
                 e.resident = True
+                e.pending = uploads
                 upload.append(e)
         return evict, upload
 
@@ -380,16 +397,22 @@ class ResidencyManager:
                     continue  # a newer plan evicted it before we got here
             try:
                 # the retrying device_put path (utils.retry) rides
-                # inside _ensure_device_cache
-                e.seg._ensure_device_cache(device=self._device_for(e.device))
+                # inside _build_device_cache
+                dev = e.seg._device or e.seg._build_device_cache(
+                    device=self._device_for(e.device))
+                # every query shape a point microbatch can probe with,
+                # compiled and run once here, against the copy no request
+                # can see yet — never inside a request
+                e.seg.warm_device_probe(self._max_probe_queries, dev)
                 with self._lock:
-                    # a plan may have evicted e WHILE the transfer ran
-                    # (its seg._device=None landed before the cache did);
-                    # an unaccounted cache with resident=False would be
-                    # invisible to every future plan — drop it now
+                    # a plan may have evicted e WHILE the transfer ran: a
+                    # cache installed on a resident=False entry would be
+                    # invisible to every future plan — it is dropped here,
+                    # never installed
                     if not e.resident:
-                        e.seg._device = None
                         continue
+                    e.seg._device = dev
+                    e.pending = False
                 if self._m_uploads is not None:
                     self._m_uploads.inc()
             except Exception as err:
@@ -399,7 +422,7 @@ class ResidencyManager:
                 # that never landed and no future plan re-uploads them
                 with self._lock:
                     for stale in upload[i:]:
-                        stale.resident = False
+                        stale.resident = stale.pending = False
                 self.log(f"residency: upload failed, serving from "
                          f"host ({err})")
                 break
@@ -409,6 +432,8 @@ class ResidencyManager:
     # -- introspection ------------------------------------------------------
 
     def resident_bytes(self) -> int:
+        """Bytes the plan holds on the device, uploads in flight included
+        (the budget's own count; the gauge)."""
         with self._lock:
             return sum(e.nbytes for e in self._entries.values() if e.resident)
 
@@ -419,7 +444,10 @@ class ResidencyManager:
             out = {
                 "budget_bytes": self.budget,
                 "candidates": len(entries),
-                "resident": sum(1 for e in entries if e.resident),
+                # landed: uploaded and its probe programs compiled
+                "resident": sum(
+                    1 for e in entries if e.resident and not e.pending
+                ),
                 "resident_bytes": sum(
                     e.nbytes for e in entries if e.resident
                 ),

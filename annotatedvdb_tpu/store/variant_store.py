@@ -46,6 +46,7 @@ import numpy as np
 from annotatedvdb_tpu.types import chromosome_label, decode_allele
 from annotatedvdb_tpu.utils import faults
 from annotatedvdb_tpu.utils import io as tio
+from annotatedvdb_tpu.utils.arrays import next_pow2
 from annotatedvdb_tpu.utils.strings import deep_update
 
 
@@ -107,6 +108,24 @@ _IDENTITY_COLUMNS = ("pos", "h", "ref_len", "alt_len")
 # searchsorted (and never on CPU backends — see _device_lookup_enabled).
 DEVICE_SEGMENT_MIN = 1 << 18
 DEVICE_QUERY_MIN = 1 << 12
+
+# Smallest query capacity a device probe is padded to.  A point microbatch
+# holds whatever the moment gives it — 1..max_batch ids a chromosome group —
+# and each power-of-two bucket is a program of its own, so the buckets a
+# server's microbatches can take are few and known: from this floor up to
+# the batcher's ``max_batch`` (32, 64, 128, 256 at the default), and the
+# residency manager runs each once before it reports a segment resident
+# (``Segment.warm_device_probe``): no reader's request compiles.  All of
+# them, eagerly: a server does not know how many readers it will get, a
+# group over 32 ids takes a larger bucket, and one compiled after
+# ``resident`` would be compiled inside somebody's request.  Where the
+# floor sits is measured (one v5e, 2^22 rows, 32 concurrent point readers,
+# PR 28): with every probe padded to 256 the program ran 0.61 ms a probe and
+# a drain's three probes took 9.1-9.2 ms; padded to 32, 0.17 ms and
+# 7.7-8.0 ms.  Under 32 a bucket saves nothing: a lone probe's wall —
+# dispatch, six uploads, two fetches — is 2.28-2.33 ms at 1 to 32 queries.
+# Sentinel queries cannot match a real row.
+DEVICE_QUERY_FLOOR = 1 << 5
 
 # The device probe must first UPLOAD the segment's identity columns
 # (~110B/row); that transfer has to amortize before the kernel beats a
@@ -265,8 +284,16 @@ _DEVICE_PROBE_FAILURE_HOOK = None
 #: cumulative device membership probes of this process (the
 #: ``utils.retry.stats`` pattern): load summaries report their session's
 #: delta, so a reader can tell whether a load reached the device lookup
-#: path at all
-probe_stats = {"device_probes": 0, "device_queries": 0}
+#: path at all; ``padded_queries`` is what the device was asked after
+#: padding (:func:`probe_query_capacity`), added once per probe
+probe_stats = {"device_probes": 0, "device_queries": 0, "padded_queries": 0}
+
+
+def probe_query_capacity(nq: int) -> int:
+    """The query shape a device probe of ``nq`` queries runs at: the next
+    power of two, never under :data:`DEVICE_QUERY_FLOOR`.  The ONE place
+    the shapes of ``lookup_in_sorted`` programs are decided."""
+    return max(next_pow2(nq), DEVICE_QUERY_FLOOR)
 
 
 def device_lookup_state(base: dict | None = None) -> dict:
@@ -652,6 +679,7 @@ class Segment:
             else:
                 probe_stats["device_probes"] += 1
                 probe_stats["device_queries"] += nq
+                probe_stats["padded_queries"] += probe_query_capacity(nq)
                 return out
         self._numpy_query_volume += nq
         lo = np.searchsorted(self.key, qkey, side="left")
@@ -687,12 +715,17 @@ class Segment:
         and can't match a real query).  ``device`` pins the destination
         (the residency manager's chromosome->device placement); None keeps
         the default device — the historical single-device layout."""
-        if self._device is not None:
-            return
+        if self._device is None:
+            self._device = self._build_device_cache(device)
+
+    def _build_device_cache(self, device=None) -> tuple:
+        """The device copy :meth:`_ensure_device_cache` installs, built and
+        handed back: the residency manager installs it itself, once the
+        probe programs have run against it."""
         from annotatedvdb_tpu.utils.arrays import POS_SENTINEL, pad_pow2
         from annotatedvdb_tpu.utils.retry import device_put
 
-        self._device = tuple(
+        return tuple(
             device_put(x, device=device) for x in (
                 pad_pow2(self.cols["pos"], POS_SENTINEL),
                 pad_pow2(self.cols["h"], 0),
@@ -707,22 +740,44 @@ class Segment:
         against an HBM-resident cache of this segment's identity columns
         (``dev``: the caller-captured tuple — eviction-race-safe; None
         builds the cache, which managed segments never request).  Query
-        arrays are padded to a power of two (sentinel positions can't
-        match) so compile count stays logarithmic in batch size."""
+        arrays are padded to :func:`probe_query_capacity` (sentinel
+        positions can't match a real row's) so compile count stays
+        logarithmic in batch size and the small probes share one
+        program."""
         from annotatedvdb_tpu.ops.dedup import lookup_in_sorted_jit
-        from annotatedvdb_tpu.utils.arrays import POS_SENTINEL, pad_pow2
+        from annotatedvdb_tpu.utils.arrays import POS_SENTINEL, pad_rows
 
         if dev is None:
             self._ensure_device_cache()
             dev = self._device
         nq = pos.shape[0]
+        cap = probe_query_capacity(nq)
         found, index = lookup_in_sorted_jit(
             *dev,
-            pad_pow2(pos, POS_SENTINEL), pad_pow2(h, 0),
-            pad_pow2(ref, 0), pad_pow2(alt, 0),
-            pad_pow2(ref_len, 0), pad_pow2(alt_len, 0),
+            pad_rows(pos, cap, POS_SENTINEL), pad_rows(h, cap, 0),
+            pad_rows(ref, cap, 0), pad_rows(alt, cap, 0),
+            pad_rows(ref_len, cap, 0), pad_rows(alt_len, cap, 0),
         )
         return np.asarray(found)[:nq], np.asarray(index)[:nq]
+
+    def warm_device_probe(self, max_queries: int, dev: tuple) -> None:
+        """Run the probe program once at every query capacity a probe of
+        1..``max_queries`` queries can take (:func:`probe_query_capacity`
+        says which), on all-zero queries, against the device copy ``dev``
+        — so the programs a point microbatch can need are compiled and
+        loaded before traffic asks for them."""
+        cols = self.cols
+        like = (cols["pos"], cols["h"], self.ref, self.alt,
+                cols["ref_len"], cols["alt_len"])
+        cap = probe_query_capacity(1)
+        while True:  # position 0 is no row's: nothing matches
+            self._probe_device(
+                *(np.zeros((cap,) + a.shape[1:], a.dtype) for a in like),
+                dev=dev,
+            )
+            if cap >= max_queries:
+                return
+            cap = probe_query_capacity(cap + 1)
 
     # -- mutation -----------------------------------------------------------
 

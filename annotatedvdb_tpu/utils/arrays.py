@@ -24,11 +24,15 @@ def mesh_capacity(n: int, n_shards: int) -> int:
     return cap + (-cap) % n_shards
 
 
-def pad_pow2(a: np.ndarray, fill) -> np.ndarray:
-    """Pad the leading axis to the next power of two with ``fill``."""
+def pad_rows(a: np.ndarray, cap: int, fill) -> np.ndarray:
+    """Pad the leading axis up to ``cap`` rows with ``fill``."""
     n = a.shape[0]
-    cap = next_pow2(n)
-    if cap == n:
+    if cap <= n:
         return a
     pad = np.full((cap - n,) + a.shape[1:], fill, a.dtype)
     return np.concatenate([a, pad], axis=0)
+
+
+def pad_pow2(a: np.ndarray, fill) -> np.ndarray:
+    """Pad the leading axis to the next power of two with ``fill``."""
+    return pad_rows(a, next_pow2(a.shape[0]), fill)

@@ -217,11 +217,10 @@ def test_stale_snapshot_cannot_regovern_backwards():
 
 def test_upload_evicted_mid_transfer_drops_cache(monkeypatch):
     """A segment evicted WHILE its host->device transfer is in flight
-    must not keep the cache: the plan's ``seg._device = None`` can land
-    before the transfer does, and an installed cache on a
-    ``resident=False`` entry would be invisible to every future plan —
-    unaccounted, unevictable HBM.  The uploader re-checks residency after
-    the transfer and drops the orphan."""
+    must not keep the cache: an installed cache on a ``resident=False``
+    entry would be invisible to every future plan — unaccounted,
+    unevictable HBM.  The uploader checks residency after the transfer
+    (and the probe programs' warm) and installs the copy only then."""
     from annotatedvdb_tpu.serve.residency import _Entry
 
     store, shard, _queries = _build_store(1)
@@ -231,22 +230,51 @@ def test_upload_evicted_mid_transfer_drops_cache(monkeypatch):
         budget_bytes=1 << 20, upload=True, min_rows=1, async_upload=False
     )
     entry = _Entry(seg, device_cache_bytes(seg, WIDTH))
-    entry.resident = True
+    entry.resident = entry.pending = True
     manager._entries = {id(seg): entry}
 
-    real = Segment._ensure_device_cache
+    real = Segment._build_device_cache
+    built = []
 
-    def racing_upload(self):
-        real(self)
-        # a newer plan evicts mid-transfer: its seg._device = None is
-        # immediately overwritten by the landing cache, leaving exactly
-        # the end-state the post-transfer re-check must clean up
-        entry.resident = False
+    def racing_upload(self, device=None):
+        built.append(real(self, device))
+        # a newer plan evicts mid-transfer
+        entry.resident = entry.pending = False
+        return built[-1]
 
-    monkeypatch.setattr(Segment, "_ensure_device_cache", racing_upload)
+    monkeypatch.setattr(Segment, "_build_device_cache", racing_upload)
     manager._do_uploads([entry])
-    assert seg._device is None
+    assert built and seg._device is None
     assert manager.resident_bytes() == 0
+    assert manager.stats()["resident"] == 0
+
+
+def test_planned_resident_is_pending_from_the_same_lock_hold():
+    """Between a plan and its apply (another thread's ``/stats`` read can
+    fall there) a segment the budget already counts is not reported
+    resident: ``pending`` is set where ``resident`` is."""
+    from annotatedvdb_tpu.serve.residency import _Entry
+
+    store, shard, _queries = _build_store(1)
+    seg = shard.segments[0]
+    manager = ResidencyManager(
+        budget_bytes=1 << 20, upload=True, min_rows=1, async_upload=False
+    )
+    entry = _Entry(seg, device_cache_bytes(seg, WIDTH))
+    entry.score = 1.0
+    manager._entries = {id(seg): entry}
+    with manager._lock:
+        plan = manager._plan([entry])
+    assert plan == ([], [entry])
+    between = manager.stats()
+    assert between["resident"] == 0
+    assert between["resident_bytes"] == entry.nbytes
+    manager._apply(plan)
+    assert manager.stats()["resident"] == 1 and seg._device is not None
+    with manager._lock:
+        entry.score = 0.0
+        assert manager._plan([entry]) == ([entry], [])
+    assert not entry.resident and not entry.pending
 
 
 def test_evict_applied_after_reupload_keeps_cache():
