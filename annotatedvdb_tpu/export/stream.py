@@ -202,7 +202,7 @@ def stream_payload(engine, params: dict,
 
 def _pack_breakered(engine, code: int, chunk: dict, host_only: bool):
     """The pack call behind the engine's device circuit breaker (the
-    ``_probe_group`` discipline): an open group — or a device failure,
+    ``_launch_group`` discipline): an open group — or a device failure,
     which the breaker records — pins this batch to the numpy twin.
     Either way the bytes are identical; only placement changes."""
     breaker = getattr(engine, "breaker", None)

@@ -32,6 +32,9 @@ TWINS: dict = {
     "ops.dedup.mark_batch_duplicates_multi_jit":
         "ops.dedup.mark_batch_duplicates_multi_np",
     "ops.dedup.lookup_in_sorted_jit": "ops.dedup.lookup_in_sorted_np",
+    # the store's device probe: the same search, its queries one packed
+    # buffer in and the index alone out (found is index >= 0)
+    "ops.dedup.lookup_in_sorted_packed_jit": "ops.dedup.lookup_in_sorted_np",
     "ops.dedup.lookup_in_sorted_multi_jit":
         "ops.dedup.lookup_in_sorted_multi_np",
     "ops.hashing.allele_hash_jit": "ops.hashing.allele_hash_np",

@@ -102,6 +102,79 @@ def lookup_in_sorted(
     return found, index
 
 
+#: bytes of one query in the packed buffer beside its two alleles: pos,
+#: hash, ref_len, alt_len, four bytes each
+_QUERY_WORDS_BYTES = 16
+
+
+def pack_queries(pos, h, ref, alt, ref_len, alt_len, cap: int):
+    """A probe's six query columns as ONE host buffer, ``cap`` queries
+    wide: ``uint8 [cap * (16 + 2 * width)]``, column after column — pos,
+    h, ref, alt, ref_len, alt_len, each contiguous, the 32-bit ones in the
+    host's (little-endian) bytes — so six copies fill it and one upload
+    carries it.  Rows past the queries are padding: the sentinel position
+    (no row's), zeros elsewhere.  :func:`lookup_in_sorted_packed` takes
+    it apart on the device."""
+    import numpy as np
+
+    from annotatedvdb_tpu.utils.arrays import POS_SENTINEL
+
+    nq, width = ref.shape
+    buf = np.zeros(cap * (_QUERY_WORDS_BYTES + 2 * width), np.uint8)
+    words = buf[: cap * 8].view(np.int32)
+    words[:nq] = pos
+    words[nq:cap] = POS_SENTINEL
+    words[cap:cap + nq] = np.asarray(h, np.uint32).view(np.int32)
+    at = cap * 8
+    for allele in (ref, alt):
+        buf[at:at + nq * width] = allele.reshape(-1)
+        at += cap * width
+    words = buf[at:].view(np.int32)
+    words[:nq] = ref_len
+    words[cap:cap + nq] = alt_len
+    return buf
+
+
+def _unpack_queries(buf, width: int):
+    """:func:`pack_queries`'s buffer as its six device columns."""
+    cap = buf.shape[0] // (_QUERY_WORDS_BYTES + 2 * width)
+
+    def column(at, dtype=jnp.int32):
+        return jax.lax.bitcast_convert_type(
+            buf[at:at + cap * 4].reshape(cap, 4), dtype
+        )
+
+    alleles = cap * 8
+    lens = alleles + 2 * cap * width
+    return (
+        column(0), column(cap * 4, jnp.uint32),
+        buf[alleles:alleles + cap * width].reshape(cap, width),
+        buf[alleles + cap * width:lens].reshape(cap, width),
+        column(lens), column(lens + cap * 4),
+    )
+
+
+def lookup_in_sorted_packed(
+    store_pos, store_h, store_ref, store_alt, store_rlen, store_alen,
+    queries,
+):
+    """:func:`lookup_in_sorted` as the store's device probe calls it: the
+    queries arrive as :func:`pack_queries`'s one buffer and the answer
+    leaves as one array, the int32 store index (-1 when absent; found is
+    ``index >= 0``) — one upload and one fetch a probe, where six arrays
+    in and two out made eight transfers; on a v5e's host a small probe
+    costs what its transfers cost (1.30 ms against 2.43 at 32 queries:
+    PERF.md section 6, PR 29).  The search is :func:`lookup_in_sorted`'s,
+    written once."""
+    with jax.named_scope("avdb.probe"):
+        query = _unpack_queries(queries, store_ref.shape[1])
+    _found, index = lookup_in_sorted(
+        store_pos, store_h, store_ref, store_alt, store_rlen, store_alen,
+        *query,
+    )
+    return index
+
+
 @jax.named_scope("avdb.dedup_multi")
 def mark_batch_duplicates_multi(chrom, pos, h, ref, alt, ref_len, alt_len):
     """Chromosome-aware :func:`mark_batch_duplicates` for mesh shards that
@@ -185,6 +258,7 @@ def lookup_in_sorted_multi(
 mark_batch_duplicates_jit = jax.jit(mark_batch_duplicates)
 mark_batch_duplicates_multi_jit = jax.jit(mark_batch_duplicates_multi)
 lookup_in_sorted_jit = jax.jit(lookup_in_sorted)
+lookup_in_sorted_packed_jit = jax.jit(lookup_in_sorted_packed)
 lookup_in_sorted_multi_jit = jax.jit(lookup_in_sorted_multi)
 
 # the sharded-call surface (pjit with batch-dim-sharded inputs) — the

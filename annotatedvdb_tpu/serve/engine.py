@@ -80,6 +80,7 @@ from annotatedvdb_tpu.store.variant_store import (
     _LONG_ALLELES,
     JSONB_COLUMNS,
     combined_key,
+    count_overlapped,
     jsonb_dumps,
 )
 from annotatedvdb_tpu.types import (
@@ -1044,6 +1045,12 @@ class QueryEngine:
             by_code: dict[int, list] = {}
             for i, (code, _pos, _ref, _alt) in enumerate(parsed):
                 by_code.setdefault(code, []).append(i)
+        # two passes: every chromosome group's probe is launched before
+        # any is collected, so group 1's program runs while groups 2 and
+        # 3 are hashed, and theirs run and travel back while group 1
+        # renders.  A group on the host path (breaker open, small or not
+        # resident, a CPU backend) is answered in its launch.
+        groups = []
         for code, idxs in by_code.items():
             shard = store.shards.get(code)
             if shard is None:
@@ -1064,9 +1071,18 @@ class QueryEngine:
                     self.residency.touch_window(
                         shard, qkey.min(), qkey.max(), len(idxs)
                     )
+            query = (pos, h, ref, alt, ref_len, alt_len)
             with clock.span("lookup.probe", chrom=code, n=len(idxs)):
-                found, gid = self._probe_group(
-                    shard, code, pos, h, ref, alt, ref_len, alt_len
+                launched, obs = self._launch_group(shard, code, query)
+            groups.append((shard, code, query, launched, obs))
+        count_overlapped(sum(
+            bool(launched.waiting) for _s, _c, _q, launched, _o in groups
+        ))
+        for shard, code, query, launched, obs in groups:
+            idxs = by_code[code]
+            with clock.span("lookup.probe", chrom=code, n=len(idxs)):
+                found, gid = self._collect_group(
+                    shard, code, query, launched, obs
                 )
             with clock.span("lookup.rows", chrom=code, n=len(idxs)):
                 at = np.flatnonzero(found)
@@ -1100,26 +1116,29 @@ class QueryEngine:
                 if n:
                     metric.inc(n)
 
-    def _probe_group(self, shard, code: int, pos, h, ref, alt,
-                     ref_len, alt_len):
-        """One chromosome group's membership probe, routed through the
-        device circuit breaker when one is installed.
+    def _launch_group(self, shard, code: int, query: tuple) -> tuple:
+        """One chromosome group's membership probe, launched
+        (``ChromosomeShard.lookup_launch``) and routed through the device
+        circuit breaker when one is installed; :meth:`_collect_group`
+        takes what this returns and finishes it.
 
         Closed/half-open groups take the normal path (the breaker's
         half-open state admits exactly one trial); an open group pins the
         probe to the byte-identical host path — no failing-device attempt
         is paid per lookup while the device is sick.  Failures reach the
         breaker two ways: REAL device errors surface through the store's
-        probe-fallback hook (``observing`` attributes them to this group),
-        and the ``engine.device_probe`` fault point injects them
-        deterministically for the matrix/chaos runs — either way the
-        caller gets correct bytes (host retry)."""
+        probe-fallback hook (``observing`` attributes them to this group,
+        in this step and again around its collect — never across another
+        group's step), and the ``engine.device_probe`` fault point injects
+        them deterministically for the matrix/chaos runs — either way the
+        caller gets correct bytes (host retry).  Returns the shard's launched
+        lookup and the breaker's observation of it — None where there is
+        no success left to record."""
         breaker = self.breaker
         if breaker is None:
-            return shard.lookup(pos, h, ref, alt, ref_len, alt_len)
+            return shard.lookup_launch(*query), None
         if not breaker.allow_device(code):
-            return shard.lookup(pos, h, ref, alt, ref_len, alt_len,
-                                host_only=True)
+            return shard.lookup_launch(*query, host_only=True), None
         try:
             with breaker.observing(code) as obs:
                 # crash point: models a device probe/upload failure
@@ -1127,11 +1146,27 @@ class QueryEngine:
                 # breaker must absorb it on the host path, never wrong
                 # bytes
                 faults.fire("engine.device_probe")
-                out = shard.lookup(pos, h, ref, alt, ref_len, alt_len)
+                launched = shard.lookup_launch(*query)
         except Exception as exc:
             breaker.record_failure(code, exc)
-            return shard.lookup(pos, h, ref, alt, ref_len, alt_len,
-                                host_only=True)
+            return shard.lookup_launch(*query, host_only=True), None
+        return launched, obs
+
+    def _collect_group(self, shard, code: int, query: tuple, launched,
+                       obs):
+        """(found, global id) of a group :meth:`_launch_group` launched.
+        A device error that surfaces only here is this group's alone: it
+        is recorded against ``code``, the group is answered again from
+        the host, and a success is recorded only after a clean collect."""
+        if obs is None:
+            return shard.lookup_collect(launched)
+        breaker = self.breaker
+        try:
+            with breaker.observing(code, obs):
+                out = shard.lookup_collect(launched)
+        except Exception as exc:
+            breaker.record_failure(code, exc)
+            return shard.lookup(*query, host_only=True)
         if not obs.failed:
             breaker.record_success(code)
         return out

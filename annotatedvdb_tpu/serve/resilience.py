@@ -436,11 +436,14 @@ class DeviceBreaker:
         variant_store.set_device_probe_failure_hook(_probe_failure_hook)
 
     @contextlib.contextmanager
-    def observing(self, code: int):
+    def observing(self, code: int, obs: "_BreakerObservation | None" = None):
         """Attribute in-window device-probe failures to ``code`` on THIS
         breaker (the probe runs fully on the calling thread on every
-        front end)."""
-        obs = _BreakerObservation()
+        front end).  A probe that is launched in one window and collected
+        in a later one hands the first window's observation to the second
+        as ``obs``: between the two the thread may observe other groups."""
+        if obs is None:
+            obs = _BreakerObservation()
         _tls.owner = (self, obs, code)
         try:
             yield obs

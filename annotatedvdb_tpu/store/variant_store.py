@@ -119,12 +119,20 @@ DEVICE_QUERY_MIN = 1 << 12
 # them, eagerly: a server does not know how many readers it will get, a
 # group over 32 ids takes a larger bucket, and one compiled after
 # ``resident`` would be compiled inside somebody's request.  Where the
-# floor sits is measured (one v5e, 2^22 rows, 32 concurrent point readers,
-# PR 28): with every probe padded to 256 the program ran 0.61 ms a probe and
-# a drain's three probes took 9.1-9.2 ms; padded to 32, 0.17 ms and
-# 7.7-8.0 ms.  Under 32 a bucket saves nothing: a lone probe's wall —
-# dispatch, six uploads, two fetches — is 2.28-2.33 ms at 1 to 32 queries.
-# Sentinel queries cannot match a real row.
+# floor sits is measured (one v5e, 2^22 rows).  With 32 concurrent point
+# readers and the six-array probe (PR 28): every probe padded to 256, the
+# program ran 0.61 ms a probe and a drain's three probes took 9.1-9.2 ms;
+# padded to 32, 0.17 ms and 7.7-8.0 ms.  Under 32 a bucket saves nothing:
+# a lone probe's wall — pack, one upload, one dispatch, one fetch — is
+# 1.27-1.38 ms at 1 to 32 queries (PR 29's microbenchmark of this form;
+# 1.36 at 64, 1.83-1.91 at 256 and 512, 4.44 at 2,048), of it 0.35 ms the
+# launch (0.40 at 2,048) and 0.20 ms the program (0.31 at 256, 0.61 at
+# 512, 2.62 at 2,048); the rest is the host waiting out this machine's
+# round trips — an upload waited for 0.56 ms, the fetch of a finished
+# 128-byte answer 0.40 ms.  The six-array form read 2.43 ms at 32 queries
+# in the same run: the count of transfers is what a small probe costs, so
+# a lookup launches all its probes before it waits for one
+# (``Segment.probe_launch``).  Sentinel queries cannot match a real row.
 DEVICE_QUERY_FLOOR = 1 << 5
 
 # The device probe must first UPLOAD the segment's identity columns
@@ -285,8 +293,13 @@ _DEVICE_PROBE_FAILURE_HOOK = None
 #: ``utils.retry.stats`` pattern): load summaries report their session's
 #: delta, so a reader can tell whether a load reached the device lookup
 #: path at all; ``padded_queries`` is what the device was asked after
-#: padding (:func:`probe_query_capacity`), added once per probe
-probe_stats = {"device_probes": 0, "device_queries": 0, "padded_queries": 0}
+#: padding (:func:`probe_query_capacity`) and ``transfers`` the host<->
+#: device array transfers those probes made (one upload, one fetch), all
+#: four added once per probe that was collected clean;
+#: ``overlapped_probes`` counts probes launched while an earlier one of
+#: the same lookup was not yet collected (:func:`count_overlapped`)
+probe_stats = {"device_probes": 0, "device_queries": 0, "padded_queries": 0,
+               "transfers": 0, "overlapped_probes": 0}
 
 
 def probe_query_capacity(nq: int) -> int:
@@ -294,6 +307,15 @@ def probe_query_capacity(nq: int) -> int:
     power of two, never under :data:`DEVICE_QUERY_FLOOR`.  The ONE place
     the shapes of ``lookup_in_sorted`` programs are decided."""
     return max(next_pow2(nq), DEVICE_QUERY_FLOOR)
+
+
+def count_overlapped(in_flight: int) -> None:
+    """``in_flight`` device probes of one lookup were launched before the
+    first of them was collected: all but the first overlapped an earlier
+    one.  Called once per lookup by whoever launched them — a shard for
+    its segments, the serving engine for its chromosome groups."""
+    if in_flight > 1:
+        probe_stats["overlapped_probes"] += in_flight - 1
 
 
 def device_lookup_state(base: dict | None = None) -> dict:
@@ -315,6 +337,30 @@ def set_device_probe_failure_hook(hook) -> None:
     """Install (or clear, with None) the device-probe failure observer."""
     global _DEVICE_PROBE_FAILURE_HOOK
     _DEVICE_PROBE_FAILURE_HOOK = hook
+
+
+def _probe_failure_owned(exc: BaseException) -> bool:
+    """Whether an installed failure observer takes a device probe's error
+    (the serving circuit breaker: per-group trip + half-open re-probe,
+    counted), so that the probe may answer from numpy; a loader, which
+    installs none, sees the error."""
+    hook = _DEVICE_PROBE_FAILURE_HOOK
+    return hook is not None and bool(hook(exc))
+
+
+class _Probe:
+    """One segment's membership probe between :meth:`Segment.probe_launch`
+    and :meth:`Segment.probe_collect`: answered already (``answer``, the
+    host path) or in flight on the device (``out``, the program's
+    un-fetched index array); ``query`` is kept for the host's retry of a
+    fetch that fails."""
+
+    __slots__ = ("query", "answer", "out")
+
+    def __init__(self, query: tuple, answer=None, out=None):
+        self.query = query
+        self.answer = answer
+        self.out = out
 
 
 def _device_lookup_enabled() -> bool:
@@ -632,20 +678,37 @@ class Segment:
 
     def probe(self, qkey, pos, h, ref, alt, ref_len, alt_len,
               host_only: bool = False):
-        """(found [N] bool, local index [N] int32; -1 when absent).
+        """(found [N] bool, local index [N] int32; -1 when absent):
+        :meth:`probe_launch`, then :meth:`probe_collect` at once.
 
         ``host_only=True`` skips the device branch outright — the serving
         circuit breaker's open-state path (byte-identical answers, no
         failing-device attempt paid per probe)."""
+        return self.probe_collect(self.probe_launch(
+            qkey, pos, h, ref, alt, ref_len, alt_len, host_only=host_only
+        ))
+
+    def probe_launch(self, qkey, pos, h, ref, alt, ref_len, alt_len,
+                     host_only: bool = False) -> "_Probe":
+        """The first half of :meth:`probe`.  Where the probe takes the
+        device it is packed, uploaded and dispatched here and this returns
+        at once, the program still running: the caller may launch other
+        segments' probes, hash the next group or render the last one
+        before :meth:`probe_collect` waits for the answer.  A probe that
+        takes the host path (numpy has nothing to wait for) is answered
+        here."""
+        query = (qkey, pos, h, ref, alt, ref_len, alt_len)
         if self.n == 0:
-            return np.zeros(pos.shape, np.bool_), np.full(pos.shape, -1, np.int32)
+            return _Probe(query, answer=(
+                np.zeros(pos.shape, np.bool_), np.full(pos.shape, -1, np.int32)
+            ))
         nq = pos.shape[0]
         # an existing HBM cache is sunk cost — use it at any size; otherwise
         # upload once the ski-rental accumulator says the transfer has paid
         # for itself in forgone device work (see DEVICE_UPLOAD_AMORTIZE)
         # capture the cache tuple ONCE: a residency manager may evict
         # (`_device = None`) from another thread between this gate and
-        # the device call — the captured tuple stays valid (the arrays
+        # the collect — the captured tuple stays valid (the arrays
         # live as long as the reference), and a managed segment whose
         # cache vanished falls back to numpy instead of re-uploading
         dev = self._device
@@ -666,21 +729,38 @@ class Segment:
                                   and (self._numpy_query_volume + nq)
                                   * DEVICE_UPLOAD_AMORTIZE >= self.n))))):
             try:
-                out = self._probe_device(pos, h, ref, alt, ref_len,
-                                         alt_len, dev=dev)
+                return _Probe(query, out=self._launch_device(
+                    pos, h, ref, alt, ref_len, alt_len, dev=dev
+                ))
             except Exception as exc:
-                # only an installed failure observer (the serving circuit
-                # breaker: per-group trip + half-open re-probe, counted)
-                # may turn a device error into a numpy answer; a loader
-                # sees the error
-                hook = _DEVICE_PROBE_FAILURE_HOOK
-                if hook is None or not hook(exc):
+                if not _probe_failure_owned(exc):
                     raise
-            else:
-                probe_stats["device_probes"] += 1
-                probe_stats["device_queries"] += nq
-                probe_stats["padded_queries"] += probe_query_capacity(nq)
-                return out
+        return _Probe(query, answer=self._probe_host(*query))
+
+    def probe_collect(self, launched: "_Probe"):
+        """The second half of :meth:`probe`: a launched probe's (found,
+        index), waiting for the device where the probe is still there.  A
+        device error that surfaces only now — asynchronous dispatch makes
+        that the likely place — is handled as one at launch is."""
+        if launched.answer is not None:
+            return launched.answer
+        nq = launched.query[1].shape[0]
+        try:
+            out = self._collect_device(launched.out, nq)
+        except Exception as exc:
+            if not _probe_failure_owned(exc):
+                raise
+            return self._probe_host(*launched.query)
+        probe_stats["device_probes"] += 1
+        probe_stats["device_queries"] += nq
+        probe_stats["padded_queries"] += probe_query_capacity(nq)
+        probe_stats["transfers"] += 2
+        return out
+
+    def _probe_host(self, qkey, pos, h, ref, alt, ref_len, alt_len):
+        """The numpy probe: every host-path answer, and the retry of a
+        device probe whose failure an observer owns."""
+        nq = pos.shape[0]
         self._numpy_query_volume += nq
         lo = np.searchsorted(self.key, qkey, side="left")
         found = np.zeros(nq, np.bool_)
@@ -735,30 +815,46 @@ class Segment:
             )
         )
 
-    def _probe_device(self, pos, h, ref, alt, ref_len, alt_len, dev=None):
-        """Large-batch membership on device (``ops/dedup.lookup_in_sorted``),
-        against an HBM-resident cache of this segment's identity columns
-        (``dev``: the caller-captured tuple — eviction-race-safe; None
-        builds the cache, which managed segments never request).  Query
-        arrays are padded to :func:`probe_query_capacity` (sentinel
-        positions can't match a real row's) so compile count stays
-        logarithmic in batch size and the small probes share one
-        program."""
-        from annotatedvdb_tpu.ops.dedup import lookup_in_sorted_jit
-        from annotatedvdb_tpu.utils.arrays import POS_SENTINEL, pad_rows
+    def _launch_device(self, pos, h, ref, alt, ref_len, alt_len, dev=None):
+        """Large-batch membership on device, launched: the queries padded
+        to :func:`probe_query_capacity` (sentinel positions can't match a
+        real row's; compile count stays logarithmic in batch size and the
+        small probes share one program), packed into ONE host buffer
+        (``ops/dedup.pack_queries``), and handed to
+        ``lookup_in_sorted_packed`` against an HBM-resident cache of this
+        segment's identity columns (``dev``: the caller-captured tuple —
+        eviction-race-safe; None builds the cache, which managed segments
+        never request).  One upload and one dispatch; what comes back is
+        the program's un-fetched index array, its copy to the host already
+        asked for, so the fetch travels while the caller works.  What the
+        round trips cost is in the comment at :data:`DEVICE_QUERY_FLOOR`."""
+        from annotatedvdb_tpu.ops.dedup import (
+            lookup_in_sorted_packed_jit,
+            pack_queries,
+        )
 
         if dev is None:
             self._ensure_device_cache()
             dev = self._device
-        nq = pos.shape[0]
-        cap = probe_query_capacity(nq)
-        found, index = lookup_in_sorted_jit(
-            *dev,
-            pad_rows(pos, cap, POS_SENTINEL), pad_rows(h, cap, 0),
-            pad_rows(ref, cap, 0), pad_rows(alt, cap, 0),
-            pad_rows(ref_len, cap, 0), pad_rows(alt_len, cap, 0),
+        out = lookup_in_sorted_packed_jit(*dev, pack_queries(
+            pos, h, ref, alt, ref_len, alt_len,
+            probe_query_capacity(pos.shape[0]),
+        ))
+        out.copy_to_host_async()
+        return out
+
+    @staticmethod
+    def _collect_device(out, nq: int):
+        """A launched device probe's (found, index): the one fetch."""
+        index = np.asarray(out)[:nq]
+        return index >= 0, index
+
+    def _probe_device(self, pos, h, ref, alt, ref_len, alt_len, dev=None):
+        """One device probe run to its end: (found, index)."""
+        return self._collect_device(
+            self._launch_device(pos, h, ref, alt, ref_len, alt_len, dev=dev),
+            pos.shape[0],
         )
-        return np.asarray(found)[:nq], np.asarray(index)[:nq]
 
     def warm_device_probe(self, max_queries: int, dev: tuple) -> None:
         """Run the probe program once at every query capacity a probe of
@@ -813,6 +909,27 @@ def _obj_array(values, order: np.ndarray | None, n: int) -> np.ndarray | None:
 
 def _dense(arr: np.ndarray | None, n: int) -> np.ndarray:
     return np.full((n,), None, object) if arr is None else arr
+
+
+class _ShardLookup:
+    """One :meth:`ChromosomeShard.lookup` between its launch and its
+    collect: the answer so far, and the probes not yet folded into it
+    (segment, its first global id, its :class:`_Probe`), oldest first —
+    empty unless a device probe is in flight."""
+
+    __slots__ = ("found", "index", "waiting")
+
+    def __init__(self, shape):
+        self.found = np.zeros(shape, np.bool_)
+        self.index = np.full(shape, -1, np.int64)
+        self.waiting: list = []
+
+    def take(self, f, idx, start: int) -> None:
+        """Fold one segment's answer in: an id found earlier keeps the
+        older segment's row."""
+        take = f & ~self.found
+        self.index = np.where(take, idx.astype(np.int64) + start, self.index)
+        self.found |= f
 
 
 class ChromosomeShard:
@@ -1041,14 +1158,26 @@ class ChromosomeShard:
         (first-wins duplicate policy).  Returned ids are invalidated by the
         next ``append``/``compact``/``delete``.  ``host_only=True`` pins
         every segment probe to the numpy path (circuit-breaker open
-        state — byte-identical answers)."""
-        found = np.zeros(pos.shape, np.bool_)
-        index = np.full(pos.shape, -1, np.int64)
+        state — byte-identical answers).  :meth:`lookup_launch`, then
+        :meth:`lookup_collect` at once."""
+        return self.lookup_collect(self.lookup_launch(
+            pos, h, ref, alt, ref_len, alt_len, host_only=host_only
+        ))
+
+    def lookup_launch(self, pos, h, ref, alt, ref_len, alt_len,
+                      host_only: bool = False) -> "_ShardLookup":
+        """The first half of :meth:`lookup`: every segment the queries can
+        touch is probed — answered at once where the probe takes the host
+        path, launched and left running where it takes the device
+        (:meth:`Segment.probe_launch`) — and nothing is waited for.  Host
+        answers that come before the first launch are folded in here, so a
+        lookup no device takes part in is whole when this returns."""
+        out = _ShardLookup(pos.shape)
         if not self.segments:
-            return found, index
+            return out
         qkey = combined_key(pos, h)
         if qkey.size == 0:
-            return found, index
+            return out
         # range pruning: a segment whose key range misses the query range
         # entirely cannot match — on position-sorted loads (many disjoint
         # segments, see maintain) this reduces the probe set to O(1)
@@ -1058,14 +1187,29 @@ class ChromosomeShard:
         for si, seg in enumerate(self.segments):
             if seg.n == 0 or seg.key_max < qlo or seg.key_min > qhi:
                 continue
-            if found.all():
+            if not out.waiting and out.found.all():
                 break
-            f, idx = seg.probe(qkey, pos, h, ref, alt, ref_len, alt_len,
-                               host_only=host_only)
-            take = f & ~found
-            index = np.where(take, idx.astype(np.int64) + starts[si], index)
-            found |= f
-        return found, index
+            probe = seg.probe_launch(qkey, pos, h, ref, alt, ref_len,
+                                     alt_len, host_only=host_only)
+            if out.waiting or probe.answer is None:
+                # first-wins is decided oldest segment first: behind a
+                # probe still in flight every later answer waits its turn
+                out.waiting.append((seg, int(starts[si]), probe))
+            else:
+                out.take(*probe.answer, int(starts[si]))
+        count_overlapped(sum(
+            probe.answer is None for _seg, _start, probe in out.waiting
+        ))
+        return out
+
+    def lookup_collect(self, launched: "_ShardLookup"):
+        """The second half of :meth:`lookup`: (found, global id), after
+        collecting what :meth:`lookup_launch` left in flight, oldest
+        segment first."""
+        for seg, start, probe in launched.waiting:
+            launched.take(*seg.probe_collect(probe), start)
+        launched.waiting.clear()
+        return launched.found, launched.index
 
     # -- mutation -----------------------------------------------------------
 

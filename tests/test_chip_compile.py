@@ -123,6 +123,17 @@ def _store_probe(shape):
     )
 
 
+def _store_probe_packed(shape):
+    """The store's own probe: the same segment, the queries one packed
+    buffer (``ops/dedup.pack_queries``: 16 + 2 x width bytes a query)."""
+    from annotatedvdb_tpu.ops.dedup import lookup_in_sorted_packed_jit
+
+    return lookup_in_sorted_packed_jit, (
+        *_identity(shape, SEGMENT_ROWS),
+        shape((BULK_QUERIES * (16 + 2 * WIDTH),), jnp.uint8),
+    )
+
+
 def _bits_spans(shape):
     from annotatedvdb_tpu.ops.intervals import bits_spans_kernel_jit
 
@@ -147,7 +158,7 @@ def _export_pack(shape):
 
 @pytest.mark.parametrize("case", [
     _hash, _dedup, _pack_outputs, _nibble_inflate, _store_probe,
-    _bits_spans, _stats_panel, _export_pack,
+    _bits_spans, _stats_panel, _export_pack, _store_probe_packed,
 ], ids=lambda case: case.__name__.lstrip("_"))
 def test_kernel_compiles(one_chip, case):
     kernel, args = case(one_chip)

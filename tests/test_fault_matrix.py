@@ -1543,12 +1543,27 @@ def test_obs_flight_sigkill_harvest_holds_final_requests(tmp_path):
                     break
             except OSError:
                 time.sleep(0.3)
-        # traffic both workers record (kernel round-robins accepts)
-        for i in range(40):
-            try:
-                get(f"/variant/3:{(i % 3 + 1) * 10}:A:C")
-            except OSError:
-                pass
+        # traffic until BOTH workers' rings hold request summaries (the
+        # kernel round-robins accepts, but /healthz answers as soon as one
+        # worker listens: a worker that came up after the traffic, or was
+        # killed inside the recorder's flush cadence, harvests nothing)
+        def recorded(worker):
+            ring = flight_mod.ring_path(store_dir, worker)
+            return os.path.isfile(ring) and any(
+                e["type"] == "request"
+                for e in flight_mod.decode_ring(ring)["events"]
+            )
+
+        deadline = time.monotonic() + 60
+        while time.monotonic() < deadline:
+            for i in range(40):
+                try:
+                    get(f"/variant/3:{(i % 3 + 1) * 10}:A:C")
+                except OSError:
+                    pass
+            if recorded(0) and recorded(1):
+                break
+            time.sleep(flight_mod.FlightRecorder.FLUSH_S)
         # arm a kill in whichever worker answers: it dies mid-accept
         body = json.dumps({"spec": "serve.accept:1:kill"}).encode()
         req = urllib.request.Request(

@@ -467,6 +467,8 @@ def _kernel_cases():
          (pos, u32, u8, u8, i32, i32), {}),
         ("ops.dedup", "lookup_in_sorted_jit", "avdb.probe",
          seg + seg, {}),
+        ("ops.dedup", "lookup_in_sorted_packed_jit", "avdb.probe",
+         seg + (np.zeros(n * (16 + 2 * w), np.uint8),), {}),
         ("ops.annotate_pallas", "annotate_bin_pallas", "avdb_annotate_bin",
          (pos, u8, u8, i32, i32), {"block_n": 128, "interpret": True}),
     ]
@@ -475,7 +477,8 @@ def _kernel_cases():
 #: program names the benchmark's breakdown (``device_ops``) sums by
 PROGRAM_NAMES = {"allele_hash_jit": "jit_allele_hash",
                  "inflate_alleles_jit": "jit_inflate_alleles",
-                 "lookup_in_sorted_jit": "jit_lookup_in_sorted"}
+                 "lookup_in_sorted_jit": "jit_lookup_in_sorted",
+                 "lookup_in_sorted_packed_jit": "jit_lookup_in_sorted_packed"}
 
 
 @pytest.mark.parametrize("case", _kernel_cases(),

@@ -40,12 +40,14 @@ from annotatedvdb_tpu.ops.dedup import (
     lookup_in_sorted_multi_jit,
     lookup_in_sorted_multi_np,
     lookup_in_sorted_np,
+    lookup_in_sorted_packed_jit,
     mark_batch_duplicates_jit,
     mark_batch_duplicates_mesh,
     mark_batch_duplicates_multi_jit,
     mark_batch_duplicates_multi_np,
     mark_batch_duplicates_np,
     mix_chrom_hash,
+    pack_queries,
 )
 from annotatedvdb_tpu.ops.export_pack import (
     export_pack_host,
@@ -295,6 +297,28 @@ def test_lookup_in_sorted_vs_np_twin():
     np.testing.assert_array_equal(np.asarray(dev[0]), host[0])
     np.testing.assert_array_equal(np.asarray(dev[1]), host[1])
     assert host[0][: n // 2].all()
+
+
+def test_lookup_in_sorted_packed_vs_np_twin():
+    """The store's probe: the queries one packed buffer, the index alone
+    back (every capacity and run shape: tests/test_probe_launch_collect)."""
+    rng = np.random.default_rng(37)
+    store = _sorted_store(rng, 256)
+    n, cap = 40, 64
+    qpos, qref, qalt, qrlen, qalen = _allele_batch(rng, n)
+    qh = allele_hash_np(qref, qalt, qrlen, qalen)
+    hit = rng.integers(0, 256, n // 2)
+    query = [qpos, qh, qref, qalt, qrlen, qalen]
+    for q, col in zip(query, store):
+        q[: n // 2] = col[hit]
+    index = np.asarray(lookup_in_sorted_packed_jit(
+        *store, pack_queries(*query, cap)
+    ))
+    found, host = lookup_in_sorted_np(*store, *query)
+    assert index.dtype == host.dtype and index.shape == (cap,)
+    np.testing.assert_array_equal(index[:n], host)
+    np.testing.assert_array_equal(index[:n] >= 0, found)
+    assert found[: n // 2].all() and (index[n:] == -1).all()
 
 
 def test_lookup_in_sorted_multi_vs_np_twin():
