@@ -20,9 +20,6 @@ AVDB6xx     hygiene: bare except, silent Exception-pass, mutable default
             args, stale noqa suppressions (``rules_hygiene``)
 AVDB7xx     async-safety: blocking calls on the event loop, await under a
             sync lock (``rules_async``)
-AVDB8xx     cross-front-end parity: duplicated response literals /
-            ``AVDB_SERVE_*`` reads, shared-helper asymmetry between
-            ``serve/http.py`` and ``serve/aio.py`` (``rules_parity``)
 AVDB9xx     device/host twin contract: jitted ``ops/`` kernels vs the
             ``ops.TWINS`` registry and its parity tests (``rules_twins``)
 AVDB10xx    durability protocol: fsync-before-rename, tmp-family

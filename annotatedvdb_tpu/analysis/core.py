@@ -146,10 +146,6 @@ class ProjectFacts:
     #: never read") additionally needs the test tree, where the
     #: AVDB_SCALE_TEST-class gates are read
     tree_scan: bool = False
-    #: {front_end_path: {"literals": {value: first_line},
-    #:                   "refs": set_of_names}} — the two serve front
-    #: ends' parity facts (rules_parity)
-    parity: dict = field(default_factory=dict)
     #: [(path, line, "module.attr")] — jitted kernels discovered under
     #: ops/ (rules_twins)
     ops_kernels: list = field(default_factory=list)
@@ -342,7 +338,6 @@ def run_paths(paths, root: str | None = None,
         rules_env,
         rules_hygiene,
         rules_locks,
-        rules_parity,
         rules_registry,
         rules_trace,
         rules_twins,
@@ -373,7 +368,6 @@ def run_paths(paths, root: str | None = None,
         rules_registry.collect,
         rules_env.collect,
         rules_cli.collect,
-        rules_parity.collect,
         rules_twins.collect,
         rules_durability.collect,
     )
@@ -381,7 +375,6 @@ def run_paths(paths, root: str | None = None,
         rules_registry.finalize,
         rules_env.finalize,
         rules_cli.finalize,
-        rules_parity.finalize,
         rules_twins.finalize,
         rules_durability.finalize,
     )

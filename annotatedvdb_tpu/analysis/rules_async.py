@@ -34,7 +34,7 @@ Nested function definitions are NOT part of the enclosing async context
 statically resolve — ``name(...)`` to a module-level function,
 ``self.name(...)`` to a method of the same class — are followed;
 cross-module and attribute-of-attribute calls are out of scope (kept
-tractable; the parity/lock families cover those surfaces).
+tractable; the lock family covers those surfaces).
 """
 
 from __future__ import annotations

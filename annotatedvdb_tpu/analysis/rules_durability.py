@@ -11,7 +11,8 @@ complement (what the executed interleaving actually did) is the
 ``AVDB_IO_TRACE`` sanitizer in :mod:`annotatedvdb_tpu.analysis.iotrace`.
 
 Codes (scoped to ``store/`` modules; fixture trees drive the same rules
-through the path-suffix convention rules_parity established):
+through the path-suffix convention — a file is judged by the tail of its
+path, so ``<fixture>/store/x.py`` is a store module):
 
 - **AVDB1001** — an ``os.replace``/``os.rename`` whose SOURCE was opened
   for writing in the same function must fsync that file object between

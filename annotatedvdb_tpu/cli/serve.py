@@ -21,8 +21,7 @@ mode.  ``--workers N`` (default ``AVDB_SERVE_WORKERS`` or 1) runs the
 multi-process fleet: N worker processes share the port (SO_REUSEPORT
 where available, parent accept handoff otherwise) and one readonly store
 generation; the supervisor restarts dead workers and drains on SIGTERM.
-The default front end is the asyncio event loop (``serve/aio.py``);
-``--frontend threaded`` keeps the PR-5 thread-per-connection server.
+The front end is the asyncio event loop (``serve/aio.py``).
 Knobs default from ``AVDB_SERVE_*`` (see README "Configuration"); flags
 override the environment.  ``--_workerIndex``/``--_listenFd`` are the
 fleet's internal worker handshake, not a user surface.
@@ -49,10 +48,6 @@ def _build_parser() -> argparse.ArgumentParser:
                         help="serve fleet size: N>1 runs N worker processes "
                              "sharing the port and one readonly store "
                              "generation (default: AVDB_SERVE_WORKERS or 1)")
-    parser.add_argument("--frontend", choices=("aio", "threaded"),
-                        default="aio",
-                        help="event-loop front end (default) or the "
-                             "thread-per-connection reference server")
     parser.add_argument("--upserts", action="store_true",
                         default=None,
                         help="enable the live write path: POST "
@@ -74,8 +69,7 @@ def _build_parser() -> argparse.ArgumentParser:
                              "the fleet supervisor: watermark-driven "
                              "background compaction, load-aware and "
                              "crash-safe (default: AVDB_MAINTAIN or off; "
-                             "aio front end only — implies fleet mode "
-                             "even with --workers 1)")
+                             "implies fleet mode even with --workers 1)")
     parser.add_argument("--maxBatch", type=int, default=None,
                         help="max point queries per coalesced microbatch "
                              "(default: AVDB_SERVE_BATCH_MAX or 256)")
@@ -129,8 +123,7 @@ def _build_parser() -> argparse.ArgumentParser:
 
 def _upserts_enabled(args) -> bool:
     """Flag wins over environment; ``AVDB_SERVE_UPSERTS`` accepts the
-    usual truthy spellings.  Resolved ONCE here (never in a front end —
-    the AVDB802 knob-resolution contract)."""
+    usual truthy spellings.  Resolved ONCE here, never in the server."""
     if args.upserts is not None:
         return bool(args.upserts)
     return os.environ.get("AVDB_SERVE_UPSERTS", "").lower() \
@@ -174,7 +167,7 @@ def _knob_args(args, workers: int) -> list[str]:
     share — N workers each enforcing the full budget could pin N x budget
     of probe caches (an explicit flag also overrides the inherited
     AVDB_SERVE_HBM_BUDGET, which would have the same problem)."""
-    out: list[str] = ["--frontend", args.frontend]
+    out: list[str] = []
     if _upserts_enabled(args):
         # every worker runs its own memtable + WAL (serve-w<idx>.*.wal):
         # the flag must reach them all
@@ -212,19 +205,6 @@ def main(argv=None):
         print(f"serve: cannot start: bad AVDB_SERVE_WORKERS ({err})",
               file=sys.stderr)
         return 1
-    if args.frontend == "threaded":
-        dead = [flag for flag, val, env in (
-            ("--clientRate", args.clientRate, "AVDB_SERVE_CLIENT_RATE"),
-            ("--streamThreshold", args.streamThreshold,
-             "AVDB_SERVE_STREAM_THRESHOLD"),
-        ) if val is not None or os.environ.get(env)]
-        if dead:
-            # the PR-5 reference server has no governor or streaming
-            # wiring; starting silently would let an operator believe
-            # hogs are throttled while nothing limits them
-            print(f"serve: {', '.join(dead)} only apply to the aio front "
-                  "end and are ignored with --frontend threaded",
-                  file=sys.stderr)
     if args.follow:
         if _upserts_enabled(args):
             # a follower is read-only BY ROLE: its overlay exists to
@@ -264,15 +244,6 @@ def main(argv=None):
                 return 1
     maintain = args._workerIndex is None and _maintain_enabled(args)
     if args._workerIndex is None and (workers > 1 or maintain):
-        if args.frontend == "threaded":
-            # the threaded server binds its own port and cannot join a
-            # shared-socket fleet (and writes no heartbeat health for
-            # the maintenance daemon) — refusing beats a crash loop
-            what = "--workers > 1" if workers > 1 else "--maintain"
-            print(f"serve: {what} requires the aio front end "
-                  "(--frontend threaded is single-process only)",
-                  file=sys.stderr)
-            return 2
         if args.metricsOut or args.traceOut:
             print("serve: --metricsOut/--traceOut are per-process exports "
                   "and are not collected in fleet mode; scrape GET "
@@ -524,11 +495,6 @@ def _run_single(args, log) -> int:
             print(f"serve: worker cannot bind: {err}", file=sys.stderr)
             return 1
 
-    if args.frontend == "threaded":
-        return _run_threaded(args, manager, registry, residency, tracer,
-                             max_wait_s, log, memtable=memtable,
-                             flight=flight, health=health, tailer=tailer)
-
     from annotatedvdb_tpu.serve.aio import build_aio_server
 
     try:
@@ -617,7 +583,7 @@ def _run_single(args, log) -> int:
             stop.wait()
             log("shutting down")
     except OSError as err:
-        # bind failure: same clean exit as the threaded front end
+        # bind failure: same clean exit as every other startup failure
         print(f"serve: cannot start: {err}", file=sys.stderr)
         return 1
     except KeyboardInterrupt:
@@ -658,57 +624,6 @@ def _worker_socket(args):
     if args._listenFd is not None:
         return socket_mod.socket(fileno=args._listenFd)
     return bind_reuseport(args.host, args.port)
-
-
-def _run_threaded(args, manager, registry, residency, tracer,
-                  max_wait_s, log, memtable=None, flight=None,
-                  health=None, tailer=None) -> int:
-    """The PR-5 thread-per-connection server (byte-parity reference)."""
-    from annotatedvdb_tpu.serve.http import build_server
-
-    try:
-        httpd = build_server(
-            manager=manager, host=args.host, port=args.port,
-            max_batch=args.maxBatch, max_wait_s=max_wait_s,
-            max_queue=args.maxQueue, region_cache_size=args.regionCache,
-            registry=registry, residency=residency, memtable=memtable,
-            tracer=tracer, log=log, flight=flight,
-            telemetry_dir=args._telemetryDir,
-            worker_index=args._workerIndex or 0,
-            health=health,
-        )
-    except (OSError, ValueError) as err:
-        print(f"serve: cannot start: {err}", file=sys.stderr)
-        return 1
-    ctx = httpd.ctx
-    if tailer is not None:
-        ctx.repl = tailer
-        ctx.follow_url = tailer.leader_url
-        tailer.start()
-    snap = ctx.manager.current()
-    host, port = httpd.server_address[:2]
-    print(f"serving {args.storeDir} (generation {snap.generation}, "
-          f"{snap.store.n} rows) on http://{host}:{port}", flush=True)
-    try:
-        httpd.serve_forever(poll_interval=0.2)
-    except KeyboardInterrupt:
-        log("shutting down")
-    finally:
-        if tailer is not None:
-            tailer.stop()
-        httpd.server_close()
-        ctx.batcher.close()
-        if memtable is not None and memtable.wal is not None:
-            memtable.wal.close(remove_if_empty=True)
-        from annotatedvdb_tpu.obs import reqtrace as reqtrace_mod
-
-        reqtrace_mod.set_background_sink(None, None)
-        if flight is not None:
-            flight.close()
-        if health is not None:
-            health.close()
-        _export(args, ctx.registry, tracer, log)
-    return 0
 
 
 def _export(args, registry, tracer, log) -> None:

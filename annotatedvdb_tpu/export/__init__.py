@@ -13,8 +13,8 @@ store for genomics models" workload; genomic-interval tokenizers, arXiv
 - :mod:`annotatedvdb_tpu.export.core` — planner + batch materializer over
   the PR-16 prefetch spine and the jitted ``ops/export_pack`` kernel
   (imports jax: pulled in only by the CLI/serve/bench entry points);
-- :mod:`annotatedvdb_tpu.export.stream` — the shared ``GET /export/stream``
-  payload builder both front ends serve byte-identically.
+- :mod:`annotatedvdb_tpu.export.stream` — the ``GET /export/stream``
+  grammar and payload builder the front end serves from.
 
 Only the import-light names are re-exported here: the serve engine imports
 ``export.tokens`` on its module path, and fsck imports ``is_export_tmp``,

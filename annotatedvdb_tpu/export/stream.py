@@ -1,5 +1,4 @@
-"""``GET /export/stream``: one corpus batch over HTTP, shared by BOTH
-front ends.
+"""``GET /export/stream``: one corpus batch over HTTP.
 
 The serving twin of the bulk exporter: a client names a ``region`` slice
 and a ``batch`` ordinal and gets back exactly what ``avdb export`` would
@@ -9,9 +8,9 @@ dictionary, the same seeded disjoint-block emission order (seed ``S``
 over ``N`` batches permutes identically here and in the corpus planner,
 because both use :data:`~annotatedvdb_tpu.export.core.SHUFFLE_BLOCK`
 windows of one ``random.Random(seed)``).  The payload builder lives here
-— ``serve/http.py`` and ``serve/aio.py`` both call
-:func:`stream_payload` (the ``/stats/region`` shared-builder discipline),
-so byte parity across front ends is structural, not tested-in.
+— ``serve/aio.py`` calls :func:`stream_payload` (the ``/stats/region``
+builder discipline), and the tests call it directly as the route's
+oracle.
 
 Packing rides the engine's device kernel behind its circuit breaker;
 an open breaker (or a device failure, recorded) falls back to the

@@ -94,9 +94,8 @@ class FlightRecorder:
     a measurable slice of the event loop, and the bench's 3% overhead
     gate failed on exactly it.  ``request`` therefore appends a raw
     tuple to a bounded deque (sub-µs, thread-safe) and :meth:`flush` —
-    called on the aio maintenance tick via the executor pool, time-gated
-    on the threaded front end's request completions, by a per-recorder
-    background thread every :data:`FLUSH_S` (a burst followed by silence
+    called on the server's maintenance tick via the executor pool, by a
+    per-recorder background thread every :data:`FLUSH_S` (a burst followed by silence
     must not strand its tail in the buffer forever), and by
     :meth:`close` — drains it to the mmap.  Serving-side flushes CAP the
     batch at :data:`FLUSH_BATCH` records: an uncapped drain is a
@@ -108,7 +107,7 @@ class FlightRecorder:
     un-flushed tail; lifecycle events (rare, and the heart of the
     postmortem) never buffer and never sample."""
 
-    #: serving-side flush cadence (both front ends gate on it)
+    #: serving-side flush cadence
     FLUSH_S = 0.25
 
     #: serving-side flush batch cap (records per flush): bounds the GIL
@@ -127,8 +126,8 @@ class FlightRecorder:
             if event_slots is None else max(int(event_slots), 1)
         self.log = log if log is not None else (lambda msg: None)
         #: serializes slot reservation + pack_into: concurrent flush()
-        #: calls (the threaded front end's time-gated inline flushes can
-        #: race) and write-through events must never interleave a
+        #: calls (the maintenance tick's and the background flusher's
+        #: can race) and write-through events must never interleave a
         #: `_seq += 1` and overwrite each other's slot.  A plain stdlib
         #: lock on purpose — obs-layer locks stay outside the serve
         #: lock-order tracer (the recorder observes INTO traced code)
@@ -154,11 +153,11 @@ class FlightRecorder:
         self._mm = mmap.mmap(self._f.fileno(), size)
         HEADER.pack_into(self._mm, 0, MAGIC, VERSION, self.slots,
                          self.event_slots)
-        #: background flusher: the front ends' flushes are gated on
-        #: request COMPLETIONS, so a traffic burst followed by silence
-        #: used to leave its whole tail buffered indefinitely — a worker
-        #: SIGKILLed while idle lost exactly the history the black box
-        #: exists to keep.  This thread bounds the at-risk window to
+        #: background flusher: a recorder outside a running server has
+        #: no maintenance tick to flush it, and a traffic burst followed
+        #: by silence must not leave its tail buffered — a worker
+        #: SIGKILLed while idle would lose exactly the history the black
+        #: box exists to keep.  This thread bounds the at-risk window to
         #: ~FLUSH_S regardless of traffic.
         self._closed = False
         self._flush_stop = threading.Event()

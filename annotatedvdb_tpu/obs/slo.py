@@ -483,9 +483,8 @@ class HealthPlane:
 
     def tick(self) -> bool:
         """Sample -> evaluate -> persist, absorbing every failure: the
-        maintenance chains driving this (the aio tick, the threaded
-        request hook) must never die — or even log per-tick — because
-        the observer did."""
+        maintenance chain driving this (the server's tick) must never
+        die — or even log per-tick — because the observer did."""
         if not self.ring.enabled:
             return False
         try:

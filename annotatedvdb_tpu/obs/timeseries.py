@@ -341,8 +341,8 @@ class TimeSeriesRing:
         return self._errors
 
     def due(self, now: float | None = None) -> bool:
-        """Time-gate for the serving-side drivers (the aio maintenance
-        tick, the threaded front end's request-completion hook)."""
+        """Time-gate for the serving-side driver (the server's
+        maintenance tick)."""
         if not self.enabled:
             return False
         now = time.monotonic() if now is None else now

@@ -1,15 +1,11 @@
-"""Asyncio event-loop serving front end: the throughput path.
+"""The serving front end: an asyncio event-loop server.
 
-The PR-5 front end (``serve/http.py``) spends a thread per connection; at
-thousands of concurrent point lookups that is thousands of parked threads
-whose only job is to wait on the batcher.  This front end is the same
-route surface on ONE event loop: requests parse in-line, point lookups
-submit to the existing continuous batcher through its non-blocking
-completion hook (``QueryBatcher.submit_nowait`` -> an asyncio future),
-and a connection costs a coroutine, not a thread — so in-flight lookups
-coalesce into the same device microbatches at a fraction of the host
-overhead (Endeavor's serving argument: keep the device batches large,
-keep the host thin).
+The route surface of ``serve/http.py`` on ONE event loop: requests parse
+in-line, point lookups submit to the loop-native continuous batcher
+(:class:`LoopBatcher` -> an asyncio future), and a connection costs a
+coroutine, not a thread — so thousands of in-flight lookups coalesce
+into device microbatches without a parked thread each (Endeavor's
+serving argument: keep the device batches large, keep the host thin).
 
 **Pipelining.**  Connections are fully pipelined: the read loop keeps
 parsing requests while earlier ones execute, and a per-connection writer
@@ -18,8 +14,8 @@ to ``PIPELINE_DEPTH`` in flight per connection — which is exactly how
 thousands of lookups from a handful of sockets fill 256-query device
 microbatches instead of trickling in one per round trip.
 
-Route/status/body bytes are **identical** to the threaded front end (the
-parity suite pins it); what this layer adds:
+Route grammar, payload builders and message constants come from
+``serve/http.py``; what this layer adds:
 
 - **weighted per-client admission** — a token bucket per client key
   (``X-Client-Id`` header scoped to the peer address — at most
@@ -122,7 +118,6 @@ from annotatedvdb_tpu.serve.fleet import HB_SLOT
 from annotatedvdb_tpu.serve.resilience import DeadlineExceeded, DeviceBreaker
 from annotatedvdb_tpu.serve.snapshot import SnapshotManager
 from annotatedvdb_tpu.utils import faults
-from annotatedvdb_tpu.utils.locks import make_lock
 
 #: request body cap (bulk id lists); larger bodies are 413, never buffered
 MAX_BODY = 1 << 26
@@ -223,22 +218,23 @@ def _status_of(resp: bytes) -> int:
 
 
 class LoopBatcher:
-    """Loop-native continuous batching: the asyncio twin of
-    :class:`~annotatedvdb_tpu.serve.batcher.QueryBatcher`.
+    """Loop-native continuous batching of concurrent point lookups.
 
-    The thread-based batcher costs every request two cross-thread
-    handoffs (submit -> drain thread -> loop wakeup); on a host with as
-    many hot threads as cores those handoffs are where tail latency goes
-    to die — each one is a scheduler timeslice boundary.  Here the drain
-    runs ON the event loop: submissions append to a list, a
+    The drain runs ON the event loop: submissions append to a list, a
     ``call_later(max_wait_s)`` timer (or a full batch) triggers the
-    drain, and the engine executes the microbatch inline — a few
+    drain, and the engine executes the microbatch inline.  A drain on a
+    thread of its own would cost every request two cross-thread handoffs
+    (submit -> drain thread -> loop wakeup), each a scheduler timeslice
+    boundary on a host with as many hot threads as cores; a few
     milliseconds of loop occupancy buys zero handoffs, zero extra hot
     threads, and the same coalescing.
 
-    API-compatible with the front end's use of ``QueryBatcher``:
-    ``depth`` / ``max_queue`` / ``drain_stats`` / ``close`` / the
-    ``serve.batch`` fault point and batch metrics."""
+    Queries are grammar-validated at submission so a malformed id fails
+    ONLY its own caller — co-batched strangers never share a client's
+    parse error.  A real engine failure mid-drain fails that one batch
+    (every waiter gets the root cause) and the loop keeps serving; the
+    ``serve.batch`` fault point fires before each drain so the matrix
+    pins exactly that behavior."""
 
     def __init__(self, engine, max_batch: int | None = None,
                  max_wait_s: float | None = None,
@@ -290,11 +286,12 @@ class LoopBatcher:
                       deadline_t: float | None = None,
                       trace=None) -> asyncio.Future:
         """Enqueue one point query; returns the future of its JSON text
-        (or None).  Admission/grammar contract of ``QueryBatcher``:
-        ``QueueFull`` / ``QueryError`` raise synchronously.  A pending
-        whose ``deadline_t`` (absolute monotonic) lapses before its drain
+        (or None).  ``QueueFull`` (the admission bound) and
+        ``QueryError`` (bad grammar, validated HERE, before the queue)
+        raise synchronously, in the caller.  A pending whose
+        ``deadline_t`` (absolute monotonic) lapses before its drain
         fails with ``DeadlineExceeded`` instead of occupying device
-        work."""
+        work — its admission slot releases."""
         if self._closed:
             raise RuntimeError("batcher is closed")
         parsed = parse_variant_id(variant_id)
@@ -428,44 +425,6 @@ class LoopBatcher:
             self._timer.cancel()
             self._timer = None
         self._drain_soon = False
-
-
-class _CompletionBridge:
-    """Drain-thread -> event-loop completion batching.
-
-    One ``call_soon_threadsafe`` per request would pay a self-pipe write
-    (a syscall) per query ON THE DRAIN THREAD — serialized against engine
-    work.  A batcher drain completes hundreds of pendings back-to-back,
-    so completions accumulate in a plain deque and the loop wakes ONCE
-    per burst to resolve them all."""
-
-    __slots__ = ("loop", "_lock", "_ready", "_scheduled")
-
-    def __init__(self, loop: asyncio.AbstractEventLoop):
-        self.loop = loop
-        self._lock = make_lock("serve.aio.bridge")
-        #: guarded by self._lock
-        self._ready: list = []
-        #: guarded by self._lock
-        self._scheduled = False
-
-    def complete(self, fut: asyncio.Future, pending) -> None:
-        """Called on the drain thread (the pending's completion hook)."""
-        with self._lock:
-            self._ready.append((fut, pending))
-            schedule = not self._scheduled
-            if schedule:
-                self._scheduled = True
-        if schedule:
-            self.loop.call_soon_threadsafe(self._flush)
-
-    def _flush(self) -> None:  # runs on the loop
-        with self._lock:
-            items = self._ready
-            self._ready = []
-            self._scheduled = False
-        for fut, pending in items:
-            _resolve_pending(fut, pending)
 
 
 #: refillable-debt horizon: an admitted bulk may indebt its bucket by at
@@ -604,8 +563,8 @@ class AioServer:
     drain when on the main thread) or on a helper thread via
     :meth:`start_background` / :meth:`shutdown` (tests, smoke, bench).
 
-    Shutdown order mirrors the threaded server: stop the server, then
-    ``ctx.batcher.close()`` (the caller owns the batcher)."""
+    Shutdown order: stop the server, then ``ctx.batcher.close()`` (the
+    caller owns the batcher)."""
 
     #: loop maintenance-tick cadence: heartbeat write + brownout-ladder
     #: evaluation + the serve.wedge fault point, all on the LOOP — a
@@ -643,8 +602,8 @@ class AioServer:
         #: runtime fault arming (POST /_chaos) for the chaos harness —
         #: gated hard on the environment so the route does not exist on
         #: a production server (404, byte-identical to any unknown
-        #: route); resolved through the ONE shared reader (the AVDB802
-        #: contract — /debug/trace shares the same gate)
+        #: route); resolved through the ONE shared reader (/debug/trace
+        #: shares the same gate)
         self._chaos_enabled = chaos_enabled_from_env()
         #: fleet telemetry publishing: the maintenance tick schedules a
         #: snapshot-file write (on the POOL — the loop never does file
@@ -655,13 +614,9 @@ class AioServer:
         #: flight flushes run from the tick on the POOL, never inline on
         #: the loop (the whole point of buffering the request summaries)
         self._flight_flush_inflight = False
-        if ctx.flight is not None:
-            ctx.flight_flush_inline = False
         #: health-plane ticks likewise run from the tick on the POOL
         #: (the persist half is file I/O, banned on the loop)
         self._health_tick_inflight = False
-        if ctx.health is not None:
-            ctx.health_tick_inline = False
         #: arming generation: each /_chaos arm bumps it so a stale ttl
         #: timer can never disarm a NEWER arming's fault
         self._chaos_seq = 0
@@ -688,8 +643,6 @@ class AioServer:
         # bound once: per-request getattr on the manager is hot-path waste
         self._refresh_due = getattr(ctx.manager, "refresh_due", None)
         self._refresh_inflight = False
-        self._bridge: _CompletionBridge | None = None
-        self._loop_batcher = isinstance(ctx.batcher, LoopBatcher)
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -740,7 +693,6 @@ class AioServer:
     async def _main(self) -> None:
         self._loop = asyncio.get_running_loop()
         self._stop = asyncio.Event()
-        self._bridge = _CompletionBridge(self._loop)
         if threading.current_thread() is threading.main_thread():
             import signal as _signal
 
@@ -1140,8 +1092,8 @@ class AioServer:
         try:
             # no wait_for wrapper (it costs a Task + timer per request):
             # every submitted pending is GUARANTEED to finish — the drain
-            # thread completes it, fails it, sheds it past its deadline,
-            # or close() fails the queue
+            # completes it, fails it, sheds it past its deadline, or
+            # close() fails the queue
             record = await fut
         except DeadlineExceeded as err:
             # the batcher shed it (and counted stage="batcher")
@@ -1352,8 +1304,8 @@ class AioServer:
             try:
                 length = int(headers.get("content-length", 0))
             except ValueError:
-                # parity with the threaded front end: a malformed
-                # Content-Length is a bad body-carrying request (400),
+                # a malformed Content-Length is a bad body-carrying
+                # request (400),
                 # not a too-large one; the body length is unknowable, so
                 # the connection cannot be reused
                 if path == "/variants":
@@ -1482,23 +1434,8 @@ class AioServer:
         if trace is not None:
             trace.since("admission", t0)
         try:
-            if self._loop_batcher:
-                # loop-native coalescing: no cross-thread handoffs
-                fut = ctx.batcher.submit_future(variant_id, deadline_t,
-                                                trace=trace)
-            else:
-                # thread-based batcher: completions cross back through
-                # the (drain-batched) bridge
-                fut = self._loop.create_future()
-                bridge = self._bridge
-
-                def on_done(pending, fut=fut, bridge=bridge):
-                    bridge.complete(fut, pending)
-
-                ctx.batcher.submit_nowait(
-                    variant_id, on_done, want_event=False,
-                    deadline_t=deadline_t, trace=trace,
-                )
+            fut = ctx.batcher.submit_future(variant_id, deadline_t,
+                                            trace=trace)
         except QueueFull as err:
             ctx.rejected("point")
             ctx.reqtrace.finish(trace, 429)
@@ -2096,16 +2033,6 @@ class AioServer:
         await writer.drain()
 
 
-def _resolve_pending(fut: asyncio.Future, pending) -> None:
-    """Completion hook target (runs on the loop via call_soon_threadsafe)."""
-    if fut.cancelled():
-        return
-    if pending.error is not None:
-        fut.set_exception(pending.error)
-    else:
-        fut.set_result(pending.result)
-
-
 def _write_chunk(writer, data: bytes) -> None:
     if data:
         writer.write(b"%x\r\n" % len(data) + data + b"\r\n")
@@ -2129,7 +2056,7 @@ def build_aio_server(store_dir: str | None = None, manager=None,
     """Wire manager -> engine -> batcher -> event-loop server (not yet
     serving; call ``serve_forever`` or ``start_background``).  The caller
     owns shutdown order: ``server.shutdown()`` then
-    ``server.ctx.batcher.close()`` — same contract as ``build_server``."""
+    ``server.ctx.batcher.close()``."""
     if manager is None:
         if store_dir is None:
             raise ValueError("build_aio_server needs store_dir or manager")
@@ -2141,8 +2068,9 @@ def build_aio_server(store_dir: str | None = None, manager=None,
     engine = QueryEngine(
         manager, registry=registry, region_cache_size=region_cache_size,
         residency=residency, breaker=breaker,
-        # mesh state budget = the residency manager's per-device share
-        # (see build_server — the two builders must not drift)
+        # the mesh state budget rides the residency manager's already-
+        # split per-device share (env/flag -> per-worker -> per-device),
+        # never the raw env
         mesh=serve_mesh_executor(
             registry=registry, breaker=breaker, log=log,
             budget_bytes=residency.budget if residency is not None

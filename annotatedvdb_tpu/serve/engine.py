@@ -750,8 +750,8 @@ class StatsColumns:
 class StatsResult:
     """One prepared analytics answer: per-interval summary dicts in
     request order, wrapped as ``{"n", "generation", "metrics", "bins",
-    "results"}``.  ``assemble()`` is the ONE renderer both front ends
-    buffer from (stats bodies are summaries — kilobytes, never
+    "results"}``.  ``assemble()`` is the ONE renderer the front end
+    buffers from (stats bodies are summaries — kilobytes, never
     row-materializing — so there is no streaming shape)."""
 
     __slots__ = ("generation", "metrics", "entries")
@@ -1307,7 +1307,7 @@ class QueryEngine:
                      limit: int | None = None, cursor: str | None = None,
                      stream_threshold: int | None = None,
                      host_only: bool = False):
-        """The front ends' region entry point: ``("text", str)`` for
+        """The front end's region entry point: ``("text", str)`` for
         responses small enough to buffer (cache-eligible when unpaged), or
         ``("page", RegionPage)`` when the row count exceeds
         ``stream_threshold`` — the caller streams prefix/rows/suffix

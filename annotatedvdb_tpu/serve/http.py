@@ -1,8 +1,9 @@
-"""Stdlib JSON API over the query engine: the serving front end.
-
-``ThreadingHTTPServer`` (one thread per connection — the point queries those
-threads carry coalesce in the batcher, so concurrency here is cheap) with a
-deliberately small route surface:
+"""The serving API's shared grammar: route spellings, body and query
+parsers, payload builders, message constants and :class:`ServeContext`
+(admission, per-kind metrics, the upsert gate) — everything about a
+response that is not socket work.  The one front end
+(:mod:`annotatedvdb_tpu.serve.aio`) renders from these.  The route
+surface is deliberately small:
 
 ====================================  =====================================
 ``GET /healthz``                      liveness + pinned generation + rows
@@ -44,36 +45,23 @@ import os
 import re
 import threading
 import time
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from urllib.parse import parse_qs, unquote, urlparse
+from urllib.parse import parse_qs
 
 #: pulls "returned":N out of the region envelope prefix (fixed field order)
 _RETURNED_RE = re.compile(r'"returned":(\d+)')
 
-from annotatedvdb_tpu.export.stream import (
-    STREAM_ROUTE as EXPORT_STREAM_ROUTE,
-    parse_stream_query,
-    stream_payload,
-)
 from annotatedvdb_tpu.obs import reqtrace as reqtrace_mod
 from annotatedvdb_tpu.obs.metrics import MetricsRegistry
 from annotatedvdb_tpu.obs.reqtrace import TraceRecorder
 from annotatedvdb_tpu.obs.slo import worst_of
 from annotatedvdb_tpu.obs.timeseries import derive_series, load_history
 from annotatedvdb_tpu.serve import resilience
-from annotatedvdb_tpu.serve.batcher import QueryBatcher, QueueFull
 from annotatedvdb_tpu.serve.engine import (
     QueryEngine,
     QueryError,
     parse_variant_id,
 )
-from annotatedvdb_tpu.serve.resilience import (
-    DeadlineExceeded,
-    DeviceBreaker,
-    OverloadGovernor,
-    PointCache,
-)
-from annotatedvdb_tpu.serve.snapshot import SnapshotManager
+from annotatedvdb_tpu.serve.resilience import OverloadGovernor, PointCache
 from annotatedvdb_tpu.utils.locks import make_lock
 
 #: per-request latency histogram edges (seconds; sub-ms to 2.5s)
@@ -87,9 +75,7 @@ DEFAULT_REGION_LIMIT = 10_000
 
 
 def healthz_payload(ctx) -> str:
-    """The ``/healthz`` body — ONE builder shared by both front ends, so
-    the route surface cannot silently fork (same reason
-    :func:`parse_region_params` lives here).  ``/healthz`` is LIVENESS
+    """The ``/healthz`` body.  ``/healthz`` is LIVENESS
     (the process answers); the ``ready`` field mirrors ``/readyz``
     (readiness: route traffic here or not)."""
     snap = ctx.manager.current()
@@ -157,9 +143,9 @@ def stats_payload(ctx) -> str:
     return json.dumps(stats)
 
 
-#: the trace-id echo header BOTH front ends return on EVERY response —
-#: the one response-shaping constant of the request-tracing plane (the
-#: AVDB801 contract: serve/aio.py imports it, never re-spells it)
+#: the trace-id echo header returned on EVERY response — the one
+#: response-shaping constant of the request-tracing plane (serve/aio.py
+#: imports it, never re-spells it)
 TRACE_HEADER = "X-Request-Id"
 
 _TRACEPARENT_RE = re.compile(
@@ -179,9 +165,8 @@ _MINT_SEQ = itertools.count(1)
 
 def resolve_trace_id(traceparent: str | None,
                      x_request_id: str | None) -> str:
-    """The request's trace id — the ONE resolution both front ends share
-    (the :func:`parse_region_params` convention: the echoed header must
-    be byte-identical across front ends for the same request).
+    """The request's trace id — the ONE resolution (the echoed header
+    is a function of the request's headers alone).
 
     Preference order: a well-formed W3C ``traceparent`` contributes its
     trace-id field; else a client ``X-Request-Id`` (sanitized to header-
@@ -201,9 +186,9 @@ def resolve_trace_id(traceparent: str | None,
 
 def chaos_enabled_from_env() -> bool:
     """``AVDB_SERVE_CHAOS`` — gates the runtime fault-arming route
-    (``POST /_chaos``, aio only) AND the on-demand trace dump
-    (``GET /debug/trace``, both front ends).  Resolved HERE once (the
-    AVDB802 knob contract); on a production server both routes 404
+    (``POST /_chaos``) AND the on-demand trace dump
+    (``GET /debug/trace``).  Resolved HERE once; on a production server
+    both routes 404
     byte-identically to any unknown route."""
     return os.environ.get("AVDB_SERVE_CHAOS", "") == "1"
 
@@ -213,7 +198,7 @@ def debug_trace_payload(ctx) -> str:
     trace-event JSON, merged with the PR-2 batcher tracer's drain spans
     on one timebase when the server runs with ``--traceOut``.  Chaos-
     gated like ``/_chaos`` (a trace dump is a debugging surface, not a
-    production route); shared by both front ends."""
+    production route)."""
     tracer = ctx.tracer
     base_ns = tracer._t0 if tracer is not None else ctx.reqtrace.t0_ns
     events = ctx.reqtrace.chrome_events(base_ns=base_ns)
@@ -224,8 +209,7 @@ def debug_trace_payload(ctx) -> str:
 
 
 def metrics_payload(ctx, query: str) -> str:
-    """The ``GET /metrics`` body — the ONE handler both front ends
-    share.  Plain scrape = this worker's registry; ``?fleet=1`` = the
+    """The ``GET /metrics`` body.  Plain scrape = this worker's registry; ``?fleet=1`` = the
     fleet-wide view (workers' published snapshots summed/maxed, plus the
     supervisor's ``avdb_fleet_*`` series), answered by WHICHEVER worker
     the kernel handed the connection to."""
@@ -270,8 +254,7 @@ def _health_sibling_docs(ctx) -> dict:
 
 
 def alerts_payload(ctx, query: str) -> str:
-    """The ``GET /alerts`` body — the ONE builder both front ends share
-    (the parity contract).  Plain = this worker's live SLO alert states;
+    """The ``GET /alerts`` body.  Plain = this worker's live SLO alert states;
     ``?fleet=1`` = per-worker states (self live, siblings from their
     persisted history mirrors, which carry the alert rows), rolled up
     into a fleet-wide ``firing`` count and worst ``state``."""
@@ -313,7 +296,7 @@ def alerts_payload(ctx, query: str) -> str:
     })
 
 
-#: the history route spelling, single-sourced for both front ends
+#: the history route spelling, single-sourced
 HISTORY_ROUTE = "/metrics/history"
 
 
@@ -372,9 +355,9 @@ def metrics_history_payload(ctx, query: str) -> str:
 
 def parse_region_params(query: str):
     """``(min_cadd, max_conseq_rank, limit, cursor)`` from a region query
-    string — the ONE parsing contract both front ends share (the parity
-    suite pins their responses byte-identical, so the parameter grammar
-    must not fork).  Raises :class:`QueryError` on a bad value;
+    string — the ONE parsing contract (single ``/region`` reads and the
+    batch API's per-interval envelopes are pinned byte-identical, so the
+    parameter grammar must not fork).  Raises :class:`QueryError` on a bad value;
     ``keep_blank_values`` so ``?cursor=`` (start a paged walk) survives."""
     params = parse_qs(query, keep_blank_values=True)
 
@@ -401,17 +384,15 @@ def parse_region_params(query: str):
     )
 
 
-#: the one grammar message for a malformed /regions body (both front ends)
+#: the one grammar message for a malformed /regions body
 REGIONS_BODY_ERROR = (
     'regions body must be {"regions": ["chr:start-end", ...]} with '
     'optional numeric "minCadd"/"maxConseqRank"/"limit" and boolean '
     '"tokenize"'
 )
 
-#: shared response-shaping messages — BOTH front ends render from these
-#: (the AVDB801 parity contract: a literal duplicated across the two
-#: front-end files forks the first time one side is edited, so the text
-#: lives here and ``serve/aio.py`` imports it)
+#: response-shaping messages — one constant each; ``serve/aio.py``
+#: imports them, and the tests compare response bodies with them
 BULK_BODY_ERROR = 'bulk body must be {"ids": ["chr:pos:ref:alt", ...]}'
 MSG_DEADLINE_ADMISSION = "deadline exhausted at admission"
 MSG_DEADLINE_EXECUTE = "deadline exhausted before execution"
@@ -423,8 +404,7 @@ MSG_UPSERTS_DISABLED = (
     "upserts are not enabled on this server (start with --upserts or "
     "AVDB_SERVE_UPSERTS=1)"
 )
-#: the 507 Insufficient Storage body — ONE constant (the AVDB801 parity
-#: rule): free disk under the store fell below the configured reserve, so
+#: the 507 Insufficient Storage body — ONE constant: free disk under the store fell below the configured reserve, so
 #: new writes are refused while everything that HOLDS or RECLAIMS space
 #: keeps running
 MSG_DISK_RESERVE = (
@@ -444,15 +424,14 @@ UPSERT_BODY_ERROR = (
 #: bigger batches belong to the offline loaders)
 UPSERT_MAX_ROWS = 4096
 
-#: the live-write route path — shared so the two front ends' routing
-#: cannot drift (the AVDB801 contract)
+#: the live-write route path
 UPSERT_ROUTE = "/variants/upsert"
 
 
 def parse_upsert_body(body: bytes) -> list[dict]:
     """Validated entries from a ``POST /variants/upsert`` JSON body — the
-    ONE body grammar both front ends share (the
-    :func:`parse_region_params` convention).  Returns
+    ONE body grammar (the :func:`parse_region_params` convention).
+    Returns
     ``[{"id", "ref_snp", "annotations"}, ...]``; raises
     :class:`QueryError` on any malformed field (the whole call fails —
     an upsert is atomic per request, never partially applied)."""
@@ -512,8 +491,7 @@ MSG_BROWNOUT_EXPORT = (
 )
 MSG_CAPACITY_EXPORT = "server at capacity (export admission bound)"
 
-#: the analytics route path — shared so the two front ends' routing
-#: cannot drift (the UPSERT_ROUTE convention)
+#: the analytics route path (the UPSERT_ROUTE convention)
 STATS_ROUTE = "/stats/region"
 
 #: the one grammar message for a malformed /stats/region body
@@ -526,8 +504,8 @@ STATS_BODY_ERROR = (
 
 def parse_stats_body(body: bytes):
     """``(specs, metrics, windows)`` from a ``POST /stats/region`` JSON
-    body — the ONE parsing contract both front ends share (the
-    :func:`parse_region_params` convention).  Shape/type errors raise
+    body — the ONE parsing contract (the :func:`parse_region_params`
+    convention).  Shape/type errors raise
     :class:`QueryError` here; value-level grammar (per-spec region
     syntax, unknown metric names, the windows range) is validated by the
     engine, which fails the one caller the same way."""
@@ -555,8 +533,8 @@ def parse_stats_body(body: bytes):
 
 def parse_regions_body(body: bytes):
     """``(specs, min_cadd, max_conseq_rank, limit, tokenize)`` from a
-    ``POST /regions`` JSON body — the ONE parsing contract both front
-    ends share (the :func:`parse_region_params` convention: the batch
+    ``POST /regions`` JSON body — the ONE parsing contract (the
+    :func:`parse_region_params` convention: the batch
     API's per-interval envelopes are pinned byte-identical to N single
     ``/region`` calls, so the parameter grammar must not fork either).
     Raises :class:`QueryError` on any malformed field; the per-spec
@@ -594,8 +572,8 @@ def parse_regions_body(body: bytes):
     )
 
 
-#: the replication ship route spellings — single-sourced for both front
-#: ends (the UPSERT_ROUTE convention); the follower's tailer
+#: the replication ship route spellings — single-sourced (the
+#: UPSERT_ROUTE convention); the follower's tailer
 #: (``store/replication.py``) fetches exactly these paths
 REPL_MANIFEST_ROUTE = "/repl/manifest"
 REPL_SEGMENT_ROUTE = "/repl/segment"
@@ -607,7 +585,7 @@ REPL_WAL_ROUTE = "/repl/wal"
 REPL_MAX_RANGE_BYTES = 64 << 20
 
 #: the 404 body when the ship surface has no on-disk store to serve from
-#: (in-memory test/bench stores) — shared by both front ends (AVDB801)
+#: (in-memory test/bench stores)
 MSG_REPL_UNAVAILABLE = (
     "replication ship surface unavailable: this server has no on-disk "
     "store directory"
@@ -617,7 +595,7 @@ MSG_REPL_UNAVAILABLE = (
 def follower_upsert_payload(ctx) -> str:
     """The 403 body an upsert gets on a replication follower — carries
     the leader's location so a well-behaved client redirects its writes
-    (ONE builder for both front ends, the AVDB801 contract)."""
+    (ONE builder)."""
     return json.dumps({
         "error": "this server is a replication follower (read-only); "
                  "send writes to the leader",
@@ -630,8 +608,8 @@ def repl_manifest_payload(ctx) -> tuple[int, str]:
     document (the consistent snapshot cut plus the WAL/ledger stable-
     prefix listing), built by
     :func:`annotatedvdb_tpu.store.replication.ship_manifest`.  ONE
-    builder for both front ends; the aio front end runs it on the
-    executor pool (it stats and reads files — AVDB701)."""
+    builder; the front end runs it on the executor pool (it stats and
+    reads files — AVDB701)."""
     if ctx.repl_store_dir is None:
         return 404, json.dumps({"error": MSG_REPL_UNAVAILABLE})
     from annotatedvdb_tpu.store.replication import ReplError, ship_manifest
@@ -672,13 +650,13 @@ def repl_file_response(ctx, query: str) -> tuple[int, "bytes | str"]:
 
 
 class ServeContext:
-    """Everything a handler thread needs, shared across requests."""
+    """Everything a request needs of the process, shared across requests."""
 
     #: published worker metric snapshots older than this are a dead
     #: worker's leavings and drop out of the fleet view
     FLEET_SNAPSHOT_TTL_S = 15.0
 
-    def __init__(self, manager, engine: QueryEngine, batcher: QueryBatcher,
+    def __init__(self, manager, engine: QueryEngine, batcher,
                  registry: MetricsRegistry, max_inflight: int | None = None,
                  memtable=None, log=None, flight=None,
                  telemetry_dir: str | None = None, tracer=None,
@@ -696,13 +674,9 @@ class ServeContext:
         self.tracer = tracer
         self.telemetry_dir = telemetry_dir
         #: the health plane (obs/slo.HealthPlane, None = disabled): the
-        #: metrics time-series ring + SLO burn-rate evaluator.  Ticking
-        #: mirrors the flight-flush split below: the threaded front end
-        #: ticks inline (time-gated, riding request completions and
-        #: health polls); the aio front end clears health_tick_inline
-        #: and ticks from its maintenance loop via the executor pool
+        #: metrics time-series ring + SLO burn-rate evaluator, ticked
+        #: from the server's maintenance tick via the executor pool
         self.health = health
-        self.health_tick_inline = True
         self.worker_index = int(worker_index)
         self.started_t = time.time()
         #: the device this process serves from, as JAX reports it
@@ -711,14 +685,6 @@ class ServeContext:
 
         self.device = device_summary()
         self.debug_trace_enabled = chaos_enabled_from_env()
-        #: flight-recorder flush cadence: request summaries buffer (the
-        #: hot path never touches the mmap) and drain every FLUSH_S.  On
-        #: the threaded front end the flush rides request completions
-        #: (inline, time-gated); the aio front end clears this flag and
-        #: flushes from its maintenance tick via the executor pool — the
-        #: event loop never does the batch write
-        self.flight_flush_inline = True
-        self._flight_flush_last = 0.0
         #: the live write path (``store/memtable.py``), or None for the
         #: historical read-only server — the upsert route answers
         #: MSG_UPSERTS_DISABLED when unset
@@ -740,8 +706,8 @@ class ServeContext:
         self.log = log if log is not None else (lambda msg: None)
         #: disk-pressure degradation (``store/maintenance.py``): while
         #: free disk under the store sits below
-        #: AVDB_STORE_DISK_RESERVE_BYTES, upserts answer 507 on both
-        #: front ends (the shared upsert_execute below is the one gate).
+        #: AVDB_STORE_DISK_RESERVE_BYTES, upserts answer 507
+        #: (upsert_execute below is the one gate).
         #: None when the server is read-only or the store has no
         #: directory (in-memory test stores)
         self.disk_guard = None
@@ -758,9 +724,8 @@ class ServeContext:
         #: default per-request deadline budget (0 = none unless the client
         #: sends X-Deadline-Ms)
         self.default_deadline_s = resilience.default_deadline_s()
-        #: the brownout ladder: fed by observe(), stepped on the aio
-        #: maintenance tick AND (time-gated) on request completion so the
-        #: threaded front end needs no extra thread
+        #: the brownout ladder: fed by observe(), stepped on the server's
+        #: maintenance tick AND (time-gated) on request completion
         self.governor = OverloadGovernor(
             depth_fn=batcher.depth, max_queue=batcher.max_queue,
             registry=registry, on_change=self._brownout_event,
@@ -865,21 +830,9 @@ class ServeContext:
             rows_c.inc(rows)
         # brownout signal: every completed request feeds the ladder; the
         # evaluation itself is time-gated inside maybe_step (one lock +
-        # compare per request on the threaded front end; the aio front end
-        # also steps on its maintenance tick)
+        # compare per request; the maintenance tick steps it too)
         self.governor.note_latency(seconds)
         self.governor.maybe_step()
-        if self.flight is not None and self.flight_flush_inline:
-            now = time.monotonic()
-            if now - self._flight_flush_last >= self.flight.FLUSH_S:
-                self._flight_flush_last = now
-                try:
-                    self.flight.flush(limit=self.flight.FLUSH_BATCH)
-                except Exception:  # avdb: noqa[AVDB602] -- the recorder already logs; a flush failure must never fail the request riding it
-                    pass
-        if self.health is not None and self.health_tick_inline \
-                and self.health.due():
-            self.health.tick()  # absorbs its own failures (obs/slo.py)
 
     def rejected(self, kind: str) -> None:
         self._kind[kind][3].inc()
@@ -989,9 +942,8 @@ class ServeContext:
         )
 
     def point_preflight(self, variant_id: str, deadline_t: float | None):
-        """The point-read admission decision BOTH front ends share (the
-        parity convention: decision logic lives once, only rendering
-        forks).  Returns one of::
+        """The point-read admission decision (decision logic lives here,
+        the front end only renders).  Returns one of::
 
             ("shed", None)        deadline dead at admission (counted)
             ("cached", record)    cache-first answer (record may be None
@@ -1021,9 +973,9 @@ class ServeContext:
 
     def upsert_execute(self, body: bytes,
                        max_rows: int | None = None, trace=None):
-        """The upsert decision+execution BOTH front ends share (the
-        ``point_preflight`` convention: logic lives once, front ends only
-        render).  Returns ``(status, json_body, rows_in_request)``.
+        """The upsert decision+execution (the ``point_preflight``
+        convention: logic lives here, the front end only renders).
+        Returns ``(status, json_body, rows_in_request)``.
 
         The 200 is the ACK: it is built only after the accepted rows'
         WAL frame is fsync'd (``Memtable.upsert`` orders WAL-then-
@@ -1040,8 +992,7 @@ class ServeContext:
             return 403, json.dumps({"error": MSG_UPSERTS_DISABLED}), 0
         if self.disk_guard is not None and self.disk_guard.breached():
             # disk-pressure degradation ladder: WRITES shed first (507,
-            # both front ends byte-identical through this one gate);
-            # reads, flushes of already-acknowledged rows, and
+            # through this one gate); reads, flushes of already-acknowledged rows, and
             # space-reclaiming compaction keep running.  Nothing durable
             # happened, nothing was acknowledged — the client retries
             # once space is freed.
@@ -1163,21 +1114,13 @@ class ServeContext:
         """(ready, reason): readiness gates routing, not liveness.  Not
         ready while a snapshot swap is loading (the warming-worker case)
         or the brownout ladder reached shed_bulk.  Health polls step the
-        ladder too (time-gated): a shed_bulk worker a router has fully
-        DRAINED completes no requests, so on the threaded front end the
-        router's own readiness probes are what lets the now-idle ladder
-        de-escalate back to ready.  Probes also check the memtable flush
-        triggers, so an idle threaded worker's age-based flush fires off
-        its health polls (the aio front end additionally checks on its
-        maintenance tick)."""
+        ladder too (time-gated), beside the maintenance tick: a
+        shed_bulk worker a router has fully DRAINED completes no
+        requests, and the router's own readiness probes let the now-idle
+        ladder de-escalate back to ready.  Probes also check the
+        memtable flush triggers, as the maintenance tick does."""
         self.governor.maybe_step()
         self.maybe_flush_memtable()
-        # the health plane ticks off probes too: an idle (or drained)
-        # threaded worker completes no requests, and its alert states
-        # must still advance — resolution especially
-        if self.health is not None and self.health_tick_inline \
-                and self.health.due():
-            self.health.tick()
         if getattr(self.manager, "swapping", False):
             return False, "snapshot swap in progress"
         if self.repl is not None and self.repl.lag_exceeded():
@@ -1223,571 +1166,3 @@ class ServeContext:
                 self._m_swaps.inc()
         except Exception as err:
             self.log(f"snapshot refresh errored: {err}")
-
-
-class ServeHandler(BaseHTTPRequestHandler):
-    """Routes one request; all state lives on ``self.server.ctx``."""
-
-    server_version = "avdb-serve/1"
-    protocol_version = "HTTP/1.1"
-
-    #: this request's resolved trace id (set at route entry, echoed on
-    #: every response — one handler instance serves one connection's
-    #: requests strictly in sequence, so an attribute is race-free)
-    _trace_id: str | None = None
-
-    # -- plumbing -----------------------------------------------------------
-
-    def log_message(self, format, *args):  # stdlib signature
-        self.server.ctx.log(f"{self.address_string()} {format % args}")
-
-    def _reply(self, status: int, body,
-               content_type: str = "application/json") -> None:
-        payload = body.encode() if isinstance(body, str) else body
-        self.send_response(status)
-        self.send_header("Content-Type", content_type)
-        self.send_header("Content-Length", str(len(payload)))
-        if self._trace_id is not None:
-            self.send_header(TRACE_HEADER, self._trace_id)
-        if status in (429, 503):
-            self.send_header("Retry-After", "1")
-        self.end_headers()
-        try:
-            self.wfile.write(payload)
-        except (BrokenPipeError, ConnectionResetError):
-            pass  # client hung up mid-response; already accounted
-
-    def _error(self, status: int, message: str) -> None:
-        self._reply(status, json.dumps({"error": message}))
-
-    # -- routes -------------------------------------------------------------
-
-    def do_GET(self):
-        ctx = self.server.ctx
-        url = urlparse(self.path)
-        path = unquote(url.path)
-        self._trace_id = resolve_trace_id(
-            self.headers.get("traceparent"),
-            self.headers.get(TRACE_HEADER),
-        )
-        if path == "/healthz":
-            ctx.refresh_snapshot()
-            self._reply(200, healthz_payload(ctx))
-            return
-        if path == "/readyz":
-            # readiness probes refresh too (TTL-coalesced): a DRAINED
-            # worker sees commits — and their swapping windows — only
-            # through its probes, and "ready" must not mean "about to
-            # block the first data request on a whole generation load"
-            ctx.refresh_snapshot()
-            status, body = readyz_payload(ctx)
-            self._reply(status, body)
-            return
-        if path == "/metrics":
-            self._reply(200, metrics_payload(ctx, url.query),
-                        content_type="text/plain; version=0.0.4")
-            return
-        if path == "/stats":
-            self._reply(200, stats_payload(ctx))
-            return
-        if path == "/alerts":
-            self._reply(200, alerts_payload(ctx, url.query))
-            return
-        if path == HISTORY_ROUTE:
-            self._reply(200, metrics_history_payload(ctx, url.query))
-            return
-        if path == REPL_MANIFEST_ROUTE:
-            status, body = repl_manifest_payload(ctx)
-            self._reply(status, body)
-            return
-        if path in (REPL_SEGMENT_ROUTE, REPL_WAL_ROUTE):
-            status, body = repl_file_response(ctx, url.query)
-            self._reply(status, body,
-                        content_type="application/octet-stream"
-                        if isinstance(body, bytes) else "application/json")
-            return
-        if path == "/debug/trace" and ctx.debug_trace_enabled:
-            # chaos-gated like /_chaos: on a production server this path
-            # 404s byte-identically to any unknown route
-            self._reply(200, debug_trace_payload(ctx))
-            return
-        if path == EXPORT_STREAM_ROUTE:
-            self._export_stream(ctx, url.query)
-            return
-        if path.startswith("/variant/"):
-            self._point(ctx, path[len("/variant/"):])
-            return
-        if path.startswith("/region/"):
-            self._region(ctx, path[len("/region/"):], url.query)
-            return
-        self._error(404, f"no such route: {path}")
-
-    def do_POST(self):
-        ctx = self.server.ctx
-        path = unquote(urlparse(self.path).path)
-        self._trace_id = resolve_trace_id(
-            self.headers.get("traceparent"),
-            self.headers.get(TRACE_HEADER),
-        )
-        if path == "/variants":
-            self._bulk(ctx)
-            return
-        if path == UPSERT_ROUTE:
-            self._upsert(ctx)
-            return
-        if path == "/regions":
-            self._regions(ctx)
-            return
-        if path == STATS_ROUTE:
-            self._stats(ctx)
-            return
-        self._error(404, f"no such route: {path}")
-
-    # -- query kinds --------------------------------------------------------
-
-    def _point(self, ctx: ServeContext, variant_id: str) -> None:
-        t0 = time.perf_counter()
-        trace = ctx.reqtrace.begin(self._trace_id, "point")
-        ctx.refresh_snapshot()
-        deadline_t = ctx.request_deadline(self.headers.get("X-Deadline-Ms"))
-        action, payload = ctx.point_preflight(variant_id, deadline_t)
-        if action == "shed":
-            ctx.reqtrace.finish(trace, 504)
-            self._error(504, MSG_DEADLINE_ADMISSION)
-            return
-        if action == "cached":
-            if payload is None:
-                ctx.observe("point", time.perf_counter() - t0)
-                ctx.reqtrace.finish(trace, 404)
-                self._error(404, f"variant {variant_id!r} not in store")
-            else:
-                ctx.observe("point", time.perf_counter() - t0, rows=1)
-                ctx.reqtrace.finish(trace, 200)
-                self._reply(200, payload)
-            return
-        generation = payload
-        if trace is not None:
-            trace.since("admission", t0)
-        try:
-            record = ctx.batcher.submit(variant_id, deadline_t=deadline_t,
-                                        trace=trace)
-        except QueueFull as err:
-            ctx.rejected("point")
-            ctx.reqtrace.finish(trace, 429)
-            self._error(429, str(err))
-            return
-        except DeadlineExceeded as err:
-            # the batcher shed it (and counted stage="batcher")
-            ctx.reqtrace.finish(trace, 504)
-            self._error(504, str(err))
-            return
-        except QueryError as err:
-            ctx.errored("point")
-            ctx.reqtrace.finish(trace, 400)
-            self._error(400, str(err))
-            return
-        except Exception as err:
-            ctx.errored("point")
-            ctx.reqtrace.finish(trace, 500)
-            self._error(500, f"{type(err).__name__}: {err}")
-            return
-        t_render = time.perf_counter_ns()
-        ctx.remember_point(generation, variant_id, record)
-        if record is None:
-            ctx.observe("point", time.perf_counter() - t0)
-            ctx.reqtrace.finish(trace, 404)
-            self._error(404, f"variant {variant_id!r} not in store")
-            return
-        ctx.observe("point", time.perf_counter() - t0, rows=1)
-        if trace is not None:
-            trace.record("render", t_render, time.perf_counter_ns())
-        ctx.reqtrace.finish(trace, 200)
-        self._reply(200, record)
-
-    def _bulk(self, ctx: ServeContext) -> None:
-        t0 = time.perf_counter()
-        if ctx.governor.shed_bulk():
-            ctx.brownout_shed()
-            self._error(503, MSG_BROWNOUT_BULK)
-            return
-        deadline_t = ctx.request_deadline(self.headers.get("X-Deadline-Ms"))
-        if deadline_t is not None and time.monotonic() >= deadline_t:
-            ctx.deadline_shed("admission")
-            self._error(504, MSG_DEADLINE_ADMISSION)
-            return
-        if not ctx.admit():
-            ctx.rejected("bulk")
-            self._error(429, MSG_CAPACITY_BULK)
-            return
-        try:
-            ctx.refresh_snapshot()
-            try:
-                length = int(self.headers.get("Content-Length", 0))
-                body = json.loads(self.rfile.read(length) or b"{}")
-                ids = body["ids"]
-                if not isinstance(ids, list) \
-                        or not all(isinstance(i, str) for i in ids):
-                    raise KeyError("ids")
-            except (ValueError, KeyError, TypeError):
-                ctx.errored("bulk")
-                self._error(400, BULK_BODY_ERROR)
-                return
-            if deadline_t is not None and time.monotonic() >= deadline_t:
-                # body read/queueing ate the budget: shed BEFORE the probe
-                ctx.deadline_shed("execute")
-                self._error(504, MSG_DEADLINE_EXECUTE)
-                return
-            trace = ctx.reqtrace.begin(self._trace_id, "bulk")
-            if trace is not None:
-                trace.since("admission", t0)
-            try:
-                with reqtrace_mod.stage(trace, "device"):
-                    results = ctx.engine.lookup_many(ids)
-            except QueryError as err:
-                ctx.errored("bulk")
-                ctx.reqtrace.finish(trace, 400)
-                self._error(400, str(err))
-                return
-            except Exception as err:
-                ctx.errored("bulk")
-                ctx.reqtrace.finish(trace, 500)
-                self._error(500, f"{type(err).__name__}: {err}")
-                return
-            with reqtrace_mod.stage(trace, "render"):
-                found = sum(1 for r in results if r is not None)
-                body = (
-                    f'{{"n":{len(results)},"found":{found},"results":['
-                    + ",".join(r if r is not None else "null" for r in results)
-                    + "]}"
-                )
-                ctx.observe("bulk", time.perf_counter() - t0, rows=found)
-            ctx.reqtrace.finish(trace, 200)
-            self._reply(200, body)
-        finally:
-            ctx.release()
-
-    def _upsert(self, ctx: ServeContext) -> None:
-        """Live write path: the bulk admission shape (brownout shed,
-        deadline at admission AND before execution, inflight slot, 429)
-        around the shared :meth:`ServeContext.upsert_execute` — the 200
-        is the durable ack."""
-        if ctx.governor.shed_bulk():
-            ctx.brownout_shed()
-            self._error(503, MSG_BROWNOUT_UPSERT)
-            return
-        deadline_t = ctx.request_deadline(self.headers.get("X-Deadline-Ms"))
-        if deadline_t is not None and time.monotonic() >= deadline_t:
-            ctx.deadline_shed("admission")
-            self._error(504, MSG_DEADLINE_ADMISSION)
-            return
-        if not ctx.admit():
-            ctx.rejected("upsert")
-            self._error(429, MSG_CAPACITY_UPSERT)
-            return
-        try:
-            ctx.refresh_snapshot()
-            try:
-                length = int(self.headers.get("Content-Length", 0))
-                raw = self.rfile.read(length) if length else b""
-            except ValueError:
-                ctx.errored("upsert")
-                self._error(400, UPSERT_BODY_ERROR)
-                return
-            if deadline_t is not None and time.monotonic() >= deadline_t:
-                # body read/queueing ate the budget: shed BEFORE the WAL
-                # write (nothing durable happened, nothing acknowledged)
-                ctx.deadline_shed("execute")
-                self._error(504, MSG_DEADLINE_EXECUTE)
-                return
-            trace = ctx.reqtrace.begin(self._trace_id, "upsert")
-            status, body, _rows = ctx.upsert_execute(raw, trace=trace)
-            ctx.reqtrace.finish(trace, status)
-            self._reply(status, body)
-            ctx.maybe_flush_memtable()
-        finally:
-            ctx.release()
-
-    def _regions(self, ctx: ServeContext) -> None:
-        """Batch region join: admission/brownout/deadline shape of
-        ``_bulk``, execution through the engine's batched BITS path."""
-        t0 = time.perf_counter()
-        if ctx.governor.shed_bulk():
-            ctx.brownout_shed()
-            self._error(503, MSG_BROWNOUT_REGION)
-            return
-        deadline_t = ctx.request_deadline(self.headers.get("X-Deadline-Ms"))
-        if deadline_t is not None and time.monotonic() >= deadline_t:
-            ctx.deadline_shed("admission")
-            self._error(504, MSG_DEADLINE_ADMISSION)
-            return
-        if not ctx.admit():
-            ctx.rejected("regions")
-            self._error(429, MSG_CAPACITY_REGION)
-            return
-        try:
-            ctx.refresh_snapshot()
-            try:
-                length = int(self.headers.get("Content-Length", 0))
-                raw = self.rfile.read(length) if length else b""
-                specs, min_cadd, max_rank, limit, tokenize = \
-                    parse_regions_body(raw)
-            except (ValueError, QueryError) as err:
-                ctx.errored("regions")
-                self._error(400, str(err) if isinstance(err, QueryError)
-                            else REGIONS_BODY_ERROR)
-                return
-            if deadline_t is not None and time.monotonic() >= deadline_t:
-                # body read/queueing ate the budget: shed BEFORE the scan
-                ctx.deadline_shed("execute")
-                self._error(504, MSG_DEADLINE_EXECUTE)
-                return
-            trace = ctx.reqtrace.begin(self._trace_id, "regions")
-            if trace is not None:
-                trace.since("admission", t0)
-            try:
-                cap = ctx.governor.region_limit_cap()
-                if cap is not None:
-                    # brownout level >= 1: bound per-interval render work
-                    limit = min(limit, cap)
-                with reqtrace_mod.stage(trace, "device"):
-                    result = ctx.engine.regions_serve(
-                        specs,
-                        min_cadd=min_cadd,
-                        max_conseq_rank=max_rank,
-                        limit=limit,
-                        tokenize=tokenize,
-                    )
-            except QueryError as err:
-                ctx.errored("regions")
-                ctx.reqtrace.finish(trace, 400)
-                self._error(400, str(err))
-                return
-            except Exception as err:
-                ctx.errored("regions")
-                ctx.reqtrace.finish(trace, 500)
-                self._error(500, f"{type(err).__name__}: {err}")
-                return
-            with reqtrace_mod.stage(trace, "render"):
-                body = result.assemble()
-                ctx.observe("regions", time.perf_counter() - t0,
-                            rows=result.returned)
-            ctx.reqtrace.finish(trace, 200)
-            self._reply(200, body)
-        finally:
-            ctx.release()
-
-    def _stats(self, ctx: ServeContext) -> None:
-        """Analytics panel: the bulk admission shape of ``_regions``
-        (brownout shed, deadline at admission AND before execution,
-        inflight slot, 429), execution through the engine's fused stats
-        path.  Bodies are summaries — never row-materializing — so the
-        response always buffers."""
-        t0 = time.perf_counter()
-        if ctx.governor.shed_bulk():
-            ctx.brownout_shed()
-            self._error(503, MSG_BROWNOUT_STATS)
-            return
-        deadline_t = ctx.request_deadline(self.headers.get("X-Deadline-Ms"))
-        if deadline_t is not None and time.monotonic() >= deadline_t:
-            ctx.deadline_shed("admission")
-            self._error(504, MSG_DEADLINE_ADMISSION)
-            return
-        if not ctx.admit():
-            ctx.rejected("stats")
-            self._error(429, MSG_CAPACITY_STATS)
-            return
-        try:
-            ctx.refresh_snapshot()
-            try:
-                length = int(self.headers.get("Content-Length", 0))
-                raw = self.rfile.read(length) if length else b""
-                specs, metrics, windows = parse_stats_body(raw)
-            except (ValueError, QueryError) as err:
-                ctx.errored("stats")
-                self._error(400, str(err) if isinstance(err, QueryError)
-                            else STATS_BODY_ERROR)
-                return
-            if deadline_t is not None and time.monotonic() >= deadline_t:
-                # body read/queueing ate the budget: shed BEFORE the scan
-                ctx.deadline_shed("execute")
-                self._error(504, MSG_DEADLINE_EXECUTE)
-                return
-            trace = ctx.reqtrace.begin(self._trace_id, "stats")
-            if trace is not None:
-                trace.since("admission", t0)
-            try:
-                with reqtrace_mod.stage(trace, "device"):
-                    result = ctx.engine.stats_serve(
-                        specs, metrics=metrics, windows=windows,
-                    )
-            except QueryError as err:
-                ctx.errored("stats")
-                ctx.reqtrace.finish(trace, 400)
-                self._error(400, str(err))
-                return
-            except Exception as err:
-                ctx.errored("stats")
-                ctx.reqtrace.finish(trace, 500)
-                self._error(500, f"{type(err).__name__}: {err}")
-                return
-            with reqtrace_mod.stage(trace, "render"):
-                body = result.assemble()
-                ctx.observe("stats", time.perf_counter() - t0,
-                            rows=result.returned)
-            ctx.reqtrace.finish(trace, 200)
-            self._reply(200, body)
-        finally:
-            ctx.release()
-
-    def _export_stream(self, ctx: ServeContext, query: str) -> None:
-        """``GET /export/stream``: one packed corpus batch of a region
-        slice — the bulk admission shape of ``_stats`` (brownout shed,
-        deadline at admission, inflight slot, 429), execution through the
-        shared :func:`stream_payload` builder (device kernel behind the
-        breaker, byte-identical host twin when it is open)."""
-        t0 = time.perf_counter()
-        if ctx.governor.shed_bulk():
-            ctx.brownout_shed()
-            self._error(503, MSG_BROWNOUT_EXPORT)
-            return
-        deadline_t = ctx.request_deadline(self.headers.get("X-Deadline-Ms"))
-        if deadline_t is not None and time.monotonic() >= deadline_t:
-            ctx.deadline_shed("admission")
-            self._error(504, MSG_DEADLINE_ADMISSION)
-            return
-        if not ctx.admit():
-            ctx.rejected("export")
-            self._error(429, MSG_CAPACITY_EXPORT)
-            return
-        try:
-            ctx.refresh_snapshot()
-            try:
-                params = parse_stream_query(query)
-            except ValueError as err:  # QueryError subclasses ValueError
-                ctx.errored("export")
-                self._error(400, str(err))
-                return
-            trace = ctx.reqtrace.begin(self._trace_id, "export")
-            if trace is not None:
-                trace.since("admission", t0)
-            try:
-                with reqtrace_mod.stage(trace, "device"):
-                    body, n_valid = stream_payload(ctx.engine, params)
-            except QueryError as err:
-                ctx.errored("export")
-                ctx.reqtrace.finish(trace, 400)
-                self._error(400, str(err))
-                return
-            except Exception as err:
-                ctx.errored("export")
-                ctx.reqtrace.finish(trace, 500)
-                self._error(500, f"{type(err).__name__}: {err}")
-                return
-            ctx.observe("export", time.perf_counter() - t0, rows=n_valid)
-            ctx.reqtrace.finish(trace, 200)
-            self._reply(200, body)
-        finally:
-            ctx.release()
-
-    def _region(self, ctx: ServeContext, spec: str, query: str) -> None:
-        t0 = time.perf_counter()
-        if ctx.governor.shed_bulk():
-            ctx.brownout_shed()
-            self._error(503, MSG_BROWNOUT_REGION)
-            return
-        deadline_t = ctx.request_deadline(self.headers.get("X-Deadline-Ms"))
-        if deadline_t is not None and time.monotonic() >= deadline_t:
-            ctx.deadline_shed("admission")
-            self._error(504, MSG_DEADLINE_ADMISSION)
-            return
-        if not ctx.admit():
-            ctx.rejected("region")
-            self._error(429, MSG_CAPACITY_REGION)
-            return
-        try:
-            ctx.refresh_snapshot()
-            trace = ctx.reqtrace.begin(self._trace_id, "region")
-            if trace is not None:
-                trace.since("admission", t0)
-            try:
-                min_cadd, max_rank, limit, cursor = \
-                    parse_region_params(query)
-                cap = ctx.governor.region_limit_cap()
-                if cap is not None:
-                    # brownout level >= 1: bound per-request render work
-                    limit = min(limit, cap)
-                with reqtrace_mod.stage(trace, "device"):
-                    text = ctx.engine.region(
-                        spec,
-                        min_cadd=min_cadd,
-                        max_conseq_rank=max_rank,
-                        limit=limit,
-                        cursor=cursor,
-                    )
-            except QueryError as err:
-                ctx.errored("region")
-                ctx.reqtrace.finish(trace, 400)
-                self._error(400, str(err))
-                return
-            except Exception as err:
-                ctx.errored("region")
-                ctx.reqtrace.finish(trace, 500)
-                self._error(500, f"{type(err).__name__}: {err}")
-                return
-            # the row count sits in the fixed-format envelope prefix —
-            # never re-parse the (up to 10k-record) response body for it
-            m = _RETURNED_RE.search(text[:256])
-            returned = int(m.group(1)) if m else 0
-            ctx.observe("region", time.perf_counter() - t0, rows=returned)
-            ctx.reqtrace.finish(trace, 200)
-            self._reply(200, text)
-        finally:
-            ctx.release()
-
-
-def build_server(store_dir: str | None = None, manager=None,
-                 host: str = "127.0.0.1", port: int = 0,
-                 max_batch: int | None = None,
-                 max_wait_s: float | None = None,
-                 max_queue: int | None = None,
-                 region_cache_size: int | None = None,
-                 registry: MetricsRegistry | None = None,
-                 residency=None, memtable=None,
-                 tracer=None, log=None, flight=None,
-                 telemetry_dir: str | None = None,
-                 worker_index: int = 0, health=None) -> ThreadingHTTPServer:
-    """Wire manager → engine → batcher → HTTP server (not yet serving; call
-    ``serve_forever`` or run it on a thread).  The server carries its
-    :class:`ServeContext` as ``httpd.ctx``; callers own shutdown order:
-    ``httpd.shutdown()`` then ``httpd.ctx.batcher.close()``."""
-    if manager is None:
-        if store_dir is None:
-            raise ValueError("build_server needs store_dir or manager")
-        manager = SnapshotManager(store_dir, log=log)
-    registry = registry if registry is not None else MetricsRegistry()
-    from annotatedvdb_tpu.serve.mesh_exec import serve_mesh_executor
-
-    breaker = DeviceBreaker(registry=registry, log=log)
-    engine = QueryEngine(
-        manager, registry=registry, region_cache_size=region_cache_size,
-        residency=residency, breaker=breaker,
-        # the mesh state budget rides the residency manager's already-
-        # split per-device share (env/flag -> per-worker -> per-device),
-        # never the raw env
-        mesh=serve_mesh_executor(
-            registry=registry, breaker=breaker, log=log,
-            budget_bytes=residency.budget if residency is not None
-            else None,
-        ),
-    )
-    batcher = QueryBatcher(
-        engine, max_batch=max_batch, max_wait_s=max_wait_s,
-        max_queue=max_queue, tracer=tracer, registry=registry,
-    )
-    httpd = ThreadingHTTPServer((host, port), ServeHandler)
-    httpd.daemon_threads = True
-    httpd.ctx = ServeContext(manager, engine, batcher, registry,
-                             memtable=memtable, log=log, flight=flight,
-                             telemetry_dir=telemetry_dir, tracer=tracer,
-                             worker_index=worker_index, health=health)
-    return httpd

@@ -108,7 +108,7 @@ def serve_mesh_on():
 
 def serve_mesh_executor(registry=None, breaker=None, log=None,
                         budget_bytes: int | None = None):
-    """The front ends' one construction point: a :class:`MeshExecutor`
+    """The server builder's one construction point: a :class:`MeshExecutor`
     when :func:`serve_mesh_on` resolves a mesh, else None (single-device
     serving pays nothing).  ``budget_bytes`` is the caller's PER-DEVICE
     resident budget — the builders pass the residency manager's already-

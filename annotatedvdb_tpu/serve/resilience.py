@@ -3,8 +3,8 @@
 PR 3 made the write path crash-safe and PR 6 made the read path fast; this
 module is the read path's FAILURE response.  Production serving survives
 overload and partial device failure through three mechanisms, each a small
-self-contained governor wired into both front ends (``serve/http.py`` and
-``serve/aio.py``) through :class:`~annotatedvdb_tpu.serve.http.ServeContext`:
+self-contained governor wired into the front end (``serve/aio.py``)
+through :class:`~annotatedvdb_tpu.serve.http.ServeContext`:
 
 - **deadline propagation** (:class:`DeadlineExceeded`, :func:`deadline_at`)
   — requests carry ``X-Deadline-Ms`` (default
@@ -74,7 +74,7 @@ LEVEL_NAMES = ("normal", "limit", "cache_first", "shed_bulk")
 
 class DeadlineExceeded(RuntimeError):
     """The request's deadline budget ran out before (or while) it executed
-    — the front ends map this to HTTP 504.  Raised for SHED work: the
+    — the front end maps this to HTTP 504.  Raised for SHED work: the
     response says "we did not do this", never "we failed doing it"."""
 
 
@@ -184,9 +184,9 @@ class OverloadGovernor:
     under a second while flapping is structurally impossible — a level
     change always out-waits the hold.
 
-    Thread-safe; on the asyncio front end :meth:`maybe_step` runs on the
-    loop's maintenance tick, on the threaded front end it rides request
-    completion (time-gated, so per-request cost is one lock + compare).
+    Thread-safe; :meth:`maybe_step` runs on the loop's maintenance tick
+    and rides request completion and health polls (time-gated, so
+    per-request cost is one lock + compare).
     """
 
     EVAL_INTERVAL_S = 0.25
@@ -308,7 +308,7 @@ class OverloadGovernor:
             except Exception:  # avdb: noqa[AVDB602] -- an observer must never fail the ladder evaluation it watches
                 pass
 
-    # -- level queries (the front ends' contract) ---------------------------
+    # -- level queries (the front end's contract) ---------------------------
 
     @property
     def level(self) -> int:

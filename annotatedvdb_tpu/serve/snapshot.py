@@ -18,7 +18,7 @@ reference's Postgres snapshot isolation).
   new generation OFF-lock when it changed, then swaps the pin atomically.
   The ``snapshot.swap`` fault point fires between load and swap: a failure
   there must leave the old generation serving, which the fault matrix pins.
-- ``maybe_refresh()`` is the front ends' coalesced entry point: at serving
+- ``maybe_refresh()`` is the front end's coalesced entry point: at serving
   QPS a per-request ``stat`` is real syscall pressure, so freshness checks
   collapse to one ``stat`` per ``AVDB_SERVE_SNAPSHOT_TTL_MS`` window
   (default 250ms — a commit becomes visible within a quarter second, not

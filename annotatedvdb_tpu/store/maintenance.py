@@ -28,8 +28,8 @@ operator noticed.  This module removes the human from that loop
 
 - :class:`DiskReserveGuard` — the ``AVDB_STORE_DISK_RESERVE_BYTES``
   degradation ladder: when free disk under the store drops below the
-  reserve, upserts answer **507 Insufficient Storage** on BOTH front
-  ends (single-source message, ``serve/http.MSG_DISK_RESERVE``) while
+  reserve, upserts answer **507 Insufficient Storage** (single-source
+  message, ``serve/http.MSG_DISK_RESERVE``) while
   reads, flushes of already-acknowledged rows, and space-*reclaiming*
   compaction keep running — a full disk becomes a designed write-shed,
   not whatever ENOSPC happens to hit first.  The ``maintain.disk_guard``

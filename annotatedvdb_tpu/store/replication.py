@@ -110,7 +110,7 @@ def is_repl_cursor(fname: str) -> bool:
     return fname == CURSOR_FILE
 
 
-# -- knobs (resolved ONCE here — the AVDB802 discipline) ---------------------
+# -- knobs (resolved ONCE here) ----------------------------------------------
 
 
 def repl_max_lag_from_env() -> float:
@@ -267,7 +267,7 @@ def wal_names(store_dir: str) -> list[str]:
     return sorted(names)
 
 
-# -- leader ship surface (used by the serve front ends' /repl routes) --------
+# -- leader ship surface (used by the serve front end's /repl routes) --------
 
 
 def ship_manifest(store_dir: str) -> dict:

@@ -51,7 +51,7 @@ _FRAME = struct.Struct("<II")  # payload byte length, crc32(payload)
 
 #: frame-length sanity bound on replay: a corrupt length field must not
 #: make the scanner try to allocate/skip gigabytes (larger than any body
-#: the front ends accept)
+#: the front end accepts)
 MAX_RECORD_BYTES = 1 << 26
 
 _WAL_RE = re.compile(r"^(?P<name>.+)\.(?P<seq>\d{6})\.wal$")

@@ -32,7 +32,9 @@ import jax
 
 jax.config.update("jax_platforms", "cpu")
 
+import asyncio
 import random
+import threading
 
 import numpy as np
 import pytest
@@ -41,6 +43,72 @@ import pytest
 @pytest.fixture
 def rng():
     return random.Random(20260729)
+
+
+def start_server(**kw):
+    """The one way a test starts a server: ``build_aio_server(**kw)`` on
+    an ephemeral port unless ``port`` says otherwise, bound and serving on
+    a loop thread when this returns (``server.server_address[1]`` is the
+    port, ``server.ctx`` the :class:`ServeContext`).  Pair with
+    :func:`stop_server`."""
+    from annotatedvdb_tpu.serve.aio import build_aio_server
+
+    kw.setdefault("port", 0)
+    server = build_aio_server(**kw)
+    server.start_background()
+    return server
+
+
+def stop_server(server):
+    """The builder's shutdown order: the server, then its batcher."""
+    server.shutdown()
+    server.ctx.batcher.close()
+
+
+def bulk_envelope(records: list) -> str:
+    """The ``POST /variants`` body for ``engine.lookup_many``'s records
+    (JSON texts, ``None`` for an absent id) — spelled here once, apart
+    from the server, so a test can compare the server's bytes with the
+    engine called directly."""
+    return '{"n":%d,"found":%d,"results":[%s]}' % (
+        len(records), sum(r is not None for r in records),
+        ",".join("null" if r is None else r for r in records))
+
+
+class BatcherOnLoop:
+    """A :class:`LoopBatcher` with a loop thread of its own, as under
+    ``AioServer``, for tests that drive the batcher without a socket.
+    :meth:`submit` blocks the calling thread like one client;
+    :meth:`run` runs ``fn(batcher)`` (a coroutine function) on the loop,
+    where several submissions can share one turn."""
+
+    def __init__(self, engine, **kw):
+        from annotatedvdb_tpu.serve.aio import LoopBatcher
+
+        self.batcher = LoopBatcher(engine, **kw)
+        self.loop = asyncio.new_event_loop()
+        self._thread = threading.Thread(
+            target=self.loop.run_forever, name="test-batcher-loop",
+            daemon=True,
+        )
+        self._thread.start()
+
+    def run(self, fn, timeout: float = 60.0):
+        return asyncio.run_coroutine_threadsafe(
+            fn(self.batcher), self.loop
+        ).result(timeout)
+
+    def submit(self, variant_id, **kw):
+        async def one(batcher):
+            return await batcher.submit_future(variant_id, **kw)
+
+        return self.run(one)
+
+    def close(self):
+        self.batcher.close()
+        self.loop.call_soon_threadsafe(self.loop.stop)
+        self._thread.join(timeout=10)
+        self.loop.close()
 
 
 BASES = "ACGT"

@@ -101,7 +101,6 @@ def test_every_rule_family_covered_by_fixtures():
     """One fixture-backed assertion per family, by construction."""
     families = set()
     tree_fixtures = [
-        os.path.join("parity_tree", "serve", "aio.py"),
         os.path.join("twins_tree", "annotatedvdb_tpu", "ops",
                      "__init__.py"),
         os.path.join("twins_tree", "annotatedvdb_tpu", "ops",
@@ -113,13 +112,19 @@ def test_every_rule_family_covered_by_fixtures():
     for name in FIXTURE_FILES + ["cli_viol.py"] + tree_fixtures:
         for _line, code in expected_pairs(os.path.join(FIXTURES, name)):
             families.add(code[:-2])  # AVDB101 -> AVDB1, AVDB1001 -> AVDB10
+    # no AVDB8: the cross-front-end parity family went with the second
+    # front end it policed
     assert families == {"AVDB1", "AVDB2", "AVDB3", "AVDB4", "AVDB5",
-                        "AVDB6", "AVDB7", "AVDB8", "AVDB9", "AVDB10"}
+                        "AVDB6", "AVDB7", "AVDB9", "AVDB10"}
+    from annotatedvdb_tpu import analysis
+
+    assert not hasattr(analysis, "rules_parity")
+    assert not os.path.exists(os.path.join(FIXTURES, "parity_tree"))
 
 
 # ---------------------------------------------------------------------------
-# tree fixtures: the parity pair (AVDB8xx) and the twins registry (AVDB9xx)
-# are cross-file rules, so their fixtures are little trees, scanned whole
+# tree fixtures: the twins registry (AVDB9xx) is a cross-file rule, so its
+# fixture is a little tree, scanned whole
 
 
 def _tree_pairs(tree, files):
@@ -131,30 +136,6 @@ def _tree_pairs(tree, files):
                 (line, code)
             )
     return want
-
-
-def test_parity_tree_fixture():
-    tree = os.path.join(FIXTURES, "parity_tree")
-    findings, n = run_paths([tree], root=tree)
-    assert n == 2
-    got = {}
-    for f in findings:
-        rel = f.path.replace("\\", "/").split("parity_tree/")[-1]
-        got.setdefault(rel, set()).add((f.line, f.code))
-    want = _tree_pairs(tree, [
-        os.path.join("serve", "http.py"), os.path.join("serve", "aio.py"),
-    ])
-    assert got == want, (got, want)
-
-
-def test_parity_silent_on_single_front_end():
-    """A scan holding only one front-end file cannot judge parity."""
-    tree = os.path.join(FIXTURES, "parity_tree")
-    findings, n = run_paths(
-        [os.path.join(tree, "serve", "aio.py")], root=tree
-    )
-    assert n == 1
-    assert [f for f in findings if f.code.startswith("AVDB8")] == []
 
 
 def test_twins_tree_fixture():

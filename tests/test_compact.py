@@ -16,7 +16,6 @@ import os
 import shutil
 import subprocess
 import sys
-import threading
 import urllib.error
 import urllib.request
 
@@ -35,6 +34,7 @@ from annotatedvdb_tpu.store.fsck import fsck
 from annotatedvdb_tpu.store.variant_store import Segment
 from annotatedvdb_tpu.serve import QueryEngine, SnapshotManager
 from annotatedvdb_tpu.utils import faults
+from conftest import bulk_envelope, start_server, stop_server
 
 
 @pytest.fixture(autouse=True)
@@ -112,7 +112,7 @@ def test_compaction_byte_parity_engine_and_brute(tmp_path):
 
 
 def _collect_http(port: int, truth: list) -> list:
-    """One response-bytes sample across every route of a front end."""
+    """One response-bytes sample across every read route."""
     out = []
     for r in truth[:25] + [truth[-1]]:
         out.append(ts._get(port, f"/variant/{ts._vid(r)}")[:2])
@@ -134,45 +134,49 @@ def _collect_http(port: int, truth: list) -> list:
     return out
 
 
-def test_compaction_byte_parity_both_front_ends(tmp_path):
-    """Pre- vs post-compaction responses on the threaded AND aio front
-    ends (fresh managers each side, so generation numbers agree)."""
-    from annotatedvdb_tpu.serve.aio import build_aio_server
-    from annotatedvdb_tpu.serve.http import build_server
+def _collect_engine(engine, truth: list) -> list:
+    """What ``_collect_http`` must read, from the engine called directly."""
+    def point(vid):
+        record = engine.lookup(vid)
+        if record is None:
+            return 404, json.dumps(
+                {"error": f"variant {vid!r} not in store"})
+        return 200, record
 
+    out = [point(ts._vid(r)) for r in truth[:25] + [truth[-1]]]
+    out.append(point("8:499:A:G"))
+    out.append((200, engine.region("8:1-10000", min_cadd=5.0, limit=8)))
+    out.append((200, engine.region("1:100000-2500000", limit=0)))
+    ids = [ts._vid(r) for r in truth[:40]] + ["8:499:A:G"]
+    records = engine.lookup_many(ids)
+    out.append((200, bulk_envelope(records)))
+    out.append((200, engine.regions_serve(
+        ["8:1-10000", "8:400-700"], limit=8).assemble()))
+    return out
+
+
+def test_compaction_byte_parity_over_http(tmp_path):
+    """Pre- vs post-compaction responses of the server (fresh managers
+    each side, so generation numbers agree).  Oracle: a ``QueryEngine``
+    over the uncompacted store called directly (``_collect_engine``)."""
     pre_dir = str(tmp_path / "pre")
     truth = _fragmented(pre_dir)
     post_dir = str(tmp_path / "post")
     shutil.copytree(pre_dir, post_dir)
     assert compact_store(post_dir)["status"] == "compacted"
 
-    def threaded_sample(store_dir):
-        httpd = build_server(store_dir=store_dir, port=0)
-        thread = threading.Thread(target=httpd.serve_forever, daemon=True)
-        thread.start()
-        try:
-            return _collect_http(httpd.server_address[1], truth)
-        finally:
-            httpd.shutdown()
-            httpd.server_close()
-            httpd.ctx.batcher.close()
-
-    def aio_sample(store_dir):
-        server = build_aio_server(store_dir=store_dir, port=0)
-        server.start_background()
+    def sample(store_dir):
+        server = start_server(store_dir=store_dir)
         try:
             return _collect_http(server.server_address[1], truth)
         finally:
-            server.shutdown()
-            server.ctx.batcher.close()
+            stop_server(server)
 
-    pre_t = threaded_sample(pre_dir)
-    post_t = threaded_sample(post_dir)
-    assert post_t == pre_t
-    pre_a = aio_sample(pre_dir)
-    post_a = aio_sample(post_dir)
-    assert post_a == pre_a
-    assert pre_a == pre_t  # and the front ends agree with each other
+    pre = sample(pre_dir)
+    assert sample(post_dir) == pre
+    # and the server agrees with the engine over the uncompacted store
+    assert pre == _collect_engine(
+        QueryEngine(SnapshotManager(pre_dir), region_cache_size=0), truth)
 
 
 def test_legacy_fragmented_store_loads_unchanged(tmp_path):

@@ -20,7 +20,6 @@ import os
 import signal
 import subprocess
 import sys
-import threading
 import urllib.error
 import urllib.request
 
@@ -35,6 +34,7 @@ from annotatedvdb_tpu.export.writer import read_manifest, read_part
 from annotatedvdb_tpu.loaders.lookup import identity_hashes
 from annotatedvdb_tpu.store import VariantStore
 from annotatedvdb_tpu.types import chromosome_label, encode_allele_array
+from conftest import start_server, stop_server
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "tools"))
@@ -322,7 +322,7 @@ def test_resume_after_sigkill_via_cli_byte_identical(exported, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# GET /export/stream: both front ends, byte parity
+# GET /export/stream: byte parity with the shared builder
 
 
 def _get(port: int, path: str):
@@ -336,28 +336,36 @@ def _get(port: int, path: str):
 
 
 @pytest.fixture()
-def both_servers(exported):
-    from annotatedvdb_tpu.serve.aio import build_aio_server
-    from annotatedvdb_tpu.serve.http import build_server
-
+def server(exported):
     store_dir, _truth, _store, _ledger, _ref = exported
-    httpd = build_server(store_dir=store_dir, port=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    aio = build_aio_server(store_dir=store_dir, port=0)
-    aio.start_background()
+    srv = start_server(store_dir=store_dir)
     try:
-        yield httpd, aio
+        yield srv
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
-        aio.shutdown()
-        aio.ctx.batcher.close()
+        stop_server(srv)
 
 
-def test_export_stream_cross_frontend_byte_parity(both_servers):
-    httpd, aio = both_servers
-    tport, aport = httpd.server_address[1], aio.server_address[1]
+def _stream_oracle(engine, query: str):
+    """(status, body) from the shared grammar + builder called directly
+    (``parse_stream_query``, ``stream_payload``): the 400 body is the
+    error either raises."""
+    from annotatedvdb_tpu.export.stream import (
+        parse_stream_query,
+        stream_payload,
+    )
+
+    try:
+        return 200, stream_payload(engine, parse_stream_query(query))[0]
+    except ValueError as err:  # QueryError subclasses ValueError
+        return 400, json.dumps({"error": str(err)})
+
+
+def test_export_stream_byte_parity_with_the_builder(server):
+    """Oracle: ``_stream_oracle`` over a fresh engine on the same store."""
+    from annotatedvdb_tpu.serve import QueryEngine
+
+    port = server.server_address[1]
+    engine = QueryEngine(server.ctx.manager, region_cache_size=0)
     queries = [
         "region=1:1-3000000&batch_rows=16&seed=3",           # shuffled
         "region=1:1-3000000&batch_rows=16&seed=3&batch=5",
@@ -366,28 +374,25 @@ def test_export_stream_cross_frontend_byte_parity(both_servers):
         "region=1:1-3000000&batch_rows=16&seed=4",           # reseeded
     ]
     for q in queries:
-        st1, b1 = _get(tport, f"/export/stream?{q}")
-        st2, b2 = _get(aport, f"/export/stream?{q}")
-        assert (st1, b1) == (st2, b2), q
+        st1, b1 = _get(port, f"/export/stream?{q}")
         assert st1 == 200, (q, b1)
+        assert (st1, b1) == _stream_oracle(engine, q), q
         doc = json.loads(b1)
         n = doc["n_valid"]
         mask = doc["arrays"]["mask"]
         assert sum(mask) == n and all(mask[:n])
         assert doc["tokens_per_row"] == export_core.TOKENS_PER_ROW
-    # kind=export counted on both front ends
-    for port in (tport, aport):
-        _st, metrics = _get(port, "/metrics")
-        assert 'avdb_query_requests_total{kind="export"}' in metrics
+    # kind=export counted
+    _st, metrics = _get(port, "/metrics")
+    assert 'avdb_query_requests_total{kind="export"}' in metrics
 
 
-def test_export_stream_shuffled_batch_matches_emission_order(both_servers):
+def test_export_stream_shuffled_batch_matches_emission_order(server):
     """The route's "seed S, batch K" is the SAME permutation the bulk
     exporter would emit: fetching shuffled slot K equals fetching plan
     batch ``emission_order(n, S)[K]`` in ordered mode, byte for byte in
     the arrays."""
-    httpd, _aio = both_servers
-    port = httpd.server_address[1]
+    port = server.server_address[1]
     base = "region=1:1-3000000&batch_rows=16"
     _st, first = _get(port, f"/export/stream?{base}&seed=3")
     n_batches = json.loads(first)["n_batches"]
@@ -402,9 +407,13 @@ def test_export_stream_shuffled_batch_matches_emission_order(both_servers):
         assert sdoc["alleles"] == odoc["alleles"]
 
 
-def test_export_stream_error_parity(both_servers):
-    httpd, aio = both_servers
-    tport, aport = httpd.server_address[1], aio.server_address[1]
+def test_export_stream_error_parity(server):
+    """Oracle: ``_stream_oracle`` — every refusal is a 400 whose body is
+    the grammar's or the builder's own error."""
+    from annotatedvdb_tpu.serve import QueryEngine
+
+    port = server.server_address[1]
+    engine = QueryEngine(server.ctx.manager, region_cache_size=0)
     for q in (
         "",                                        # missing region
         "region=nope",                             # bad grammar
@@ -414,9 +423,9 @@ def test_export_stream_error_parity(both_servers):
         "region=21:1-100",                         # chromosome not in store
         "region=1:1-3000000&batch_rows=16&batch=500",  # batch out of range
     ):
-        st1, b1 = _get(tport, f"/export/stream?{q}")
-        st2, b2 = _get(aport, f"/export/stream?{q}")
-        assert st1 == 400 and (st1, b1) == (st2, b2), q
+        st1, b1 = _get(port, f"/export/stream?{q}")
+        assert st1 == 400, (q, b1)
+        assert (st1, b1) == _stream_oracle(engine, q), q
 
 
 # ---------------------------------------------------------------------------

@@ -30,6 +30,7 @@ from annotatedvdb_tpu.config import StoreConfig
 from annotatedvdb_tpu.store import VariantStore
 from annotatedvdb_tpu.store.fsck import fsck
 from annotatedvdb_tpu.utils import faults
+from conftest import BatcherOnLoop, start_server, stop_server
 
 N_ROWS = 2600
 BATCH = 512  # ~6 chunks => ~6 checkpoints per committed load
@@ -289,13 +290,13 @@ def test_egress_flush_raise_aborts_clean_and_rerun_completes(tmp_path):
 
 def test_serve_batch_raise_fails_only_that_batch_and_recovers():
     """An injected fault mid-drain (serve.batch:1:raise) must surface the
-    root cause to every caller of THAT microbatch and leave the drain
-    thread serving the next one."""
-    from annotatedvdb_tpu.serve import QueryBatcher, QueryEngine, StaticSnapshots
+    root cause to every caller of THAT microbatch and leave the loop
+    serving the next one."""
+    from annotatedvdb_tpu.serve import QueryEngine, StaticSnapshots
     from annotatedvdb_tpu.utils.faults import InjectedFault
 
     engine = QueryEngine(StaticSnapshots(_tiny_store()), region_cache_size=0)
-    batcher = QueryBatcher(engine, max_batch=4, max_wait_s=0.001)
+    batcher = BatcherOnLoop(engine, max_batch=4, max_wait_s=0.001)
     try:
         faults.reset("serve.batch:1:raise")
         with pytest.raises(InjectedFault):
@@ -303,7 +304,7 @@ def test_serve_batch_raise_fails_only_that_batch_and_recovers():
         faults.reset("")
         # the batcher survived its failed drain: same query now answers
         assert batcher.submit("3:10:A:C") is not None
-        stats = batcher.drain_stats()
+        stats = batcher.batcher.drain_stats()
         assert stats["batches"] == 1  # only the clean drain counted
     finally:
         faults.reset("")
@@ -1250,18 +1251,14 @@ def test_maintain_tick_fault_daemon_thread_survives(tmp_path):
         daemon.stop()
 
 
-def test_maintain_disk_guard_fault_flips_507_both_front_ends_and_clears(
-        tmp_path):
+def test_maintain_disk_guard_fault_flips_507_and_clears(tmp_path):
     """maintain.disk_guard (raise/eio): an injected free-space reading
     failure IS a low-disk observation — the guard reports breached, the
-    shared upsert gate answers 507 with the single-source body on BOTH
-    front ends, nothing becomes durable, and the next (clean) reading
+    upsert gate answers 507 with the single-source body, nothing becomes
+    durable, and the next (clean) reading
     clears the degradation."""
     from annotatedvdb_tpu.obs.metrics import MetricsRegistry
-    from annotatedvdb_tpu.serve.http import (
-        MSG_DISK_RESERVE,
-        build_server,
-    )
+    from annotatedvdb_tpu.serve.http import MSG_DISK_RESERVE
     from annotatedvdb_tpu.serve.snapshot import (
         MemtableSnapshots,
         SnapshotManager,
@@ -1283,10 +1280,8 @@ def test_maintain_disk_guard_fault_flips_507_both_front_ends_and_clears(
     assert breached is False and free > 0
     faults.reset("")
 
-    # route level: the ONE shared gate (ServeContext.upsert_execute)
-    # renders the 507 for both front ends, so asserting it per-context
-    # IS the parity proof at the decision layer (the HTTP-level parity
-    # battery lives in tests/test_maintenance.py)
+    # route level: the ONE gate (ServeContext.upsert_execute) renders
+    # the 507 (the HTTP-level battery lives in tests/test_maintenance.py)
     registry = MetricsRegistry()
     mgr = SnapshotManager(store_dir, log=lambda m: None)
     mem = Memtable(
@@ -1294,8 +1289,8 @@ def test_maintain_disk_guard_fault_flips_507_both_front_ends_and_clears(
         wal=WriteAheadLog(store_dir, "serve-dg", log=lambda m: None),
         registry=registry, log=lambda m: None,
     )
-    httpd = build_server(manager=MemtableSnapshots(mgr, mem), port=0,
-                        memtable=mem, registry=registry)
+    httpd = start_server(manager=MemtableSnapshots(mgr, mem),
+                         memtable=mem, registry=registry)
     ctx = httpd.ctx
     try:
         ctx.disk_guard = DiskReserveGuard(store_dir, reserve=1,
@@ -1316,8 +1311,7 @@ def test_maintain_disk_guard_fault_flips_507_both_front_ends_and_clears(
         assert mem.rows == 1
     finally:
         faults.reset("")
-        httpd.server_close()
-        ctx.batcher.close()
+        stop_server(httpd)
         mem.wal.close(remove_if_empty=True)
 
 
@@ -1421,18 +1415,15 @@ def test_mesh_dispatch_eio_panel_falls_back_byte_identical():
 def test_obs_flight_ring_write_failure_absorbed_while_serving(tmp_path):
     """obs.flight (raise) inside a request-summary write: the request
     still answers 200, the failure is counted, recording continues."""
-    import threading
     import urllib.request
 
     from annotatedvdb_tpu.obs.flight import FlightRecorder, decode_ring
-    from annotatedvdb_tpu.serve.http import build_server
 
     store_dir = str(tmp_path / "fstore")
     _tiny_store().save(store_dir)
     ring = str(tmp_path / "w0.ring")
     flight = FlightRecorder(ring, slots=16, log=lambda m: None)
-    httpd = build_server(store_dir=store_dir, port=0, flight=flight)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    httpd = start_server(store_dir=store_dir, flight=flight)
     try:
         port = httpd.server_address[1]
 
@@ -1454,9 +1445,7 @@ def test_obs_flight_ring_write_failure_absorbed_while_serving(tmp_path):
         assert len(reqs) == 1
     finally:
         faults.reset("")
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
         flight.close()
 
 
@@ -1690,24 +1679,21 @@ def test_obs_tick_persist_fault_keeps_previous_mirror(tmp_path):
 
 
 def test_obs_tick_fault_while_serving_requests_still_answer(tmp_path):
-    """obs.tick (raise) under the threaded front end's inline driver:
-    the request that carried the dying tick still answers 200, the
-    failure is counted, and the next due tick samples normally."""
-    import threading
+    """obs.tick (raise) under the server's maintenance tick: requests
+    keep answering 200 while a tick dies, the failure is counted, and
+    the next due tick samples normally."""
     import urllib.request
 
     from annotatedvdb_tpu.obs.metrics import MetricsRegistry
     from annotatedvdb_tpu.obs.slo import HealthPlane
-    from annotatedvdb_tpu.serve.http import build_server
 
     store_dir = str(tmp_path / "hstore")
     _tiny_store().save(store_dir)
     registry = MetricsRegistry()
     health = HealthPlane(registry, store_dir=store_dir, worker=0,
                          tick_s=0.01, history_s=60.0)
-    httpd = build_server(store_dir=store_dir, port=0, registry=registry,
-                        health=health)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    httpd = start_server(store_dir=store_dir, registry=registry,
+                         health=health)
     try:
         port = httpd.server_address[1]
 
@@ -1717,18 +1703,23 @@ def test_obs_tick_fault_while_serving_requests_still_answer(tmp_path):
             ) as r:
                 return r.status
 
+        def wait_for(cond):
+            deadline = time.monotonic() + 10
+            while not cond() and time.monotonic() < deadline:
+                assert get("/variant/3:10:A:C") == 200
+                time.sleep(0.02)
+            return cond()
+
         faults.reset("obs.tick:1:raise")
-        assert get("/variant/3:10:A:C") == 200  # the tick died silently
+        assert wait_for(lambda: health.errors >= 1)  # a tick died silently
         faults.reset("")
         assert health.errors == 1
-        time.sleep(0.02)  # past the tick gate
-        assert get("/variant/3:20:A:C") == 200
-        assert len(health.ring.samples()) >= 1  # recording resumed
+        n0 = len(health.ring.samples())
+        # recording resumed on the next due tick
+        assert wait_for(lambda: len(health.ring.samples()) > n0)
     finally:
         faults.reset("")
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 def test_obs_tick_harvest_failure_absorbed_by_supervisor(tmp_path):
@@ -1779,12 +1770,9 @@ def test_obs_tick_harvest_failure_absorbed_by_supervisor(tmp_path):
 
 
 def _repl_leader(tmp_path, rows):
-    """One in-process leader (store + memtable + WAL + threaded front
-    end) with ``rows`` upserted — the replication matrix's write source."""
-    import threading
-
+    """One in-process leader (store + memtable + WAL + server) with
+    ``rows`` upserted — the replication matrix's write source."""
     from annotatedvdb_tpu.obs.metrics import MetricsRegistry
-    from annotatedvdb_tpu.serve.http import build_server
     from annotatedvdb_tpu.serve.snapshot import (
         MemtableSnapshots,
         SnapshotManager,
@@ -1802,13 +1790,12 @@ def _repl_leader(tmp_path, rows):
     store = VariantStore.load(store_dir, readonly=True)
     for row in rows:
         mem.upsert(store, [row], durable=True)
-    httpd = build_server(
+    httpd = start_server(
         manager=MemtableSnapshots(
             SnapshotManager(store_dir, log=lambda m: None), mem
         ),
-        port=0, memtable=mem,
+        memtable=mem,
     )
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
     url = f"http://127.0.0.1:{httpd.server_address[1]}"
     return store_dir, url, httpd
 
@@ -1847,8 +1834,7 @@ def test_repl_ship_fault_cycle_retries_to_identical_state(tmp_path, fault):
                 assert f.read() == leader_bytes
     finally:
         faults.reset("")
-        httpd.shutdown()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 def test_repl_apply_fault_restart_lands_on_applied_lsn_prefix(tmp_path):
@@ -1886,8 +1872,7 @@ def test_repl_apply_fault_restart_lands_on_applied_lsn_prefix(tmp_path):
         assert recovered + len(live) >= 1
     finally:
         faults.reset("")
-        httpd.shutdown()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 def test_repl_promote_fault_leaves_promotable_follower(tmp_path):
@@ -1931,8 +1916,7 @@ def test_repl_promote_fault_leaves_promotable_follower(tmp_path):
         assert "fenced" in result["reason"]
     finally:
         faults.reset("")
-        httpd.shutdown()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 # ---------------------------------------------------------------------------

@@ -6,11 +6,10 @@ ground truth; the sample arithmetic (counter deltas/rates, histogram
 window deltas, fraction-above interpolation); the snapshot ring with its
 persisted mirror, harvest, and replay; the SLO state machines
 (multi-window burn + ok -> pending -> firing -> resolved hysteresis);
-and the ``/alerts`` + ``/metrics/history`` routes byte-identical across
-both front ends, with the fleet views and ``doctor slo`` on top."""
+and the ``/alerts`` + ``/metrics/history`` routes byte-identical to
+their payload builders, with the fleet views and ``doctor slo`` on top."""
 
 import json
-import threading
 import time
 
 import numpy as np
@@ -39,6 +38,7 @@ from annotatedvdb_tpu.obs.timeseries import (
     trailing_samples,
     window_samples,
 )
+from conftest import start_server, stop_server
 
 # ---------------------------------------------------------------------------
 # quantile estimation (the satellite: pinned against numpy)
@@ -516,12 +516,9 @@ def _get(port: int, path: str):
 
 @pytest.fixture()
 def health_served(tmp_path):
-    """Both front ends over one store sharing ONE HealthPlane (tick_s
-    high enough that only the test's manual ticks move it — the payloads
-    must be deterministic for byte-parity)."""
-    from annotatedvdb_tpu.serve.aio import build_aio_server
-    from annotatedvdb_tpu.serve.http import build_server
-
+    """A server over one store with a HealthPlane (tick_s high enough
+    that only the test's manual ticks move it — the payloads must be
+    deterministic for byte-parity)."""
     store_dir = str(tmp_path / "store")
     _build_store(store_dir)
     clk = {"t": 2000.0}
@@ -533,24 +530,16 @@ def health_served(tmp_path):
         tick_s=30.0, history_s=600.0, fast_s=1.0, slow_s=2.0,
         burn_threshold=2.0, clock=lambda: clk["t"],
     )
-    # start the time-gate NOW: neither front end's driver may sneak a
-    # startup tick in — only the test's manual ticks move the ring
+    # start the time-gate NOW: the server's maintenance tick may not
+    # sneak a startup tick in — only the test's manual ticks move the ring
     health.ring._last_tick = time.monotonic()
-    httpd = build_server(store_dir=store_dir, port=0, registry=registry,
-                        health=health)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    aio = build_aio_server(store_dir=store_dir, port=0,
-                           registry=registry, health=health)
-    aio.start_background()
+    server = start_server(store_dir=store_dir, registry=registry,
+                          health=health)
     try:
-        yield (store_dir, clk, health, httpd.server_address[1],
-               aio.server_address[1])
+        yield (store_dir, clk, health, server.server_address[1],
+               server.ctx)
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
-        aio.shutdown()
-        aio.ctx.batcher.close()
+        stop_server(server)
 
 
 def _tick_n(clk, health, n: int, step: float = 1.0) -> None:
@@ -559,15 +548,25 @@ def _tick_n(clk, health, n: int, step: float = 1.0) -> None:
         clk["t"] += step
 
 
-def test_alerts_and_history_byte_parity_across_front_ends(health_served):
-    _store_dir, clk, health, tport, aport = health_served
+def test_alerts_and_history_byte_parity_with_the_builders(health_served):
+    """Oracle: ``alerts_payload`` / ``metrics_history_payload`` called
+    directly on the server's context with the same query string."""
+    from urllib.parse import urlparse
+
+    from annotatedvdb_tpu.serve.http import (
+        alerts_payload,
+        metrics_history_payload,
+    )
+
+    _store_dir, clk, health, tport, ctx = health_served
     _tick_n(clk, health, 4)
     for path in ("/alerts", "/metrics/history", "/metrics/history?window=2",
                  "/metrics/history?window=junk"):
-        ts, tbody = _get(tport, path)
-        as_, abody = _get(aport, path)
-        assert ts == as_ == 200, (path, ts, as_)
-        assert tbody == abody, path
+        status, body = _get(tport, path)
+        assert status == 200, (path, status)
+        builder = alerts_payload if path.startswith("/alerts") \
+            else metrics_history_payload
+        assert body == builder(ctx, urlparse(path).query), path
 
     rec = json.loads(_get(tport, "/alerts")[1])
     assert rec["enabled"] is True and rec["worker"] == 0
@@ -587,18 +586,17 @@ def test_alerts_and_history_byte_parity_across_front_ends(health_served):
 
 
 def test_healthz_and_prometheus_carry_alert_state(health_served):
-    _store_dir, clk, health, tport, aport = health_served
+    _store_dir, clk, health, port, _ctx = health_served
     _tick_n(clk, health, 2)
-    for port in (tport, aport):
-        hz = json.loads(_get(port, "/healthz")[1])
-        assert hz["alerts"] == "ok" and hz["alerts_firing"] == 0
-        _status, metrics = _get(port, "/metrics")
-        assert "avdb_slo_burn_rate" in metrics
-        assert "avdb_alerts_firing" in metrics
+    hz = json.loads(_get(port, "/healthz")[1])
+    assert hz["alerts"] == "ok" and hz["alerts_firing"] == 0
+    _status, metrics = _get(port, "/metrics")
+    assert "avdb_slo_burn_rate" in metrics
+    assert "avdb_alerts_firing" in metrics
 
 
 def test_fleet_views_merge_sibling_mirrors(health_served):
-    store_dir, clk, health, tport, aport = health_served
+    store_dir, clk, health, port, _ctx = health_served
     _tick_n(clk, health, 3)
     # a sibling worker's persisted mirror (fresh enough for the TTL)
     sib_reg = MetricsRegistry()
@@ -610,25 +608,49 @@ def test_fleet_views_merge_sibling_mirrors(health_served):
     sib.sample()
     sib.persist({"alerts": [{"slo": "availability", "state": "firing"}],
                  "firing": 1}, force=True)
-    for port in (tport, aport):
-        rec = json.loads(_get(port, "/alerts?fleet=1")[1])
-        assert rec["fleet"] is True
-        assert set(rec["workers"]) == {"0", "1"}
-        assert rec["workers"]["1"]["state"] == "firing"
-        assert rec["firing"] == 1
-        assert rec["state"] == "firing"  # worst across the fleet
-        hist = json.loads(_get(port, "/metrics/history?fleet=1")[1])
-        assert set(hist["workers"]) == {"0", "1"}
-        assert hist["workers"]["1"]["samples"] == 2
+    rec = json.loads(_get(port, "/alerts?fleet=1")[1])
+    assert rec["fleet"] is True
+    assert set(rec["workers"]) == {"0", "1"}
+    assert rec["workers"]["1"]["state"] == "firing"
+    assert rec["firing"] == 1
+    assert rec["state"] == "firing"  # worst across the fleet
+    hist = json.loads(_get(port, "/metrics/history?fleet=1")[1])
+    assert set(hist["workers"]) == {"0", "1"}
+    assert hist["workers"]["1"]["samples"] == 2
 
 
-def test_disabled_plane_payloads(tmp_path):
-    from annotatedvdb_tpu.serve.http import build_server
+def test_requests_and_probes_never_tick_the_plane(tmp_path):
+    """The plane is ticked by the server's maintenance tick alone: a
+    completed request (``ctx.observe``) and a readiness probe
+    (``ctx.ready_state``) leave a due plane untouched — neither a request
+    nor the event loop ever pays for a sample + persist."""
+    from annotatedvdb_tpu.serve import QueryEngine, SnapshotManager
+    from annotatedvdb_tpu.serve.aio import LoopBatcher
+    from annotatedvdb_tpu.serve.http import ServeContext
 
     store_dir = str(tmp_path / "store")
     _build_store(store_dir)
-    httpd = build_server(store_dir=store_dir, port=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    registry = MetricsRegistry()
+    health = HealthPlane(registry, store_dir=store_dir, worker=0,
+                         tick_s=0.01, history_s=60.0)
+    manager = SnapshotManager(store_dir, log=lambda m: None)
+    engine = QueryEngine(manager, registry=registry)
+    ctx = ServeContext(manager, engine, LoopBatcher(engine), registry,
+                       health=health)
+    time.sleep(0.02)
+    assert health.due()
+    for _ in range(3):
+        ctx.observe("point", 0.001, rows=1)
+        assert ctx.ready_state() == (True, "ok")
+    assert health.due() and health.ring.samples() == []
+    assert health.tick()  # what the maintenance tick runs on its pool
+    assert len(health.ring.samples()) == 1
+
+
+def test_disabled_plane_payloads(tmp_path):
+    store_dir = str(tmp_path / "store")
+    _build_store(store_dir)
+    httpd = start_server(store_dir=store_dir)
     try:
         port = httpd.server_address[1]
         rec = json.loads(_get(port, "/alerts")[1])
@@ -639,9 +661,7 @@ def test_disabled_plane_payloads(tmp_path):
         hz = json.loads(_get(port, "/healthz")[1])
         assert hz["alerts"] == "disabled" and hz["alerts_firing"] == 0
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 # ---------------------------------------------------------------------------

@@ -25,6 +25,7 @@ import pytest
 
 from annotatedvdb_tpu.analysis.lockorder import RECORDER, LockOrderRecorder
 from annotatedvdb_tpu.utils.locks import TracedLock, make_lock
+from conftest import BatcherOnLoop
 
 
 # ---------------------------------------------------------------------------
@@ -247,7 +248,6 @@ def test_serve_battery_traces_clean(tmp_path, traced_recorder):
     graph must be acyclic, and the stack's named locks must actually
     show up (an empty graph would mean the battery proved nothing)."""
     from annotatedvdb_tpu.obs.metrics import MetricsRegistry
-    from annotatedvdb_tpu.serve.batcher import QueryBatcher
     from annotatedvdb_tpu.serve.engine import QueryEngine
     from annotatedvdb_tpu.serve.http import ServeContext
     from annotatedvdb_tpu.serve.snapshot import SnapshotManager
@@ -257,9 +257,9 @@ def test_serve_battery_traces_clean(tmp_path, traced_recorder):
     manager = SnapshotManager(store_dir)
     registry = MetricsRegistry()
     engine = QueryEngine(manager, registry=registry, region_cache_size=8)
-    batcher = QueryBatcher(engine, max_batch=16, max_wait_s=0.001,
-                           registry=registry)
-    ctx = ServeContext(manager, engine, batcher, registry)
+    batcher = BatcherOnLoop(engine, max_batch=16, max_wait_s=0.001,
+                            registry=registry)
+    ctx = ServeContext(manager, engine, batcher.batcher, registry)
     try:
         errors: list = []
 
@@ -289,6 +289,6 @@ def test_serve_battery_traces_clean(tmp_path, traced_recorder):
     rep = traced_recorder.report()
     assert rep["cycles"] == [], rep
     seen = set(rep["locks"])
-    assert {"serve.engine.cache", "serve.batcher.stats",
-            "serve.ctx.inflight", "serve.snapshot.pin"} <= seen, seen
+    assert {"serve.engine.cache", "serve.ctx.inflight",
+            "serve.snapshot.pin"} <= seen, seen
     assert rep["held"]["serve.ctx.inflight"]["count"] >= 80

@@ -8,7 +8,6 @@ import os
 import signal
 import subprocess
 import sys
-import threading
 import time
 import urllib.error
 import urllib.request
@@ -26,6 +25,7 @@ from annotatedvdb_tpu.store.maintenance import (
 from annotatedvdb_tpu.store.variant_store import Segment
 from annotatedvdb_tpu.utils import faults
 from annotatedvdb_tpu.utils.retry import retry_preempted
+from conftest import bulk_envelope, start_server, stop_server
 
 WIDTH = 8
 
@@ -295,16 +295,6 @@ def test_bad_watermark_knob_fails_fleet_startup(tmp_path, monkeypatch):
         ServeFleet(str(tmp_path), port=0, workers=1, maintain=True)
 
 
-def test_maintain_requires_aio_front_end(tmp_path, capsys):
-    from annotatedvdb_tpu.cli.serve import main as serve_main
-
-    rc = serve_main(["--storeDir", str(tmp_path), "--frontend",
-                     "threaded", "--maintain"])
-    assert rc == 2
-    assert "--maintain requires the aio front end" in \
-        capsys.readouterr().err
-
-
 # ---------------------------------------------------------------------------
 # retry_preempted (the shared policy itself)
 
@@ -481,103 +471,84 @@ def _request(port, method, path, body=None, timeout=15):
 
 
 @pytest.fixture()
-def pair(tmp_path):
-    """Both front ends over ONE on-disk store, each with its own
-    memtable + WAL (the test_upsert fleet shape)."""
+def live(tmp_path):
+    """A write-enabled server over an on-disk store with its memtable +
+    WAL (the test_upsert shape): ``{"store_dir", "port", "ctx"}``."""
     from annotatedvdb_tpu.serve import MemtableSnapshots, SnapshotManager
-    from annotatedvdb_tpu.serve.aio import build_aio_server
-    from annotatedvdb_tpu.serve.http import build_server
     from annotatedvdb_tpu.store.memtable import Memtable
     from annotatedvdb_tpu.store.wal import WriteAheadLog
 
     store_dir = str(tmp_path / "store")
     _seed_serve_store().save(store_dir)
-    built = []
-
-    def one(tag, build):
-        registry = MetricsRegistry()
-        mgr = SnapshotManager(store_dir, log=lambda m: None)
-        mem = Memtable(
-            width=WIDTH, store_dir=store_dir,
-            wal=WriteAheadLog(store_dir, f"serve-{tag}",
-                              log=lambda m: None),
-            registry=registry, log=lambda m: None,
-        )
-        server = build(manager=MemtableSnapshots(mgr, mem), port=0,
-                       memtable=mem, registry=registry)
-        built.append((server, mem))
-        return server, mem
-
-    httpd, mem_t = one("t", build_server)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    aio, mem_a = one("a", build_aio_server)
-    aio.start_background()
+    registry = MetricsRegistry()
+    mgr = SnapshotManager(store_dir, log=lambda m: None)
+    mem = Memtable(
+        width=WIDTH, store_dir=store_dir,
+        wal=WriteAheadLog(store_dir, "serve-w0", log=lambda m: None),
+        registry=registry, log=lambda m: None,
+    )
+    server = start_server(manager=MemtableSnapshots(mgr, mem),
+                          memtable=mem, registry=registry)
     yield {
-        "store_dir": store_dir,
-        "pt": httpd.server_address[1], "pa": aio.server_address[1],
-        "ctx_t": httpd.ctx, "ctx_a": aio.ctx,
+        "store_dir": store_dir, "port": server.server_address[1],
+        "ctx": server.ctx,
     }
-    aio.shutdown()
-    aio.ctx.batcher.close()
-    httpd.shutdown()
-    httpd.server_close()
-    httpd.ctx.batcher.close()
-    for _server, mem in built:
-        if mem.wal is not None:
-            mem.wal.close(remove_if_empty=True)
+    stop_server(server)
+    mem.wal.close(remove_if_empty=True)
 
 
-def test_disk_reserve_507_parity_reads_survive_and_recovery(pair):
+def test_disk_reserve_507_parity_reads_survive_and_recovery(live):
     """The disk-pressure contract end to end: with the reserve breached
-    both front ends 507 upserts BYTE-IDENTICALLY while point/bulk reads
-    keep serving; freeing space (reserve cleared) resumes upserts."""
-    store_dir = pair["store_dir"]
-    for ctx in (pair["ctx_t"], pair["ctx_a"]):
-        ctx.disk_guard = DiskReserveGuard(
-            store_dir, reserve=1 << 60, ttl_s=0.0, log=lambda m: None
-        )
-    up = {"variants": [{"id": "3:70:A:G"}]}
-    st_t, body_t = _request(pair["pt"], "POST", "/variants/upsert", up)
-    st_a, body_a = _request(pair["pa"], "POST", "/variants/upsert", up)
-    assert st_t == st_a == 507
-    assert body_t == body_a  # single-source message constant
+    upserts answer 507 while point/bulk reads keep serving; freeing
+    space (reserve cleared) resumes upserts.  Oracle: the one message
+    constant ``MSG_DISK_RESERVE``, and for the reads the engine called
+    directly (``lookup_many``)."""
+    from annotatedvdb_tpu.serve import QueryEngine
     from annotatedvdb_tpu.serve.http import MSG_DISK_RESERVE
 
-    assert json.loads(body_t)["error"] == MSG_DISK_RESERVE
-    # reads keep serving through the degraded window, on both fronts
-    for port in (pair["pt"], pair["pa"]):
-        status, body = _request(port, "GET", "/variant/3:10:A:C")
-        assert status == 200 and b'"3:10:A:C"' in body
-        status, body = _request(port, "POST", "/variants",
-                                {"ids": ["3:10:A:C", "3:20:A:C"]})
-        assert status == 200 and json.loads(body)["found"] == 2
+    store_dir, port, ctx = live["store_dir"], live["port"], live["ctx"]
+    ctx.disk_guard = DiskReserveGuard(
+        store_dir, reserve=1 << 60, ttl_s=0.0, log=lambda m: None
+    )
+    up = {"variants": [{"id": "3:70:A:G"}]}
+    status, body = _request(port, "POST", "/variants/upsert", up)
+    assert status == 507
+    assert json.loads(body) == {"error": MSG_DISK_RESERVE}
+    # reads keep serving through the degraded window
+    ids = ["3:10:A:C", "3:20:A:C"]
+    records = QueryEngine(ctx.manager, region_cache_size=0).lookup_many(ids)
+    status, body = _request(port, "GET", "/variant/3:10:A:C")
+    assert (status, body.decode()) == (200, records[0])
+    assert b'"3:10:A:C"' in body
+    status, body = _request(port, "POST", "/variants", {"ids": ids})
+    assert (status, body.decode()) == (200, bulk_envelope(records))
+    assert all(records)
     # the shed is visible in metrics
     assert "avdb_upsert_disk_shed_total 1" in \
-        pair["ctx_t"].registry.render_prometheus()
-    # space freed -> upserts resume (recovery), identically on both
-    for ctx in (pair["ctx_t"], pair["ctx_a"]):
-        ctx.disk_guard = DiskReserveGuard(
-            store_dir, reserve=1, ttl_s=0.0, log=lambda m: None
-        )
-    st_t, body_t = _request(pair["pt"], "POST", "/variants/upsert", up)
-    assert st_t == 200 and json.loads(body_t)["accepted"] == 1
-    st_a, body_a = _request(pair["pa"], "POST", "/variants/upsert",
+        ctx.registry.render_prometheus()
+    # space freed -> upserts resume (recovery)
+    ctx.disk_guard = DiskReserveGuard(
+        store_dir, reserve=1, ttl_s=0.0, log=lambda m: None
+    )
+    status, body = _request(port, "POST", "/variants/upsert", up)
+    assert status == 200 and json.loads(body)["accepted"] == 1
+    status, body = _request(port, "POST", "/variants/upsert",
                             {"variants": [{"id": "3:77:A:G"}]})
-    assert st_a == 200 and json.loads(body_a)["accepted"] == 1
+    assert status == 200 and json.loads(body)["accepted"] == 1
 
 
-def test_flush_of_acked_rows_runs_under_disk_guard(pair):
+def test_flush_of_acked_rows_runs_under_disk_guard(live):
     """The guard sheds NEW writes only: a memtable flush of rows acked
     before the window commits to segments (it is what drains the WAL)."""
-    store_dir = pair["store_dir"]
-    ctx = pair["ctx_t"]
-    st, _ = _request(pair["pt"], "POST", "/variants/upsert",
+    store_dir = live["store_dir"]
+    ctx = live["ctx"]
+    st, _ = _request(live["port"], "POST", "/variants/upsert",
                      {"variants": [{"id": "3:90:A:G"}]})
     assert st == 200
     ctx.disk_guard = DiskReserveGuard(
         store_dir, reserve=1 << 60, ttl_s=0.0, log=lambda m: None
     )
-    st, _ = _request(pair["pt"], "POST", "/variants/upsert",
+    st, _ = _request(live["port"], "POST", "/variants/upsert",
                      {"variants": [{"id": "3:91:A:G"}]})
     assert st == 507
     result = ctx.memtable.flush(base_manager=ctx.manager.base)
@@ -588,11 +559,11 @@ def test_flush_of_acked_rows_runs_under_disk_guard(pair):
     assert int(rows["3"]) == 4  # 3 loaded + the acked upsert
 
 
-def test_flush_retries_transient_io(pair):
+def test_flush_retries_transient_io(live):
     """ENOSPC/EIO on a flush gets the bounded backoff-retry: one
     injected blip and the flush still lands (nothing wedges)."""
-    ctx = pair["ctx_t"]
-    st, _ = _request(pair["pt"], "POST", "/variants/upsert",
+    ctx = live["ctx"]
+    st, _ = _request(live["port"], "POST", "/variants/upsert",
                      {"variants": [{"id": "3:95:A:G"}]})
     assert st == 200
     assert ctx.memtable.rows == 1

@@ -14,7 +14,6 @@ surfaces ride along.
 from __future__ import annotations
 
 import json
-import threading
 import urllib.error
 import urllib.request
 
@@ -38,6 +37,7 @@ from annotatedvdb_tpu.types import (
     chromosome_label,
     encode_allele_array,
 )
+from conftest import bulk_envelope, start_server, stop_server
 
 WIDTH = 8
 CHROMS = (1, 8, 23)
@@ -398,7 +398,6 @@ def test_builders_hand_mesh_the_per_device_budget(tmp_path, monkeypatch):
     per-device share — never the raw AVDB_SERVE_HBM_BUDGET env (a fleet
     worker reading the env whole would overcommit HBM N-fold)."""
     from annotatedvdb_tpu.serve import ResidencyManager
-    from annotatedvdb_tpu.serve.http import build_server
 
     store, _truth = _build_store()
     store_dir = str(tmp_path / "budget_store")
@@ -406,20 +405,18 @@ def test_builders_hand_mesh_the_per_device_budget(tmp_path, monkeypatch):
     monkeypatch.setenv("AVDB_SERVE_MESH", "1")
     monkeypatch.setenv("AVDB_SERVE_HBM_BUDGET", "8g")  # must be ignored
     residency = ResidencyManager(1 << 20)  # the worker's split share
-    httpd = build_server(store_dir=store_dir, port=0, residency=residency)
+    httpd = start_server(store_dir=store_dir, residency=residency)
     try:
         assert httpd.ctx.engine.mesh is not None
         assert httpd.ctx.engine.mesh.budget == 1 << 20
     finally:
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
     # no residency manager = unmanaged mesh state, not env-budgeted
-    httpd = build_server(store_dir=store_dir, port=0)
+    httpd = start_server(store_dir=store_dir)
     try:
         assert httpd.ctx.engine.mesh.budget == 0
     finally:
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 def test_mesh_bulk_keeps_residency_warm(served):
@@ -444,7 +441,7 @@ def test_mesh_bulk_keeps_residency_warm(served):
 
 
 # ---------------------------------------------------------------------------
-# byte-identity over BOTH HTTP front ends
+# byte-identity over HTTP
 
 
 def _get(port, path):
@@ -471,12 +468,11 @@ def _post(port, path, payload):
 
 
 def test_front_end_parity_mesh_vs_single_device(tmp_path, monkeypatch):
-    """Each front end with the mesh FORCED answers byte-identically to
+    """The server with the mesh FORCED answers byte-identically to
     itself without the mesh, across point/bulk/region/regions — the
-    serving acceptance gate."""
-    from annotatedvdb_tpu.serve.aio import build_aio_server
-    from annotatedvdb_tpu.serve.http import build_server
-
+    serving acceptance gate.  Oracle: a mesh-less ``QueryEngine`` over
+    the same store called directly (``lookup_many``, ``region``,
+    ``regions_serve(...).assemble()``)."""
     store, truth = _build_store()
     store_dir = str(tmp_path / "http_store")
     store.save(store_dir)
@@ -486,40 +482,43 @@ def test_front_end_parity_mesh_vs_single_device(tmp_path, monkeypatch):
         + [f"/region/{s}" for s in SPECS[:4]]
         + ["/region/8:490-600?minCadd=4.0&limit=3"]
     )
+    plain = QueryEngine(
+        StaticSnapshots(VariantStore.load(store_dir, readonly=True)),
+        region_cache_size=0,
+    )
+    records = plain.lookup_many(ids)
+    want = (
+        [records[0], records[-1]]
+        + [plain.region(s) for s in SPECS[:4]]
+        + [plain.region("8:490-600", min_cadd=4.0, limit=3)]
+        + [bulk_envelope(records),
+           plain.regions_serve(SPECS, limit=5).assemble()]
+    )
     bodies = {}
     for mesh_mode in ("0", "1"):
         monkeypatch.setenv("AVDB_SERVE_MESH", mesh_mode)
         monkeypatch.setenv("AVDB_MESH_BULK_MIN", "0")
-        httpd = build_server(store_dir=store_dir, port=0)
-        threading.Thread(target=httpd.serve_forever, daemon=True).start()
-        aio = build_aio_server(store_dir=store_dir, port=0)
-        aio.start_background()
+        server = start_server(store_dir=store_dir)
         try:
-            assert (httpd.ctx.engine.mesh is not None) \
+            assert (server.ctx.engine.mesh is not None) \
                 == (mesh_mode == "1")
-            for name, port in (("threaded", httpd.server_address[1]),
-                               ("aio", aio.server_address[1])):
-                out = [body for _s, body in (
-                    _get(port, p) for p in paths
-                )]
-                st, bulk = _post(port, "/variants", {"ids": ids})
-                assert st == 200
-                out.append(bulk)
-                st, regions = _post(port, "/regions",
-                                    {"regions": SPECS, "limit": 5})
-                assert st == 200
-                out.append(regions)
-                bodies[(name, mesh_mode)] = out
+            port = server.server_address[1]
+            got = [_get(port, p) for p in paths]
+            assert all(st == 200 for st, _body in got)
+            out = [body for _s, body in got]
+            st, bulk = _post(port, "/variants", {"ids": ids})
+            assert st == 200
+            out.append(bulk)
+            st, regions = _post(port, "/regions",
+                                {"regions": SPECS, "limit": 5})
+            assert st == 200
+            out.append(regions)
+            bodies[mesh_mode] = out
         finally:
-            httpd.shutdown()
-            httpd.server_close()
-            httpd.ctx.batcher.close()
-            aio.shutdown()
-            aio.ctx.batcher.close()
-    for name in ("threaded", "aio"):
-        assert bodies[(name, "1")] == bodies[(name, "0")], name
-    # and cross-front-end parity holds on the mesh path too
-    assert bodies[("threaded", "1")] == bodies[("aio", "1")]
+            stop_server(server)
+    assert bodies["1"] == bodies["0"]
+    # and the mesh path answers with the engine's own bytes
+    assert bodies["1"] == want
 
 
 # ---------------------------------------------------------------------------

@@ -37,7 +37,6 @@ from annotatedvdb_tpu.io.synth import write_synth_vcf
 from annotatedvdb_tpu.loaders.lookup import identity_hashes
 from annotatedvdb_tpu.obs.metrics import MetricsRegistry
 from annotatedvdb_tpu.serve import (
-    QueryBatcher,
     QueryEngine,
     ResidencyManager,
     StaticSnapshots,
@@ -54,6 +53,7 @@ from annotatedvdb_tpu.store.variant_store import (
 )
 from annotatedvdb_tpu.types import encode_allele_array
 from annotatedvdb_tpu.utils import runtime
+from conftest import BatcherOnLoop
 
 CHROMOSOMES = ("1", "2", "22")
 RECORDS = 3000
@@ -435,7 +435,7 @@ def _batch_spans(trace_dir: str) -> list:
     return spans
 
 
-@pytest.mark.parametrize("front", ["loop_batcher", "query_batcher"])
+@pytest.mark.parametrize("front", ["server", "filled_batch"])
 def test_a_drain_is_a_span_on_the_profilers_clock(store_dir, oracle, front,
                                                   tmp_path):
     import jax
@@ -445,7 +445,7 @@ def test_a_drain_is_a_span_on_the_profilers_clock(store_dir, oracle, front,
            for label in CHROMOSOMES]
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
-    if front == "loop_batcher":
+    if front == "server":
         srv = build_aio_server(store_dir=store_dir, port=0)
         srv.start_background()
         conn = http.client.HTTPConnection(
@@ -466,12 +466,16 @@ def test_a_drain_is_a_span_on_the_profilers_clock(store_dir, oracle, front,
     else:
         engine = QueryEngine(StaticSnapshots(
             VariantStore.load(store_dir, readonly=True)))
-        batcher = QueryBatcher(engine, max_batch=len(ids), max_wait_s=5.0)
+        batcher = BatcherOnLoop(engine, max_batch=len(ids), max_wait_s=5.0)
+
+        async def one_turn(b):
+            import asyncio
+
+            return await asyncio.gather(*[b.submit_future(i) for i in ids])
+
         jax.profiler.start_trace(str(tmp_path), profiler_options=options)
         try:
-            pending = [batcher.submit_nowait(i) for i in ids]
-            for p, ident in zip(pending, ids):
-                assert p.done.wait(30) and p.result == records[ident]
+            assert batcher.run(one_turn) == [records[i] for i in ids]
         finally:
             jax.profiler.stop_trace()
             batcher.close()

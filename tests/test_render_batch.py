@@ -33,10 +33,10 @@ from annotatedvdb_tpu.serve.engine import (
     render_variant,
     segment_alleles,
 )
-from annotatedvdb_tpu.serve.http import build_server
 from annotatedvdb_tpu.store import VariantStore
 from annotatedvdb_tpu.store.variant_store import RawJson, Segment
 from annotatedvdb_tpu.types import chromosome_label, encode_allele_array
+from conftest import start_server, stop_server
 
 WIDTH = 8
 CODE = 8
@@ -539,8 +539,7 @@ def test_stats_and_metrics_carry_render_batch(tmp_path):
 
     store_dir = str(tmp_path / "vdb")
     truth = _build_store(store_dir)
-    server = build_server(store_dir=store_dir, port=0)
-    threading.Thread(target=server.serve_forever, daemon=True).start()
+    server = start_server(store_dir=store_dir)
     port, ctx = server.server_address[1], server.ctx
     try:
         def call(path, payload=None):
@@ -564,6 +563,4 @@ def test_stats_and_metrics_carry_render_batch(tmp_path):
         assert (f'avdb_render_batch_rows_total{{path="columnar"}} '
                 f'{batch["rows"]}') in metrics
     finally:
-        server.shutdown()
-        server.server_close()
-        ctx.batcher.close()
+        stop_server(server)

@@ -23,12 +23,12 @@ import pytest
 from annotatedvdb_tpu.loaders.lookup import identity_hashes
 from annotatedvdb_tpu.obs.metrics import MetricsRegistry
 from annotatedvdb_tpu.serve import MemtableSnapshots, SnapshotManager
-from annotatedvdb_tpu.serve.http import build_server
 from annotatedvdb_tpu.store import VariantStore
 from annotatedvdb_tpu.store import replication as repl
 from annotatedvdb_tpu.store.memtable import Memtable
 from annotatedvdb_tpu.store.wal import WriteAheadLog, count_records
 from annotatedvdb_tpu.types import encode_allele_array
+from conftest import start_server, stop_server
 
 WIDTH = 8
 
@@ -60,7 +60,7 @@ def _request(port, method, path, body=None, timeout=15):
 
 
 class _Leader:
-    """One in-process threaded leader: on-disk store + memtable + WAL."""
+    """One in-process leader: on-disk store + memtable + WAL."""
 
     def __init__(self, store_dir: str):
         self.store_dir = store_dir
@@ -72,13 +72,10 @@ class _Leader:
             wal=WriteAheadLog(store_dir, "serve-w0", log=lambda m: None),
             registry=self.registry, log=lambda m: None,
         )
-        self.httpd = build_server(
-            manager=MemtableSnapshots(self.mgr, self.mem), port=0,
+        self.httpd = start_server(
+            manager=MemtableSnapshots(self.mgr, self.mem),
             memtable=self.mem, registry=self.registry,
         )
-        threading.Thread(
-            target=self.httpd.serve_forever, daemon=True
-        ).start()
         self.port = self.httpd.server_address[1]
         self.url = f"http://127.0.0.1:{self.port}"
 
@@ -89,8 +86,7 @@ class _Leader:
         return json.loads(body)
 
     def close(self):
-        self.httpd.shutdown()
-        self.httpd.ctx.batcher.close()
+        stop_server(self.httpd)
 
 
 @pytest.fixture()
@@ -101,18 +97,17 @@ def leader(tmp_path):
 
 
 def _follower_server(follower_dir, tailer):
-    """A read-only follower front end over the mirrored store directory
+    """A read-only follower server over the mirrored store directory
     with the tailer's overlay — the serve --follow wiring, in-process."""
     registry = MetricsRegistry()
     mgr = SnapshotManager(follower_dir, log=lambda m: None)
     mem = Memtable(width=WIDTH, store_dir=None, wal=None,
                    flush_bytes=0, flush_age_s=0.0, log=lambda m: None)
     manager = MemtableSnapshots(mgr, mem)
-    httpd = build_server(manager=manager, port=0, memtable=None,
+    httpd = start_server(manager=manager, memtable=None,
                          registry=registry)
     httpd.ctx.repl = tailer
     httpd.ctx.follow_url = tailer.leader_url
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
     return httpd, manager, mem, mgr
 
 
@@ -283,16 +278,14 @@ def test_repl_routes_404_without_store_dir():
     surface: /repl/* answer 404, not a crash."""
     from annotatedvdb_tpu.serve import StaticSnapshots
 
-    httpd = build_server(manager=StaticSnapshots(_seed_store()), port=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    httpd = start_server(manager=StaticSnapshots(_seed_store()))
     try:
         port = httpd.server_address[1]
         for path in ("/repl/manifest", "/repl/wal?name=x", "/repl/segment"):
             status, body = _request(port, "GET", path)
             assert status == 404, (path, body)
     finally:
-        httpd.shutdown()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 # -- bootstrap + tail --------------------------------------------------------
@@ -335,8 +328,7 @@ def test_bootstrap_then_tail_byte_identical_reads(leader, tmp_path):
             fs, fb = _request(fport, "GET", path)
             assert (ls, lb) == (fs, fb), path
     finally:
-        httpd.shutdown()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 def _mgr_store(manager):
@@ -483,8 +475,7 @@ def test_lag_gauge_readyz_and_follower_403(leader, tmp_path):
         status, _ = _request(fport, "GET", "/readyz")
         assert status == 200
     finally:
-        httpd.shutdown()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 def test_background_tail_thread_tracks_leader(leader, tmp_path):

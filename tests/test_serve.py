@@ -26,7 +26,6 @@ import pytest
 from annotatedvdb_tpu.loaders.lookup import identity_hashes
 from annotatedvdb_tpu.oracle.binindex import closed_form_bin, closed_form_path
 from annotatedvdb_tpu.serve import (
-    QueryBatcher,
     QueryEngine,
     QueryError,
     QueueFull,
@@ -39,6 +38,7 @@ from annotatedvdb_tpu.serve import (
 from annotatedvdb_tpu.store import VariantStore
 from annotatedvdb_tpu.store.variant_store import RawJson, Segment
 from annotatedvdb_tpu.types import chromosome_label, encode_allele_array
+from conftest import BatcherOnLoop, start_server, stop_server
 
 WIDTH = 8
 CHROMS = (1, 8, 23)  # "1", "8", "X"
@@ -416,8 +416,8 @@ def test_batcher_32_concurrent_clients(served):
     ids = [_vid(r) for r in truth]
     expected = {i: engine.lookup(i) for i in ids}
     expected["8:499:A:G"] = None
-    batcher = QueryBatcher(engine, max_batch=64, max_wait_s=0.005,
-                           max_queue=10_000)
+    batcher = BatcherOnLoop(engine, max_batch=64, max_wait_s=0.005,
+                            max_queue=10_000)
     n_threads, per_thread = 32, 25
     failures: list = []
     barrier = threading.Barrier(n_threads)
@@ -442,7 +442,7 @@ def test_batcher_32_concurrent_clients(served):
         t.join(timeout=60)
     try:
         assert not failures, failures[:5]
-        stats = batcher.drain_stats()
+        stats = batcher.batcher.drain_stats()
         assert stats["queries"] == n_threads * per_thread
         # coalescing actually happened: far fewer drains than queries
         assert stats["batches"] < stats["queries"]
@@ -453,11 +453,11 @@ def test_batcher_32_concurrent_clients(served):
 
 def test_batcher_bad_grammar_stays_with_its_caller(served):
     _dir, truth, _manager, engine = served
-    batcher = QueryBatcher(engine, max_batch=8, max_wait_s=0.001)
+    batcher = BatcherOnLoop(engine, max_batch=8, max_wait_s=0.001)
     try:
         with pytest.raises(QueryError):
             batcher.submit("not-a-variant")
-        # the drain thread is unharmed and still answers real queries
+        # the loop is unharmed and still answers real queries
         assert batcher.submit(_vid(truth[0])) is not None
     finally:
         batcher.close()
@@ -465,8 +465,8 @@ def test_batcher_bad_grammar_stays_with_its_caller(served):
 
 def test_batcher_admission_bound(served):
     _dir, truth, _manager, engine = served
-    batcher = QueryBatcher(engine, max_batch=8, max_wait_s=0.001,
-                           max_queue=0)
+    batcher = BatcherOnLoop(engine, max_batch=8, max_wait_s=0.001,
+                            max_queue=0)
     try:
         with pytest.raises(QueueFull):
             batcher.submit(_vid(truth[0]))
@@ -534,18 +534,12 @@ def _get(port: int, path: str):
 
 @pytest.fixture()
 def http_server(served):
-    from annotatedvdb_tpu.serve.http import build_server
-
     store_dir, truth, _manager, _engine = served
-    httpd = build_server(store_dir=store_dir, port=0)
-    thread = threading.Thread(target=httpd.serve_forever, daemon=True)
-    thread.start()
+    httpd = start_server(store_dir=store_dir)
     try:
         yield httpd, httpd.server_address[1], truth
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 def test_http_end_to_end(http_server):
@@ -614,12 +608,8 @@ def test_http_end_to_end(http_server):
 
 
 def test_http_429_under_forced_backpressure(served):
-    from annotatedvdb_tpu.serve.http import build_server
-
     store_dir, truth, _manager, _engine = served
-    httpd = build_server(store_dir=store_dir, port=0, max_queue=0)
-    thread = threading.Thread(target=httpd.serve_forever, daemon=True)
-    thread.start()
+    httpd = start_server(store_dir=store_dir, max_queue=0)
     try:
         port = httpd.server_address[1]
         status, _body, headers = _get(port, f"/variant/{_vid(truth[0])}")
@@ -630,9 +620,7 @@ def test_http_429_under_forced_backpressure(served):
         status, body, _ = _get(port, "/metrics")
         assert "avdb_query_rejected_total" in body
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,8 @@
-"""The asyncio serving front end: byte-parity against the threaded
-reference server (point/bulk/region, hits and errors), weighted
-per-client fairness under a hog, chunked region streaming, continuation
-paging, the coalesced snapshot TTL, and the batcher's non-blocking
-submission path it rides on."""
+"""The asyncio serving front end: byte-parity against the engine and
+the shared payload builders called directly (point/bulk/region, hits and
+errors), weighted per-client fairness under a hog, chunked region
+streaming, continuation paging, the coalesced snapshot TTL, and the
+loop batcher it rides on."""
 
 from __future__ import annotations
 
@@ -16,13 +16,20 @@ import urllib.request
 
 import pytest
 
-from annotatedvdb_tpu.serve import QueryBatcher, QueryEngine, SnapshotManager
+from annotatedvdb_tpu.serve import QueryEngine, QueryError, SnapshotManager
 from annotatedvdb_tpu.serve import snapshot as snapshot_mod
+from annotatedvdb_tpu.serve.http import (
+    BULK_BODY_ERROR,
+    healthz_payload,
+    parse_region_params,
+    stats_payload,
+)
+from conftest import bulk_envelope
 from test_serve import _build_store, _commit_more_rows, _vid
 
 
 # ---------------------------------------------------------------------------
-# fixtures: one store, both front ends
+# fixtures: one store, one server, and the engine as the oracle
 
 
 @pytest.fixture(scope="module")
@@ -47,19 +54,16 @@ def aio_server(store):
 
 
 @pytest.fixture(scope="module")
-def threaded_server(store):
-    from annotatedvdb_tpu.serve.http import build_server
-
+def oracle(store):
+    """A ``QueryEngine`` of the test's own over the same store: what the
+    server's bytes are compared with (``test_serve.py`` pins the engine
+    itself against the brute-force scan)."""
     store_dir, _truth = store
-    httpd = build_server(store_dir=store_dir, port=0)
-    thread = threading.Thread(target=httpd.serve_forever, daemon=True)
-    thread.start()
-    try:
-        yield httpd
-    finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+    return QueryEngine(SnapshotManager(store_dir), region_cache_size=0)
+
+
+def _error(message: str) -> str:
+    return json.dumps({"error": message})
 
 
 def _get(port: int, path: str, headers=None):
@@ -85,39 +89,48 @@ def _post(port: int, path: str, payload: bytes):
 
 
 # ---------------------------------------------------------------------------
-# byte parity vs the threaded reference front end
+# byte parity vs the engine called directly
 
 
-def test_point_parity_hits_misses_errors(store, aio_server, threaded_server):
+def test_point_parity_hits_misses_errors(store, aio_server, oracle):
+    """Oracle: ``engine.lookup`` on the test's own engine; a miss is the
+    404 body, a grammar error the 400 body with the ``QueryError``."""
     _dir, truth = store
-    a_port = aio_server.server_address[1]
-    t_port = threaded_server.server_address[1]
-    paths = [f"/variant/{_vid(r)}" for r in truth[::5]]
-    paths += ["/variant/8:499:A:G",       # miss -> 404
-              "/variant/garbage",          # grammar -> 400
-              "/variant/2:500:A:G"]        # unloaded chromosome -> 404
-    for path in paths:
-        astatus, abody, _ = _get(a_port, path)
-        tstatus, tbody, _ = _get(t_port, path)
-        assert (astatus, abody) == (tstatus, tbody), path
+    port = aio_server.server_address[1]
+    for vid in [_vid(r) for r in truth[::5]]:
+        assert _get(port, f"/variant/{vid}")[:2] \
+            == (200, oracle.lookup(vid)), vid
+    for vid in ("8:499:A:G",       # miss
+                "2:500:A:G"):      # unloaded chromosome
+        assert oracle.lookup(vid) is None
+        assert _get(port, f"/variant/{vid}")[:2] \
+            == (404, _error(f"variant {vid!r} not in store")), vid
+    with pytest.raises(QueryError) as grammar:
+        oracle.lookup("garbage")
+    assert _get(port, "/variant/garbage")[:2] \
+        == (400, _error(str(grammar.value)))
 
 
-def test_bulk_parity_including_bad_bodies(store, aio_server, threaded_server):
+def test_bulk_parity_including_bad_bodies(store, aio_server, oracle):
+    """Oracle: ``engine.lookup_many`` in the bulk envelope; every
+    malformed body is 400 with the one grammar message."""
     _dir, truth = store
-    a_port = aio_server.server_address[1]
-    t_port = threaded_server.server_address[1]
+    port = aio_server.server_address[1]
     ids = [_vid(r) for r in truth[:40]] + ["8:499:A:G"]
     payload = json.dumps({"ids": ids}).encode()
-    assert _post(a_port, "/variants", payload) \
-        == _post(t_port, "/variants", payload)
+    assert _post(port, "/variants", payload) \
+        == (200, bulk_envelope(oracle.lookup_many(ids)))
     for bad in (b"[1,2]", b'{"ids": [1]}', b'{"ids": "x"}', b"{nope"):
-        assert _post(a_port, "/variants", bad) \
-            == _post(t_port, "/variants", bad), bad
+        assert _post(port, "/variants", bad) \
+            == (400, _error(BULK_BODY_ERROR)), bad
 
 
-def test_region_parity_with_filters(store, aio_server, threaded_server):
-    a_port = aio_server.server_address[1]
-    t_port = threaded_server.server_address[1]
+def test_region_parity_with_filters(store, aio_server, oracle):
+    """Oracle: the shared query grammar (``parse_region_params``) and
+    ``engine.region`` called directly; a refusal is 400 with the
+    ``QueryError`` either raises."""
+    port = aio_server.server_address[1]
+    statuses = []
     for path in (
         "/region/8:1-10000",
         "/region/8:1-10000?minCadd=5&limit=4",
@@ -127,9 +140,17 @@ def test_region_parity_with_filters(store, aio_server, threaded_server):
         "/region/8:9-3",                       # bad range -> 400
         "/region/8:1-10000?limit=zebra",       # bad param -> 400
     ):
-        astatus, abody, _ = _get(a_port, path)
-        tstatus, tbody, _ = _get(t_port, path)
-        assert (astatus, abody) == (tstatus, tbody), path
+        spec, _, query = path[len("/region/"):].partition("?")
+        try:
+            min_cadd, max_rank, limit, cursor = parse_region_params(query)
+            want = 200, oracle.region(
+                spec, min_cadd=min_cadd, max_conseq_rank=max_rank,
+                limit=limit, cursor=cursor)
+        except QueryError as err:
+            want = 400, _error(str(err))
+        assert _get(port, path)[:2] == want, path
+        statuses.append(want[0])
+    assert statuses == [200, 200, 200, 200, 200, 400, 400]
 
 
 def test_aio_routes_and_metrics(aio_server):
@@ -365,22 +386,21 @@ def test_bind_failure_raises_cleanly(store):
         blocker.close()
 
 
-def test_healthz_stats_and_bad_content_length_parity(
-        store, aio_server, threaded_server):
-    """The ops routes and the malformed-Content-Length POST answer
-    identically on both front ends (the payload builders are shared in
-    http.py for exactly this reason)."""
+def test_healthz_stats_and_bad_content_length_parity(store, aio_server):
+    """Oracle: ``healthz_payload`` / ``stats_payload`` called directly
+    on the server's context; the malformed-Content-Length POST is 400
+    with the one bulk grammar message."""
     aport = aio_server.server_address[1]
-    tport = threaded_server.server_address[1]
-    sa, ba, _h = _get(aport, "/healthz")
-    st, bt, _h = _get(tport, "/healthz")
-    assert (sa, ba) == (st, bt)
-    sa, ba, _h = _get(aport, "/stats")
-    st, bt, _h = _get(tport, "/stats")
-    # drain counters differ across the shared fixtures; the surface
-    # (status + key set) must not fork
-    assert sa == st
-    assert json.loads(ba).keys() == json.loads(bt).keys()
+    ctx = aio_server.ctx
+    assert _get(aport, "/healthz")[:2] == (200, healthz_payload(ctx))
+    status, body, _h = _get(aport, "/stats")
+    # counters move between the request and the direct call; the surface
+    # (status + key set, and what holds still) must not fork
+    assert status == 200
+    got, want = json.loads(body), json.loads(stats_payload(ctx))
+    assert got.keys() == want.keys()
+    for key in ("generation", "rows", "device", "device_lookup"):
+        assert got[key] == want[key], key
 
     def bad_cl(port):
         conn = http.client.HTTPConnection("127.0.0.1", port, timeout=30)
@@ -393,8 +413,7 @@ def test_healthz_stats_and_bad_content_length_parity(
         finally:
             conn.close()
 
-    assert bad_cl(aport) == bad_cl(tport)
-    assert bad_cl(aport)[0] == 400
+    assert bad_cl(aport) == (400, _error(BULK_BODY_ERROR).encode())
 
 
 def test_bulk_charges_per_id_against_bucket(store):
@@ -478,11 +497,10 @@ def test_bulk_charges_per_id_against_bucket(store):
         server.ctx.batcher.close()
 
 
-@pytest.mark.parametrize("frontend", ["aio", "threaded"])
-def test_bad_env_knob_exits_cleanly(store, frontend):
+def test_bad_env_knob_exits_cleanly(store):
     """An unparseable ``AVDB_SERVE_*`` knob must exit ``serve: cannot
-    start`` rc=1 on BOTH front ends, not a traceback — a fleet worker
-    dying with a traceback would respawn into a crash loop."""
+    start`` rc=1, not a traceback — a fleet worker dying with a
+    traceback would respawn into a crash loop."""
     import os
     import subprocess
     import sys
@@ -491,7 +509,7 @@ def test_bad_env_knob_exits_cleanly(store, frontend):
     env = dict(os.environ, AVDB_SERVE_BATCH_MAX="abc")
     p = subprocess.run(
         [sys.executable, "-m", "annotatedvdb_tpu", "serve",
-         "--storeDir", store_dir, "--port", "0", "--frontend", frontend],
+         "--storeDir", store_dir, "--port", "0"],
         env=env, capture_output=True, text=True, timeout=120,
     )
     assert p.returncode == 1, p.stderr[-2000:]
@@ -499,26 +517,47 @@ def test_bad_env_knob_exits_cleanly(store, frontend):
     assert "Traceback" not in p.stderr
 
 
-def test_threaded_frontend_warns_on_aio_only_knobs(tmp_path, capsys,
-                                                   monkeypatch):
-    """--clientRate/--streamThreshold have no wiring on the threaded
-    front end: starting silently would let an operator believe hogs are
-    throttled while nothing limits them."""
+def test_frontend_option_is_a_usage_error(store, capsys):
+    """There is one front end and no switch: ``--frontend`` in either of
+    its old spellings is an unknown option (argparse exit 2), refused
+    before a store is opened or a port bound."""
     from annotatedvdb_tpu.cli.serve import main
 
-    monkeypatch.delenv("AVDB_SERVE_CLIENT_RATE", raising=False)
-    monkeypatch.delenv("AVDB_SERVE_STREAM_THRESHOLD", raising=False)
-    missing = str(tmp_path / "no_store")
-    rc = main(["--storeDir", missing, "--frontend", "threaded",
-               "--clientRate", "10", "--streamThreshold", "5"])
-    assert rc == 1  # missing store still fails cleanly after the warning
-    err = capsys.readouterr().err
-    assert "--clientRate" in err and "--streamThreshold" in err
-    assert "ignored with --frontend threaded" in err
-    # the same knobs on the default (aio) front end must NOT warn
-    rc = main(["--storeDir", missing, "--clientRate", "10"])
-    assert rc == 1
-    assert "ignored" not in capsys.readouterr().err
+    store_dir, _truth = store
+    for value in ("threaded", "aio"):
+        with pytest.raises(SystemExit) as usage:
+            main(["--storeDir", store_dir, "--port", "0",
+                  "--frontend", value])
+        assert usage.value.code == 2
+        out, err = capsys.readouterr()
+        assert "unrecognized arguments: --frontend" in err
+        assert "serving" not in out  # no banner: nothing was bound
+
+
+def test_the_second_front_end_stays_gone():
+    """The threaded server, its batcher and the bridge between them and
+    the loop were one fork of the serving path; nothing may bring a piece
+    back by import.  (``serve.http`` stays: it holds the API's grammar
+    and ``ServeContext``, and serves nothing.)"""
+    import importlib
+
+    gone = {
+        "annotatedvdb_tpu.serve": ("QueryBatcher",),
+        "annotatedvdb_tpu.serve.batcher": ("QueryBatcher", "_Pending"),
+        "annotatedvdb_tpu.serve.http": ("build_server", "ServeHandler"),
+        "annotatedvdb_tpu.serve.aio": ("_CompletionBridge",
+                                       "_resolve_pending"),
+        "annotatedvdb_tpu.cli.serve": ("_run_threaded",),
+    }
+    for module, names in gone.items():
+        mod = importlib.import_module(module)
+        for name in names:
+            assert not hasattr(mod, name), f"{module}.{name}"
+    import annotatedvdb_tpu.serve as serve_pkg
+
+    assert "QueryBatcher" not in serve_pkg.__all__
+    with pytest.raises(ImportError):
+        importlib.import_module("annotatedvdb_tpu.analysis.rules_parity")
 
 
 def test_abandoned_stream_items_release_admission_slots(store):
@@ -579,7 +618,7 @@ def test_client_weight_applies_per_request():
 # chunked region streaming + paging
 
 
-def test_region_streams_chunked_above_threshold(store, threaded_server):
+def test_region_streams_chunked_above_threshold(store, oracle):
     from annotatedvdb_tpu.serve.aio import build_aio_server
 
     store_dir, _truth = store
@@ -597,10 +636,9 @@ def test_region_streams_chunked_above_threshold(store, threaded_server):
         assert resp.getheader("Content-Length") is None
         streamed = resp.read().decode()
         conn.close()
-        # de-chunked bytes identical to the buffered reference server
-        t_port = threaded_server.server_address[1]
-        _status, buffered, _ = _get(t_port, "/region/8:1-3000000")
-        assert streamed == buffered
+        # de-chunked bytes identical to the engine's buffered text
+        assert streamed == oracle.region(
+            "8:1-3000000", limit=parse_region_params("")[2])
         rec = json.loads(streamed)
         assert rec["returned"] > 5
         # small regions stay buffered (Content-Length, not chunked)
@@ -773,34 +811,38 @@ def test_snapshot_ttl_commit_visible_within_window(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# batcher non-blocking submission (the aio front end's primitive)
+# the loop batcher
 
 
-def test_submit_nowait_callback_completes_off_thread(store):
-    store_dir, truth = store
-    manager = SnapshotManager(store_dir)
-    engine = QueryEngine(manager, region_cache_size=0)
-    batcher = QueryBatcher(engine, max_batch=16, max_wait_s=0.001)
-    try:
-        done = threading.Event()
-        got = {}
+def test_loop_batcher_close_fails_queued_and_refuses_new():
+    """Shutdown contract: a query still queued when the batcher closes
+    is failed (cancelled), never left to hang its waiter, and the engine
+    is not called for it; a submission after close is refused."""
+    import asyncio
 
-        def cb(pending):
-            got["result"] = pending.result
-            got["error"] = pending.error
-            done.set()
+    from annotatedvdb_tpu.serve.aio import LoopBatcher
 
-        pending = batcher.submit_nowait(
-            _vid(truth[0]), cb, want_event=False
-        )
-        assert pending.done is None  # no Event allocated on this path
-        assert done.wait(10)
-        assert got["error"] is None
-        assert json.loads(got["result"])["position"] == truth[0]["pos"]
-        # blocking submit still works on the same batcher
-        assert batcher.submit(_vid(truth[1])) is not None
-    finally:
-        batcher.close()
+    class _Engine:
+        calls = 0
+
+        def lookup_many(self, ids, parsed=None):
+            self.calls += 1
+            return [None] * len(ids)
+
+    async def scenario():
+        engine = _Engine()
+        b = LoopBatcher(engine, max_batch=8, max_wait_s=30.0, max_queue=8)
+        queued = [b.submit_future(f"1:{100 + i}:A:T") for i in range(3)]
+        assert b.depth() == 3
+        b.close()
+        assert b.depth() == 0 and b._timer is None
+        done = await asyncio.gather(*queued, return_exceptions=True)
+        assert all(isinstance(e, asyncio.CancelledError) for e in done)
+        assert engine.calls == 0
+        with pytest.raises(RuntimeError, match="closed"):
+            b.submit_future("1:200:A:T")
+
+    asyncio.run(scenario())
 
 
 def test_loop_batcher_burst_leaves_no_orphan_drain():

@@ -198,6 +198,35 @@ def test_drain_survives_a_worker_that_outlives_sigkill(fleet_store):
     assert any("not waiting for it" in ln for ln in lines), lines
 
 
+def test_every_forwarded_knob_is_an_option_a_worker_parses(monkeypatch):
+    """The supervisor forwards knob flags to every worker verbatim: one
+    the worker's parser does not know is a usage error in every worker,
+    which is a respawn loop.  With every knob set, the forwarded argv
+    parses, carries the values, and names no front end (there is one)."""
+    from annotatedvdb_tpu.cli.serve import _build_parser, _knob_args
+
+    monkeypatch.delenv("AVDB_SERVE_HBM_BUDGET", raising=False)
+    monkeypatch.delenv("AVDB_SERVE_UPSERTS", raising=False)
+    parser = _build_parser()
+    args = parser.parse_args([
+        "--storeDir", "x", "--upserts", "--maxBatch", "64",
+        "--batchWaitMs", "1.5", "--maxQueue", "99", "--regionCache", "7",
+        "--clientRate", "5", "--streamThreshold", "12",
+        "--snapshotTtlMs", "40", "--hbmBudget", "1m",
+    ])
+    knobs = _knob_args(args, workers=2)
+    assert "--frontend" not in knobs
+    worker = parser.parse_args(
+        ["--storeDir", "x", "--_workerIndex", "0"] + knobs)
+    assert worker.upserts is True
+    assert (worker.maxBatch, worker.batchWaitMs, worker.maxQueue,
+            worker.regionCache, worker.clientRate, worker.streamThreshold,
+            worker.snapshotTtlMs) == (64, 1.5, 99, 7, 5.0, 12, 40.0)
+    assert worker.hbmBudget == str((1 << 20) // 2)
+    # with nothing set, nothing is forwarded
+    assert _knob_args(parser.parse_args(["--storeDir", "x"]), 2) == []
+
+
 def test_fleet_splits_hbm_budget_across_workers(monkeypatch):
     """The HBM budget caps ONE shared device: each worker must get an
     equal share, never the full budget (flag and env var alike)."""

@@ -1,8 +1,7 @@
 """Request-scoped tracing + fleet telemetry through the serving stack.
 
 The trace-id echo contract (W3C ``traceparent`` / ``X-Request-Id`` /
-minted, byte-identical across BOTH front ends), stage attribution
-through both batchers and the engine, trace-id propagation across a
+minted), stage attribution through the batcher and the engine, trace-id propagation across a
 paged cursor walk and a batched ``/regions`` panel, the WAL-fsync stage
 of an upsert ack, the chaos-gated ``/debug/trace`` dump, the
 ``/metrics?fleet=1`` fleet view, and the lifecycle events (brownout,
@@ -12,7 +11,6 @@ breaker) the flight recorder keeps.
 from __future__ import annotations
 
 import json
-import threading
 import time
 import urllib.error
 import urllib.request
@@ -24,15 +22,12 @@ from annotatedvdb_tpu.loaders.lookup import identity_hashes
 from annotatedvdb_tpu.obs.flight import FlightRecorder, decode_ring
 from annotatedvdb_tpu.obs.metrics import MetricsRegistry
 from annotatedvdb_tpu.serve import MemtableSnapshots, SnapshotManager
-from annotatedvdb_tpu.serve.aio import build_aio_server
-from annotatedvdb_tpu.serve.http import (
-    build_server,
-    resolve_trace_id,
-)
+from annotatedvdb_tpu.serve.http import resolve_trace_id
 from annotatedvdb_tpu.store import VariantStore
 from annotatedvdb_tpu.store.memtable import Memtable
 from annotatedvdb_tpu.store.wal import WriteAheadLog
 from annotatedvdb_tpu.types import encode_allele_array
+from conftest import start_server, stop_server
 from test_serve import _build_store, _vid
 
 WIDTH = 8
@@ -46,24 +41,14 @@ def store(tmp_path_factory):
 
 
 @pytest.fixture(scope="module")
-def pair(store):
-    """Both front ends over one store — the parity rig."""
+def server(store):
+    """One server over the module's store: ``(port, ctx)``."""
     store_dir, _truth = store
-    httpd = build_server(store_dir=store_dir, port=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    aio = build_aio_server(store_dir=store_dir, port=0)
-    aio.start_background()
+    srv = start_server(store_dir=store_dir)
     try:
-        yield {
-            "pt": httpd.server_address[1], "pa": aio.server_address[1],
-            "ctx_t": httpd.ctx, "ctx_a": aio.ctx,
-        }
+        yield srv.server_address[1], srv.ctx
     finally:
-        aio.shutdown()
-        aio.ctx.batcher.close()
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(srv)
 
 
 def _get(port, path, headers=None):
@@ -117,10 +102,13 @@ def test_resolve_trace_id_grammar():
 
 
 # ---------------------------------------------------------------------------
-# header echo parity
+# header echo
 
 
-def test_trace_header_echoes_byte_identical_on_both(store, pair):
+def test_trace_header_echoes_the_resolved_id(store, server):
+    """Oracle: ``resolve_trace_id`` called directly on the same headers —
+    the echoed ``X-Request-Id`` is exactly what the one resolver gives."""
+    port, _ctx = server
     _store_dir, truth = store
     vid = _vid(truth[0])
     for hdrs, want in (
@@ -128,45 +116,42 @@ def test_trace_header_echoes_byte_identical_on_both(store, pair):
         ({"traceparent": "00-" + "ef" * 16 + "-" + "12" * 8 + "-00"},
          "ef" * 16),
     ):
-        st, _bt, ht = _get(pair["pt"], f"/variant/{vid}", hdrs)
-        sa, _ba, ha = _get(pair["pa"], f"/variant/{vid}", hdrs)
-        assert st == sa == 200
-        assert ht.get("X-Request-Id") == ha.get("X-Request-Id") == want
+        status, _b, got = _get(port, f"/variant/{vid}", hdrs)
+        assert status == 200
+        assert got.get("X-Request-Id") == want == resolve_trace_id(
+            hdrs.get("traceparent"), hdrs.get("X-Request-Id"))
     # minted when absent: 32 hex chars on every route, errors included
     for path in (f"/variant/{vid}", "/variant/zzz", "/healthz",
                  "/nosuchroute"):
-        _s, _b, ht = _get(pair["pt"], path)
-        _s, _b, ha = _get(pair["pa"], path)
-        assert len(ht.get("X-Request-Id", "")) == 32, path
-        assert len(ha.get("X-Request-Id", "")) == 32, path
+        _s, _b, got = _get(port, path)
+        assert len(got.get("X-Request-Id", "")) == 32, path
 
 
-def test_stage_breakdown_recorded_per_point_request(store, pair):
+def test_stage_breakdown_recorded_per_point_request(store, server):
+    port, ctx = server
     _store_dir, truth = store
     vid = _vid(truth[1])
-    for port, ctx in ((pair["pt"], pair["ctx_t"]),
-                      (pair["pa"], pair["ctx_a"])):
-        tid = f"stages-{port}"
-        status, _b, _h = _get(port, f"/variant/{vid}",
-                              {"X-Request-Id": tid})
-        assert status == 200
-        recs = _records_for(ctx, tid)
-        assert len(recs) == 1 and recs[0][1] == "point"
-        stages = dict(recs[0][5])
-        # the queue/device split comes from the batcher drain; the rest
-        # from the front end
-        assert set(stages) >= {"admission", "queue", "device", "render"}
-        assert all(s >= 0 for s in stages.values())
+    tid = f"stages-{port}"
+    status, _b, _h = _get(port, f"/variant/{vid}", {"X-Request-Id": tid})
+    assert status == 200
+    recs = _records_for(ctx, tid)
+    assert len(recs) == 1 and recs[0][1] == "point"
+    stages = dict(recs[0][5])
+    # the queue/device split comes from the batcher drain; the rest
+    # from the front end
+    assert set(stages) >= {"admission", "queue", "device", "render"}
+    assert all(s >= 0 for s in stages.values())
 
 
 # ---------------------------------------------------------------------------
 # propagation: paged cursor walk + batched /regions panel
 
 
-def test_cursor_walk_pages_share_the_trace_id(store, pair):
+def test_cursor_walk_pages_share_the_trace_id(store, server):
+    port, ctx = server
     tid = "walk-1"
     status, body, hdrs = _get(
-        pair["pa"], "/region/8:1-3000000?limit=25&cursor=",
+        port, "/region/8:1-3000000?limit=25&cursor=",
         {"X-Request-Id": tid},
     )
     assert status == 200
@@ -175,14 +160,14 @@ def test_cursor_walk_pages_share_the_trace_id(store, pair):
     nxt = json.loads(body).get("next")
     while nxt and pages < 4:
         status, body, hdrs = _get(
-            pair["pa"], f"/region/8:1-3000000?limit=25&cursor={nxt}",
+            port, f"/region/8:1-3000000?limit=25&cursor={nxt}",
             {"X-Request-Id": tid},
         )
         assert status == 200 and hdrs.get("X-Request-Id") == tid
         nxt = json.loads(body).get("next")
         pages += 1
     assert pages >= 2, "walk never continued: the fixture store shrank?"
-    recs = _records_for(pair["ctx_a"], tid)
+    recs = _records_for(ctx, tid)
     assert len(recs) == pages
     for r in recs:
         assert r[1] == "region"
@@ -190,24 +175,22 @@ def test_cursor_walk_pages_share_the_trace_id(store, pair):
                    for name, _s, _e, parent in r[6]), r[6]
 
 
-def test_regions_panel_intervals_share_the_trace_id(store, pair):
+def test_regions_panel_intervals_share_the_trace_id(store, server):
+    port, ctx = server
     body = {"regions": ["8:400-600", "8:119000-121000", "1:400-600"],
             "limit": 10}
-    for port, ctx in ((pair["pt"], pair["ctx_t"]),
-                      (pair["pa"], pair["ctx_a"])):
-        tid = f"panel-{port}"
-        status, _b, hdrs = _post(port, "/regions", body,
-                                 {"X-Request-Id": tid})
-        assert status == 200
-        assert hdrs.get("X-Request-Id") == tid
-        recs = _records_for(ctx, tid)
-        assert len(recs) == 1 and recs[0][1] == "regions"
-        span_names = {name for name, _s, _e, parent in recs[0][6]
-                      if parent == "device"}
-        # every touched chromosome group's span hangs off the PANEL's id
-        assert {"regions.chr8", "regions.chr1"} <= span_names
-        stages = dict(recs[0][5])
-        assert {"admission", "device", "render"} <= set(stages)
+    tid = f"panel-{port}"
+    status, _b, hdrs = _post(port, "/regions", body, {"X-Request-Id": tid})
+    assert status == 200
+    assert hdrs.get("X-Request-Id") == tid
+    recs = _records_for(ctx, tid)
+    assert len(recs) == 1 and recs[0][1] == "regions"
+    span_names = {name for name, _s, _e, parent in recs[0][6]
+                  if parent == "device"}
+    # every touched chromosome group's span hangs off the PANEL's id
+    assert {"regions.chr8", "regions.chr1"} <= span_names
+    stages = dict(recs[0][5])
+    assert {"admission", "device", "render"} <= set(stages)
 
 
 # ---------------------------------------------------------------------------
@@ -233,9 +216,8 @@ def test_upsert_ack_attributes_wal_fsync(tmp_path):
         wal=WriteAheadLog(store_dir, "serve-obs", log=lambda m: None),
         registry=registry, log=lambda m: None,
     )
-    httpd = build_server(manager=MemtableSnapshots(mgr, mem), port=0,
+    httpd = start_server(manager=MemtableSnapshots(mgr, mem),
                          memtable=mem, registry=registry)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
     try:
         port = httpd.server_address[1]
         status, body, hdrs = _post(
@@ -254,71 +236,63 @@ def test_upsert_ack_attributes_wal_fsync(tmp_path):
         text = registry.render_prometheus()
         assert 'avdb_stage_seconds_count{stage="wal_fsync"} 1' in text
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
         mem.wal.close()
 
 
 # ---------------------------------------------------------------------------
-# /debug/trace (chaos-gated, both front ends)
+# /debug/trace (chaos-gated)
 
 
-def test_debug_trace_is_gated_off_like_chaos(store, pair):
-    # the module fixture servers run WITHOUT AVDB_SERVE_CHAOS: the route
-    # must 404 byte-identically to any unknown route on BOTH front ends
-    st, bt, _h = _get(pair["pt"], "/debug/trace")
-    sa, ba, _h = _get(pair["pa"], "/debug/trace")
-    assert st == sa == 404
-    assert bt == ba
-    assert "no such route" in bt
+def test_debug_trace_is_gated_off_like_chaos(store, server):
+    # the module fixture server runs WITHOUT AVDB_SERVE_CHAOS: the route
+    # must 404 with the body any unknown route gets
+    port, _ctx = server
+    status, body, _h = _get(port, "/debug/trace")
+    assert status == 404
+    assert json.loads(body) == {"error": "no such route: /debug/trace"}
+    assert _get(port, "/_chaos")[0] == 404
 
 
 def test_debug_trace_dumps_chrome_events_when_enabled(store, monkeypatch):
     monkeypatch.setenv("AVDB_SERVE_CHAOS", "1")
     store_dir, truth = store
     vid = _vid(truth[0])
-    httpd = build_server(store_dir=store_dir, port=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    aio = build_aio_server(store_dir=store_dir, port=0)
-    aio.start_background()
+    httpd = start_server(store_dir=store_dir)
     try:
-        for port in (httpd.server_address[1], aio.server_address[1]):
-            _get(port, f"/variant/{vid}", {"X-Request-Id": "dump-me"})
-            status, body, _h = _get(port, "/debug/trace")
-            assert status == 200
-            doc = json.loads(body)
-            assert doc["displayTimeUnit"] == "ms"
-            reqs = [e for e in doc["traceEvents"]
-                    if e.get("ph") == "X" and e.get("cat") == "request"]
-            assert any(e["args"]["trace_id"] == "dump-me" for e in reqs)
-            tracks = [e for e in doc["traceEvents"]
-                      if e.get("name") == "thread_name"]
-            assert {t["args"]["name"] for t in tracks} >= {
-                "requests", "background"}
+        port = httpd.server_address[1]
+        _get(port, f"/variant/{vid}", {"X-Request-Id": "dump-me"})
+        status, body, _h = _get(port, "/debug/trace")
+        assert status == 200
+        doc = json.loads(body)
+        assert doc["displayTimeUnit"] == "ms"
+        reqs = [e for e in doc["traceEvents"]
+                if e.get("ph") == "X" and e.get("cat") == "request"]
+        assert any(e["args"]["trace_id"] == "dump-me" for e in reqs)
+        tracks = [e for e in doc["traceEvents"]
+                  if e.get("name") == "thread_name"]
+        assert {t["args"]["name"] for t in tracks} >= {
+            "requests", "background"}
     finally:
-        aio.shutdown()
-        aio.ctx.batcher.close()
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 # ---------------------------------------------------------------------------
 # fleet telemetry plane (/metrics?fleet=1)
 
 
-def test_plain_metrics_unchanged_and_fleet_view_single_process(store, pair):
-    for port in (pair["pt"], pair["pa"]):
-        status, body, _h = _get(port, "/metrics")
-        assert status == 200
-        assert "avdb_fleet_workers_live" not in body  # plain scrape
-        status, body, _h = _get(port, "/metrics?fleet=1")
-        assert status == 200
-        assert "avdb_fleet_workers_live 1" in body
-        assert "avdb_fleet_respawns_total 0" in body
-        assert "avdb_fleet_worker_age_seconds" in body
-        assert "avdb_query_requests_total" in body
+def test_plain_metrics_unchanged_and_fleet_view_single_process(store,
+                                                               server):
+    port, _ctx = server
+    status, body, _h = _get(port, "/metrics")
+    assert status == 200
+    assert "avdb_fleet_workers_live" not in body  # plain scrape
+    status, body, _h = _get(port, "/metrics?fleet=1")
+    assert status == 200
+    assert "avdb_fleet_workers_live 1" in body
+    assert "avdb_fleet_respawns_total 0" in body
+    assert "avdb_fleet_worker_age_seconds" in body
+    assert "avdb_query_requests_total" in body
 
 
 def test_fleet_view_sums_published_worker_snapshots(store, tmp_path):
@@ -347,9 +321,7 @@ def test_fleet_view_sums_published_worker_snapshots(store, tmp_path):
     # a DEAD supervisor's fleet.json must age out exactly like a dead
     # worker's snapshot (checked below via the fresh file; see the
     # stale-supervisor test for the other side)
-    httpd = build_server(store_dir=store_dir, port=0, telemetry_dir=tdir,
-                         worker_index=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    httpd = start_server(store_dir=store_dir, telemetry_dir=tdir)
     try:
         port = httpd.server_address[1]
         vid = _vid(truth[0])
@@ -365,9 +337,7 @@ def test_fleet_view_sums_published_worker_snapshots(store, tmp_path):
         # gauges take the fleet max
         assert "avdb_serve_queue_depth 10" in body
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 def test_fleet_view_ages_out_a_dead_supervisors_facts(store, tmp_path):
@@ -383,8 +353,7 @@ def test_fleet_view_ages_out_a_dead_supervisors_facts(store, tmp_path):
     with open(os.path.join(tdir, "fleet.json"), "w") as f:
         json.dump({"t": time.time() - 3600, "workers_live": 4,
                    "respawns_total": 9, "worker_age_seconds": 77.0}, f)
-    httpd = build_server(store_dir=store_dir, port=0, telemetry_dir=tdir)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    httpd = start_server(store_dir=store_dir, telemetry_dir=tdir)
     try:
         status, body, _h = _get(httpd.server_address[1],
                                 "/metrics?fleet=1")
@@ -392,9 +361,7 @@ def test_fleet_view_ages_out_a_dead_supervisors_facts(store, tmp_path):
         assert "avdb_fleet_workers_live 1" in body  # NOT the stale 4
         assert "avdb_fleet_respawns_total 0" in body
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 def test_fleet_view_ignores_torn_snapshot_files(store, tmp_path):
@@ -405,17 +372,14 @@ def test_fleet_view_ignores_torn_snapshot_files(store, tmp_path):
     os.makedirs(tdir)
     with open(os.path.join(tdir, "worker-1.json"), "w") as f:
         f.write('{"index": 1, "t":')  # torn mid-publish
-    httpd = build_server(store_dir=store_dir, port=0, telemetry_dir=tdir)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    httpd = start_server(store_dir=store_dir, telemetry_dir=tdir)
     try:
         status, body, _h = _get(httpd.server_address[1],
                                 "/metrics?fleet=1")
         assert status == 200  # the scrape never fails on a torn sibling
         assert "avdb_fleet_workers_live 1" in body
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
 # ---------------------------------------------------------------------------
@@ -427,7 +391,7 @@ def test_brownout_and_breaker_transitions_land_on_the_flight(store,
     store_dir, _truth = store
     ring = str(tmp_path / "w0.ring")
     flight = FlightRecorder(ring, slots=32)
-    httpd = build_server(store_dir=store_dir, port=0, flight=flight)
+    httpd = start_server(store_dir=store_dir, flight=flight)
     try:
         ctx = httpd.ctx
         ctx.governor.force_level(3)
@@ -443,8 +407,7 @@ def test_brownout_and_breaker_transitions_land_on_the_flight(store,
         assert any(n == "breaker" and "group 8 tripped open" in d
                    for n, d in names)
     finally:
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
         flight.close()
 
 
@@ -452,8 +415,7 @@ def test_request_summaries_land_on_the_flight(store, tmp_path):
     store_dir, truth = store
     ring = str(tmp_path / "wr.ring")
     flight = FlightRecorder(ring, slots=32)
-    httpd = build_server(store_dir=store_dir, port=0, flight=flight)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    httpd = start_server(store_dir=store_dir, flight=flight)
     try:
         port = httpd.server_address[1]
         vid = _vid(truth[0])
@@ -466,9 +428,7 @@ def test_request_summaries_land_on_the_flight(store, tmp_path):
                    and e["status"] == 200 and "stages" in e
                    for e in reqs), reqs
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
         flight.close()
 
 

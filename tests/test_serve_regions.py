@@ -14,7 +14,6 @@ against the scalar bin oracle and the brute counts.
 from __future__ import annotations
 
 import json
-import threading
 import urllib.error
 import urllib.request
 
@@ -33,7 +32,9 @@ from annotatedvdb_tpu.serve import (
 )
 from annotatedvdb_tpu.store import VariantStore
 from annotatedvdb_tpu.store.variant_store import RawJson, Segment
+from annotatedvdb_tpu.serve.http import DEFAULT_REGION_LIMIT
 from annotatedvdb_tpu.types import chromosome_label, encode_allele_array
+from conftest import start_server, stop_server
 
 WIDTH = 8
 CHROMS = (1, 8, 23)
@@ -465,19 +466,24 @@ def test_concurrent_index_builds_deduplicate(served):
     assert len(got) == 8 and all(i is got[0] for i in got)
 
 
-def test_aio_malformed_content_length_is_400_parity(both_servers):
-    """A bogus Content-Length on POST /regions must answer 400 on BOTH
-    front ends (the aio fallthrough used to 404 it)."""
+def test_malformed_content_length_is_400_with_the_grammar_body(server_port):
+    """A bogus Content-Length on POST /regions answers 400 (the
+    fallthrough used to 404 it).  Oracle: the one grammar message,
+    ``REGIONS_BODY_ERROR``."""
     import socket
+
+    from annotatedvdb_tpu.serve.http import REGIONS_BODY_ERROR
 
     raw = (b"POST /regions HTTP/1.1\r\nHost: t\r\n"
            b"Content-Length: abc\r\n\r\n")
-    for port in both_servers:
-        with socket.create_connection(("127.0.0.1", port), timeout=15) as s:
-            s.sendall(raw)
-            s.settimeout(15)
-            head = s.recv(4096)
-        assert b" 400 " in head.split(b"\r\n", 1)[0], (port, head[:80])
+    with socket.create_connection(
+            ("127.0.0.1", server_port), timeout=15) as s:
+        s.sendall(raw)
+        s.settimeout(15)
+        resp = s.recv(4096)
+    head, _, body = resp.partition(b"\r\n\r\n")
+    assert b" 400 " in head.split(b"\r\n", 1)[0], head[:80]
+    assert json.loads(body) == {"error": REGIONS_BODY_ERROR}
 
 
 def test_cursor_walk_unaffected_by_interleaved_batches(served):
@@ -568,49 +574,45 @@ def _post(port: int, path: str, payload) -> tuple[int, str]:
 
 
 @pytest.fixture()
-def both_servers(served):
-    from annotatedvdb_tpu.serve.aio import build_aio_server
-    from annotatedvdb_tpu.serve.http import build_server
-
+def server_port(served):
+    """A server that streams any panel above 16 rows."""
     store_dir, truth, _manager, _engine = served
-    httpd = build_server(store_dir=store_dir, port=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    aio = build_aio_server(store_dir=store_dir, port=0, stream_threshold=16)
-    aio.start_background()
+    server = start_server(store_dir=store_dir, stream_threshold=16)
     try:
-        yield httpd.server_address[1], aio.server_address[1]
+        yield server.server_address[1]
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
-        aio.shutdown()
-        aio.ctx.batcher.close()
+        stop_server(server)
 
 
-def test_http_regions_byte_parity_both_front_ends(both_servers):
-    tport, aport = both_servers
+def test_http_regions_byte_parity_with_the_engine(served, server_port):
+    """Oracle: ``engine.regions_serve(...).assemble()`` called directly,
+    and each interval's single ``GET /region`` body."""
+    port = server_port
+    engine = served[3]
     specs = _specs()
     payload = {"regions": specs, "minCadd": 4.0, "limit": 6}
-    st_t, body_t = _post(tport, "/regions", payload)
-    st_a, body_a = _post(aport, "/regions", payload)
-    assert st_t == st_a == 200
-    assert body_t == body_a  # cross-front-end parity (aio streams: 11*6
-    # rows < threshold? returned <= 66 > 16 -> CHUNKED; de-chunked equal)
-    obj = json.loads(body_t)
+    status, body = _post(port, "/regions", payload)
+    assert status == 200
+    # the server streams this panel (up to 11*6 rows > 16): de-chunked,
+    # the bytes are the engine's buffered assembly
+    assert body == engine.regions_serve(
+        specs, min_cadd=4.0, max_conseq_rank=None, limit=6, tokenize=False,
+    ).assemble()
+    obj = json.loads(body)
     assert obj["n"] == len(specs)
     for spec, envelope in zip(specs, obj["results"]):
         status, single = _get(
-            tport, f"/region/{spec}?minCadd=4.0&limit=6"
+            port, f"/region/{spec}?minCadd=4.0&limit=6"
         )
         assert status == 200
         # byte-identical: the batch envelope is the single body verbatim
         assert json.dumps(envelope, separators=(",", ":")) \
             == json.dumps(json.loads(single), separators=(",", ":"))
-        assert single in body_t
+        assert single in body
 
 
-def test_http_regions_count_only_and_tokens(both_servers):
-    _tport, aport = both_servers
+def test_http_regions_count_only_and_tokens(server_port):
+    aport = server_port
     st, body = _post(aport, "/regions",
                      {"regions": ["8:1-10000"], "limit": 0,
                       "tokenize": True})
@@ -620,28 +622,24 @@ def test_http_regions_count_only_and_tokens(both_servers):
     assert obj["results"][0]["count"] == obj["tokens"]["count"][0] > 0
 
 
-def test_http_regions_bad_bodies_are_400(both_servers):
-    tport, aport = both_servers
-    for port in (tport, aport):
-        for bad in ({"regions": "x"}, {"regions": [1]}, {"nope": []},
-                    {"regions": ["8:9-3"]}, {"regions": ["junk"]},
-                    {"regions": ["8:1-2"], "limit": "ten"},
-                    {"regions": ["8:1-2"], "tokenize": "yes"},
-                    {"regions": ["8:1-2"], "minCadd": True}):
-            st, body = _post(port, "/regions", bad)
-            assert st == 400, (port, bad, st, body[:200])
-        # the route answers normally afterwards
-        st, _ = _post(port, "/regions", {"regions": ["8:1-2"]})
-        assert st == 200
+def test_http_regions_bad_bodies_are_400(server_port):
+    port = server_port
+    for bad in ({"regions": "x"}, {"regions": [1]}, {"nope": []},
+                {"regions": ["8:9-3"]}, {"regions": ["junk"]},
+                {"regions": ["8:1-2"], "limit": "ten"},
+                {"regions": ["8:1-2"], "tokenize": "yes"},
+                {"regions": ["8:1-2"], "minCadd": True}):
+        st, body = _post(port, "/regions", bad)
+        assert st == 400, (bad, st, body[:200])
+    # the route answers normally afterwards
+    st, _ = _post(port, "/regions", {"regions": ["8:1-2"]})
+    assert st == 200
 
 
 def test_http_regions_cap_is_400(served, monkeypatch):
-    from annotatedvdb_tpu.serve.http import build_server
-
     monkeypatch.setenv("AVDB_SERVE_REGIONS_MAX", "2")
     store_dir, _truth, _manager, _engine = served
-    httpd = build_server(store_dir=store_dir, port=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    httpd = start_server(store_dir=store_dir)
     try:
         port = httpd.server_address[1]
         st, body = _post(port, "/regions",
@@ -650,15 +648,13 @@ def test_http_regions_cap_is_400(served, monkeypatch):
         st, _ = _post(port, "/regions", {"regions": ["8:1-2", "8:3-4"]})
         assert st == 200
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
-def test_http_regions_fault_fails_one_request_and_metrics(both_servers):
+def test_http_regions_fault_fails_one_request_and_metrics(server_port):
     from annotatedvdb_tpu.utils import faults
 
-    tport, _aport = both_servers
+    tport = server_port
     try:
         faults.reset("serve.regions:1:raise")
         st, body = _post(tport, "/regions", {"regions": ["8:1-100"]})
@@ -674,26 +670,23 @@ def test_http_regions_fault_fails_one_request_and_metrics(both_servers):
 
 
 def test_http_regions_streaming_parity_with_buffered(served):
-    """A panel whose total rows exceed the aio stream threshold must
-    de-chunk to exactly the buffered (threaded) bytes."""
-    from annotatedvdb_tpu.serve.aio import build_aio_server
-    from annotatedvdb_tpu.serve.http import build_server
-
-    store_dir, _truth, _manager, _engine = served
-    httpd = build_server(store_dir=store_dir, port=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    aio = build_aio_server(store_dir=store_dir, port=0, stream_threshold=4)
-    aio.start_background()
+    """A panel whose total rows exceed the stream threshold must
+    de-chunk to exactly the buffered bytes.  Oracle: the engine's own
+    ``regions_serve(...).assemble()``, and the same server's answer with
+    streaming out of reach."""
+    store_dir, _truth, _manager, engine = served
+    streamed = start_server(store_dir=store_dir, stream_threshold=4)
+    buffered = start_server(store_dir=store_dir, stream_threshold=1 << 30)
     try:
         payload = {"regions": _specs()}
-        st_a, body_a = _post(aio.server_address[1], "/regions", payload)
-        st_t, body_t = _post(httpd.server_address[1], "/regions", payload)
-        assert st_a == st_t == 200
-        assert body_a == body_t
-        assert json.loads(body_a)["n"] == len(PANEL)
+        st_s, body_s = _post(streamed.server_address[1], "/regions", payload)
+        st_b, body_b = _post(buffered.server_address[1], "/regions", payload)
+        assert st_s == st_b == 200
+        assert body_s == body_b == engine.regions_serve(
+            _specs(), min_cadd=None, max_conseq_rank=None,
+            limit=DEFAULT_REGION_LIMIT, tokenize=False,
+        ).assemble()
+        assert json.loads(body_s)["n"] == len(PANEL)
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
-        aio.shutdown()
-        aio.ctx.batcher.close()
+        stop_server(streamed)
+        stop_server(buffered)

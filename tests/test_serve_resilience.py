@@ -21,7 +21,6 @@ from annotatedvdb_tpu.serve import (
     DeadlineExceeded,
     DeviceBreaker,
     OverloadGovernor,
-    QueryBatcher,
     QueryEngine,
     SnapshotManager,
     StaticSnapshots,
@@ -29,6 +28,7 @@ from annotatedvdb_tpu.serve import (
 from annotatedvdb_tpu.serve import resilience
 from annotatedvdb_tpu.store import VariantStore
 from annotatedvdb_tpu.utils import faults
+from conftest import BatcherOnLoop, start_server, stop_server
 from test_serve import _build_store, _commit_more_rows, _vid
 
 
@@ -165,276 +165,262 @@ def test_governor_idle_decay_releases_latency_signal():
 # deadline: batcher-queue shedding under a FULL queue (satellite)
 
 
-class _GatedEngine:
-    """lookup_many blocks until released — a drain in progress while the
-    queue fills behind it."""
+class _CountingEngine:
+    """lookup_many answers absence and counts its calls."""
 
     def __init__(self):
-        self.gate = threading.Event()
         self.calls = 0
 
     def lookup_many(self, ids, parsed=None):
         self.calls += 1
-        assert self.gate.wait(10), "test gate never released"
         return [None] * len(ids)
 
 
 def test_deadline_shed_under_full_queue_releases_admission_slots():
-    engine = _GatedEngine()
-    batcher = QueryBatcher(engine, max_batch=1, max_wait_s=0.0, max_queue=4)
-    try:
-        # drain 1 picks up the first pending and blocks in the engine
-        first = batcher.submit_nowait("3:10:A:C")
-        deadline = time.monotonic() + 2
-        while batcher.depth() > 0 and time.monotonic() < deadline:
-            time.sleep(0.005)
+    """The drain runs on the loop, so the queue fills while the loop is
+    busy: one turn admits the first query and four whose budget dies
+    before the loop gets back to draining."""
+    import asyncio
+
+    from annotatedvdb_tpu.serve import QueueFull
+
+    engine = _CountingEngine()
+    batcher = BatcherOnLoop(engine, max_batch=1, max_wait_s=0.0,
+                            max_queue=5)
+
+    async def one_busy_turn(b):
+        first = b.submit_future("3:10:A:C")
         # the queue fills with requests whose budget dies immediately
         dead = [
-            batcher.submit_nowait(
-                "3:10:A:C", deadline_t=time.monotonic() + 0.01
-            )
+            b.submit_future("3:10:A:C", deadline_t=time.monotonic() + 0.01)
             for _ in range(4)
         ]
+        assert b.depth() == 5
         # admission bound reached: the 429 path still works
-        from annotatedvdb_tpu.serve import QueueFull
-
         with pytest.raises(QueueFull):
-            batcher.submit_nowait("3:10:A:C")
-        time.sleep(0.05)  # every queued deadline lapses
-        engine.gate.set()
+            b.submit_future("3:10:A:C")
+        # the loop stays busy (as under an engine call); every deadline lapses
+        time.sleep(0.05)  # avdb: noqa[AVDB701] -- the test's subject IS a blocked loop
+        return await first, await asyncio.gather(
+            *dead, return_exceptions=True)
+
+    try:
+        first, dead = batcher.run(one_busy_turn)
+        assert first is None  # served: the row is absent
         # the shed drains release their queue slots and fail their callers
         # with the honest cause
-        for pending in dead:
-            assert pending.done.wait(5)
-            assert isinstance(pending.error, DeadlineExceeded)
-        assert first.done.wait(5) and first.error is None
+        assert len(dead) == 4
+        assert all(isinstance(e, DeadlineExceeded) for e in dead)
+        assert batcher.batcher.depth() == 0
         # slots released: a fresh submission is admitted AND served
         assert batcher.submit("3:10:A:C") is None
-        # the shed batch never reached the engine: exactly the first
+        # the shed pendings never reached the engine: exactly the first
         # drain and the fresh one executed
         assert engine.calls == 2
     finally:
-        engine.gate.set()
         batcher.close()
 
 
-def test_blocking_submit_surfaces_deadline_exceeded():
-    engine = _GatedEngine()
-    batcher = QueryBatcher(engine, max_batch=1, max_wait_s=0.0, max_queue=8)
+def test_queued_submit_surfaces_deadline_exceeded():
+    """A query whose budget lapses inside the batch-wait window fails
+    with DeadlineExceeded when its drain comes, before the engine."""
+    engine = _CountingEngine()
+    batcher = BatcherOnLoop(engine, max_batch=8, max_wait_s=0.08,
+                            max_queue=8)
     try:
-        batcher.submit_nowait("3:10:A:C")  # occupies the drain thread
-        time.sleep(0.02)
         with pytest.raises(DeadlineExceeded):
             batcher.submit("3:10:A:C",
-                           deadline_t=time.monotonic() + 0.05)
+                           deadline_t=time.monotonic() + 0.01)
+        assert engine.calls == 0
     finally:
-        engine.gate.set()
         batcher.close()
 
 
 # ---------------------------------------------------------------------------
-# deadline: HTTP 504 end-to-end on BOTH front ends
+# deadline: HTTP 504 end-to-end
 
 
-def _deadline_server(kind: str, store_dir: str):
-    """A server whose batcher waits 80ms before draining: a 10ms request
-    deadline deterministically lapses in the queue."""
-    if kind == "aio":
-        from annotatedvdb_tpu.serve.aio import build_aio_server
+@pytest.mark.parametrize("dies_at, budget_ms", [("batcher", "10"),
+                                                ("admission", "0.0001")])
+def test_point_deadline_maps_to_504_and_counter(store, dies_at, budget_ms):
+    """A point read whose budget runs out is shed as 504 where it dies,
+    and counted there: in the batcher's queue (the drain sheds it with
+    ``DeadlineExceeded``), or already at admission (the one message
+    constant, ``MSG_DEADLINE_ADMISSION``)."""
+    from annotatedvdb_tpu.serve.http import MSG_DEADLINE_ADMISSION
 
-        server = build_aio_server(
-            store_dir=store_dir, port=0, max_wait_s=0.08
-        )
-        server.start_background()
-        return server, server.server_address[1], server
-    from annotatedvdb_tpu.serve.http import build_server
-
-    httpd = build_server(store_dir=store_dir, port=0, max_wait_s=0.08)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    return httpd, httpd.server_address[1], None
-
-
-@pytest.mark.parametrize("kind", ["threaded", "aio"])
-def test_point_deadline_maps_to_504_and_counter(store, kind):
     store_dir, truth = store
-    server, port, aio = _deadline_server(kind, store_dir)
+    # a batcher that waits 80ms before draining: a 10ms request deadline
+    # deterministically lapses in the queue; a sub-microsecond one is
+    # dead by the admission check
+    server = start_server(store_dir=store_dir, max_wait_s=0.08)
+    port = server.server_address[1]
     try:
         vid = _vid(truth[0])
         # generous deadline: served normally
         status, _body, _ = _get(port, f"/variant/{vid}",
                                 headers={"X-Deadline-Ms": "5000"})
         assert status == 200
-        # a 10ms budget dies in the 80ms batch-wait window: shed as 504
         status, body, _ = _get(port, f"/variant/{vid}",
-                               headers={"X-Deadline-Ms": "10"})
+                               headers={"X-Deadline-Ms": budget_ms})
         assert status == 504, body
-        assert "deadline" in body
-        # the 504 races the drain's shed by design (the caller stops
-        # waiting first): poll until the batcher-side counter lands
+        if dies_at == "admission":
+            assert json.loads(body) == {"error": MSG_DEADLINE_ADMISSION}
+            assert server.ctx.batcher.drain_stats()["queries"] == 1
+        else:
+            assert json.loads(body) == {"error": (
+                f"query {vid!r} exceeded its deadline in the serve queue")}
+        # poll until the counter of the stage that shed it lands
+        counted = f'avdb_deadline_shed_total{{stage="{dies_at}"}} 1'
         deadline = time.monotonic() + 5
         while time.monotonic() < deadline:
             _s, metrics, _h = _get(port, "/metrics")
-            if 'avdb_deadline_shed_total{stage="batcher"} 1' in metrics:
+            if counted in metrics:
                 break
             time.sleep(0.05)
-        assert 'avdb_deadline_shed_total{stage="batcher"} 1' in metrics
+        assert counted in metrics
     finally:
-        if kind == "aio":
-            server.shutdown()
-        else:
-            server.shutdown()
-            server.server_close()
-        server.ctx.batcher.close()
+        stop_server(server)
 
 
 # ---------------------------------------------------------------------------
-# brownout ladder end-to-end (forced levels; both front ends)
+# brownout ladder end-to-end (forced levels)
 
 
 @pytest.fixture()
-def ladder_servers():
-    """Both front ends over the wide store (region cap must bite)."""
-    from annotatedvdb_tpu.serve.aio import build_aio_server
-    from annotatedvdb_tpu.serve.http import build_server
-
-    wide = _wide_store()
-    aio = build_aio_server(manager=StaticSnapshots(wide), port=0)
-    aio.start_background()
-    httpd = build_server(manager=StaticSnapshots(wide), port=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+def ladder_server():
+    """A server over the wide store (region cap must bite)."""
+    server = start_server(manager=StaticSnapshots(_wide_store()))
     try:
-        yield aio, httpd
+        yield server
     finally:
-        aio.shutdown()
-        httpd.shutdown()
-        httpd.server_close()
-        aio.ctx.batcher.close()
-        httpd.ctx.batcher.close()
+        stop_server(server)
 
 
-def _ports(ladder_servers):
-    aio, httpd = ladder_servers
-    return ((aio.ctx, aio.server_address[1]),
-            (httpd.ctx, httpd.server_address[1]))
-
-
-def test_brownout_level1_caps_region_limits(ladder_servers):
-    for ctx, port in _ports(ladder_servers):
+def test_brownout_level1_caps_region_limits(ladder_server):
+    ctx, port = ladder_server.ctx, ladder_server.server_address[1]
+    status, body, _ = _get(port, "/region/8:1-100000?limit=2000")
+    assert status == 200 and json.loads(body)["returned"] == 2000
+    ctx.governor.force_level(1)
+    try:
         status, body, _ = _get(port, "/region/8:1-100000?limit=2000")
-        assert status == 200 and json.loads(body)["returned"] == 2000
-        ctx.governor.force_level(1)
-        try:
-            status, body, _ = _get(port, "/region/8:1-100000?limit=2000")
-            assert status == 200
-            assert json.loads(body)["returned"] \
-                == resilience.BROWNOUT_REGION_LIMIT
-        finally:
-            ctx.governor.force_level(0)
-
-
-def test_brownout_level2_serves_points_cache_first(ladder_servers):
-    for ctx, port in _ports(ladder_servers):
-        # level 0 populates the id-keyed cache (hit and miss both cache)
-        s1, cached_body, _ = _get(port, "/variant/8:1000:A:G")
-        assert s1 == 200
-        s2, _b, _ = _get(port, "/variant/8:999:A:G")
-        assert s2 == 404
-        ctx.governor.force_level(2)
-        real = ctx.engine.lookup_many
-
-        def boom(ids, parsed=None):
-            raise RuntimeError("engine must not be consulted")
-
-        ctx.engine.lookup_many = boom
-        try:
-            # cached id answers without touching the (broken) engine —
-            # byte-identical to the level-0 response
-            status, body, _ = _get(port, "/variant/8:1000:A:G")
-            assert (status, body) == (200, cached_body)
-            status, _body, _ = _get(port, "/variant/8:999:A:G")
-            assert status == 404  # cached absence is absence
-            # an UNcached id still goes to the engine (and fails here)
-            status, _body, _ = _get(port, "/variant/8:1001:C:T")
-            assert status == 500
-        finally:
-            ctx.engine.lookup_many = real
-            ctx.governor.force_level(0)
-
-
-def test_brownout_level3_sheds_bulk_region_keeps_points(ladder_servers):
-    for ctx, port in _ports(ladder_servers):
-        ctx.governor.force_level(3)
-        try:
-            status, body, headers = _get(port, "/region/8:1-100000")
-            assert status == 503 and "brownout" in body
-            assert headers.get("Retry-After") == "1"
-            status, body = _post(
-                port, "/variants",
-                json.dumps({"ids": ["8:1000:A:G"]}).encode(),
-            )
-            assert status == 503 and "brownout" in body
-            # the traffic that matters keeps serving
-            status, _body, _ = _get(port, "/variant/8:1000:A:G")
-            assert status == 200
-            # readiness flips (liveness stays 200); re-pin the level
-            # right before the probes — health polls legitimately step
-            # the ladder, and a slow test run must not race the hold
-            ctx.governor.force_level(3)
-            status, body, _ = _get(port, "/readyz")
-            assert status == 503 and not json.loads(body)["ready"]
-            ctx.governor.force_level(3)
-            status, body, _ = _get(port, "/healthz")
-            assert status == 200
-            h = json.loads(body)
-            assert h["brownout_level"] == 3 and h["ready"] is False
-        finally:
-            ctx.governor.force_level(0)
-        status, _body, _ = _get(port, "/readyz")
         assert status == 200
+        assert json.loads(body)["returned"] \
+            == resilience.BROWNOUT_REGION_LIMIT
+    finally:
+        ctx.governor.force_level(0)
 
 
-def test_health_polls_deescalate_a_fully_drained_worker(ladder_servers):
+def test_brownout_level2_serves_points_cache_first(ladder_server):
+    ctx, port = ladder_server.ctx, ladder_server.server_address[1]
+    # level 0 populates the id-keyed cache (hit and miss both cache)
+    s1, cached_body, _ = _get(port, "/variant/8:1000:A:G")
+    assert s1 == 200
+    s2, _b, _ = _get(port, "/variant/8:999:A:G")
+    assert s2 == 404
+    ctx.governor.force_level(2)
+    real = ctx.engine.lookup_many
+
+    def boom(ids, parsed=None):
+        raise RuntimeError("engine must not be consulted")
+
+    ctx.engine.lookup_many = boom
+    try:
+        # cached id answers without touching the (broken) engine —
+        # byte-identical to the level-0 response
+        status, body, _ = _get(port, "/variant/8:1000:A:G")
+        assert (status, body) == (200, cached_body)
+        status, _body, _ = _get(port, "/variant/8:999:A:G")
+        assert status == 404  # cached absence is absence
+        # an UNcached id still goes to the engine (and fails here)
+        status, _body, _ = _get(port, "/variant/8:1001:C:T")
+        assert status == 500
+    finally:
+        ctx.engine.lookup_many = real
+        ctx.governor.force_level(0)
+
+
+def test_brownout_level3_sheds_bulk_region_keeps_points(ladder_server):
+    ctx, port = ladder_server.ctx, ladder_server.server_address[1]
+    ctx.governor.force_level(3)
+    try:
+        status, body, headers = _get(port, "/region/8:1-100000")
+        assert status == 503 and "brownout" in body
+        assert headers.get("Retry-After") == "1"
+        status, body = _post(
+            port, "/variants",
+            json.dumps({"ids": ["8:1000:A:G"]}).encode(),
+        )
+        assert status == 503 and "brownout" in body
+        # the traffic that matters keeps serving
+        status, _body, _ = _get(port, "/variant/8:1000:A:G")
+        assert status == 200
+        # readiness flips (liveness stays 200); re-pin the level
+        # right before the probes — health polls legitimately step
+        # the ladder, and a slow test run must not race the hold
+        ctx.governor.force_level(3)
+        status, body, _ = _get(port, "/readyz")
+        assert status == 503 and not json.loads(body)["ready"]
+        ctx.governor.force_level(3)
+        status, body, _ = _get(port, "/healthz")
+        assert status == 200
+        h = json.loads(body)
+        assert h["brownout_level"] == 3 and h["ready"] is False
+    finally:
+        ctx.governor.force_level(0)
+    status, _body, _ = _get(port, "/readyz")
+    assert status == 200
+
+
+def test_health_polls_deescalate_a_fully_drained_worker(ladder_server):
     """A shed_bulk worker a router has DRAINED completes no requests —
-    on the threaded front end the router's own readiness probes must be
-    enough for the idle ladder to step back down to ready (the aio front
-    end additionally has its maintenance tick)."""
-    for ctx, port in _ports(ladder_servers):
-        g = ctx.governor
-        old_interval, old_hold = g.eval_interval_s, g.hold_s
-        g.eval_interval_s = 0.0
-        g.hold_s = 0.0
-        g.force_level(3)
-        try:
-            status = None
-            for _ in range(10):  # readiness probes ONLY, no data traffic
-                status, _body, _ = _get(port, "/readyz")
-                if status == 200:
-                    break
-                # a pre-existing eval window (set before the test shrank
-                # the interval) may still be open: pace the probes like a
-                # real router would
-                time.sleep(0.3)
-            assert status == 200
-            assert g.level < 3  # readiness returns as soon as shed_bulk clears
-            # and continued probes unwind the ladder all the way down
-            for _ in range(10):
-                if g.level == 0:
-                    break
-                _get(port, "/readyz")
-                time.sleep(0.15)
-            assert g.level == 0
-        finally:
-            g.eval_interval_s, g.hold_s = old_interval, old_hold
-            g.force_level(0)
+    the router's own readiness probes (and the server's maintenance
+    tick) step the idle ladder back down to ready."""
+    ctx, port = ladder_server.ctx, ladder_server.server_address[1]
+    g = ctx.governor
+    old_interval, old_hold = g.eval_interval_s, g.hold_s
+    g.eval_interval_s = 0.0
+    g.hold_s = 0.0
+    g.force_level(3)
+    try:
+        status = None
+        for _ in range(10):  # readiness probes ONLY, no data traffic
+            status, _body, _ = _get(port, "/readyz")
+            if status == 200:
+                break
+            # a pre-existing eval window (set before the test shrank
+            # the interval) may still be open: pace the probes like a
+            # real router would
+            time.sleep(0.3)
+        assert status == 200
+        assert g.level < 3  # readiness returns as soon as shed_bulk clears
+        # and continued probes unwind the ladder all the way down
+        for _ in range(10):
+            if g.level == 0:
+                break
+            _get(port, "/readyz")
+            time.sleep(0.15)
+        assert g.level == 0
+    finally:
+        g.eval_interval_s, g.hold_s = old_interval, old_hold
+        g.force_level(0)
 
 
-def test_healthz_and_readyz_parity_across_front_ends(ladder_servers):
-    aio, httpd = ladder_servers
-    ap, tp = aio.server_address[1], httpd.server_address[1]
-    for path in ("/healthz", "/readyz"):
-        astatus, abody, _ = _get(ap, path)
-        tstatus, tbody, _ = _get(tp, path)
-        assert (astatus, abody) == (tstatus, tbody), path
+def test_healthz_and_readyz_parity_with_the_builders(ladder_server):
+    """Oracle: ``healthz_payload`` / ``readyz_payload`` called directly
+    on the idle server's context."""
+    from annotatedvdb_tpu.serve.http import healthz_payload, readyz_payload
+
+    ctx, port = ladder_server.ctx, ladder_server.server_address[1]
+    assert _get(port, "/healthz")[:2] == (200, healthz_payload(ctx))
+    assert _get(port, "/readyz")[:2] == readyz_payload(ctx)
+    ctx.governor.force_level(3)
+    try:
+        status, body = readyz_payload(ctx)
+        assert status == 503
+        assert _get(port, "/readyz")[:2] == (status, body)
+    finally:
+        ctx.governor.force_level(0)
 
 
 def test_snapshot_manager_reports_swapping_during_generation_load(
@@ -466,10 +452,9 @@ def test_snapshot_manager_reports_swapping_during_generation_load(
     assert manager.swapping is False
 
 
-def test_readyz_not_ready_during_snapshot_swap(ladder_servers):
-    aio, _httpd = ladder_servers
-    port = aio.server_address[1]
-    manager = aio.ctx.manager
+def test_readyz_not_ready_during_snapshot_swap(ladder_server):
+    port = ladder_server.server_address[1]
+    manager = ladder_server.ctx.manager
     manager.swapping = True  # StaticSnapshots: simulate a loading swap
     try:
         status, body, _ = _get(port, "/readyz")

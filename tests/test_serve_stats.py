@@ -12,7 +12,6 @@ brute-force reference that shares only the decode/summary helpers
 from __future__ import annotations
 
 import json
-import threading
 import time
 import urllib.error
 import urllib.request
@@ -32,6 +31,7 @@ from annotatedvdb_tpu.store import VariantStore
 from annotatedvdb_tpu.store.variant_store import RawJson
 from annotatedvdb_tpu.types import chromosome_label, encode_allele_array
 from annotatedvdb_tpu.utils import faults
+from conftest import start_server, stop_server
 
 WIDTH = 8
 CHROMS = (1, 8, 23)
@@ -339,30 +339,19 @@ def _post(port: int, path: str, payload, headers=None):
 
 
 @pytest.fixture()
-def both_servers(served):
-    from annotatedvdb_tpu.serve.aio import build_aio_server
-    from annotatedvdb_tpu.serve.http import build_server
-
+def server(served):
     store_dir, _truth, _manager, _engine = served
-    httpd = build_server(store_dir=store_dir, port=0)
-    thread = threading.Thread(target=httpd.serve_forever, daemon=True)
-    thread.start()
-    aio = build_aio_server(store_dir=store_dir, port=0)
-    aio.start_background()
+    srv = start_server(store_dir=store_dir)
     try:
-        yield httpd, aio
+        yield srv
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
-        aio.shutdown()
-        aio.ctx.batcher.close()
+        stop_server(srv)
 
 
-def test_http_stats_cross_frontend_byte_parity(both_servers, served):
+def test_http_stats_byte_parity_with_the_engine(server, served):
+    """Oracle: ``engine.stats_serve(...).assemble()`` called directly."""
     _dir, _truth, _manager, engine = served
-    httpd, aio = both_servers
-    tport, aport = httpd.server_address[1], aio.server_address[1]
+    port = server.server_address[1]
     bodies = [
         {"regions": _specs()},
         {"regions": _specs(), "metrics": ["af", "conseq"]},
@@ -370,25 +359,37 @@ def test_http_stats_cross_frontend_byte_parity(both_servers, served):
         {"regions": []},
     ]
     for body in bodies:
-        st1, b1 = _post(tport, "/stats/region", body)
-        st2, b2 = _post(aport, "/stats/region", body)
-        assert (st1, b1) == (st2, b2), body
-        assert st1 == 200
-        # and both match the engine's own rendering
+        status, text = _post(port, "/stats/region", body)
+        assert status == 200
         want = engine.stats_serve(
             body["regions"], metrics=body.get("metrics"),
             windows=body.get("windows"),
         ).assemble()
-        assert b1 == want
-    # kind=stats metrics counted on both front ends
-    for port in (tport, aport):
-        _st, metrics = _get(port, "/metrics")
-        assert 'avdb_query_requests_total{kind="stats"}' in metrics
+        assert text == want, body
+    # kind=stats metrics counted
+    _st, metrics = _get(port, "/metrics")
+    assert 'avdb_query_requests_total{kind="stats"}' in metrics
 
 
-def test_http_stats_grammar_400_parity(both_servers):
-    httpd, aio = both_servers
-    tport, aport = httpd.server_address[1], aio.server_address[1]
+def test_http_stats_grammar_400_parity(server, served):
+    """Oracle: the shared body grammar (``parse_stats_body``) and the
+    engine's own validation called directly — the 400 body is the
+    ``QueryError`` either raises."""
+    from annotatedvdb_tpu.serve.http import STATS_BODY_ERROR, parse_stats_body
+
+    engine = served[3]
+    port = server.server_address[1]
+
+    def refusal(body):
+        try:
+            specs, metrics, windows = parse_stats_body(
+                json.dumps(body).encode())
+            engine.stats_serve(specs, metrics=metrics, windows=windows)
+        except QueryError as err:
+            return str(err)
+        raise AssertionError(f"{body!r} was accepted")
+
+    assert refusal({"regions": "x"}) == STATS_BODY_ERROR
     for body in (
         {"regions": "x"},
         {"regions": [3]},
@@ -399,43 +400,40 @@ def test_http_stats_grammar_400_parity(both_servers):
         {"regions": ["8:1-10"], "windows": 0},
         ["not", "an", "object"],
     ):
-        st1, b1 = _post(tport, "/stats/region", body)
-        st2, b2 = _post(aport, "/stats/region", body)
-        assert st1 == 400 and (st1, b1) == (st2, b2), body
+        status, text = _post(port, "/stats/region", body)
+        assert status == 400, body
+        assert json.loads(text) == {"error": refusal(body)}, body
 
 
-def test_http_stats_brownout_and_deadline_parity(both_servers):
+def test_http_stats_brownout_and_deadline_parity(server):
     from annotatedvdb_tpu.serve.http import (
         MSG_BROWNOUT_STATS,
         MSG_DEADLINE_ADMISSION,
     )
 
-    httpd, aio = both_servers
     body = {"regions": ["8:1-10000"]}
-    for ctx, port in ((httpd.ctx, httpd.server_address[1]),
-                      (aio.ctx, aio.server_address[1])):
-        # a sub-microsecond budget is dead by the admission check: 504
-        status, text = _post(port, "/stats/region", body,
-                             headers={"X-Deadline-Ms": "0.0001"})
-        assert status == 504 and MSG_DEADLINE_ADMISSION in text
-        # brownout level 3 sheds analytics while point reads keep serving
-        ctx.governor.force_level(3)
-        try:
-            status, text = _post(port, "/stats/region", body)
-            assert status == 503 and MSG_BROWNOUT_STATS in text
-        finally:
-            ctx.governor.force_level(0)
-        status, _text = _post(port, "/stats/region", body)
-        assert status == 200
+    ctx, port = server.ctx, server.server_address[1]
+    # a sub-microsecond budget is dead by the admission check: 504
+    status, text = _post(port, "/stats/region", body,
+                         headers={"X-Deadline-Ms": "0.0001"})
+    assert status == 504
+    assert json.loads(text) == {"error": MSG_DEADLINE_ADMISSION}
+    # brownout level 3 sheds analytics while point reads keep serving
+    ctx.governor.force_level(3)
+    try:
+        status, text = _post(port, "/stats/region", body)
+        assert status == 503
+        assert json.loads(text) == {"error": MSG_BROWNOUT_STATS}
+    finally:
+        ctx.governor.force_level(0)
+    status, _text = _post(port, "/stats/region", body)
+    assert status == 200
 
 
 def test_http_stats_cap_is_400(served, monkeypatch):
-    from annotatedvdb_tpu.serve.http import build_server
-
     monkeypatch.setenv("AVDB_SERVE_STATS_MAX", "2")
     store_dir, _truth, _manager, _engine = served
-    httpd = build_server(store_dir=store_dir, port=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    httpd = start_server(store_dir=store_dir)
     try:
         port = httpd.server_address[1]
         status, text = _post(port, "/stats/region",
@@ -444,23 +442,20 @@ def test_http_stats_cap_is_400(served, monkeypatch):
         status, _ = _post(port, "/stats/region", {"regions": ["8:1-10"]})
         assert status == 200
     finally:
-        httpd.shutdown()
-        httpd.server_close()
-        httpd.ctx.batcher.close()
+        stop_server(httpd)
 
 
-def test_http_stats_fault_500_once_then_serves(both_servers):
+def test_http_stats_fault_500_once_then_serves(server):
     """An armed serve.stats fault surfaces as ONE 500 to the one caller;
-    the next request answers normally on the same front end."""
-    httpd, aio = both_servers
+    the next request answers normally."""
     body = {"regions": ["8:1-10000"]}
-    for port in (httpd.server_address[1], aio.server_address[1]):
-        _st, want = _post(port, "/stats/region", body)
-        try:
-            faults.reset("serve.stats:1:raise")
-            status, text = _post(port, "/stats/region", body)
-            assert status == 500 and "InjectedFault" in text
-        finally:
-            faults.reset("")
+    port = server.server_address[1]
+    _st, want = _post(port, "/stats/region", body)
+    try:
+        faults.reset("serve.stats:1:raise")
         status, text = _post(port, "/stats/region", body)
-        assert status == 200 and text == want
+        assert status == 500 and "InjectedFault" in text
+    finally:
+        faults.reset("")
+    status, text = _post(port, "/stats/region", body)
+    assert status == 200 and text == want

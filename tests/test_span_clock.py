@@ -15,7 +15,6 @@ from __future__ import annotations
 import glob
 import json
 import os
-import threading
 import urllib.request
 
 import pytest
@@ -24,11 +23,9 @@ from annotatedvdb_tpu.obs import reqtrace
 from annotatedvdb_tpu.obs.metrics import MetricsRegistry
 from annotatedvdb_tpu.obs.reqtrace import LOOKUP_STAGES, TraceRecorder
 from annotatedvdb_tpu.serve import SnapshotManager
-from annotatedvdb_tpu.serve.aio import build_aio_server
-from annotatedvdb_tpu.serve.batcher import QueryBatcher
 from annotatedvdb_tpu.serve.engine import QueryEngine
-from annotatedvdb_tpu.serve.http import build_server
 from annotatedvdb_tpu.utils import profiling
+from conftest import BatcherOnLoop, start_server, stop_server
 from test_serve import _build_store, _vid
 
 LOAD_STAGES = ("ingest", "dispatch", "annotate", "lookup", "gather",
@@ -247,12 +244,12 @@ def test_each_lookup_histogram_observes_once_per_call(store, path):
         engine.lookup_many(ids, parsed=[parse_variant_id(s) for s in ids])
         calls = 1
     else:
-        batcher = QueryBatcher(engine, max_batch=8, max_wait_s=0.0)
+        batcher = BatcherOnLoop(engine, max_batch=8, max_wait_s=0.0)
         rec = TraceRecorder(sample=1.0)
         try:
             trace = rec.begin("via-batcher", "point")
             assert batcher.submit(ids[0], trace=trace) is not None
-            calls = batcher.drain_stats()["batches"]
+            calls = batcher.batcher.drain_stats()["batches"]
         finally:
             batcher.close()
         assert calls == 1
@@ -292,15 +289,9 @@ def test_render_cache_counts_add_up_to_the_found_ids(store):
     assert "avdb_render_cache_misses_total 50" in text
 
 
-@pytest.mark.parametrize("frontend", ["threaded", "aio"])
-def test_front_end_bulk_request_splits_its_lookup_stage(store, frontend):
+def test_front_end_bulk_request_splits_its_lookup_stage(store):
     store_dir, truth = store
-    if frontend == "threaded":
-        server = build_server(store_dir=store_dir, port=0)
-        threading.Thread(target=server.serve_forever, daemon=True).start()
-    else:
-        server = build_aio_server(store_dir=store_dir, port=0)
-        server.start_background()
+    server = start_server(store_dir=store_dir)
     port, ctx = server.server_address[1], server.ctx
     try:
         def call(path, payload=None, tid=None):
@@ -331,10 +322,7 @@ def test_front_end_bulk_request_splits_its_lookup_stage(store, frontend):
         (device,) = [s for s in rec[6] if s[0] == "device"]
         assert all(device[1] <= s[1] <= s[2] <= device[2] for s in subs)
     finally:
-        server.shutdown()
-        if frontend == "threaded":
-            server.server_close()
-        ctx.batcher.close()
+        stop_server(server)
 
 
 # ---------------------------------------------------------------------------

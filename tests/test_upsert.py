@@ -7,7 +7,6 @@ store segments."""
 
 import json
 import os
-import threading
 import time
 import urllib.error
 import urllib.request
@@ -24,10 +23,9 @@ from annotatedvdb_tpu.serve import (
     SnapshotManager,
     StaticSnapshots,
 )
-from annotatedvdb_tpu.serve.aio import build_aio_server
 from annotatedvdb_tpu.serve.http import (
+    MSG_UPSERTS_DISABLED,
     UPSERT_MAX_ROWS,
-    build_server,
     parse_upsert_body,
 )
 from annotatedvdb_tpu.store import VariantStore
@@ -38,6 +36,7 @@ from annotatedvdb_tpu.store.memtable import (
 )
 from annotatedvdb_tpu.store.wal import WriteAheadLog
 from annotatedvdb_tpu.types import encode_allele_array
+from conftest import bulk_envelope, start_server, stop_server
 
 WIDTH = 8
 
@@ -71,43 +70,25 @@ def _request(port, method, path, body=None, timeout=15):
 
 
 @pytest.fixture()
-def pair(tmp_path):
-    """(threaded port, aio port, store_dir, contexts): both front ends
-    over ONE on-disk store, each with its own memtable + WAL (the fleet
-    shape: per-worker write state, shared read generation)."""
+def live(tmp_path):
+    """A write-enabled server over an on-disk store, with its memtable +
+    WAL: ``{"port", "store_dir", "ctx", "mem", "mgr"}``."""
     store_dir = str(tmp_path / "store")
     _seed_store().save(store_dir)
-    servers = []
-
-    def one(tag, build):
-        registry = MetricsRegistry()
-        mgr = SnapshotManager(store_dir, log=lambda m: None)
-        mem = Memtable(
-            width=WIDTH, store_dir=store_dir,
-            wal=WriteAheadLog(store_dir, f"serve-{tag}",
-                              log=lambda m: None),
-            registry=registry, log=lambda m: None,
-        )
-        return build(manager=MemtableSnapshots(mgr, mem), port=0,
-                     memtable=mem, registry=registry), mem, mgr
-
-    httpd, mem_t, mgr_t = one("t", build_server)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    aio, mem_a, mgr_a = one("a", build_aio_server)
-    aio.start_background()
-    servers = [(httpd, "threaded"), (aio, "aio")]
+    registry = MetricsRegistry()
+    mgr = SnapshotManager(store_dir, log=lambda m: None)
+    mem = Memtable(
+        width=WIDTH, store_dir=store_dir,
+        wal=WriteAheadLog(store_dir, "serve-w0", log=lambda m: None),
+        registry=registry, log=lambda m: None,
+    )
+    server = start_server(manager=MemtableSnapshots(mgr, mem),
+                          memtable=mem, registry=registry)
     yield {
-        "pt": httpd.server_address[1], "pa": aio.server_address[1],
-        "store_dir": store_dir,
-        "ctx_t": httpd.ctx, "ctx_a": aio.ctx,
-        "mem_t": mem_t, "mem_a": mem_a,
-        "mgr_t": mgr_t, "mgr_a": mgr_a,
+        "port": server.server_address[1], "store_dir": store_dir,
+        "ctx": server.ctx, "mem": mem, "mgr": mgr,
     }
-    aio.shutdown()
-    aio.ctx.batcher.close()
-    httpd.shutdown()
-    httpd.ctx.batcher.close()
-    del servers
+    stop_server(server)
 
 
 UPSERT_BODY = {"variants": [
@@ -193,7 +174,7 @@ def test_wal_files_are_per_worker(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# body grammar (single source, shared by both front ends)
+# body grammar (single source)
 
 
 def test_parse_upsert_body_accepts_canonical_shape():
@@ -228,37 +209,43 @@ def test_parse_upsert_body_row_cap():
 
 
 # ---------------------------------------------------------------------------
-# read-your-writes: both front ends, every read path, byte-identical
+# read-your-writes: every read path, byte-identical to the engine
 
 
-def test_upsert_read_your_writes_parity_both_front_ends(pair):
-    pt, pa = pair["pt"], pair["pa"]
-    # ack on both (per-worker memtables: each accepts the new rows)
-    for port in (pt, pa):
-        status, body = _request(port, "POST", "/variants/upsert",
-                                UPSERT_BODY)
-        assert status == 200, body
-        assert json.loads(body) == {
-            "n": 2, "accepted": 2, "shadowed": 0,
-            "generation": json.loads(body)["generation"],
-        }
-    # IMMEDIATE visibility through every read path, byte-identical
-    # across the two front ends
+def test_upsert_read_your_writes_parity_with_the_engine(live):
+    """Oracle: a fresh ``QueryEngine`` over the server's overlay manager
+    called directly (``lookup_many``, ``region``,
+    ``regions_serve(...).assemble()``)."""
+    pt = live["port"]
+    status, body = _request(pt, "POST", "/variants/upsert", UPSERT_BODY)
+    assert status == 200, body
+    assert json.loads(body) == {
+        "n": 2, "accepted": 2, "shadowed": 0,
+        "generation": json.loads(body)["generation"],
+    }
+    # IMMEDIATE visibility through every read path, byte-identical to
+    # the engine's own answers
+    engine = QueryEngine(live["ctx"].manager, region_cache_size=0)
+    ids = ["3:15:A:G", "3:25:AT:A", "3:10:A:C", "3:99:A:C"]
+    records = engine.lookup_many(ids)
+    assert [r is not None for r in records] == [True, True, True, False]
     reads = [
-        ("GET", "/variant/3:15:A:G", None),
-        ("GET", "/variant/3:25:AT:A", None),
-        ("GET", "/variant/3:20:A:C", None),          # loaded row untouched
-        ("POST", "/variants",
-         {"ids": ["3:15:A:G", "3:25:AT:A", "3:10:A:C", "3:99:A:C"]}),
-        ("GET", "/region/3:1-100", None),
-        ("GET", "/region/3:1-100?minCadd=30", None),  # filter sees upsert
-        ("POST", "/regions", {"regions": ["3:1-100", "3:14-16"]}),
+        ("GET", "/variant/3:15:A:G", None, records[0]),
+        ("GET", "/variant/3:25:AT:A", None, records[1]),
+        # loaded row untouched
+        ("GET", "/variant/3:20:A:C", None, engine.lookup("3:20:A:C")),
+        ("POST", "/variants", {"ids": ids}, bulk_envelope(records)),
+        ("GET", "/region/3:1-100", None, engine.region("3:1-100")),
+        # filter sees upsert
+        ("GET", "/region/3:1-100?minCadd=30", None,
+         engine.region("3:1-100", min_cadd=30.0)),
+        ("POST", "/regions", {"regions": ["3:1-100", "3:14-16"]},
+         engine.regions_serve(["3:1-100", "3:14-16"]).assemble()),
     ]
-    for method, path, body in reads:
-        s1, b1 = _request(pt, method, path, body)
-        s2, b2 = _request(pa, method, path, body)
-        assert s1 == s2 == 200, (path, s1, s2, b1, b2)
-        assert b1 == b2, (path, b1, b2)
+    for method, path, body, want in reads:
+        status, got = _request(pt, method, path, body)
+        assert status == 200, (path, status, got)
+        assert got.decode() == want, (path, got, want)
     # and the content is right: the region count grew, the upserted row
     # renders with its annotations, the filter finds the new CADD row
     _s, region = _request(pt, "GET", "/region/3:1-100")
@@ -270,11 +257,11 @@ def test_upsert_read_your_writes_parity_both_front_ends(pair):
     assert json.loads(filtered)["count"] == 1
 
 
-def test_upsert_shadowed_by_loaded_row_first_wins(pair):
+def test_upsert_shadowed_by_loaded_row_first_wins(live):
     """An upsert whose identity the store already holds is SHADOWED: the
     stored row keeps answering byte-identically, the response reports
     the shadow, and the rejected-rows counter moves."""
-    pt = pair["pt"]
+    pt = live["port"]
     _s, before = _request(pt, "GET", "/variant/3:20:A:C")
     status, body = _request(pt, "POST", "/variants/upsert", {"variants": [
         {"id": "3:20:A:C",
@@ -298,12 +285,12 @@ def test_upsert_shadowed_by_loaded_row_first_wins(pair):
     assert b'"rs1"' in rec
 
 
-def test_upsert_visible_through_concurrent_cursor_walk(pair):
+def test_upsert_visible_through_concurrent_cursor_walk(live):
     """A paged region walk started BEFORE an upsert picks the new row up
     on pages rendered after it: cursor offsets re-apply against the new
     generation (the best-effort continuation contract cursors already
     have across loader commits)."""
-    pt = pair["pt"]
+    pt = live["port"]
     s, page1 = _request(pt, "GET", "/region/3:1-100?limit=1&cursor=")
     assert s == 200
     env1 = json.loads(page1)
@@ -329,27 +316,34 @@ def test_upsert_visible_through_concurrent_cursor_walk(pair):
 
 
 def test_upserts_disabled_route_403_parity(tmp_path):
+    """Oracle: the one message constant, ``MSG_UPSERTS_DISABLED``."""
     store_dir = str(tmp_path / "ro")
     _seed_store().save(store_dir)
-    httpd = build_server(store_dir=store_dir, port=0)
-    threading.Thread(target=httpd.serve_forever, daemon=True).start()
-    aio = build_aio_server(store_dir=store_dir, port=0)
-    aio.start_background()
+    server = start_server(store_dir=store_dir)
     try:
-        s1, b1 = _request(httpd.server_address[1], "POST",
-                          "/variants/upsert", UPSERT_BODY)
-        s2, b2 = _request(aio.server_address[1], "POST",
-                          "/variants/upsert", UPSERT_BODY)
-        assert s1 == s2 == 403 and b1 == b2
-        assert b"not enabled" in b1
+        status, body = _request(server.server_address[1], "POST",
+                                "/variants/upsert", UPSERT_BODY)
+        assert status == 403
+        assert json.loads(body) == {"error": MSG_UPSERTS_DISABLED}
+        assert "not enabled" in MSG_UPSERTS_DISABLED
     finally:
-        aio.shutdown()
-        aio.ctx.batcher.close()
-        httpd.shutdown()
-        httpd.ctx.batcher.close()
+        stop_server(server)
 
 
-def test_upsert_grammar_errors_are_parity_400s(pair):
+def test_upsert_grammar_errors_are_parity_400s(live):
+    """Oracle: the shared body grammar (``parse_upsert_body``) and the
+    context's id/width validation called directly — the 400 body is the
+    ``QueryError`` either raises."""
+    ctx = live["ctx"]
+
+    def refusal(body):
+        try:
+            ctx.upsert_parse_entries(
+                parse_upsert_body(json.dumps(body).encode()))
+        except QueryError as err:
+            return str(err)
+        raise AssertionError(f"{body!r} was accepted")
+
     cases = [
         {"nope": 1},
         {"variants": [{"id": "3:15:A:G", "annotations": {"bogus": 1}}]},
@@ -357,19 +351,20 @@ def test_upsert_grammar_errors_are_parity_400s(pair):
         {"variants": [{"id": "3:15:" + "A" * 20 + ":G"}]},  # over-width
     ]
     for body in cases:
-        s1, b1 = _request(pair["pt"], "POST", "/variants/upsert", body)
-        s2, b2 = _request(pair["pa"], "POST", "/variants/upsert", body)
-        assert s1 == s2 == 400, (body, s1, s2)
-        assert b1 == b2, (body, b1, b2)
+        status, got = _request(live["port"], "POST", "/variants/upsert",
+                               body)
+        assert status == 400, (body, status)
+        assert json.loads(got) == {"error": refusal(body)}, body
+    assert live["mem"].rows == 0  # nothing was applied
 
 
 # ---------------------------------------------------------------------------
 # flush: pre/post byte identity, WAL truncation, ledger record
 
 
-def test_flush_preserves_read_bytes_and_truncates_wal(pair):
-    pt, mem, mgr = pair["pt"], pair["mem_t"], pair["mgr_t"]
-    store_dir = pair["store_dir"]
+def test_flush_preserves_read_bytes_and_truncates_wal(live):
+    pt, mem, mgr = live["port"], live["mem"], live["mgr"]
+    store_dir = live["store_dir"]
     status, _b = _request(pt, "POST", "/variants/upsert", UPSERT_BODY)
     assert status == 200
     reads = [
@@ -417,8 +412,8 @@ def test_flush_preserves_read_bytes_and_truncates_wal(pair):
         and flushes[-1]["labels"] == ["3"]
 
 
-def test_generation_strictly_increases_across_upserts_and_flush(pair):
-    pt, mem, mgr = pair["pt"], pair["mem_t"], pair["mgr_t"]
+def test_generation_strictly_increases_across_upserts_and_flush(live):
+    pt, mem, mgr = live["port"], live["mem"], live["mgr"]
     gens = []
 
     def healthz_gen():
@@ -465,10 +460,10 @@ def test_flush_triggers_and_env_knobs(tmp_path, monkeypatch):
         flush_age_from_env()
 
 
-def test_upsert_metrics_move(pair):
-    ctx, mem = pair["ctx_a"], pair["mem_a"]
+def test_upsert_metrics_move(live):
+    ctx, mem = live["ctx"], live["mem"]
     reg: MetricsRegistry = ctx.registry
-    _request(pair["pa"], "POST", "/variants/upsert", {"variants": [
+    _request(live["port"], "POST", "/variants/upsert", {"variants": [
         {"id": "3:60:A:G"},
         {"id": "3:10:A:C"},   # shadowed
     ]})
@@ -482,7 +477,7 @@ def test_upsert_metrics_move(pair):
     kinds = {tuple(sorted(e["labels"].items())): e["value"]
              for e in snap["avdb_query_requests_total"]}
     assert kinds[(("kind", "upsert"),)] == 1
-    assert mem.flush(base_manager=pair["mgr_a"])["status"] == "flushed"
+    assert mem.flush(base_manager=live["mgr"])["status"] == "flushed"
     snap = reg.snapshot()
     assert snap["avdb_upsert_flushes_total"][0]["value"] == 1
     assert snap["avdb_memtable_bytes"][0]["value"] == 0

@@ -1,10 +1,9 @@
 #!/usr/bin/env python
 """Project-native static analysis driver (``annotatedvdb_tpu.analysis``).
 
-Runs the ten AVDB rule families (trace-safety, lock-discipline,
+Runs the nine AVDB rule families (trace-safety, lock-discipline,
 registry-drift, env-var drift, CLI-contract, hygiene, async-safety,
-cross-front-end parity, device/host twin contract, durability protocol)
-over the tree.  See
+device/host twin contract, durability protocol) over the tree.  See
 README "Static analysis & code health" for the rule catalog and the
 suppression policy (``# avdb: noqa[CODE] -- reason``).
 

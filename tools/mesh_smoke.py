@@ -185,13 +185,11 @@ def main() -> int:
             "AVDB_MESH_BULK_MIN": "0",
         })
         procs.append(fleet)
-        import threading
-
-        from annotatedvdb_tpu.serve.http import build_server
+        from annotatedvdb_tpu.serve.aio import build_aio_server
 
         os.environ["AVDB_SERVE_MESH"] = "0"
-        httpd = build_server(store_dir=mesh_dir, port=0)
-        threading.Thread(target=httpd.serve_forever, daemon=True).start()
+        httpd = build_aio_server(store_dir=mesh_dir, port=0)
+        httpd.start_background()
         servers.append(httpd)
         rhost, rport = httpd.server_address[:2]
         wait_ready(fhost, fport)
@@ -286,7 +284,6 @@ def main() -> int:
         for httpd in servers:
             try:
                 httpd.shutdown()
-                httpd.server_close()
                 httpd.ctx.batcher.close()
             except Exception as exc:
                 log(f"reference-server teardown: {exc}")

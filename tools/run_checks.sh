@@ -5,8 +5,7 @@
 #
 #   1. avdb_check  — project-native rules (trace-safety, lock-discipline,
 #                    registry-drift, env-drift, CLI-contract, hygiene,
-#                    async-safety, cross-front-end parity, twin contract,
-#                    durability protocol)
+#                    async-safety, twin contract, durability protocol)
 #   2. ruff        — generic pyflakes-class lint (pyproject.toml subset);
 #                    SKIPPED with a notice when ruff is not installed
 #                    (the container image does not ship it)

@@ -1,9 +1,9 @@
 #!/usr/bin/env python
-"""Serve smoke-check: build a tiny store, stand up BOTH serving front
-ends (the threaded reference server and the asyncio event-loop server)
-on ephemeral loopback ports, and drive one request of every kind through
-each — plus the aio-only surfaces: chunked region streaming, cursor
-paging, and byte-parity between the two front ends.
+"""Serve smoke-check: build a tiny store, stand up the serving front
+end (the asyncio event-loop server) on an ephemeral loopback port, and
+drive one request of every kind through it — plus chunked region
+streaming, cursor paging, and byte-parity of the region and stats bodies
+with the engine called directly.
 
 Part of ``tools/run_checks.sh`` (tier-1 shells that script), so a PR that
 breaks the serving wiring — routes, batcher, snapshot pinning, metrics —
@@ -19,7 +19,6 @@ import json
 import os
 import sys
 import tempfile
-import threading
 import urllib.error
 import urllib.request
 
@@ -83,8 +82,12 @@ def _post(port: int, path: str, payload) -> tuple[int, str]:
         return err.code, err.read().decode()
 
 
+#: the interval panel of the batch-region and stats legs
+SPECS = ["8:1-100000", "8:1000-1400", "8:999000-999999"]
+
+
 def _drive_routes(port: int, n: int, check) -> str:
-    """The shared route battery; returns the region body for parity."""
+    """The route battery; returns the region + stats bodies for parity."""
     status, body = _get(port, "/healthz")
     check("healthz", status == 200
           and json.loads(body)["rows"] == n, body)
@@ -106,7 +109,7 @@ def _drive_routes(port: int, n: int, check) -> str:
     # batch region join: per-interval envelopes must be byte-identical to
     # the single /region bodies (the BITS batch-API contract), plus the
     # count-only and tokenize modes
-    specs = ["8:1-100000", "8:1000-1400", "8:999000-999999"]
+    specs = SPECS
     status, batch = _post(port, "/regions",
                           {"regions": specs, "minCadd": 1, "limit": 5})
     rec = json.loads(batch) if status == 200 else {}
@@ -123,8 +126,8 @@ def _drive_routes(port: int, n: int, check) -> str:
     status, body = _post(port, "/regions", {"regions": ["8:9-3"]})
     check("regions 400", status == 400, body[:200])
     # analytics: the fused stats panel answers summaries (counts, CADD
-    # histogram, windowed scan) and both front ends must render them
-    # byte-identically (the returned blob joins the parity compare)
+    # histogram, windowed scan); the returned blob joins the parity
+    # compare with the engine's own rendering
     status, stats_body = _post(port, "/stats/region",
                                {"regions": specs, "windows": 4})
     rec = json.loads(stats_body) if status == 200 else {}
@@ -141,55 +144,43 @@ def _drive_routes(port: int, n: int, check) -> str:
 
 def main() -> int:
     from annotatedvdb_tpu.serve.aio import build_aio_server
-    from annotatedvdb_tpu.serve.http import build_server
 
     work = tempfile.mkdtemp(prefix="avdb_serve_smoke_")
     store_dir = os.path.join(work, "store")
-    httpd = aio = None
+    aio = None
     failures: list[str] = []
 
     def check(label: str, ok: bool, detail: str = "") -> None:
         if not ok:
             failures.append(f"{label}: {detail}"[:300])
 
-    # everything that can fail to start lives inside the try: an aio
-    # startup timeout must still shut the threaded server down, remove
-    # the temp store, and report through the FAIL path — not a traceback
+    # everything that can fail to start lives inside the try: a startup
+    # timeout must still remove the temp store and report through the
+    # FAIL path — not a traceback
     try:
         n = _build_store(store_dir)
-        httpd = build_server(store_dir=store_dir, port=0)
-        thread = threading.Thread(target=httpd.serve_forever, daemon=True)
-        thread.start()
         aio = build_aio_server(
             store_dir=store_dir, port=0, stream_threshold=4
         )
         aio.start_background()
-        port = httpd.server_address[1]
-        threaded_region = _drive_routes(port, n, check)
-
         aport = aio.server_address[1]
-        aio_region = _drive_routes(
-            aport, n, lambda label, ok, detail="":
-            check(f"aio {label}", ok, detail)
-        )
-        check("aio parity", aio_region == threaded_region,
-              "region/stats bodies differ between front ends")
-        # aio-only surfaces: chunked streaming (threshold 4 forces it)
-        # and cursor paging
+        served = _drive_routes(aport, n, check)
+        engine = aio.ctx.engine
+        check("engine parity", served == (
+            engine.region("8:1-100000", min_cadd=1.0, limit=5)
+            + engine.stats_serve(SPECS, windows=4).assemble()
+        ), "region/stats bodies differ from the engine's own rendering")
+        # chunked streaming (threshold 4 forces it) and cursor paging
         status, body = _get(aport, "/region/8:1-100000?limit=20")
         rec = json.loads(body) if status == 200 else {}
-        check("aio stream", status == 200 and rec.get("returned") == 20,
+        check("stream", status == 200 and rec.get("returned") == 20,
               body[:200])
         status, body = _get(aport, "/region/8:1-100000?limit=5&cursor=")
         rec = json.loads(body) if status == 200 else {}
-        check("aio page", status == 200 and rec.get("next"), body[:200])
+        check("page", status == 200 and rec.get("next"), body[:200])
     except Exception as exc:
         check("startup", False, repr(exc))
     finally:
-        if httpd is not None:
-            httpd.shutdown()
-            httpd.server_close()
-            httpd.ctx.batcher.close()
         if aio is not None:
             aio.shutdown()
             aio.ctx.batcher.close()
@@ -220,7 +211,7 @@ def main() -> int:
         for f in failures:
             print(f"serve_smoke FAIL {f}", file=sys.stderr)
         return 1
-    print(f"serve_smoke: ok ({n} rows; threaded + aio front ends, "
+    print(f"serve_smoke: ok ({n} rows; the asyncio front end, "
           "streaming, paging and stats answered)", file=sys.stderr)
     return 0
 
