@@ -40,7 +40,7 @@ from annotatedvdb_tpu.models.pipeline import annotate_fn
 from annotatedvdb_tpu.ops.hashing import allele_hash_jit
 from annotatedvdb_tpu.ops.vrs import VrsDigestGenerator
 from annotatedvdb_tpu.store import AlgorithmLedger, VariantStore
-from annotatedvdb_tpu.store.variant_store import Segment
+from annotatedvdb_tpu.store.variant_store import Segment, SparseValues
 from annotatedvdb_tpu.utils.profiling import bulk_load_gc
 
 class _LoadCtx(NamedTuple):
@@ -1381,17 +1381,24 @@ class TpuVcfLoader:
                     j = slice(offset, offset + k)
                     jj = np.arange(offset, offset + k)
                     code = int(batch.chrom[rows[0]])
-                    # reader-flagged FREQ rows only: a FREQ-less slice (the
-                    # common case) skips the per-row lazy column entirely
-                    if (chunk.has_freq is None
-                            or bool(chunk.has_freq[rows].any())):
+                    # a reader that flags its FREQ rows is asked for those
+                    # rows' values only (a FREQ-less slice, the common case,
+                    # for none); any other chunk for a per-row list
+                    if chunk.has_freq is None:
                         annotations = {
                             "allele_frequencies": [
                                 chunk.frequencies[i] for i in rows
                             ],
                         }
                     else:
-                        annotations = {}
+                        freq = _at_rows(
+                            chunk.has_freq[rows],
+                            lambda jx: chunk.frequencies[int(rows[jx])],
+                        )
+                        annotations = (
+                            {} if freq is None
+                            else {"allele_frequencies": freq}
+                        )
                     if display is not None:
                         annotations["display_attributes"] = (
                             display[offset:offset + k]
@@ -1415,20 +1422,17 @@ class TpuVcfLoader:
                         sub.ref[j],
                         sub.alt[j],
                         annotations=annotations,
-                        # per-row comprehensions only when the rare tails
-                        # are present (digest PKs / width-truncated alleles)
-                        digest_pk=(
-                            [pks[jx] if needs_digest[jx] else None
-                             for jx in jj]
-                            if needs_digest[j].any() else None
+                        # the rare tails (digest PKs / width-truncated
+                        # alleles) know their rows
+                        digest_pk=_at_rows(
+                            needs_digest[j], lambda jx: pks[offset + jx]
                         ),
                         # retain original strings for width-truncated rows:
                         # the device arrays can't reconstruct them and later
                         # joins (CADD) and VCF export need the exact alleles
-                        long_alleles=(
-                            [(refs[jx], alts[jx]) if over[jx] else None
-                             for jx in jj]
-                            if over[j].any() else None
+                        long_alleles=_at_rows(
+                            over[j],
+                            lambda jx: (refs[offset + jx], alts[offset + jx]),
                         ),
                     )
                     payload.append((code, seg))
@@ -1483,6 +1487,15 @@ class TpuVcfLoader:
                         )
                 mapping_fh.write("\n".join(lines) + "\n")
         return payload
+
+
+def _at_rows(flags: np.ndarray, value_at) -> SparseValues | None:
+    """``value_at(row)`` for the flagged rows of a slice, as the sparse
+    column :meth:`Segment.build` takes; None when no row is flagged."""
+    at = np.flatnonzero(flags)
+    if not at.size:
+        return None
+    return SparseValues(at, [value_at(jx) for jx in at.tolist()])
 
 
 def _fnv32_str(ref: str, alt: str) -> np.uint32:

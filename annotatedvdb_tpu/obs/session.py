@@ -52,18 +52,23 @@ def config_hash(params: dict) -> str:
     return hashlib.sha256(blob.encode()).hexdigest()[:12]
 
 
-def execution_facts(probe_base: dict | None = None) -> dict:
+def execution_facts(probe_base: dict | None = None,
+                    sidecar_base: dict | None = None) -> dict:
     """Where and how this process ran its load: the device as JAX reports
     it, the annotate kernel that was selected, the transport verdicts, the
-    native tokenizer's state and the device membership probes since
-    ``probe_base`` — the run record's ``execution`` block, so a reader can
-    tell a chip run from a CPU run, see which device paths a load
-    reached, and take compile seconds apart from the load itself.
+    native tokenizer's state, the device membership probes since
+    ``probe_base`` and the sidecar writer's rows since ``sidecar_base`` —
+    the run record's ``execution`` block, so a reader can tell a chip run
+    from a CPU run, see which device paths a load reached, and take
+    compile seconds apart from the load itself.
     Reports only: nothing here selects, probes or builds."""
     from annotatedvdb_tpu import native
     from annotatedvdb_tpu.models.pipeline import selected_kernel
     from annotatedvdb_tpu.ops.pack import transport_state
-    from annotatedvdb_tpu.store.variant_store import device_lookup_state
+    from annotatedvdb_tpu.store.variant_store import (
+        device_lookup_state,
+        sidecar_state,
+    )
     from annotatedvdb_tpu.utils.profiling import STARTUP_SECONDS
     from annotatedvdb_tpu.utils.runtime import compile_summary, device_summary
 
@@ -81,6 +86,9 @@ def execution_facts(probe_base: dict | None = None) -> dict:
         "pack_transport": transport_state(),
         "native_ingest": native.status(),
         "device_lookup": device_lookup_state(probe_base),
+        # rows of the segments this load wrote, rows their sidecar walk
+        # looked at, lines it wrote (store.variant_store.sidecar_lines)
+        "sidecar": sidecar_state(sidecar_base),
     }
 
 
@@ -217,9 +225,13 @@ class ObsSession:
 
         self._faults_base = _faults.fired()
         self._retry_base = dict(_retry.stats)
-        from annotatedvdb_tpu.store.variant_store import probe_stats
+        from annotatedvdb_tpu.store.variant_store import (
+            probe_stats,
+            sidecar_stats,
+        )
 
         self._probe_base = dict(probe_stats)
+        self._sidecar_base = dict(sidecar_stats)
 
     @classmethod
     def from_args(cls, script: str, args, params: dict) -> "ObsSession":
@@ -315,8 +327,10 @@ class ObsSession:
                     wall, stages=stages, queue_stalls=stalls, error=error,
                     # an aborted load may have died OF the backend: its
                     # record must still land, without the block
-                    execution=(execution_facts(self._probe_base)
-                               if error is None else None),
+                    execution=(
+                        execution_facts(self._probe_base, self._sidecar_base)
+                        if error is None else None
+                    ),
                 ))
         except Exception as err:
             print(f"obs: run-ledger append failed ({err})", file=sys.stderr)

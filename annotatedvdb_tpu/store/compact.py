@@ -80,7 +80,7 @@ from annotatedvdb_tpu.store.variant_store import (
     VariantStore,
     _fsync_wanted,
     _verify_mode,
-    sidecar_line,
+    sidecar_lines,
 )
 from annotatedvdb_tpu.utils import faults
 from annotatedvdb_tpu.utils import io as tio
@@ -540,17 +540,13 @@ def _merge_label_to_temp(store_dir: str, label: str, glist: list,
                 if _cancelled(cancel):
                     raise _Preempted("cancelled mid-merge")
                 idx = kept[lo:lo + chunk]
-                cols = {col: _gather_obj(parts, starts, idx, col)
-                        for col in present}
-                out: list[str] = []
-                for k in range(idx.size):
-                    # the ONE sidecar serializer save() also uses — byte
-                    # parity between saved and compacted sidecars
-                    line = sidecar_line(
-                        ((c, cols[c][k]) for c in present), lo + k
-                    )
-                    if line is not None:
-                        out.append(line)
+                # the ONE row walk save() also uses — byte parity between
+                # saved and compacted sidecars
+                out = list(sidecar_lines(
+                    ((c, _gather_obj(parts, starts, idx, c))
+                     for c in present),
+                    int(idx.size), lo,
+                ))
                 if out:
                     f.write(comp.compress("".join(out).encode()))
             f.write(comp.flush())

@@ -44,6 +44,7 @@ from annotatedvdb_tpu.store.variant_store import (
     ChromosomeShard,
     Segment,
     VariantStore,
+    holds_value,
 )
 from annotatedvdb_tpu.store.wal import WriteAheadLog
 from annotatedvdb_tpu.types import chromosome_label
@@ -384,9 +385,8 @@ class Memtable:
         for col, arr in seg.obj.items():
             if arr is None:
                 continue
-            for v in arr:
-                if v is not None:
-                    total += len(json.dumps(v))
+            for v in arr[holds_value(arr)]:
+                total += len(json.dumps(v))
         return total
 
     def replay(self, base_store) -> int:

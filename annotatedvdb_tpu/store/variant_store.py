@@ -36,10 +36,12 @@ flat ``cols``/``ref``/``alt``/``annotations`` views are available.
 
 from __future__ import annotations
 
+import itertools
 import json
+import operator
 import os
 import zlib
-from typing import Iterable
+from typing import Iterable, NamedTuple
 
 import numpy as np
 
@@ -218,6 +220,10 @@ def crc32_file(path: str) -> int:
     return crc
 
 
+#: sidecar lines joined into one write by ``_write_segment``
+_SIDECAR_BLOCK = 4096
+
+
 class _CrcWriter:
     """File-object wrapper accumulating crc32 + byte count over every write
     — the integrity record is computed on the bytes ALREADY IN HAND on the
@@ -300,6 +306,21 @@ _DEVICE_PROBE_FAILURE_HOOK = None
 #: the same lookup was not yet collected (:func:`count_overlapped`)
 probe_stats = {"device_probes": 0, "device_queries": 0, "padded_queries": 0,
                "transfers": 0, "overlapped_probes": 0}
+
+
+#: cumulative work of the annotation-sidecar writer in this process (the
+#: ``probe_stats`` pattern; a load's run record reports its session's
+#: delta as ``execution.sidecar``): ``rows`` of the segments (or gathered
+#: chunks) handed to :func:`sidecar_lines`, ``visited`` the rows it looked
+#: at — those that hold a value — and ``lines`` the lines it gave; all
+#: three added once a call, never a row
+sidecar_stats = {"rows": 0, "visited": 0, "lines": 0}
+
+
+def sidecar_state(base: dict | None = None) -> dict:
+    """:data:`sidecar_stats` relative to ``base`` (an earlier copy)."""
+    base = base or {}
+    return {k: v - base.get(k, 0) for k, v in sidecar_stats.items()}
 
 
 def probe_query_capacity(nq: int) -> int:
@@ -476,6 +497,51 @@ def sidecar_line(named_values, i: int) -> str | None:
     return "{" + ",".join(parts) + "}\n"
 
 
+def holds_value(col: np.ndarray) -> np.ndarray:
+    """[n] bool: which rows of an object column hold a value.  One C-level
+    identity pass (``is not None``), no Python frame a row — and never
+    ``!=``, which would ask ``RawJson.__eq__`` and parse every value."""
+    return np.fromiter(
+        map(operator.is_not, col, itertools.repeat(None)),
+        np.bool_, col.shape[0],
+    )
+
+
+def sidecar_lines(named_cols, n: int, base: int = 0):
+    """The annotation-sidecar lines of ``n`` rows — a segment, or a chunk
+    of gathered rows whose first is row ``base`` of its file — in row
+    order, each through :func:`sidecar_line`.  ``named_cols`` is (column,
+    [n] object array or None) pairs.  Only rows that hold a value in some
+    column are visited (the file is sparse, so the walk is too): a column
+    of a value a row costs what a walk of every row would, a column with
+    one row in ten a tenth.  The ONE row walk shared by ``save()``'s
+    segment writer and the compactor."""
+    present = [(c, col) for c, col in named_cols if col is not None]
+    sidecar_stats["rows"] += n
+    if not present or not n:
+        return
+    held = holds_value(present[0][1])
+    for _, col in present[1:]:
+        held |= holds_value(col)
+    rows = np.flatnonzero(held)
+    sidecar_stats["visited"] += int(rows.size)
+    names = [c for c, _ in present]
+    values = [col[rows].tolist() for _, col in present]
+    for i, *vs in zip(rows.tolist(), *values):
+        yield sidecar_line(zip(names, vs), base + i)  # a value: never None
+    sidecar_stats["lines"] += int(rows.size)
+
+
+class SparseValues(NamedTuple):
+    """An object column given only where it holds a value — the form
+    :meth:`Segment.build` takes beside a per-row list: ``rows`` are
+    ascending positions in the build's input order, ``values`` one value
+    each (a None among them is one more row without a value)."""
+
+    rows: np.ndarray
+    values: list
+
+
 class Segment:
     """One sorted run of rows: numeric columns + packed alleles + object cols.
 
@@ -541,7 +607,11 @@ class Segment:
 
         Already-sorted input (the insert loader pre-sorts each flush by
         identity key) skips the argsort AND the per-column gather — the
-        arrays are owned as-is, so build is O(n) dtype checks."""
+        arrays are owned as-is, so build is O(n) dtype checks.
+
+        Each object column (``annotations[c]``, ``digest_pk``,
+        ``long_alleles``) is a per-row list or, from a caller that knows
+        which rows hold a value, a :class:`SparseValues`."""
         k = rows["pos"].shape[0]
         cols = {}
         for name, dtype in _NUMERIC_COLUMNS:
@@ -893,17 +963,34 @@ class Segment:
 
 
 def _obj_array(values, order: np.ndarray | None, n: int) -> np.ndarray | None:
-    """Object column from per-row values; None when the column is all-None
-    (lazily-materialized columns keep annotation-free segments free).
-    ``order=None`` means the rows are already in sorted order."""
-    if values is None or all(v is None for v in values):
+    """Object column from a per-row list or :class:`SparseValues`; None
+    when no row holds a value (lazily-materialized columns keep
+    annotation-free segments free).  ``order=None`` means the rows are
+    already in sorted order.  No Python frame a row in either form, and
+    values are never handed to numpy as a LIST: its shape sniffing asks
+    each element's ``__len__``, and ``RawJson.__len__`` parses."""
+    if values is None:
         return None
-    out = np.empty((n,), object)
-    if order is None:
-        out[:] = list(values) if not isinstance(values, np.ndarray) else values
+    if isinstance(values, SparseValues):
+        rows = np.asarray(values.rows, np.intp)
+        vals = np.fromiter(values.values, object, rows.size)
     else:
-        for j, i in enumerate(order):
-            out[j] = values[i]
+        rows = None
+        vals = (values.astype(object) if isinstance(values, np.ndarray)
+                else np.fromiter(values, object, n))
+    held = holds_value(vals)
+    if not held.any():
+        return None
+    if rows is None:  # per-row form: already the dense column
+        return vals if order is None else vals[order]
+    if not held.all():
+        rows, vals = rows[held], vals[held]
+    if order is not None:  # input position -> sorted position
+        inverse = np.empty((n,), np.intp)
+        inverse[order] = np.arange(n)
+        rows = inverse[rows]
+    out = np.full((n,), None, object)
+    out[rows] = vals
     return out
 
 
@@ -1812,14 +1899,13 @@ class VariantStore:
         atmp = os.path.join(path, f".{stem}.tmp{os.getpid()}.ann.jsonl")
         with tio.open(atmp, "wb") as raw_f:
             f = _CrcWriter(raw_f)
-            present = [(c, seg.obj[c]) for c in OBJECT_COLUMNS
-                       if seg.obj[c] is not None]
-            for i in range(seg.n) if present else ():
-                line = sidecar_line(
-                    ((c, col[i]) for c, col in present), i
-                )
-                if line is not None:
-                    f.write(line.encode())
+            # lines go out in blocks: one encode, crc32 and write a block
+            # (the same bytes in the same order as one a line)
+            lines = sidecar_lines(
+                ((c, seg.obj[c]) for c in OBJECT_COLUMNS), seg.n
+            )
+            while block := list(itertools.islice(lines, _SIDECAR_BLOCK)):
+                f.write("".join(block).encode())
             if fsync_data:
                 f.flush()
                 tio.fsync(raw_f)

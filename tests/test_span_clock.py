@@ -119,9 +119,11 @@ def test_profile_capture_has_waits_startup_and_no_python_tracer(
     assert any(a.get("items", 0) > 0
                for _s, _d, a in events["avdb.load.build"])
     # the Python tracer is off: a Python-heavy load would be millions of
-    # call events (named "$file:line function")
+    # call events (named "$file:line function"); what the runtime itself
+    # records grows with the devices it drives (51,403 events on the
+    # suite's 8 CPU devices), so the limit sits an order above that
     assert not [n for n in events if n.startswith("$")]
-    assert sum(len(v) for v in events.values()) < 50_000
+    assert sum(len(v) for v in events.values()) < 500_000
     # start-up phases: recorded seconds, cumulative for the process
     startup = record["execution"]["startup"]
     assert "programs" in startup and startup["programs"] > 0
