@@ -96,6 +96,17 @@ STAGE_SECONDS_EDGES = (
 LOOKUP_STAGES = ("lookup.parse", "lookup.hash", "lookup.probe",
                  "lookup.rows")
 
+#: a ``POST /regions`` panel, split where the work happens: the body to
+#: parsed specs and their chromosome groups (``serve.aio`` and
+#: ``serve.engine.regions_serve``); each group's span search (index fetch,
+#: pad, upload, program, fetch); locating every interval's rows and
+#: building its page; rendering the rows — on whichever thread renders,
+#: the executor's for a buffered body, the event loop's for a streamed
+#: one.  Each observed by the ENGINE once a panel, summed over its groups
+#: and chunks (the ``LOOKUP_STAGES`` discipline)
+REGION_STAGES = ("regions.parse", "regions.spans", "regions.rows",
+                 "regions.render")
+
 
 def stage_histograms(registry, stages) -> dict:
     """{stage: its ``avdb_stage_seconds`` series} on ``registry``."""

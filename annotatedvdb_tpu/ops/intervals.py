@@ -33,12 +33,17 @@ position-sorted, first-wins-deduplicated view per chromosome group per
 store generation — so spans ARE post-dedup row ranges and a span width is
 the exact region count.
 
-Shapes are padded to powers of two (``interval_spans``) so repeated panel
-queries of drifting sizes reuse one traced program; the numpy twin
-(``interval_spans_host``) is byte-identical by construction (both sides
-run the same textbook binary search over the same int32 values) and is
-the path the serving circuit breaker — or an explicit ``host_only`` — can
-always take.
+Shapes are padded (``interval_spans``): rows to a power of two, queries to
+:func:`span_query_capacity` — the ONE place the span program's query
+shapes are decided (a power of two, never under :data:`SPAN_QUERY_FLOOR`).
+A group of ``nq`` intervals runs the program of that capacity and no
+other, so the programs a server can be asked for are
+:func:`span_query_shapes` of its knobs — eight at the defaults — and
+:func:`warm_spans` runs each once against an uploaded index before a
+request can.  The numpy twin (``interval_spans_host``) is byte-identical
+by construction (both sides run the same textbook binary search over the
+same int32 values) and is the path the serving circuit breaker — or an
+explicit ``host_only`` — can always take.
 """
 
 from __future__ import annotations
@@ -48,13 +53,49 @@ import jax.numpy as jnp
 import numpy as np
 
 from annotatedvdb_tpu.ops.binindex import LEAF_SIZE, NUM_BIN_LEVELS
-from annotatedvdb_tpu.utils.arrays import POS_SENTINEL, pad_pow2
+from annotatedvdb_tpu.utils.arrays import (
+    POS_SENTINEL,
+    next_pow2,
+    pad_pow2,
+    pad_rows,
+)
 
 #: query coordinates are clamped below the position sentinel before either
 #: search path: store positions are int32 (< POS_SENTINEL by construction),
 #: so the clamp never changes an answer, and the device kernel's int32
 #: casts can never wrap on an absurd-but-grammatical query bound
 MAX_QUERY_POS = int(POS_SENTINEL) - 16
+
+
+#: the smallest query shape the span program runs at: the default
+#: ``AVDB_SERVE_REGIONS_DEVICE_MIN`` (smaller groups take the host twin),
+#: so a lowered minimum adds no program
+SPAN_QUERY_FLOOR = 32
+
+#: host<->device array transfers of one :func:`interval_spans` call on a
+#: device-resident index: ``starts`` and ``ends`` up, ``lo``, ``hi``,
+#: ``level`` and ``leaf`` back
+SPAN_TRANSFERS = 6
+
+
+def span_query_capacity(nq: int) -> int:
+    """The query shape a span search of ``nq`` intervals runs at: the next
+    power of two, never under :data:`SPAN_QUERY_FLOOR`.  The ONE place the
+    shapes of ``bits_spans_kernel`` programs are decided (the
+    ``store.variant_store.probe_query_capacity`` pattern)."""
+    return max(next_pow2(nq), SPAN_QUERY_FLOOR)
+
+
+def span_query_shapes(nq_min: int, nq_max: int) -> list[int]:
+    """Every query shape a group of ``nq_min``..``nq_max`` intervals can
+    take, ascending (empty when ``nq_min`` > ``nq_max``: no group reaches
+    the device)."""
+    if nq_min > nq_max:
+        return []
+    shapes = [span_query_capacity(max(nq_min, 1))]
+    while shapes[-1] < span_query_capacity(nq_max):
+        shapes.append(shapes[-1] * 2)
+    return shapes
 
 
 @jax.named_scope("avdb.bits_spans")
@@ -165,9 +206,10 @@ def clamped_queries(starts, ends):
 
 
 def interval_spans(pos, starts, ends, *, pos_padded: bool = False):
-    """Device entry point: pad to pow2 capacities (rows with the position
-    sentinel, queries with zeros), run the jitted kernel once, slice the
-    padding back off.  Returns numpy ``(lo, hi, level, leaf)``.
+    """Device entry point: pad to capacities (rows to a power of two with
+    the position sentinel, queries to :func:`span_query_capacity` with
+    zeros), run the jitted kernel once, slice the padding back off.
+    Returns numpy ``(lo, hi, level, leaf)``.
 
     ``pos_padded=True`` marks ``pos`` as already sentinel-padded (e.g. a
     device-resident array uploaded once per index) and skips the host-side
@@ -180,13 +222,25 @@ def interval_spans(pos, starts, ends, *, pos_padded: bool = False):
     nq = starts.shape[0]
     pos_p = pos if pos_padded \
         else pad_pow2(np.asarray(pos, np.int32), POS_SENTINEL)
+    cap = span_query_capacity(nq)
     lo, hi, level, leaf = bits_spans_kernel_jit(
-        pos_p, pad_pow2(starts, 0), pad_pow2(ends, 0)
+        pos_p, pad_rows(starts, cap, 0), pad_rows(ends, cap, 0)
     )
     return (
         np.asarray(lo)[:nq], np.asarray(hi)[:nq],
         np.asarray(level)[:nq], np.asarray(leaf)[:nq],
     )
+
+
+def warm_spans(pos_padded, nq_min: int, nq_max: int) -> None:
+    """Run the span program once at every query shape a group of
+    ``nq_min``..``nq_max`` intervals can take (:func:`span_query_shapes`),
+    on all-zero queries, against the sentinel-padded device array
+    ``pos_padded`` — so the programs a panel can need are compiled and
+    loaded before traffic asks for them."""
+    for cap in span_query_shapes(nq_min, nq_max):
+        zeros = np.zeros(cap, np.int32)
+        jax.block_until_ready(bits_spans_kernel_jit(pos_padded, zeros, zeros))
 
 
 def interval_spans_host(pos: np.ndarray, starts, ends):

@@ -1678,9 +1678,11 @@ class AioServer:
                 return _error(504, MSG_DEADLINE_EXECUTE)
             if trace is not None:
                 trace.since("admission", t0)
+            clock = ctx.engine.panel_clock()
             try:
-                specs, min_cadd, max_rank, limit, tokenize = \
-                    parse_regions_body(body)
+                with clock.span("regions.parse"):
+                    specs, min_cadd, max_rank, limit, tokenize = \
+                        parse_regions_body(body)
             except QueryError as err:
                 ctx.errored("regions")
                 return _error(400, str(err))
@@ -1712,6 +1714,7 @@ class AioServer:
                         max_conseq_rank=max_rank,
                         limit=limit,
                         tokenize=tokenize,
+                        clock=clock,
                     )
             except QueryError as err:
                 ctx.errored("regions")
@@ -1723,7 +1726,10 @@ class AioServer:
                 stream_holds_slot = True
                 return ("stream", result)  # the writer releases that slot
             with reqtrace_mod.stage(trace, "render"):
-                resp = _resp(200, result.assemble())
+                with clock.span("regions.render"):
+                    text = result.assemble()
+                ctx.engine.regions_rendered(clock, streamed=False)
+                resp = _resp(200, text)
                 ctx.observe("regions", time.perf_counter() - t0,
                             rows=result.returned)
             return resp
@@ -1993,36 +1999,37 @@ class AioServer:
             + b"Transfer-Encoding: chunked\r\n\r\n"
         )
         _write_chunk(writer, page.prefix().encode())
-        buf: list[str] = []
-        buf_bytes = 0
+        # a panel's rows render inside ``avdb.regions.render``, one span a
+        # chunk, on this (the event loop's) thread — never across the
+        # ``await`` between chunks, where other connections run
+        clock = getattr(page, "clock", None)
+        chunks = _row_chunks(page.rows())
         first = True
         truncated = cancelled = False
         try:
-            for row in page.rows():
+            while True:
                 if self._stop is not None and self._stop.is_set():
                     # graceful drain: finish THIS response as truncated
                     # within the budget instead of racing the cancel
                     truncated = True
                     break
-                buf.append(("" if first else ",") + row)
-                buf_bytes += len(buf[-1])
+                with (clock.span("regions.render") if clock is not None
+                      else contextlib.nullcontext()):
+                    chunk = next(chunks, None)  # renders one chunk's rows
+                if chunk is None:
+                    break
+                _write_chunk(
+                    writer, (("" if first else ",") + chunk).encode()
+                )
                 first = False
-                # flush on a byte bound too: a RegionsResult "row" is a
-                # whole per-interval envelope, and 256 of those must not
-                # accumulate panel-sized RSS before the first write
-                if len(buf) >= _STREAM_ROWS_PER_CHUNK \
-                        or buf_bytes >= _WRITE_HIGH_WATER:
-                    _write_chunk(writer, "".join(buf).encode())
-                    buf.clear()
-                    buf_bytes = 0
-                    await writer.drain()  # flow control + loop fairness
+                await writer.drain()  # flow control + loop fairness
         except asyncio.CancelledError:
             # the drain budget expired with this stream still writing:
             # terminate the framing before the cancellation propagates
             # (the writes below are synchronous buffer appends)
             truncated = cancelled = True
-        if buf:
-            _write_chunk(writer, "".join(buf).encode())
+        if clock is not None:  # a panel: its render, observed once
+            self.ctx.engine.regions_rendered(clock, streamed=True)
         if truncated:
             _write_chunk(writer, b'],"truncated":true}')
         else:
@@ -2031,6 +2038,24 @@ class AioServer:
         if cancelled:
             raise asyncio.CancelledError
         await writer.drain()
+
+
+def _row_chunks(rows):
+    """``rows`` (rendered lazily) joined into chunk-sized texts: at most
+    ``_STREAM_ROWS_PER_CHUNK`` rows, and cut on a byte bound too — a
+    RegionsResult "row" is a whole per-interval envelope, and 256 of those
+    must not accumulate panel-sized RSS before the first write.  Each
+    ``next`` renders one chunk's rows."""
+    buf: list[str] = []
+    size = 0
+    for row in rows:
+        buf.append(row)
+        size += len(row)
+        if len(buf) >= _STREAM_ROWS_PER_CHUNK or size >= _WRITE_HIGH_WATER:
+            yield ",".join(buf)
+            buf, size = [], 0
+    if buf:
+        yield ",".join(buf)
 
 
 def _write_chunk(writer, data: bytes) -> None:

@@ -541,15 +541,21 @@ class IntervalIndex:
 
     ``device_pos()`` lazily uploads the sentinel-padded position array
     once per index, so a panel's kernel calls re-use the resident copy
-    instead of re-shipping the index per request."""
+    instead of re-shipping the index per request.  An index the residency
+    manager's uploader prepared (:meth:`QueryEngine.warm_region_index`)
+    has its copy installed already, after every span program ran against
+    it once (``warmed``)."""
 
-    __slots__ = ("pos", "si", "jj", "_dev_pos")
+    __slots__ = ("pos", "si", "jj", "_dev_pos", "warmed")
 
     def __init__(self, pos, si, jj):
         self.pos = pos  # [K] int32, sorted
         self.si = si    # [K] int32 segment index per kept row
         self.jj = jj    # [K] int64 local row per kept row
         self._dev_pos = None
+        #: every span program a panel can ask for has run against the
+        #: installed device copy
+        self.warmed = False
 
     @property
     def n(self) -> int:
@@ -609,18 +615,21 @@ class IntervalIndex:
                    np.ascontiguousarray(si_o[keep]),
                    np.ascontiguousarray(jj_o[keep]))
 
+    def upload(self):
+        """A fresh sentinel-padded device copy of the position array, not
+        installed (a failure propagates to the caller)."""
+        import jax
+
+        from annotatedvdb_tpu.utils.arrays import POS_SENTINEL, pad_pow2
+
+        return jax.device_put(pad_pow2(self.pos, POS_SENTINEL))
+
     def device_pos(self):
         """The sentinel-padded position array on device (uploaded once;
         a failure propagates to the caller, which falls back host-side
         and feeds the circuit breaker)."""
         if self._dev_pos is None:
-            import jax
-
-            from annotatedvdb_tpu.utils.arrays import POS_SENTINEL, pad_pow2
-
-            self._dev_pos = jax.device_put(
-                pad_pow2(self.pos, POS_SENTINEL)
-            )
+            self._dev_pos = self.upload()
         return self._dev_pos
 
     def device_bytes(self) -> int:
@@ -638,6 +647,7 @@ class IntervalIndex:
         re-uploads cleanly (host arrays stay; correctness is
         unaffected)."""
         self._dev_pos = None
+        self.warmed = False
 
 
 class StatsColumns:
@@ -784,13 +794,17 @@ class RegionsResult:
     "results": [...]}``.  Same prefix/rows/suffix surface as
     :class:`RegionPage`, so the streaming writer handles both shapes —
     ``rows()`` yields one assembled per-interval envelope at a time (RSS
-    holds one interval's body, not the panel's)."""
+    holds one interval's body, not the panel's).  ``clock`` is the
+    panel's :class:`_PanelClock`: whoever renders the body times it there
+    (``regions.render``) and hands it to
+    :meth:`QueryEngine.regions_rendered`."""
 
-    __slots__ = ("pages", "tokens")
+    __slots__ = ("pages", "tokens", "clock")
 
-    def __init__(self, pages: list, tokens: dict | None = None):
+    def __init__(self, pages: list, tokens: dict | None = None, clock=None):
         self.pages = pages
         self.tokens = tokens
+        self.clock = clock
 
     @property
     def returned(self) -> int:
@@ -819,29 +833,18 @@ class RegionsResult:
         return self.prefix() + ",".join(self.rows()) + self.suffix()
 
 
-class _LookupClock:
-    """One ``lookup_many`` call's sub-stage seconds and render tallies
-    (one call runs on one thread: plain integers, no lock).  :meth:`span`
-    times one sub-stage of one chromosome group where the work happens:
-    nanoseconds summed per stage over the call, a sub-span on the calling
-    thread's active request stage (true start and end, parent ``device``),
-    and a profiler annotation ``avdb.<stage>`` — about ten a call, never
-    one per id.  The tallies are added once per chromosome group (render
-    cache) and once per touched segment (renderer route)."""
+class _StageClock:
+    """One engine call's sub-stage seconds (one call runs on one thread at
+    a time: plain integers, no lock).  :meth:`span` times one sub-stage
+    where the work happens: nanoseconds summed per stage over the call, a
+    sub-span on the calling thread's active request stage (true start and
+    end), and a profiler annotation ``avdb.<stage>`` — about ten a call,
+    never one per id or per interval."""
 
-    __slots__ = ("ns", "found", "misses", "batch_rows", "scalar_rows")
+    __slots__ = ("ns",)
 
-    def __init__(self):
-        self.ns = dict.fromkeys(reqtrace.LOOKUP_STAGES, 0)
-        #: ids found (each is answered through the render cache) and the
-        #: distinct rows among them that were not in it
-        self.found = 0
-        self.misses = 0
-        #: of those misses: rows the columnar pass assembled, and rows
-        #: that went through the scalar ``_render_row`` (retained host
-        #: strings, an over-width length, a group of one miss)
-        self.batch_rows = 0
-        self.scalar_rows = 0
+    def __init__(self, stages):
+        self.ns = dict.fromkeys(stages, 0)
 
     @contextlib.contextmanager
     def span(self, stage: str, **args):
@@ -853,6 +856,45 @@ class _LookupClock:
                 end_ns = time.perf_counter_ns()
                 self.ns[stage] += end_ns - start_ns
                 reqtrace.record_active(stage, start_ns, end_ns)
+
+
+class _PanelClock(_StageClock):
+    """One ``POST /regions`` panel's sub-stage seconds
+    (``reqtrace.REGION_STAGES``) and tallies, added to the engine's
+    counters once a panel (:meth:`QueryEngine._regions_done`): a
+    chromosome group counts once, by who answered its span search."""
+
+    __slots__ = ("device_groups", "host_groups", "transfers")
+
+    def __init__(self):
+        super().__init__(reqtrace.REGION_STAGES)
+        #: groups the device answered / groups that took the numpy twin
+        #: (below the minimum, breaker open, device failure, ``host_only``)
+        self.device_groups = 0
+        self.host_groups = 0
+        #: host<->device array transfers of the collected span calls
+        self.transfers = 0
+
+
+class _LookupClock(_StageClock):
+    """One ``lookup_many`` call's sub-stage seconds
+    (``reqtrace.LOOKUP_STAGES``, parent ``device``) and render tallies.
+    The tallies are added once per chromosome group (render cache) and
+    once per touched segment (renderer route)."""
+
+    __slots__ = ("found", "misses", "batch_rows", "scalar_rows")
+
+    def __init__(self):
+        super().__init__(reqtrace.LOOKUP_STAGES)
+        #: ids found (each is answered through the render cache) and the
+        #: distinct rows among them that were not in it
+        self.found = 0
+        self.misses = 0
+        #: of those misses: rows the columnar pass assembled, and rows
+        #: that went through the scalar ``_render_row`` (retained host
+        #: strings, an over-width length, a group of one miss)
+        self.batch_rows = 0
+        self.scalar_rows = 0
 
 
 class QueryEngine:
@@ -980,12 +1022,31 @@ class QueryEngine:
         #: rows of the columnar pass / rows of the scalar ``_render_row``
         self.render_batch_rows = 0
         self.render_scalar_rows = 0
-        self._lookup_hist = None
+        #: ``/stats`` ``region_panels``: answered ``POST /regions`` panels
+        #: and what they took, added once a panel (:meth:`_regions_done`,
+        #: :meth:`regions_rendered`) — ints under the GIL
+        self.region_panels = dict.fromkeys(
+            ("panels", "intervals", "device_groups", "host_groups",
+             "rows_rendered", "streamed", "transfers"), 0)
+        #: ``/stats`` ``region_index``: interval indexes built and device
+        #: copies uploaded, counted once each wherever it happened (the
+        #: uploader's thread or, lazily, a request)
+        self.index_builds = 0
+        self.index_uploads = 0
+        if residency is not None:
+            # a segment the manager uploads has its chromosome's interval
+            # index built, uploaded and warmed on the same thread, before
+            # the segment counts as resident
+            residency.index_warmer = self.warm_region_index
+        self._lookup_hist = self._regions_hist = None
         self._m_render_hits = self._m_render_misses = None
         self._m_batch_rows = self._m_scalar_rows = None
         if registry is not None:
             self._lookup_hist = reqtrace.stage_histograms(
                 registry, reqtrace.LOOKUP_STAGES
+            )
+            self._regions_hist = reqtrace.stage_histograms(
+                registry, reqtrace.REGION_STAGES
             )
             self._m_render_hits = registry.counter(
                 "avdb_render_cache_hits_total",
@@ -1337,7 +1398,8 @@ class QueryEngine:
 
     def regions_serve(self, specs: list, min_cadd=None, max_conseq_rank=None,
                       limit: int | None = None, tokenize: bool = False,
-                      host_only: bool = False) -> RegionsResult:
+                      host_only: bool = False,
+                      clock: "_PanelClock | None" = None) -> RegionsResult:
         """Bulk region join: a batch of ``chr:start-end`` specs answered
         with ONE BITS kernel call per touched chromosome group.
 
@@ -1355,14 +1417,24 @@ class QueryEngine:
         ``tokenize=True`` adds the fixed-width interval-token arrays
         (``bin_level``/``leaf_bin``/``bin_index`` path, ``row_lo``/
         ``row_hi`` spans into the generation's interval index, pre-filter
-        ``count``) for ML consumers."""
+        ``count``) for ML consumers.
+
+        ``clock`` is the panel's :class:`_PanelClock` when the caller
+        opened one (:meth:`panel_clock`: the front end times the body's
+        parse on it); the result carries it on to whoever renders."""
         if len(specs) > self.regions_max:
             raise QueryError(
                 f"regions batch of {len(specs)} exceeds the "
                 f"{self.regions_max}-interval cap (AVDB_SERVE_REGIONS_MAX); "
                 "split the request"
             )
-        parsed = [parse_region(s) for s in specs]
+        if clock is None:
+            clock = _PanelClock()
+        with clock.span("regions.parse", n=len(specs)):
+            parsed = [parse_region(s) for s in specs]
+            by_code: dict[int, list[int]] = {}
+            for i, (code, _s, _e) in enumerate(parsed):
+                by_code.setdefault(code, []).append(i)
         snap = self.snapshots.current()
         if self.residency is not None:
             self.residency.govern(snap)
@@ -1370,9 +1442,6 @@ class QueryEngine:
         # here must fail exactly this batch's caller and leave the engine
         # serving the next one
         faults.fire("serve.regions")
-        by_code: dict[int, list[int]] = {}
-        for i, (code, _s, _e) in enumerate(parsed):
-            by_code.setdefault(code, []).append(i)
         # per-interval kernel outputs, scattered back to request order
         n = len(parsed)
         lo = np.zeros(n, np.int64)
@@ -1386,37 +1455,42 @@ class QueryEngine:
             # touched group answered on the device that owns it); a None
             # return or a missing code falls through to the per-group
             # path below — byte-identical either way
-            mesh_spans = self.mesh.panel_spans(
-                snap,
-                {
-                    code: interval_ops.clamped_queries(
-                        [parsed[i][1] for i in idxs],
-                        [parsed[i][2] for i in idxs],
-                    )
-                    for code, idxs in by_code.items()
-                },
-                lambda code: self._interval_index(snap, code),
-            )
+            with clock.span("regions.spans", n=n):
+                mesh_spans = self.mesh.panel_spans(
+                    snap,
+                    {
+                        code: interval_ops.clamped_queries(
+                            [parsed[i][1] for i in idxs],
+                            [parsed[i][2] for i in idxs],
+                        )
+                        for code, idxs in by_code.items()
+                    },
+                    lambda code: self._interval_index(snap, code),
+                )
         for code, idxs in by_code.items():
             t_group = time.perf_counter_ns()
-            index = indexes[code] = self._interval_index(snap, code)
-            if index is None:
-                level[idxs], leaf[idxs] = interval_ops.bin_tokens_host(
-                    [parsed[i][1] for i in idxs],
-                    [parsed[i][2] for i in idxs],
+            with clock.span("regions.spans", chrom=code, n=len(idxs)):
+                starts = [parsed[i][1] for i in idxs]
+                ends = [parsed[i][2] for i in idxs]
+                index = indexes[code] = self._interval_index(snap, code)
+                if index is None:
+                    level[idxs], leaf[idxs] = interval_ops.bin_tokens_host(
+                        starts, ends
+                    )
+                    continue
+                self._touch_region(
+                    snap.store.shards[code], min(starts), max(ends),
+                    len(idxs),
                 )
-                continue
-            if mesh_spans is not None and code in mesh_spans:
-                g_lo, g_hi, g_level, g_leaf = mesh_spans[code]
-            else:
-                g_lo, g_hi, g_level, g_leaf = self._interval_spans(
-                    index, code,
-                    [parsed[i][1] for i in idxs],
-                    [parsed[i][2] for i in idxs],
-                    host_only,
-                )
-            lo[idxs], hi[idxs] = g_lo, g_hi
-            level[idxs], leaf[idxs] = g_level, g_leaf
+                if mesh_spans is not None and code in mesh_spans:
+                    g_lo, g_hi, g_level, g_leaf = mesh_spans[code]
+                    clock.device_groups += 1
+                else:
+                    g_lo, g_hi, g_level, g_leaf = self._interval_spans(
+                        index, code, starts, ends, host_only, clock
+                    )
+                lo[idxs], hi[idxs] = g_lo, g_hi
+                level[idxs], leaf[idxs] = g_level, g_leaf
             # per-group sub-span onto the request's trace (no-op outside
             # an active trace): a panel's every interval shares the
             # request's trace id, and the group split is where device
@@ -1425,6 +1499,29 @@ class QueryEngine:
                 f"regions.chr{chromosome_label(code)}",
                 t_group, time.perf_counter_ns(),
             )
+        with clock.span("regions.rows", n=n):
+            pages = self._region_pages(
+                snap, parsed, indexes, lo, hi, level, leaf,
+                min_cadd, max_conseq_rank, limit,
+            )
+        tokens = None
+        if tokenize:
+            # the PR-8 envelope now lives in export.tokens — the export
+            # packer shares the exact field list and path renderer
+            tokens = build_region_tokens(
+                snap.generation,
+                [parsed[i][0] for i in range(n)],
+                level, leaf, lo, hi,
+                [indexes[parsed[i][0]] is not None for i in range(n)],
+            )
+        result = RegionsResult(pages, tokens, clock)
+        self._regions_done(clock, n, result.returned)
+        return result
+
+    def _region_pages(self, snap, parsed, indexes, lo, hi, level, leaf,
+                      min_cadd, max_conseq_rank, limit) -> list:
+        """One :class:`RegionPage` per parsed interval, request order:
+        the rows of its span located (and filtered), cut to ``limit``."""
         no_filters = min_cadd is None and max_conseq_rank is None
         pages = []
         for i, (code, start, end) in enumerate(parsed):
@@ -1464,17 +1561,42 @@ class QueryEngine:
                 count, snap.generation, kept[:stop],
                 f"{label}:{start}-{end}", None, paged=False,
             ))
-        tokens = None
-        if tokenize:
-            # the PR-8 envelope now lives in export.tokens — the export
-            # packer shares the exact field list and path renderer
-            tokens = build_region_tokens(
-                snap.generation,
-                [parsed[i][0] for i in range(n)],
-                level, leaf, lo, hi,
-                [indexes[parsed[i][0]] is not None for i in range(n)],
+        return pages
+
+    @staticmethod
+    def panel_clock() -> "_PanelClock":
+        """A panel's clock, for a front end that times the body's parse on
+        it (``regions.parse``) before it calls :meth:`regions_serve`."""
+        return _PanelClock()
+
+    def _regions_done(self, clock: "_PanelClock", intervals: int,
+                      rows: int) -> None:
+        """One answered panel's accounts, added once: the ``/stats``
+        ``region_panels`` tallies and the three sub-stages the engine ran
+        (summed over the panel's chromosome groups; the render is the
+        renderer's to report: :meth:`regions_rendered`)."""
+        tally = self.region_panels
+        tally["panels"] += 1
+        tally["intervals"] += intervals
+        tally["device_groups"] += clock.device_groups
+        tally["host_groups"] += clock.host_groups
+        tally["transfers"] += clock.transfers
+        tally["rows_rendered"] += rows
+        if self._regions_hist is not None:
+            for stage in ("regions.parse", "regions.spans", "regions.rows"):
+                self._regions_hist[stage].observe(clock.ns[stage] / 1e9)
+
+    def regions_rendered(self, clock: "_PanelClock", streamed: bool) -> None:
+        """The panel's body has been rendered — buffered on the executor's
+        thread or streamed chunk by chunk on the event loop's, each chunk
+        inside ``clock.span("regions.render")``: one observation a
+        panel."""
+        if streamed:
+            self.region_panels["streamed"] += 1
+        if self._regions_hist is not None:
+            self._regions_hist["regions.render"].observe(
+                clock.ns["regions.render"] / 1e9
             )
-        return RegionsResult(pages, tokens)
 
     # -- analytics (the fused stats panel) -----------------------------------
 
@@ -1633,7 +1755,8 @@ class QueryEngine:
         monkeypatch to model a failing device)."""
         af, cadd, rank = feats.device()
         return stats_ops.stats_panel(
-            index.device_pos(), af, cadd, rank, starts, ends, padded=True
+            self._device_pos(index), af, cadd, rank, starts, ends,
+            padded=True
         )
 
     def _device_windows(self, index: IntervalIndex, feats: StatsColumns,
@@ -1641,7 +1764,8 @@ class QueryEngine:
         """One windowed-scan kernel call on device (test seam)."""
         _af, cadd, _rank = feats.device()
         return stats_ops.windowed_stats(
-            index.device_pos(), cadd, starts, ends, windows, padded=True
+            self._device_pos(index), cadd, starts, ends, windows,
+            padded=True
         )
 
     def _stats_panel(self, code: int, index: IntervalIndex,
@@ -1727,6 +1851,7 @@ class QueryEngine:
             kept: list[tuple[int, int]] = []  # (segment index, local row)
             index = self._interval_index(snap, code)
             if index is not None:
+                self._touch_region(shard, start, end, 1)
                 # the single-region route rides the SAME interval-index +
                 # BITS-span machinery as the batch API (one query is just
                 # a panel of one); the breaker/host_only fallback is
@@ -1815,7 +1940,13 @@ class QueryEngine:
         shard = snap.store.shards.get(code)
         if shard is None or not shard.n:
             return None
-        key = (snap.generation, code)
+        return self._index_of(snap.generation, code, shard)
+
+    def _index_of(self, generation: int, code: int, shard) -> IntervalIndex:
+        """``shard``'s interval index under its (generation, chromosome)
+        key: the cached one, or built here — by a request that found none,
+        or ahead of requests by :meth:`warm_region_index`."""
+        key = (generation, code)
         with self._cache_lock:
             index = self._index_cache.get(key)
             if index is not None:
@@ -1831,6 +1962,7 @@ class QueryEngine:
                     self._index_cache.move_to_end(key)
                     return index
             index = IntervalIndex.build(shard)
+            self.index_builds += 1
             evicted: list[IntervalIndex] = []
             with self._cache_lock:
                 self._index_cache[key] = index
@@ -1845,21 +1977,98 @@ class QueryEngine:
             old.drop_device()
         return index
 
+    def warm_region_index(self, generation: int, code: int, shard) -> None:
+        """Make ``shard``'s interval index ready for panels before any
+        asks: build it, upload its position array, run the span program
+        once at every query shape a panel's group can take
+        (``interval_ops.span_query_shapes`` of this engine's knobs)
+        against that copy, and only then install it.  The residency
+        manager's uploader calls this for each segment it has uploaded,
+        before the segment counts as resident — never inside a request.
+        The copy comes out of ``INDEX_DEVICE_BYTES`` like a lazily
+        uploaded one."""
+        index = self._index_of(generation, code, shard)
+        if index.warmed:
+            return
+        with annotation("avdb.regions.warm", chrom=code, rows=index.n):
+            dev = index._dev_pos
+            if dev is None:
+                dev = index.upload()
+                self.index_uploads += 1
+            interval_ops.warm_spans(
+                dev, self.regions_device_min, self.regions_max
+            )
+            index._dev_pos = dev
+            index.warmed = True
+        self._note_index_device(index)
+
+    def region_index_stats(self) -> dict:
+        """``/stats`` ``region_index``: of the chromosomes that hold a
+        residency candidate segment in the governed generation
+        (``candidates``), how many have their interval index ``built`` and
+        how many have it on the ``device``, uploaded and probed by every
+        warmed shape — a server is ready for region reads when ``device``
+        equals ``candidates``; and the ``builds`` and ``uploads`` this
+        process has made, each counted once."""
+        generation, codes = (
+            self.residency.candidate_chromosomes()
+            if self.residency is not None else (None, ())
+        )
+        with self._cache_lock:
+            held = [self._index_cache.get((generation, code))
+                    for code in codes]
+        return {
+            "candidates": len(held),
+            "built": sum(index is not None for index in held),
+            "device": sum(index is not None and index.warmed
+                          for index in held),
+            "builds": self.index_builds,
+            "uploads": self.index_uploads,
+        }
+
+    def _touch_region(self, shard, start: int, end: int, n: int) -> None:
+        """A region read's window feeds the residency manager's heat like
+        a probe's: ``n`` intervals between ``start`` and ``end`` touch the
+        segments whose key range they overlap, so a server that is asked
+        for regions holds those segments — and, through
+        :meth:`warm_region_index`, their interval indexes — on the
+        device."""
+        if self.residency is None:
+            return
+        top = interval_ops.MAX_QUERY_POS
+        self.residency.touch_window(
+            shard,
+            np.uint64(min(max(int(start), 0), top)) << np.uint64(32),
+            (np.uint64(min(max(int(end), 0), top)) << np.uint64(32))
+            | np.uint64(0xFFFFFFFF),
+            n,
+        )
+
     def _device_spans(self, index: IntervalIndex, starts, ends):
         """One batched BITS kernel call (test seam: monkeypatch to model
         a failing device)."""
         return interval_ops.interval_spans(
-            index.device_pos(), starts, ends, pos_padded=True
+            self._device_pos(index), starts, ends, pos_padded=True
         )
 
+    def _device_pos(self, index: IntervalIndex):
+        """``index``'s position array on the device; an upload it takes is
+        counted (``region_index.uploads``)."""
+        if index._dev_pos is None:
+            index.device_pos()
+            self.index_uploads += 1
+        return index._dev_pos
+
     def _interval_spans(self, index: IntervalIndex, code: int,
-                        starts, ends, host_only: bool = False):
+                        starts, ends, host_only: bool = False,
+                        clock: "_PanelClock | None" = None):
         """(lo, hi, level, leaf) per query interval — the device kernel
         when the batch is worth a dispatch and the group's circuit
         breaker allows it, the byte-identical numpy twin otherwise.  A
         device failure feeds the breaker (so a sick device stops being
         attempted per panel) and falls back host-side: correct bytes
-        either way, the serving contract."""
+        either way, the serving contract.  A panel's ``clock`` is told
+        who answered the group."""
         breaker = self.breaker
         if (not host_only
                 and len(starts) >= self.regions_device_min
@@ -1876,7 +2085,12 @@ class QueryEngine:
                 if breaker is not None:
                     breaker.record_success(code)
                 self._note_index_device(index)
+                if clock is not None:
+                    clock.device_groups += 1
+                    clock.transfers += interval_ops.SPAN_TRANSFERS
                 return out
+        if clock is not None:
+            clock.host_groups += 1
         return interval_ops.interval_spans_host(index.pos, starts, ends)
 
     def _note_index_device(self, index) -> None:

@@ -130,6 +130,8 @@ def stats_payload(ctx) -> str:
                          "misses": ctx.engine.render_cache_misses},
         "render_batch": {"rows": ctx.engine.render_batch_rows,
                          "scalar_rows": ctx.engine.render_scalar_rows},
+        "region_index": ctx.engine.region_index_stats(),
+        "region_panels": dict(ctx.engine.region_panels),
     }
     if ctx.engine.residency is not None:
         stats["residency"] = ctx.engine.residency.stats()

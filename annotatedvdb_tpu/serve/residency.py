@@ -103,15 +103,21 @@ class _Entry:
     under the manager lock."""
 
     __slots__ = ("seg", "nbytes", "score", "resident", "pending",
-                 "key_min", "key_max", "device")
+                 "key_min", "key_max", "device", "code", "shard")
 
-    def __init__(self, seg, nbytes: int, device: int | None = None):
+    def __init__(self, seg, nbytes: int, device: int | None = None,
+                 code: int | None = None, shard=None):
         self.seg = seg
+        #: the chromosome shard the segment belongs to (its interval index
+        #: is warmed with the segment's upload)
+        self.code = code
+        self.shard = shard
         self.nbytes = nbytes
         self.score = 0.0
         self.resident = False
-        #: planned resident, its upload (and probe-program warm) not landed
-        #: yet: counted by the budget, not reported resident
+        #: planned resident, its upload (and probe-program warm, and its
+        #: chromosome's interval-index warm) not landed yet: counted by
+        #: the budget, not reported resident
         self.pending = False
         self.key_min, self.key_max = _key_bounds(seg)
         #: placement device index (None = default device / no placement)
@@ -166,6 +172,10 @@ class ResidencyManager:
 
         self._max_probe_queries = resolve_batch_knobs(max_batch, None, None)[0]
         self._uploader = None  # lazily-built single-thread executor
+        #: ``warm(generation, chromosome code, shard)``, set by the query
+        #: engine that owns this manager: builds, uploads and warms the
+        #: shard's interval index, on the thread that uploaded the segment
+        self.index_warmer = None
         if min_rows is None:
             from annotatedvdb_tpu.store.variant_store import DEVICE_SEGMENT_MIN
 
@@ -239,7 +249,7 @@ class ResidencyManager:
                 if seg.n >= self.min_rows:
                     entries[id(seg)] = _Entry(
                         seg, device_cache_bytes(seg, shard.width),
-                        device=device,
+                        device=device, code=code, shard=shard,
                     )
         with self._lock:
             if (self._generation is not None
@@ -391,6 +401,7 @@ class ResidencyManager:
         return self._devices[index]
 
     def _do_uploads(self, upload: list) -> None:
+        landed = []
         for i, e in enumerate(upload):
             with self._lock:
                 if not e.resident:
@@ -412,7 +423,8 @@ class ResidencyManager:
                     if not e.resident:
                         continue
                     e.seg._device = dev
-                    e.pending = False
+                    generation = self._generation
+                landed.append((e, generation))
                 if self._m_uploads is not None:
                     self._m_uploads.inc()
             except Exception as err:
@@ -426,8 +438,31 @@ class ResidencyManager:
                 self.log(f"residency: upload failed, serving from "
                          f"host ({err})")
                 break
+        # the probes of every uploaded segment run on the device from
+        # here; each segment is REPORTED resident once its chromosome's
+        # interval index is ready for panels too, so a server that says
+        # its segments are resident compiles, builds and uploads nothing
+        # more for either kind of read
+        for e, generation in landed:
+            self._warm_index(e, generation)
+            with self._lock:
+                e.pending = False
         if self._m_resident is not None:
             self._m_resident.set(self.resident_bytes())
+
+    def _warm_index(self, e: _Entry, generation) -> None:
+        warm = self.index_warmer
+        with self._lock:
+            current = e.resident and generation == self._generation
+        if warm is None or not current:
+            return
+        try:
+            warm(generation, e.code, e.shard)
+        except Exception as err:
+            # the segment's own copy landed; region reads of this
+            # chromosome keep building and uploading lazily
+            self.log(f"residency: interval index of chromosome code "
+                     f"{e.code} not warmed ({err})")
 
     # -- introspection ------------------------------------------------------
 
@@ -436,6 +471,15 @@ class ResidencyManager:
         (the budget's own count; the gauge)."""
         with self._lock:
             return sum(e.nbytes for e in self._entries.values() if e.resident)
+
+    def candidate_chromosomes(self) -> tuple:
+        """(governed generation, sorted codes of the chromosomes that hold
+        a candidate segment): whose interval indexes the uploader warms."""
+        with self._lock:
+            return self._generation, sorted(
+                {e.code for e in self._entries.values()
+                 if e.code is not None}
+            )
 
     def stats(self) -> dict:
         """Summary for ``/stats`` and tests."""
