@@ -17,7 +17,8 @@ buffered or streamed rendering) must equal byte for byte.  A row's JSON
 text is the caller's ``render_row(shard, code, global row id)`` — the
 record renderer is the one thing the two sides share
 (``serve.engine.render_variant``, whose scalar ``_render_row`` is every
-renderer's definition).
+renderer's definition: the serving path renders a panel's rows in
+columnar blocks, ``serve.engine.render_located``, held to those bytes).
 """
 
 from __future__ import annotations

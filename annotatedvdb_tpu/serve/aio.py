@@ -1980,8 +1980,10 @@ class AioServer:
         """Chunked transfer of one RegionPage — or one RegionsResult,
         whose "rows" are whole per-interval envelopes (same
         prefix/rows/suffix surface): prefix, rows in
-        ``_STREAM_ROWS_PER_CHUNK`` batches (rendered lazily — RSS holds
-        one batch, not the body), suffix.  De-chunked, the bytes are
+        ``_STREAM_ROWS_PER_CHUNK`` batches, suffix.  The rows render
+        lazily, a block of ``engine.REGION_RENDER_BLOCK`` at a time as
+        the chunks draw on them — RSS holds one chunk and the rest of
+        one rendered block, not the body.  De-chunked, the bytes are
         exactly ``page.assemble()``.
 
         A SIGTERM drain (or the drain-budget cancellation) arriving
@@ -2000,8 +2002,10 @@ class AioServer:
         )
         _write_chunk(writer, page.prefix().encode())
         # a panel's rows render inside ``avdb.regions.render``, one span a
-        # chunk, on this (the event loop's) thread — never across the
-        # ``await`` between chunks, where other connections run
+        # chunk (which renders the next block of rows when the chunk
+        # reaches past the last one), on this (the event loop's) thread —
+        # never across the ``await`` between chunks, where other
+        # connections run
         clock = getattr(page, "clock", None)
         chunks = _row_chunks(page.rows())
         first = True
@@ -2015,7 +2019,7 @@ class AioServer:
                     break
                 with (clock.span("regions.render") if clock is not None
                       else contextlib.nullcontext()):
-                    chunk = next(chunks, None)  # renders one chunk's rows
+                    chunk = next(chunks, None)  # renders what the chunk needs
                 if chunk is None:
                     break
                 _write_chunk(
@@ -2045,7 +2049,8 @@ def _row_chunks(rows):
     ``_STREAM_ROWS_PER_CHUNK`` rows, and cut on a byte bound too — a
     RegionsResult "row" is a whole per-interval envelope, and 256 of those
     must not accumulate panel-sized RSS before the first write.  Each
-    ``next`` renders one chunk's rows."""
+    ``next`` draws one chunk's rows from ``rows``, which renders them a
+    block at a time (``engine.REGION_RENDER_BLOCK``)."""
     buf: list[str] = []
     size = 0
     for row in rows:

@@ -28,9 +28,10 @@ Records render as JSON **text** through the same codec the egress path uses
 stored text verbatim — zero parse/re-serialize on the hot read path — and
 rendering never mutates the snapshot (unlike ``get_ann``, which
 materializes parsed trees back into the column).  ``_render_row`` is the
-scalar definition (region pages, rows that keep host strings, a lone
-miss); a point/bulk lookup renders a chromosome group's cache misses in
-one columnar pass (``render_rows``), byte for byte the same text.
+scalar definition (rows that keep host strings, a lone row); a point/bulk
+lookup renders a chromosome group's cache misses in one columnar pass
+(``render_rows``), and a region answer renders its located rows the same
+way a block at a time (``render_located``): byte for byte the same text.
 
 Rendered region responses sit in a small LRU keyed by store generation
 (``AVDB_SERVE_REGION_CACHE``), so a hot region costs one dict probe until
@@ -258,7 +259,10 @@ def render_variant(shard, code: int, gid: int) -> str:
 
 def _render_row(seg, j: int, label: str, width: int) -> str:
     """One segment row as JSON text (fixed field order; annotation values
-    splice through ``jsonb_dumps`` — raw-text columns copy verbatim).
+    splice through ``jsonb_dumps`` — raw-text columns copy verbatim).  The
+    scalar definition: the columnar pass (:func:`render_rows`,
+    :func:`render_located`) is held to these bytes, and hands this
+    function the rows it does not assemble.
     Identity strings are assembled without ``json.dumps``: alleles, labels,
     and PKs are [A-Za-z0-9:._-] by construction, nothing to escape."""
     ref, alt = segment_alleles(seg, j, width)
@@ -446,6 +450,83 @@ def _render_columnar(seg, j: np.ndarray, ref_len: np.ndarray,
     return out
 
 
+#: a region answer that shows no row: (segment index, local row) arrays
+_NO_ROWS = (np.empty(0, np.int32), np.empty(0, np.int64))
+
+#: rows a region answer hands the columnar pass at a time.  A panel's per-row
+#: cost is flat from ~250 to ~1,000 rows a call (PERF.md section 6, PR 33);
+#: a block is also what a streamed body holds rendered, so not larger.
+REGION_RENDER_BLOCK = 512
+
+
+def render_located(runs, clock=None) -> list:
+    """Rows already located — ``runs`` of ``(shard, label, si, jj)``, the
+    segment index and local row of each as arrays (a region page's, or
+    several pages') — as JSON text, flat, in run-then-row order: byte for
+    byte ``_render_row`` of each.
+
+    :func:`render_rows`' pass without its locate: the rows are grouped by
+    what they are, (chromosome shard, segment), whatever order the runs
+    came in, and each group is one :func:`_render_segment_rows` call
+    (which sends a row that keeps host strings, or an over-width length,
+    through :func:`_render_row`).  One row in all has nothing to amortise
+    and is rendered by ``_render_row``.  ``clock`` (a :class:`_PanelClock`)
+    is told how many rows took each route, once per group."""
+    runs = [run for run in runs if run[3].shape[0]]
+    n = sum(run[3].shape[0] for run in runs)
+    if n == 0:
+        return []
+    if n == 1:
+        shard, label, si, jj = runs[0]
+        if clock is not None:
+            clock.scalar_rows += 1
+        return [_render_row(shard.segments[int(si[0])], int(jj[0]), label,
+                            shard.width)]
+    by_shard: dict = {}
+    for shard, label, si, jj in runs:
+        group = by_shard.setdefault(id(shard), (shard, label, [], []))
+        group[2].append(si)
+        group[3].append(jj)
+    # each shard's rows in one pass, its texts in the order its runs came
+    texts = {
+        key: _render_shard_rows(
+            shard, label, np.concatenate(si), np.concatenate(jj), clock
+        )
+        for key, (shard, label, si, jj) in by_shard.items()
+    }
+    if len(texts) == 1:
+        return texts.popitem()[1]
+    # deal them back: a run's rows are the next of its shard's texts
+    out: list = []
+    taken = dict.fromkeys(texts, 0)
+    for shard, _label, _si, jj in runs:
+        lo = taken[id(shard)]
+        taken[id(shard)] = hi = lo + jj.shape[0]
+        out += texts[id(shard)][lo:hi]
+    return out
+
+
+def _render_shard_rows(shard, label: str, si: np.ndarray, jj: np.ndarray,
+                       clock) -> list:
+    """Rows ``(si, jj)`` of one shard as JSON text, in the order given: one
+    :func:`_render_segment_rows` a touched segment (what
+    :func:`render_rows` does once it has located its ids)."""
+    first = int(si[0])
+    if bool((si == first).all()):
+        return _render_segment_rows(
+            shard.segments[first], jj, label, shard.width, clock
+        )
+    out: list = [None] * jj.shape[0]
+    for s in np.unique(si).tolist():
+        at = np.flatnonzero(si == s)
+        texts = _render_segment_rows(
+            shard.segments[s], jj[at], label, shard.width, clock
+        )
+        for k, text in zip(at.tolist(), texts):
+            out[k] = text
+    return out
+
+
 def _ann_number(seg, j: int, column: str, field: str):
     """Numeric ``field`` of row j's ``column`` annotation, or None.  Reads
     the object column without materializing (RawJson stays raw for every
@@ -467,30 +548,37 @@ class RegionPage:
     what the streaming front end writes chunk by chunk, and what
     :meth:`QueryEngine.region` joins into the PR-5 byte-identical body.
 
+    The rows to show are held as the columnar renderer takes them: ``si``
+    and ``jj``, the segment index and local row of each, in response
+    order (slices of the interval index, or of a cursor walk's match
+    list).
+
     Unpaged pages (``cursor=None`` at prepare time) close with exactly
     ``]}`` — byte-identical to the pre-paging envelope; paged ones append
     a ``"next"`` field carrying the continuation token (null on the last
     page)."""
 
     __slots__ = ("shard", "label", "level", "bin_path", "count",
-                 "generation", "shown", "region_str", "next_token", "paged")
+                 "generation", "si", "jj", "region_str", "next_token",
+                 "paged")
 
     def __init__(self, shard, label, level, bin_path, count, generation,
-                 shown, region_str, next_token, paged):
+                 si, jj, region_str, next_token, paged):
         self.shard = shard
         self.label = label
         self.level = level
         self.bin_path = bin_path
         self.count = count
         self.generation = generation
-        self.shown = shown
+        self.si = si
+        self.jj = jj
         self.region_str = region_str
         self.next_token = next_token
         self.paged = paged
 
     @property
     def returned(self) -> int:
-        return len(self.shown)
+        return int(self.jj.shape[0])
 
     def prefix(self) -> str:
         return (
@@ -498,17 +586,24 @@ class RegionPage:
             f',"bin_level":{self.level}'
             f',"bin_index":{json.dumps(self.bin_path)}'
             f',"count":{self.count}'
-            f',"returned":{len(self.shown)}'
+            f',"returned":{self.returned}'
             f',"generation":{self.generation}'
             ',"variants":['
         )
 
+    def located(self, lo: int = 0, hi: int | None = None) -> tuple:
+        """Rows ``[lo, hi)`` of the page as a :func:`render_located` run."""
+        return self.shard, self.label, self.si[lo:hi], self.jj[lo:hi]
+
     def rows(self):
-        """Rendered JSON text per row, in response order — a generator, so
-        a streaming writer holds one row (not the whole body) at a time."""
-        shard = self.shard
-        for si, j in self.shown:
-            yield _render_row(shard.segments[si], j, self.label, shard.width)
+        """Rendered JSON text per row, in response order — a generator
+        that renders ``REGION_RENDER_BLOCK`` rows at a time through the
+        columnar pass (:func:`render_located`), so a streaming writer
+        holds one block (not the whole body)."""
+        for lo in range(0, self.returned, REGION_RENDER_BLOCK):
+            yield from render_located(
+                [self.located(lo, lo + REGION_RENDER_BLOCK)]
+            )
 
     def suffix(self) -> str:
         if not self.paged:
@@ -793,11 +888,12 @@ class RegionsResult:
     in request order, wrapped as ``{"n": N[, "tokens": {...}],
     "results": [...]}``.  Same prefix/rows/suffix surface as
     :class:`RegionPage`, so the streaming writer handles both shapes —
-    ``rows()`` yields one assembled per-interval envelope at a time (RSS
-    holds one interval's body, not the panel's).  ``clock`` is the
-    panel's :class:`_PanelClock`: whoever renders the body times it there
-    (``regions.render``) and hands it to
-    :meth:`QueryEngine.regions_rendered`."""
+    ``rows()`` yields one assembled per-interval envelope at a time and
+    renders a block of consecutive pages at a time (RSS holds one block's
+    rows, not the panel's).  ``clock`` is the panel's
+    :class:`_PanelClock`: the renderer tallies its rows' routes there,
+    whoever renders the body times it there (``regions.render``) and
+    hands it to :meth:`QueryEngine.regions_rendered`."""
 
     __slots__ = ("pages", "tokens", "clock")
 
@@ -823,8 +919,30 @@ class RegionsResult:
         return head + ',"results":['
 
     def rows(self):
-        for page in self.pages:
-            yield page.assemble()
+        """One assembled envelope per interval, request order, lazily:
+        consecutive pages are gathered until they hold
+        ``REGION_RENDER_BLOCK`` rows, their rows rendered together
+        (:func:`render_located` groups them by chromosome shard and
+        segment — a panel's targets come in any order) and dealt back to
+        their pages."""
+        pages = self.pages
+        start = 0
+        while start < len(pages):
+            stop, held = start, 0
+            while stop < len(pages) and held < REGION_RENDER_BLOCK:
+                held += pages[stop].returned
+                stop += 1
+            block = pages[start:stop]
+            texts = render_located(
+                [page.located() for page in block], self.clock
+            )
+            at = 0
+            for page in block:
+                upto = at + page.returned
+                yield page.prefix() + ",".join(texts[at:upto]) \
+                    + page.suffix()
+                at = upto
+            start = stop
 
     def suffix(self) -> str:
         return "]}"
@@ -864,7 +982,8 @@ class _PanelClock(_StageClock):
     counters once a panel (:meth:`QueryEngine._regions_done`): a
     chromosome group counts once, by who answered its span search."""
 
-    __slots__ = ("device_groups", "host_groups", "transfers")
+    __slots__ = ("device_groups", "host_groups", "transfers",
+                 "batch_rows", "scalar_rows")
 
     def __init__(self):
         super().__init__(reqtrace.REGION_STAGES)
@@ -874,6 +993,12 @@ class _PanelClock(_StageClock):
         self.host_groups = 0
         #: host<->device array transfers of the collected span calls
         self.transfers = 0
+        #: rows the body's render handed the columnar pass, and rows that
+        #: went through the scalar ``_render_row`` (retained host strings,
+        #: an over-width length, a block of one row) — told by
+        #: :func:`render_located` once per (shard, segment) group of a block
+        self.batch_rows = 0
+        self.scalar_rows = 0
 
 
 class _LookupClock(_StageClock):
@@ -1027,7 +1152,8 @@ class QueryEngine:
         #: :meth:`regions_rendered`) — ints under the GIL
         self.region_panels = dict.fromkeys(
             ("panels", "intervals", "device_groups", "host_groups",
-             "rows_rendered", "streamed", "transfers"), 0)
+             "rows_rendered", "rows_batched", "rows_scalar", "streamed",
+             "transfers"), 0)
         #: ``/stats`` ``region_index``: interval indexes built and device
         #: copies uploaded, counted once each wherever it happened (the
         #: uploader's thread or, lazily, a request)
@@ -1531,17 +1657,18 @@ class QueryEngine:
             i_lo, i_hi = int(lo[i]), int(hi[i])
             span = i_hi - i_lo
             if index is None:
-                kept: list = []
+                si, jj = _NO_ROWS
                 count = 0
             elif no_filters:
                 # the index is deduplicated, so the span width IS the
-                # post-filter count — materialize ONLY the rows that will
-                # render (limit=0 is the pure count-only mode: none)
+                # post-filter count — take ONLY the rows that will render
+                # (limit=0 is the pure count-only mode: none), as slices
+                # of the index: no row is touched before the render
                 count = span
                 take = span if limit is None \
                     else min(max(int(limit), 0), span)
-                kept = list(zip(index.si[i_lo:i_lo + take].tolist(),
-                                index.jj[i_lo:i_lo + take].tolist()))
+                si = index.si[i_lo:i_lo + take]
+                jj = index.jj[i_lo:i_lo + take]
             else:
                 # filters vectorize over the cached feature columns —
                 # never a per-row sidecar parse (semantics pinned
@@ -1550,15 +1677,14 @@ class QueryEngine:
                     snap, code, index, i_lo, i_hi, min_cadd,
                     max_conseq_rank,
                 )
-                kept = list(zip(index.si[sel].tolist(),
-                                index.jj[sel].tolist()))
-                count = len(kept)
-            stop = len(kept) if limit is None \
-                else min(max(int(limit), 0), len(kept))
+                count = int(sel.shape[0])
+                if limit is not None:
+                    sel = sel[:max(int(limit), 0)]
+                si, jj = index.si[sel], index.jj[sel]
             pages.append(RegionPage(
                 shard, label, int(level[i]),
                 closed_form_path(label, int(level[i]), int(leaf[i])),
-                count, snap.generation, kept[:stop],
+                count, snap.generation, si, jj,
                 f"{label}:{start}-{end}", None, paged=False,
             ))
         return pages
@@ -1590,9 +1716,14 @@ class QueryEngine:
         """The panel's body has been rendered — buffered on the executor's
         thread or streamed chunk by chunk on the event loop's, each chunk
         inside ``clock.span("regions.render")``: one observation a
-        panel."""
+        panel, and the routes its rows took (``rows_batched`` +
+        ``rows_scalar`` = the panel's ``rows_rendered``, unless a drain cut
+        the body short), added once."""
+        tally = self.region_panels
+        tally["rows_batched"] += clock.batch_rows
+        tally["rows_scalar"] += clock.scalar_rows
         if streamed:
-            self.region_panels["streamed"] += 1
+            tally["streamed"] += 1
         if self._regions_hist is not None:
             self._regions_hist["regions.render"].observe(
                 clock.ns["regions.render"] / 1e9
@@ -1848,7 +1979,7 @@ class QueryEngine:
                     self._walk_cache.move_to_end(wkey)
         full_count = None
         if hit is None:
-            kept: list[tuple[int, int]] = []  # (segment index, local row)
+            si, jj = _NO_ROWS  # (segment index, local row) of each match
             index = self._interval_index(snap, code)
             if index is not None:
                 self._touch_region(shard, start, end, 1)
@@ -1869,27 +2000,23 @@ class QueryEngine:
                         snap, code, index, i_lo, i_hi, min_cadd,
                         max_conseq_rank,
                     )
-                    kept = list(zip(index.si[sel].tolist(),
-                                    index.jj[sel].tolist()))
+                    si, jj = index.si[sel], index.jj[sel]
                 else:
                     if not paged:
                         # dedup'd span width IS the count; no filter pass
-                        # and no walk cache to fill — materialize only
-                        # the rows that will render
+                        # and no walk cache to fill — take only the rows
+                        # that will render
                         full_count = i_hi - i_lo
                         take = full_count if limit is None \
                             else min(max(int(limit), 0), full_count)
                         i_hi = i_lo + take
-                    kept = list(zip(index.si[i_lo:i_hi].tolist(),
-                                    index.jj[i_lo:i_hi].tolist()))
+                    si, jj = index.si[i_lo:i_hi], index.jj[i_lo:i_hi]
             if paged:
                 # without this an N-page walk re-runs the full region
                 # scan + filter pass per page (O(N x region) for what the
-                # client sees as keyset pagination)
-                hit = (
-                    np.fromiter((t[0] for t in kept), np.int64, len(kept)),
-                    np.fromiter((t[1] for t in kept), np.int64, len(kept)),
-                )
+                # client sees as keyset pagination); copies, so a cached
+                # walk never pins a stale generation's whole index
+                hit = (si.copy(), jj.copy())
                 with self._cache_lock:
                     self._walk_cache[wkey] = hit
                     while len(self._walk_cache) > self.WALK_CACHE:
@@ -1900,8 +2027,6 @@ class QueryEngine:
             offset = decode_cursor(cursor, ckey)
             stop = total if limit is None \
                 else min(offset + max(int(limit), 0), total)
-            shown = list(zip(hit[0][offset:stop].tolist(),
-                             hit[1][offset:stop].tolist()))
             next_token = None
             # a page must ADVANCE to mint a continuation (limit=0
             # count-only pages would otherwise hand back a
@@ -1915,17 +2040,18 @@ class QueryEngine:
                                    time.perf_counter_ns())
             return RegionPage(
                 shard, label, level, closed_form_path(label, level, leaf),
-                total, snap.generation, shown, f"{label}:{start}-{end}",
+                total, snap.generation, hit[0][offset:stop],
+                hit[1][offset:stop], f"{label}:{start}-{end}",
                 next_token, paged=True,
             )
-        stop = len(kept) if limit is None \
-            else min(max(int(limit), 0), len(kept))
+        matched = int(jj.shape[0])
+        stop = matched if limit is None else max(int(limit), 0)
         reqtrace.record_active(f"region.chr{label}", t_page,
                                time.perf_counter_ns())
         return RegionPage(
             shard, label, level, closed_form_path(label, level, leaf),
-            len(kept) if full_count is None else full_count,
-            snap.generation, kept[:stop],
+            matched if full_count is None else full_count,
+            snap.generation, si[:stop], jj[:stop],
             f"{label}:{start}-{end}", None, paged=False,
         )
 

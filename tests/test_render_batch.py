@@ -1,14 +1,16 @@
 """The columnar row renderer against the scalar one.
 
 ``engine.render_rows`` renders a chromosome group's found rows in one pass
-per touched segment; ``engine._render_row`` stays the definition (it also
-renders region pages, rows that keep host strings, and a group's lone
-miss).  Parity here is byte for byte on hand-built segments that hold every
-hazard the two could disagree on; the bulk allele decode is held to
-``segment_alleles`` (the definition the export coder shares); and the
-cached-batch path of ``lookup_many`` (two lock holds a chromosome group) is
-held to the per-id loop it replaced — a reference model of that loop lives
-in this file — on answers, tallies, LRU content and byte tally.
+per touched segment, and ``engine.render_located`` renders a region
+answer's rows the same way, a block at a time (``RegionPage.rows``,
+``RegionsResult.rows``); ``engine._render_row`` stays the definition (it
+also renders rows that keep host strings, and a lone row).  Parity here is
+byte for byte on hand-built segments that hold every hazard the two could
+disagree on; the bulk allele decode is held to ``segment_alleles`` (the
+definition the export coder shares); and the cached-batch path of
+``lookup_many`` (two lock holds a chromosome group) is held to the per-id
+loop it replaced — a reference model of that loop lives in this file — on
+answers, tallies, LRU content and byte tally.
 """
 
 from __future__ import annotations
@@ -26,7 +28,10 @@ from annotatedvdb_tpu.obs.metrics import MetricsRegistry
 from annotatedvdb_tpu.serve import QueryEngine, StaticSnapshots
 from annotatedvdb_tpu.serve import engine as engine_mod
 from annotatedvdb_tpu.serve.engine import (
+    RegionPage,
+    RegionsResult,
     _LookupClock,
+    _PanelClock,
     _render_row,
     decode_allele_rows,
     render_rows,
@@ -230,6 +235,217 @@ def test_over_width_without_retained_strings_raises_the_same_error(gids):
     assert str(columnar.value) == str(scalar.value)
     assert "exceeds store width 8 with no retained strings" in str(
         columnar.value)
+
+
+# ---------------------------------------------------------------------------
+# region answers: RegionPage / RegionsResult against the scalar renderer
+
+
+def _page(shard, gids, paged: bool = False) -> RegionPage:
+    """A page showing ``gids`` of ``shard``, in that order, as the engine
+    builds one: (segment index, local row) arrays."""
+    si, jj = shard.locate_rows(np.asarray(gids, np.int64))
+    return RegionPage(
+        shard, chromosome_label(CODE), 0, "chr8", len(gids), 7, si, jj,
+        "8:1-64000000", "token" if paged else None, paged=paged)
+
+
+def _scalar_envelope(page) -> str:
+    """The page's body with every row through ``_render_row``, one row at
+    a time: what ``RegionPage.assemble`` was before the columnar pass."""
+    rows = [
+        _render_row(page.shard.segments[si], j, page.label, page.shard.width)
+        for si, j in zip(page.si.tolist(), page.jj.tolist())
+    ]
+    return page.prefix() + ",".join(rows) + page.suffix()
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_a_region_page_is_the_scalar_renderer_byte_for_byte(shard, case):
+    gids, scalar_rows = CASES[case]
+    page = _page(shard, gids)
+    want = _scalar(shard, gids)
+    assert list(page.rows()) == want
+    assert page.assemble() == _scalar_envelope(page)
+    assert page.returned == len(gids)
+    assert json.loads(page.assemble())["returned"] == len(gids)
+    # the same page as a panel's only one, and the routes its rows took
+    clock = _PanelClock()
+    result = RegionsResult([page], None, clock)
+    assert list(result.rows()) == [_scalar_envelope(page)]
+    assert (clock.batch_rows, clock.scalar_rows) == (
+        len(gids) - scalar_rows, scalar_rows)
+    assert result.assemble() == \
+        '{"n":1,"results":[' + _scalar_envelope(page) + "]}"
+
+
+#: panel -> the gids of each page, in order (pages over one shard: what a
+#: block gathers is grouped by segment across its pages)
+PANELS = {
+    "pages_of_two_and_three_segments": [
+        [GID["snv"], GID["adsp_null"]],
+        [GID["at_width"], GID["adsp_true"], GID["ann_raw"], GID["level_0"]],
+    ],
+    "a_retained_long_allele_among_plain_pages": [
+        [GID["snv"], GID["insertion"]], [GID["long_no_digest"], GID["snv"]],
+        [GID["deletion"]],
+    ],
+    "a_digest_pk_among_plain_pages": [
+        [GID["adsp_false"]], [GID["digest_only"]], [GID["long_digest"]],
+        [GID["adsp_true"], GID["adsp_null"]],
+    ],
+    "every_annotation_shape": [
+        [GID["ann_raw"], GID["ann_dict"]], [GID["ann_two"]],
+        [GID["ann_none"], GID["snv"]],
+    ],
+    "empty_pages_between": [
+        [], [GID["snv"], GID["ann_two"]], [], [], [GID["level_0"]], [],
+    ],
+    "only_empty_pages": [[], [], []],
+    "one_row_in_all": [[], [GID["ann_dict"]], []],
+    "one_scalar_row_in_all": [[GID["long_digest"]]],
+    "one_row_a_page": [[g] for g in range(len(ROWS))],
+    "every_row_twice_over": [list(range(len(ROWS))),
+                             list(range(len(ROWS)))[::-1]],
+}
+#: rows of each panel that keep host strings (the scalar route at any block
+#: size); a block that holds one row in all sends that row there too
+HOST_STRING_ROWS = {GID["long_digest"], GID["long_no_digest"],
+                    GID["digest_only"]}
+
+
+@pytest.mark.parametrize("block", [1, 3, 512])
+@pytest.mark.parametrize("panel", sorted(PANELS))
+def test_a_panel_renders_in_blocks_byte_for_byte(shard, monkeypatch, panel,
+                                                 block):
+    monkeypatch.setattr(engine_mod, "REGION_RENDER_BLOCK", block)
+    pages = [_page(shard, gids) for gids in PANELS[panel]]
+    clock = _PanelClock()
+    result = RegionsResult(pages, None, clock)
+    want = [_scalar_envelope(page) for page in pages]
+    assert list(result.rows()) == want
+    rows = sum(len(gids) for gids in PANELS[panel])
+    assert clock.batch_rows + clock.scalar_rows == rows == result.returned
+    kept = sum(g in HOST_STRING_ROWS for gids in PANELS[panel] for g in gids)
+    if block == 512:
+        assert clock.scalar_rows == (1 if rows == 1 else kept)
+    else:
+        assert clock.scalar_rows >= kept
+    assert result.assemble() == \
+        f'{{"n":{len(pages)},"results":[' + ",".join(want) + "]}"
+    for page, text in zip(pages, want):
+        assert page.assemble() == text  # and alone, whatever the block
+
+
+@pytest.mark.parametrize("gids", [
+    [GID["long_no_digest"], PLAIN],
+    [PLAIN, GID["long_digest"], GID["snv"]],
+    [GID["long_digest"]],
+], ids=["alt_over_width", "ref_over_width", "alone"])
+def test_a_region_page_raises_the_scalar_error_on_an_over_width_row(gids):
+    store = VariantStore(width=WIDTH)
+    shard = store.shard(CODE)
+    rows = [r for r in ROWS if r["tag"] in ("snv", "long_digest",
+                                            "long_no_digest")]
+    shard.append_segment(_segment(
+        [dict(r, digest=None) for r in rows], retain=False))
+    shard._starts_cache = None
+    local = [[r["tag"] for r in rows].index(ROWS[g]["tag"]) for g in gids]
+    with pytest.raises(ValueError) as scalar:
+        _scalar(shard, local)
+    page = _page(shard, local)
+    with pytest.raises(ValueError) as alone:
+        page.assemble()
+    with pytest.raises(ValueError) as in_a_panel:
+        RegionsResult([_page(shard, [0]), page]).assemble()
+    assert str(alone.value) == str(in_a_panel.value) == str(scalar.value)
+
+
+#: every stored row of a chromosome, and windows inside single segments
+WHOLE = "{c}:1-64000000"
+READS = {
+    "whole_chromosome": dict(specs=[WHOLE], how={}),
+    "limit_cut": dict(specs=[WHOLE], how=dict(limit=5)),
+    "limit_of_one": dict(specs=[WHOLE], how=dict(limit=1)),
+    "limit_zero": dict(specs=[WHOLE], how=dict(limit=0)),
+    "filtered_by_cadd": dict(specs=[WHOLE], how=dict(min_cadd=10.0)),
+    "filtered_by_rank": dict(specs=[WHOLE, "{c}:1-30000"],
+                             how=dict(max_conseq_rank=10)),
+    "filtered_and_cut": dict(specs=[WHOLE],
+                             how=dict(min_cadd=1.0, limit=1)),
+    "nothing_stored_there": dict(specs=["{c}:5000-6000"], how={}),
+    "one_stored_row": dict(specs=["{c}:1000-1000"], how={}),
+}
+
+
+@pytest.mark.parametrize("read", sorted(READS))
+def test_served_reads_render_the_scalar_bytes(served, read):
+    """Through the engine: the single read, and the same interval in a
+    panel, against the scalar envelope of the page the engine built."""
+    engine, _where = served
+    how = READS[read]["how"]
+    for code in (CODE, 1):
+        specs = [s.format(c=chromosome_label(code))
+                 for s in READS[read]["specs"]]
+        result = engine.regions_serve(specs, **how)
+        want = [_scalar_envelope(page) for page in result.pages]
+        assert list(result.rows()) == want
+        for spec, page, text in zip(specs, result.pages, want):
+            assert engine.region(spec, **how) == text
+            doc = json.loads(text)
+            assert doc["returned"] == page.returned == len(doc["variants"])
+            if "limit" in how:
+                assert doc["returned"] <= how["limit"]
+            else:
+                assert doc["returned"] == doc["count"]
+    whole = json.loads(engine.region(WHOLE.format(c="8")))
+    assert whole["count"] == len(ROWS)  # the reads above reach every hazard
+
+
+@pytest.mark.parametrize("limit", [1, 4, 6, 17, 40])
+def test_a_cursor_walk_renders_the_scalar_bytes_page_by_page(served, limit):
+    engine, _where = served
+    spec = WHOLE.format(c="8")
+    snap = engine.snapshots.current()
+    unpaged = json.loads(engine.region(spec))["variants"]
+    rows, cursor = [], ""
+    while cursor is not None:
+        page = engine._region_page(snap, CODE, 1, 64_000_000, None, None,
+                                   limit, cursor)
+        assert page.paged and page.returned <= limit
+        text = engine.region(spec, limit=limit, cursor=cursor)
+        assert text == _scalar_envelope(page) == page.assemble()
+        doc = json.loads(text)
+        rows.extend(doc["variants"])
+        cursor = doc["next"]
+    assert rows == unpaged and len(rows) == len(ROWS)
+
+
+@pytest.mark.parametrize("block", [2, 5, 512])
+def test_a_block_whose_pages_alternate_chromosomes(served, monkeypatch,
+                                                   block):
+    """A panel's targets come in the order drawn: consecutive pages sit on
+    different shards, and a block's rows are grouped by what they are."""
+    monkeypatch.setattr(engine_mod, "REGION_RENDER_BLOCK", block)
+    engine, _where = served
+    windows = ["1-64000000", "1000-1030", "20000-20050", "5000-6000",
+               "3000000-3000020", "1-64000000", "1050-1050"]
+    specs = [f"{chromosome_label(code)}:{w}"
+             for w in windows for code in (CODE, 1)]
+    specs += specs[::-1][:5]  # and two of one chromosome in a row
+    result = engine.regions_serve(specs, limit=9)
+    want = [_scalar_envelope(page) for page in result.pages]
+    assert [page.label for page in result.pages[:4]] == ["8", "1", "8", "1"]
+    assert list(result.rows()) == want
+    assert result.assemble() == \
+        f'{{"n":{len(specs)},"results":[' + ",".join(want) + "]}"
+    assert want == [engine.region(spec, limit=9) for spec in specs]
+    # rendered twice above (``rows()``, ``assemble()``), tallied each time
+    clock = result.clock
+    assert clock.batch_rows + clock.scalar_rows == 2 * result.returned
+    for doc, page in zip(json.loads(result.assemble())["results"],
+                         result.pages):
+        assert all(v["chromosome"] == page.label for v in doc["variants"])
 
 
 def test_bulk_allele_decode_is_segment_alleles_row_by_row(shard):

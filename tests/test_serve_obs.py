@@ -194,6 +194,81 @@ def test_regions_panel_intervals_share_the_trace_id(store, server):
 
 
 # ---------------------------------------------------------------------------
+# /stats region_panels: the routes a panel's rows took, added once a panel
+
+#: two of chromosome 8's segments, the long-allele row's window (positions
+#: 500, 501 and 600: the row at 600 keeps its host strings), two other
+#: chromosomes and one that holds nothing: one row on the scalar route
+_COUNTED_PANEL = ["8:100000-3000000", "1:1-3000000", "8:490-700",
+                  "X:1-100000", "11:1-5000"]
+
+
+class _CountingTally(dict):
+    """``region_panels`` that counts how often each key was added to."""
+
+    def __init__(self, tally):
+        super().__init__(tally)
+        self.additions = dict.fromkeys(tally, 0)
+
+    def __setitem__(self, key, value):
+        self.additions[key] += 1
+        super().__setitem__(key, value)
+
+
+@pytest.mark.parametrize("body_form,panels", [
+    ("streamed", 1), ("streamed", 3), ("buffered", 1), ("buffered", 3)])
+def test_region_panel_rows_are_tallied_by_route_once_a_panel(
+        store, body_form, panels):
+    store_dir, _truth = store
+    srv = start_server(
+        store_dir=store_dir, region_cache_size=0,
+        stream_threshold=4 if body_form == "streamed" else 1 << 30)
+    port, engine = srv.server_address[1], srv.ctx.engine
+    tally = engine.region_panels = _CountingTally(engine.region_panels)
+    try:
+        for _ in range(panels):
+            status, body, hdrs = _post(port, "/regions",
+                                       {"regions": _COUNTED_PANEL})
+            assert status == 200
+            assert (hdrs.get("Transfer-Encoding") == "chunked") \
+                == (body_form == "streamed")
+        doc = json.loads(body)
+        rows = sum(e["returned"] for e in doc["results"])
+        long_rows = [v for e in doc["results"] for v in e["variants"]
+                     if len(v["ref"]) > WIDTH]
+        assert len(long_rows) == 1 and rows > 100
+        stats = json.loads(_get(port, "/stats")[1])["region_panels"]
+    finally:
+        stop_server(srv)
+    assert stats["panels"] == panels
+    assert stats["rows_rendered"] == panels * rows
+    assert stats["rows_scalar"] == panels  # the one retained-string row
+    assert stats["rows_batched"] == panels * (rows - 1)
+    assert stats["streamed"] == (panels if body_form == "streamed" else 0)
+    # never per row, per interval or per block: once a panel each
+    assert tally.additions["rows_batched"] == panels
+    assert tally.additions["rows_scalar"] == panels
+    assert tally.additions["rows_rendered"] == panels
+
+
+def test_a_panel_of_one_retained_string_row_is_one_scalar_row(store):
+    """A body of one row in all has nothing to amortise: the scalar
+    renderer, and the tally says so."""
+    from annotatedvdb_tpu.serve import QueryEngine
+
+    store_dir, _truth = store
+    engine = QueryEngine(SnapshotManager(store_dir), region_cache_size=0)
+    result = engine.regions_serve(["8:600-600", "1:1-10"])
+    doc = json.loads(result.assemble())
+    assert [e["returned"] for e in doc["results"]] == [1, 0]
+    assert doc["results"][0]["variants"][0]["ref"] == "A" * 20
+    engine.regions_rendered(result.clock, streamed=False)
+    tally = engine.region_panels
+    assert (tally["rows_rendered"], tally["rows_batched"],
+            tally["rows_scalar"]) == (1, 0, 1)
+
+
+# ---------------------------------------------------------------------------
 # upsert: the WAL-fsync stage is attributed to the ack
 
 

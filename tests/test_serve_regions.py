@@ -22,6 +22,7 @@ import pytest
 
 from annotatedvdb_tpu.loaders.lookup import identity_hashes
 from annotatedvdb_tpu.oracle.binindex import closed_form_bin, closed_form_path
+from annotatedvdb_tpu.serve import engine as engine_mod
 from annotatedvdb_tpu.serve import (
     DeviceBreaker,
     QueryEngine,
@@ -261,7 +262,7 @@ def test_count_only_never_materializes_rows(served):
     _dir, _truth, _manager, engine = served
     result = engine.regions_serve(_specs(), limit=0)
     for page in result.pages:
-        assert page.shown == []
+        assert page.returned == 0 and page.si.shape == (0,)
     counts = [json.loads(p.assemble())["count"] for p in result.pages]
     assert counts[0] > 0 and counts[6] == 0  # loaded vs unloaded chrom
 
@@ -420,7 +421,7 @@ def test_unfiltered_limit_keeps_full_count_with_lazy_materialization(served):
     _dir, _truth, _manager, engine = served
     result = engine.regions_serve(["8:1-3000000", "1:1-3000000"], limit=3)
     for page in result.pages:
-        assert len(page.shown) == 3
+        assert page.returned == 3
         env = json.loads(page.assemble())
         assert env["returned"] == 3
         assert env["count"] > 3  # the whole chromosome matched
@@ -690,3 +691,76 @@ def test_http_regions_streaming_parity_with_buffered(served):
     finally:
         stop_server(streamed)
         stop_server(buffered)
+
+
+# ---------------------------------------------------------------------------
+# block-wise render: a body larger than one block, and its laziness
+
+#: whole chromosomes in turn (120-123 rows each), twelve times over: ~4,400
+#: rows, nine blocks of ``REGION_RENDER_BLOCK`` and every block a mix of
+#: shards and segments
+BIG_PANEL = [f"{chromosome_label(c)}:1-3000000" for c in CHROMS] * 12 \
+    + ["11:1-5000", "8:490-600"]
+
+
+def test_http_panel_of_many_blocks_streamed_buffered_and_single_reads(
+        served):
+    """De-chunked, a streamed panel of several render blocks is the
+    buffered body, and each envelope is its own single ``region()``."""
+    store_dir, _truth, _manager, engine = served
+    streamed = start_server(store_dir=store_dir, stream_threshold=4)
+    buffered = start_server(store_dir=store_dir, stream_threshold=1 << 30)
+    try:
+        payload = {"regions": BIG_PANEL, "limit": 1000}
+        st_s, body_s = _post(streamed.server_address[1], "/regions", payload)
+        st_b, body_b = _post(buffered.server_address[1], "/regions", payload)
+        assert st_s == st_b == 200
+        singles = [engine.region(spec, limit=1000) for spec in BIG_PANEL]
+        assert body_s == body_b == \
+            f'{{"n":{len(BIG_PANEL)},"results":[' + ",".join(singles) + "]}"
+        rows = sum(json.loads(t)["returned"] for t in singles)
+        assert rows > 4 * engine_mod.REGION_RENDER_BLOCK
+        for server, streams in ((streamed, 1), (buffered, 0)):
+            tally = server.ctx.engine.region_panels
+            assert tally["rows_batched"] + tally["rows_scalar"] \
+                == tally["rows_rendered"] == rows
+            assert tally["streamed"] == streams
+    finally:
+        stop_server(streamed)
+        stop_server(buffered)
+
+
+def test_first_envelope_renders_one_block_and_no_more(served, monkeypatch):
+    """``RegionsResult.rows()`` is lazy by the block: taking one envelope
+    renders the pages gathered up to ``REGION_RENDER_BLOCK`` rows — one
+    columnar call a touched (shard, segment) — and nothing after them."""
+    _dir, _truth, _manager, engine = served
+    calls = []
+    real = engine_mod._render_segment_rows
+
+    def counted(seg, j, label, width, clock):
+        calls.append((label, int(j.shape[0])))
+        return real(seg, j, label, width, clock)
+
+    monkeypatch.setattr(engine_mod, "_render_segment_rows", counted)
+    result = engine.regions_serve(BIG_PANEL, limit=1000)
+    assert not calls  # prepared, nothing rendered
+    envelopes = result.rows()
+    first = next(envelopes)
+    assert first == engine.region(BIG_PANEL[0], limit=1000)
+    sizes = [page.returned for page in result.pages]
+    held, gathered = 0, 0
+    while held < engine_mod.REGION_RENDER_BLOCK:
+        held += sizes[gathered]
+        gathered += 1
+    assert gathered < len(sizes) // 2  # a block is a small part of it
+    assert sum(n for _label, n in calls) == held
+    # one call a (shard, segment) the block touches: each chromosome has
+    # three segments (chromosome 8 a fourth, overlapping one)
+    assert len(calls) == 3 + 4 + 3
+    assert {label for label, _n in calls} == {"1", "8", "X"}
+    rest = list(envelopes)
+    assert len(rest) == len(BIG_PANEL) - 1
+    assert sum(n for _label, n in calls) == result.returned
+    assert (result.clock.batch_rows, result.clock.scalar_rows) \
+        == (result.returned, 0)

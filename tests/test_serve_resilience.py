@@ -581,6 +581,81 @@ def test_drain_mid_stream_truncates_cleanly_with_trailer():
         assert doc["returned"] == doc["count"]
 
 
+def _panel_request(specs: list) -> bytes:
+    body = json.dumps({"regions": specs}).encode()
+    return (b"POST /regions HTTP/1.1\r\nHost: t\r\n"
+            b"Content-Type: application/json\r\n"
+            + f"Content-Length: {len(body)}\r\n\r\n".encode() + body)
+
+
+#: the two bodies the streaming writer carries: one region's rows, and a
+#: panel's per-interval envelopes (60 intervals of 100 rows each)
+_STREAMS = {
+    "region": b"GET /region/8:1-100000 HTTP/1.1\r\nHost: t\r\n\r\n",
+    "regions_panel": _panel_request(
+        [f"8:{1000 + 700 * k}-{1699 + 700 * k}" for k in range(60)]),
+}
+
+
+@pytest.mark.parametrize("stream", sorted(_STREAMS))
+def test_drain_between_render_blocks_ends_the_body_whole(stream,
+                                                         monkeypatch):
+    """The drain arrives while the body's third block of rows renders (on
+    the event loop's thread, where a signal handler would set it): the
+    chunk in hand is written, and the body closes at a row — for a panel
+    an envelope — boundary, saying it is partial."""
+    from annotatedvdb_tpu.serve import engine as engine_mod
+    from annotatedvdb_tpu.serve.aio import build_aio_server
+
+    server = build_aio_server(
+        manager=StaticSnapshots(_wide_store(6000)), port=0,
+        stream_threshold=4,
+    )
+    server.drain_s = 5.0
+    blocks = []
+    real = engine_mod._render_segment_rows
+
+    def third_block_drains(seg, j, label, width, clock):
+        blocks.append(int(j.shape[0]))
+        if len(blocks) == 3:
+            server._stop.set()
+        return real(seg, j, label, width, clock)
+
+    monkeypatch.setattr(engine_mod, "_render_segment_rows",
+                        third_block_drains)
+    server.start_background()
+    received = bytearray()
+    try:
+        with socket.create_connection(
+            ("127.0.0.1", server.server_address[1]), timeout=30
+        ) as sock:
+            sock.sendall(_STREAMS[stream])
+            # to the chunked terminator (the connection is keep-alive)
+            while not received.endswith(b"\r\n0\r\n\r\n"):
+                chunk = sock.recv(1 << 16)
+                assert chunk, "connection closed before the body ended"
+                received.extend(chunk)
+    finally:
+        server.shutdown()
+        server.ctx.batcher.close()
+    head, _, rest = bytes(received).partition(b"\r\n\r\n")
+    assert b"200 OK" in head and b"chunked" in head
+    body, saw_end = _dechunk(rest)
+    assert saw_end, "chunked framing was torn (no terminating 0-chunk)"
+    doc = json.loads(body)
+    assert doc["truncated"] is True
+    assert len(blocks) == 3  # nothing rendered after the drain was seen
+    if stream == "region":
+        assert doc["returned"] == doc["count"] == 6000
+        assert 0 < len(doc["variants"]) <= sum(blocks) < 6000
+        return
+    assert doc["n"] == 60
+    assert 0 < len(doc["results"]) < 60
+    for envelope in doc["results"]:
+        assert envelope["returned"] == len(envelope["variants"]) == 100
+    assert 100 * len(doc["results"]) <= sum(blocks)
+
+
 # ---------------------------------------------------------------------------
 # /_chaos runtime arming route
 

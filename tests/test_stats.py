@@ -395,7 +395,9 @@ def test_filtered_region_bytes_unchanged(min_cadd, max_rank):
     level, leaf = _region_bin(1, 100_000)
     want = RegionPage(
         shard, "8", level, closed_form_path("8", level, leaf),
-        len(kept), 1, kept, "8:1-100000", None, paged=False,
+        len(kept), 1, np.asarray([si for si, _j in kept], np.int32),
+        np.asarray([j for _si, j in kept], np.int64), "8:1-100000", None,
+        paged=False,
     ).assemble()
     assert got == want
     # the cursor-paged walk rides the same filter path
