@@ -5,6 +5,15 @@ COPY rows) happens only here, after the device pipeline — the reference
 builds these strings inside its per-variant hot loop
 (``vcf_variant_loader.py:318-341``).
 
+The load's mapping sidecar is the exception to "strings": its lines are
+written as bytes from the chunk's columns by one native pass
+(``native/mapping.py``).  The scalar helpers here — ``decode_alleles``,
+``metaseq_ids``, ``primary_keys_from_ints``, ``bin_paths`` and
+``mapping_lines``, the definition of a line — serve the rows that pass
+cannot write (verbatim and multi-allelic ids, odd rs ids, digest keys,
+over-width or unprintable alleles), every row of a process without the
+native library, and export / ``io/pg_egress.py`` as before.
+
 Output parity targets:
 - record primary key: ``chr:pos:ref:alt[:refsnp]`` for short alleles,
   ``chr:pos:<VRS digest>[:refsnp]`` beyond 50bp combined
@@ -20,6 +29,7 @@ Output parity targets:
 
 from __future__ import annotations
 
+import json
 from functools import reduce
 
 import numpy as np
@@ -189,13 +199,16 @@ def _digest_tail(out, batch, ann, refs, alts, digester, rs_str) -> np.ndarray:
     return out
 
 
-def bin_paths(batch: VariantBatch, ann: AnnotatedBatch) -> np.ndarray:
-    """ltree paths (semantics of ``oracle.binindex.closed_form_path``).
+def bin_path_table(
+    batch: VariantBatch, ann: AnnotatedBatch
+) -> tuple[np.ndarray, np.ndarray]:
+    """``(paths, index)``: the batch's distinct ltree paths (semantics of
+    ``oracle.binindex.closed_form_path``) and each row's index into them.
 
     Position-sorted chunks touch few distinct bins (a 131k-row chunk spans
     ~dozens of 15.6kb leaves), so paths are assembled once per unique
-    (chrom, level, leaf) and scattered back — the reference exploits the
-    same locality with its current-bin cache (``bin_index.py:20-22``)."""
+    (chrom, level, leaf) — the reference exploits the same locality with
+    its current-bin cache (``bin_index.py:20-22``)."""
     level = np.asarray(ann.bin_level).astype(np.int64)
     leaf = np.asarray(ann.leaf_bin).astype(np.int64)
     chrom = np.asarray(batch.chrom, np.int64)
@@ -205,16 +218,18 @@ def bin_paths(batch: VariantBatch, ann: AnnotatedBatch) -> np.ndarray:
     )
     uniq, inverse = np.unique(key, return_inverse=True)
     if uniq.size >= level.shape[0] // 4:
-        # low locality: the column-wise assembly is cheaper than dedup
-        out = np.char.add("chr", _CHROM_LABELS[chrom])
+        # low locality: the column-wise assembly over the distinct keys
+        # is cheaper than a Python call a key
+        chrom, level, leaf = uniq >> 40, (uniq >> 32) & 0xFF, uniq & 0xFFFFFFFF
+        paths = np.char.add("chr", _CHROM_LABELS[chrom])
         for l in range(1, 14):
             g = leaf >> (13 - l)
             b = (g + 1) if l == 1 else ((g & 1) + 1)
             seg = np.where(
                 level >= l, _concat(f".L{l}.B", b.astype("U11")), ""
             )
-            out = np.char.add(out, seg)
-        return out
+            paths = np.char.add(paths, seg)
+        return paths, inverse
     from annotatedvdb_tpu.oracle.binindex import closed_form_path
 
     paths = np.array(
@@ -229,7 +244,52 @@ def bin_paths(batch: VariantBatch, ann: AnnotatedBatch) -> np.ndarray:
         ],
         dtype="U",
     )
-    return paths[inverse]
+    return paths, inverse
+
+
+def bin_paths(batch: VariantBatch, ann: AnnotatedBatch) -> np.ndarray:
+    """One ltree path a row: :func:`bin_path_table`, scattered back."""
+    paths, index = bin_path_table(batch, ann)
+    return paths[index]
+
+
+#: rows whose mapping line a load wrote, by route — tallied once a chunk
+#: (``loaders/vcf_loader.py``), read into the run record's
+#: ``execution.mapping`` (``obs/session.py``)
+mapping_stats = {"rows": 0, "native_rows": 0, "scalar_rows": 0}
+
+
+def mapping_state(base: dict | None = None) -> dict:
+    """:data:`mapping_stats` relative to ``base`` (an earlier copy)."""
+    base = base or {}
+    return {k: v - base.get(k, 0) for k, v in mapping_stats.items()}
+
+
+def mapping_lines(vids, pks, bins) -> list:
+    """The mapping sidecar's line (no newline) for each ``(variant id,
+    primary key, bin path)``: per-line JSON with a single
+    no-escaping-needed check across the id and the key (``json.dumps``
+    only for the exceptions).  THE definition of a line;
+    ``native/avdb_native.cpp`` ``avdb_mapping_lines`` writes the same
+    bytes for the rows whose three strings are functions of the chunk's
+    columns."""
+    lines = []
+    for vid, pk, b in zip(vids, pks, bins):
+        pk = str(pk)
+        probe = vid + pk
+        if (probe.isascii() and probe.isprintable()
+                and '"' not in probe and "\\" not in probe):
+            lines.append(
+                f'{{"{vid}": [{{"primary_key": "{pk}", '
+                f'"bin_index": "{b}"}}]}}'
+            )
+        else:
+            lines.append(
+                f'{{{json.dumps(vid)}: '
+                f'[{{"primary_key": {json.dumps(pk)}, '
+                f'"bin_index": {json.dumps(b)}}}]}}'
+            )
+    return lines
 
 
 def shard_strings(shard, lo: int = 0, hi: int | None = None):

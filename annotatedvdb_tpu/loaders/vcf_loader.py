@@ -24,7 +24,6 @@ byte-identical output to the serial double-buffered loop
 
 from __future__ import annotations
 
-import json
 import time
 from typing import NamedTuple
 
@@ -37,6 +36,7 @@ from annotatedvdb_tpu.io.vcf import rs_number as _io_rs_number
 from annotatedvdb_tpu.oracle.binindex import closed_form_bin
 from annotatedvdb_tpu.types import AnnotatedBatch, VariantBatch
 from annotatedvdb_tpu.models.pipeline import annotate_fn
+from annotatedvdb_tpu.native import mapping as native_mapping
 from annotatedvdb_tpu.ops.hashing import allele_hash_jit
 from annotatedvdb_tpu.ops.vrs import VrsDigestGenerator
 from annotatedvdb_tpu.store import AlgorithmLedger, VariantStore
@@ -312,7 +312,7 @@ class TpuVcfLoader:
         resume_line = self.ledger.last_checkpoint(path) if resume else 0
         if resume_line:
             self.log(f"resuming {path} after committed line {resume_line}")
-        mapping_fh = open(mapping_path, "w") if mapping_path else None
+        mapping_fh = open(mapping_path, "wb") if mapping_path else None
         import os as _os
 
         # async store pipeline (append/persist/checkpoint on the writer
@@ -1271,29 +1271,13 @@ class TpuVcfLoader:
                 (sub.ref_len > self.store.width)
                 | (sub.alt_len > self.store.width)
             )
-            # allele-string object arrays cost a PyObject per row: build
-            # them only for the paths that read them (PKs for the mapping
-            # sidecar / digest rows, genome validation, display attributes,
-            # retained long alleles).  The common insert path stores the
-            # fixed-width byte matrices directly and never needs strings.
-            need_strings = (
-                mapping_fh is not None
-                or self.genome is not None
-                or self.store_display_attributes
-                or bool(over.any())
-                or bool(np.asarray(sub_ann.needs_digest).any())
+            needs_digest = np.asarray(sub_ann.needs_digest)
+            host_fallback = np.asarray(sub_ann.host_fallback)
+            # rows that read their allele strings whatever else happens:
+            # retained long alleles, digest PKs, host-side bin recompute
+            strings_anyway = (
+                over | needs_digest.astype(bool) | host_fallback.astype(bool)
             )
-            if need_strings:
-                # vectorized view-decode; only the over-width tail needs
-                # the parser sidecar's original strings (a lazy per-row
-                # span decode, ~µs each)
-                refs, alts = egress.decode_alleles(sub)
-                refs, alts = refs.astype(object), alts.astype(object)
-                for j in np.where(over)[0]:
-                    refs[j] = chunk.refs[int(sel[j])]
-                    alts[j] = chunk.alts[int(sel[j])]
-            else:
-                refs = alts = None
             # rs numbers come pre-parsed from the reader (one int64 column);
             # the string forms are only materialized on the PK path below
             if chunk.rs_number is not None:
@@ -1305,12 +1289,69 @@ class TpuVcfLoader:
             else:  # chunks from non-reader builders: derive both per row
                 from annotatedvdb_tpu.io.vcf import rs_is_weird
 
-                strs = [chunk.ref_snp[i] for i in sel]
-                rs_sel = np.array([_rs_number(r) for r in strs], np.int64)
+                rs_strs = [chunk.ref_snp[i] for i in sel]
+                rs_sel = np.array([_rs_number(r) for r in rs_strs], np.int64)
                 rs_weird_sel = np.array(
-                    [rs_is_weird(r, n) for r, n in zip(strs, rs_sel)],
+                    [rs_is_weird(r, n) for r, n in zip(rs_strs, rs_sel)],
                     dtype=bool,
                 )
+            # a mapping line is, for most rows, a function of the columns:
+            # those rows (``fast``) are written as bytes by the native pass
+            # and never become Python strings.  Every other row — and every
+            # row of a chunk without the reader's flag columns, or of a
+            # process without the native library (``fast`` None) — takes
+            # the scalar route: the definition, and the oracle
+            fast = None
+            if mapping_fh is not None and chunk.id_verbatim is not None:
+                scalar_only = (
+                    strings_anyway
+                    | chunk.id_verbatim[sel] | chunk.is_multi_allelic[sel]
+                )
+                if rs_weird_sel is not None:
+                    scalar_only |= rs_weird_sel
+                fast = native_mapping.fast_rows(sub, ~scalar_only)
+            # allele-string object arrays cost a PyObject per row: build
+            # them only for the rows that read them — ``part`` (None =
+            # every row): the scalar route's mapping rows and
+            # ``strings_anyway``; genome validation and display attributes
+            # read every row's.  The common insert path stores the
+            # fixed-width byte matrices directly and never needs strings.
+            if (self.genome is not None or self.store_display_attributes
+                    or (mapping_fh is not None and fast is None)):
+                part = None
+            elif fast is not None:
+                part = np.flatnonzero(~fast)
+            else:
+                part = np.flatnonzero(strings_anyway)
+            if part is None:
+                strs, strs_ann = sub, sub_ann
+            else:
+                pick = lambda x: np.take(np.asarray(x), part, axis=0)
+                strs = VariantBatch(*(pick(x) for x in sub))
+                strs_ann = _slim_annotated(
+                    part.size, pick(sub_ann.bin_level),
+                    pick(sub_ann.leaf_bin), pick(needs_digest),
+                    pick(host_fallback),
+                )
+
+            def spread(values):
+                """``part``'s values at their rows of ``sub`` (object)."""
+                if part is None:
+                    return values.astype(object)
+                out = np.empty(sel.size, object)
+                out[part] = values
+                return out
+
+            if strs.n:
+                # vectorized view-decode; only the over-width tail needs
+                # the parser sidecar's original strings (a lazy per-row
+                # span decode, ~µs each)
+                refs, alts = map(spread, egress.decode_alleles(strs))
+                for j in np.where(over)[0]:
+                    refs[j] = chunk.refs[int(sel[j])]
+                    alts[j] = chunk.alts[int(sel[j])]
+            else:
+                refs = alts = None
 
         if self.genome is not None:
             # validate only the rows actually being inserted (post dedup /
@@ -1329,22 +1370,28 @@ class TpuVcfLoader:
                     + ", ".join(chunk.variant_id[int(sel[j])] for j in bad)
                 )
         with self.timer.stage("egress", items=int(sel.size)):
-            needs_digest = np.asarray(sub_ann.needs_digest)
-            # the literal-PK bulk is needed only for the mapping sidecar;
-            # digest PKs (rare tail) are always needed — the store retains
-            # them as the row's record PK
-            if mapping_fh is not None or needs_digest.any():
+            # literal ids and PKs are built for the string rows only: the
+            # scalar route's mapping lines read them, and digest PKs (rare
+            # tail) are always needed — the store retains them as the
+            # row's record PK
+            if strs.n and (mapping_fh is not None or needs_digest.any()):
                 # assembled from the reader's pre-parsed rs column; only
                 # 'weird' refsnp rows materialize their sidecar string.
                 # The literal id strings are shared with the mapping
                 # stage's vectorized vid assembly below.
-                literal = egress.metaseq_ids(sub, refs, alts)
-                pks = egress.primary_keys_from_ints(
-                    sub, sub_ann, rs_sel, self.digester, refs, alts,
-                    rs_weird=rs_weird_sel,
-                    ref_snp_at=lambda j: chunk.ref_snp[int(sel[j])],
+                at = slice(None) if part is None else part
+                src = sel[at]
+                literal = egress.metaseq_ids(strs, refs[at], alts[at])
+                pks = spread(egress.primary_keys_from_ints(
+                    strs, strs_ann, rs_sel[at], self.digester,
+                    refs[at], alts[at],
+                    rs_weird=(
+                        None if rs_weird_sel is None else rs_weird_sel[at]
+                    ),
+                    ref_snp_at=lambda j: chunk.ref_snp[int(src[j])],
                     literal=literal,
-                )
+                ))
+                literal = spread(literal)
             else:
                 pks = literal = None
             # display attributes are derivable: built here only when the
@@ -1357,12 +1404,16 @@ class TpuVcfLoader:
             # recompute
             bin_level = np.asarray(sub_ann.bin_level).copy()
             leaf_bin = np.asarray(sub_ann.leaf_bin).copy()
-            for j in np.where(np.asarray(sub_ann.host_fallback))[0]:
+            for j in np.where(host_fallback)[0]:
                 end = oracle.infer_end_location(refs[j], alts[j], int(sub.pos[j]))
                 bin_level[j], leaf_bin[j] = closed_form_bin(int(sub.pos[j]), end)
             sub_ann = sub_ann._replace(bin_level=bin_level, leaf_bin=leaf_bin)
-            bins = (
-                egress.bin_paths(sub, sub_ann) if mapping_fh is not None else None
+            # the chunk's distinct ltree paths and each row's index into
+            # them: the native pass copies a path's bytes, the scalar
+            # route reads its rows' strings
+            paths, path_idx = (
+                egress.bin_path_table(sub, sub_ann)
+                if mapping_fh is not None else (None, None)
             )
 
         payload: list[tuple[int, Segment]] | None = None
@@ -1448,44 +1499,47 @@ class TpuVcfLoader:
 
         if mapping_fh is not None:
             with self.timer.stage("mapping", items=int(sel.size)):
-                # mapping ids: rows whose ID is '.' or an rs accession use
+                # the scalar route's rows, as rows of ``sub``.
+                # Mapping ids: rows whose ID is '.' or an rs accession use
                 # the assembled chr:pos:ref:altcol form — for single-alt
                 # rows that IS the metaseq id already built vectorized;
                 # only verbatim-ID and multi-allelic rows (rare in dbSNP
                 # loads) materialize their sidecar string
-                if chunk.id_verbatim is not None:
-                    slow = (
-                        chunk.id_verbatim[sel]
-                        | chunk.is_multi_allelic[sel]
-                    )
-                    vids = literal.astype(object)
-                    for j in np.where(slow)[0]:
-                        vids[j] = chunk.variant_id[int(sel[j])]
-                    vids = vids.tolist()
-                else:
-                    vids = [chunk.variant_id[i] for i in sel]
-                # one write per chunk; per-line JSON with a single
-                # no-escaping-needed check across all three fields
-                # (json.dumps only for the exceptions)
+                scalar = (
+                    np.arange(sel.size) if fast is None
+                    else np.flatnonzero(~fast)
+                )
                 lines = []
-                bins_l = bins.tolist()
-                for j, vid in enumerate(vids):
-                    pk = str(pks[j])
-                    b = bins_l[j]
-                    probe = vid + pk
-                    if (probe.isascii() and probe.isprintable()
-                            and '"' not in probe and "\\" not in probe):
-                        lines.append(
-                            f'{{"{vid}": [{{"primary_key": "{pk}", '
-                            f'"bin_index": "{b}"}}]}}'
+                if scalar.size:
+                    at_chunk = sel[scalar]
+                    if chunk.id_verbatim is not None:
+                        slow = (
+                            chunk.id_verbatim[at_chunk]
+                            | chunk.is_multi_allelic[at_chunk]
                         )
+                        vids = literal[scalar]
+                        for j in np.where(slow)[0]:
+                            vids[j] = chunk.variant_id[int(at_chunk[j])]
+                        vids = vids.tolist()
                     else:
-                        lines.append(
-                            f'{{{json.dumps(vid)}: '
-                            f'[{{"primary_key": {json.dumps(pk)}, '
-                            f'"bin_index": {json.dumps(b)}}}]}}'
-                        )
-                mapping_fh.write("\n".join(lines) + "\n")
+                        vids = [chunk.variant_id[i] for i in at_chunk]
+                    lines = egress.mapping_lines(
+                        vids, pks[scalar], paths[path_idx[scalar]].tolist()
+                    )
+                # one write per chunk
+                if fast is None:
+                    mapping_fh.write(
+                        ("\n".join(lines) + "\n").encode("ascii")
+                    )
+                else:
+                    mapping_fh.write(native_mapping.mapping_lines(
+                        sub, rs_sel, path_idx, paths.tolist(), fast, lines
+                    ))
+                egress.mapping_stats["rows"] += int(sel.size)
+                egress.mapping_stats["scalar_rows"] += len(lines)
+                egress.mapping_stats["native_rows"] += (
+                    int(sel.size) - len(lines)
+                )
         return payload
 
 

@@ -120,6 +120,21 @@ def load():
             c.c_int32, c.c_int32,                              # identity_only, want_packed
             c.c_void_p, c.c_void_p, c.c_void_p,               # counters, consumed, need_more
         ]
+        rows = [
+            c.c_int64, c.c_int32,                              # n, width
+            c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p,   # chrom,pos,ref,alt
+            c.c_void_p, c.c_void_p,                            # rlen, alen
+        ]
+        lib.avdb_mapping_fast_rows.restype = c.c_int64
+        lib.avdb_mapping_fast_rows.argtypes = rows + [c.c_void_p]  # fast
+        lib.avdb_mapping_lines.restype = c.c_int64
+        lib.avdb_mapping_lines.argtypes = rows + [
+            c.c_void_p,                                        # rs_number
+            c.c_void_p, c.c_void_p, c.c_void_p, c.c_int64,    # path idx, bytes, off, n
+            c.c_void_p,                                        # fast
+            c.c_void_p, c.c_void_p,                            # slow bytes, end
+            c.c_void_p, c.c_int64,                            # out, cap
+        ]
         _lib = lib
         return _lib
 

@@ -53,16 +53,19 @@ def config_hash(params: dict) -> str:
 
 
 def execution_facts(probe_base: dict | None = None,
-                    sidecar_base: dict | None = None) -> dict:
+                    sidecar_base: dict | None = None,
+                    mapping_base: dict | None = None) -> dict:
     """Where and how this process ran its load: the device as JAX reports
     it, the annotate kernel that was selected, the transport verdicts, the
     native tokenizer's state, the device membership probes since
-    ``probe_base`` and the sidecar writer's rows since ``sidecar_base`` —
+    ``probe_base``, the sidecar writer's rows since ``sidecar_base`` and
+    the mapping file's rows since ``mapping_base`` —
     the run record's ``execution`` block, so a reader can tell a chip run
     from a CPU run, see which device paths a load reached, and take
     compile seconds apart from the load itself.
     Reports only: nothing here selects, probes or builds."""
     from annotatedvdb_tpu import native
+    from annotatedvdb_tpu.io.egress import mapping_state
     from annotatedvdb_tpu.models.pipeline import selected_kernel
     from annotatedvdb_tpu.ops.pack import transport_state
     from annotatedvdb_tpu.store.variant_store import (
@@ -89,6 +92,10 @@ def execution_facts(probe_base: dict | None = None,
         # rows of the segments this load wrote, rows their sidecar walk
         # looked at, lines it wrote (store.variant_store.sidecar_lines)
         "sidecar": sidecar_state(sidecar_base),
+        # rows whose mapping line this load wrote, by route: as bytes from
+        # the chunk's columns (native/mapping.py), or through the scalar
+        # strings (io/egress.py mapping_lines); tallied once a chunk
+        "mapping": mapping_state(mapping_base),
     }
 
 
@@ -232,6 +239,9 @@ class ObsSession:
 
         self._probe_base = dict(probe_stats)
         self._sidecar_base = dict(sidecar_stats)
+        from annotatedvdb_tpu.io.egress import mapping_stats
+
+        self._mapping_base = dict(mapping_stats)
 
     @classmethod
     def from_args(cls, script: str, args, params: dict) -> "ObsSession":
@@ -328,7 +338,8 @@ class ObsSession:
                     # an aborted load may have died OF the backend: its
                     # record must still land, without the block
                     execution=(
-                        execution_facts(self._probe_base, self._sidecar_base)
+                        execution_facts(self._probe_base, self._sidecar_base,
+                                        self._mapping_base)
                         if error is None else None
                     ),
                 ))

@@ -214,6 +214,65 @@ inline int64_t rs_number_of(const Span& id, const Span& info, bool has_info,
     return result;
 }
 
+
+// bytes a mapping line may carry verbatim inside a JSON string: printable
+// ASCII less '"' and '\\' — the loader's no-escaping-needed check
+// (isascii + isprintable + the two quote tests), as a table
+struct PlainLut {
+    uint8_t ok[256];
+    PlainLut() {
+        memset(ok, 0, sizeof(ok));
+        for (int c = 0x20; c < 0x7f; ++c) ok[c] = 1;
+        ok[static_cast<uint8_t>('"')] = 0;
+        ok[static_cast<uint8_t>('\\')] = 0;
+    }
+};
+const PlainLut kPlain;
+
+// an allele cell the scalar route would read as the same string: len plain
+// bytes, then zero padding to the width (decode_alleles reads the cell up
+// to its last non-zero byte, not up to len)
+inline bool plain_allele(const uint8_t* s, int len, int width) {
+    uint8_t ok = 1, pad = 0;
+    for (int i = 0; i < len; ++i) ok &= kPlain.ok[s[i]];
+    for (int i = len; i < width; ++i) pad |= s[i];
+    return ok != 0 && pad == 0;
+}
+
+inline uint8_t* put_bytes(uint8_t* p, const void* s, int64_t len) {
+    memcpy(p, s, static_cast<size_t>(len));
+    return p + len;
+}
+
+inline uint8_t* put_uint(uint8_t* p, uint64_t v) {
+    uint8_t tmp[20];
+    int k = 0;
+    do {
+        tmp[k++] = static_cast<uint8_t>('0' + v % 10);
+        v /= 10;
+    } while (v);
+    while (k) *p++ = tmp[--k];
+    return p;
+}
+
+// chr:pos:ref:alt — the metaseq id of io/egress.py metaseq_ids, for a row
+// that passed avdb_mapping_fast_rows (code 1..25, pos >= 0, alleles within
+// the width)
+inline uint8_t* put_metaseq(uint8_t* p, int8_t code, int32_t pos,
+                            const uint8_t* ref, int32_t rl,
+                            const uint8_t* alt, int32_t al) {
+    if (code <= 22) p = put_uint(p, static_cast<uint64_t>(code));
+    else *p++ = static_cast<uint8_t>("XYM"[code - 23]);
+    *p++ = ':';
+    p = put_uint(p, static_cast<uint64_t>(pos));
+    *p++ = ':';
+    p = put_bytes(p, ref, rl);
+    *p++ = ':';
+    return put_bytes(p, alt, al);
+}
+
+#define AVDB_LIT(p, s) put_bytes((p), (s), sizeof(s) - 1)
+
 }  // namespace
 
 extern "C" {
@@ -453,6 +512,101 @@ int64_t avdb_parse_vcf_chunk(
     counters[4] = line - line_base;
     *consumed = offset;
     return rows;
+}
+
+// ---- the load's mapping sidecar, written from the chunk's columns ----
+//
+// One line a row, exactly the loader's (loaders/vcf_loader.py, the scalar
+// route of io/egress.py mapping_lines):
+//   {"<id>": [{"primary_key": "<id>[:rs<N>]", "bin_index": "<path>"}]}\n
+// with <id> = chr:pos:ref:alt.  Rows whose line is not that function of
+// the columns (verbatim ids, multi-allelic sites, digest keys, ...) are
+// the caller's: it renders them and hands their lines in.
+
+// Clears fast[i] for every row this file cannot write from the columns
+// alone: a chromosome code outside 1..25, a negative position, an allele
+// longer than the width (or of negative length), an allele byte that a
+// JSON string cannot carry verbatim, or a cell not zero-padded past its
+// length.  The caller has already cleared the
+// rows its flag columns rule out.  Returns the rows still set.
+int64_t avdb_mapping_fast_rows(
+    int64_t n, int32_t width,
+    const int8_t* chrom, const int32_t* pos,
+    const uint8_t* ref, const uint8_t* alt,
+    const int32_t* ref_len, const int32_t* alt_len,
+    uint8_t* fast) {
+    int64_t kept = 0;
+    for (int64_t i = 0; i < n; ++i) {
+        if (!fast[i]) continue;
+        int32_t rl = ref_len[i], al = alt_len[i];
+        bool ok = chrom[i] >= 1 && chrom[i] <= 25 && pos[i] >= 0
+            && rl >= 0 && rl <= width && al >= 0 && al <= width
+            && plain_allele(ref + i * width, rl, width)
+            && plain_allele(alt + i * width, al, width);
+        fast[i] = ok ? 1 : 0;
+        kept += ok;
+    }
+    return kept;
+}
+
+// Writes the n rows' lines into out in row order: a fast row's from the
+// columns, any other row's copied from the caller's rendered lines
+// (slow_bytes, cut at slow_end[k] for the k-th row that is not fast, each
+// with its newline).  path_idx[i] indexes the chunk's table of distinct
+// bin paths: entry t is path_bytes[path_off[t] .. path_off[t + 1]).
+// Returns the bytes written, -1 if out_cap would not hold them (nothing
+// past out_cap is touched), or -2 if a row marked fast is not one
+// avdb_mapping_fast_rows would keep or indexes no path of the table.
+int64_t avdb_mapping_lines(
+    int64_t n, int32_t width,
+    const int8_t* chrom, const int32_t* pos,
+    const uint8_t* ref, const uint8_t* alt,
+    const int32_t* ref_len, const int32_t* alt_len,
+    const int64_t* rs_number,
+    const int64_t* path_idx, const uint8_t* path_bytes,
+    const int64_t* path_off, int64_t n_paths,
+    const uint8_t* fast,
+    const uint8_t* slow_bytes, const int64_t* slow_end,
+    uint8_t* out, int64_t out_cap) {
+    uint8_t* p = out;
+    uint8_t* const cap = out + out_cap;
+    int64_t k = 0, slow_at = 0;
+    for (int64_t i = 0; i < n; ++i) {
+        if (!fast[i]) {
+            int64_t len = slow_end[k++] - slow_at;
+            if (len > cap - p) return -1;
+            p = put_bytes(p, slow_bytes + slow_at, len);
+            slow_at += len;
+            continue;
+        }
+        int32_t rl = ref_len[i], al = alt_len[i];
+        if (chrom[i] < 1 || chrom[i] > 25 || pos[i] < 0 || rl < 0
+            || rl > width || al < 0 || al > width || path_idx[i] < 0
+            || path_idx[i] >= n_paths)
+            return -2;
+        int64_t at = path_off[path_idx[i]];
+        int64_t path_len = path_off[path_idx[i] + 1] - at;
+        // 45 bytes of punctuation, two ids of at most 2+10+3 bytes beside
+        // their alleles, ":rs" and at most 19 digits
+        if (45 + 2 * (15 + int64_t(rl) + al) + 22 + path_len > cap - p)
+            return -1;
+        const uint8_t* r = ref + i * width;
+        const uint8_t* a = alt + i * width;
+        p = AVDB_LIT(p, "{\"");
+        const uint8_t* id = p;
+        p = put_metaseq(p, chrom[i], pos[i], r, rl, a, al);
+        int64_t id_len = p - id;
+        p = AVDB_LIT(p, "\": [{\"primary_key\": \"");
+        p = put_bytes(p, id, id_len);
+        if (rs_number[i] >= 0) {
+            p = AVDB_LIT(p, ":rs");
+            p = put_uint(p, static_cast<uint64_t>(rs_number[i]));
+        }
+        p = AVDB_LIT(p, "\", \"bin_index\": \"");
+        p = put_bytes(p, path_bytes + at, path_len);
+        p = AVDB_LIT(p, "\"}]}\n");
+    }
+    return p - out;
 }
 
 }  // extern "C"
