@@ -158,6 +158,8 @@ def _render_blackbox(meta: dict, events: list, limit: int) -> None:
     for e in requests[-limit:]:
         stages = e.get("stages") or {}
         breakdown = " ".join(f"{k}={v}ms" for k, v in stages.items())
+        if e.get("stages_cut"):
+            breakdown += f" (cut to fit: {','.join(e['stages_cut'])})"
         print(f"    {_fmt_t(e['t'])}  {e.get('kind', '?'):<7} "
               f"{e.get('status', 0):<4} {e.get('ms', '?')}ms  "
               f"trace={e.get('trace', '-')}  {breakdown}",

@@ -50,6 +50,7 @@ import threading
 import time
 import zlib
 
+from annotatedvdb_tpu.obs.reqtrace import LOOP_STAGES
 from annotatedvdb_tpu.utils import faults
 
 MAGIC = b"AVDBFLT1"
@@ -254,8 +255,14 @@ class FlightRecorder:
             }
             payload = json.dumps(doc, separators=(",", ":")).encode()
             if len(payload) > PAYLOAD_MAX:
-                # trimmed to fit the fixed slot: stages drop before the
-                # headline does
+                # trimmed to fit the fixed slot: the loop's three stages
+                # go first (and the summary says so), then every stage —
+                # stages drop before the headline does
+                doc["st"] = {s: ms for s, ms in doc["st"].items()
+                             if s not in LOOP_STAGES}
+                doc["cut"] = 1
+                payload = json.dumps(doc, separators=(",", ":")).encode()
+            if len(payload) > PAYLOAD_MAX:
                 doc.pop("st", None)
                 payload = json.dumps(doc, separators=(",", ":")).encode()
             self._write(KIND_REQUEST, status, trace_id, payload, t=t)
@@ -345,6 +352,8 @@ def decode_ring(path: str) -> dict:
             ev["ms"] = doc.get("ms")
             if "st" in doc:
                 ev["stages"] = doc["st"]
+            if doc.get("cut"):
+                ev["stages_cut"] = list(LOOP_STAGES)
         else:
             ev["name"] = nb.decode("utf-8", "replace")
             ev["detail"] = doc.get("d", "")

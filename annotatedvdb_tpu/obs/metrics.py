@@ -183,10 +183,11 @@ class Histogram:
         self._count = 0
 
     def observe(self, v: float) -> None:
-        i = bisect.bisect_left(self.edges, float(v))
+        v = float(v)
+        i = bisect.bisect_left(self.edges, v)
         with self._lock:
             self._counts[i] += 1
-            self._sum += float(v)
+            self._sum += v
             self._count += 1
 
     def snapshot(self) -> dict:

@@ -5,8 +5,9 @@ mesh-sharded workers); when p99 moves, aggregate counters say THAT it
 moved, never WHY.  This module is the Dapper-shaped answer: every request
 carries a trace id (minted at admission or adopted from the client's
 ``traceparent``/``X-Request-Id`` — see ``serve.http.resolve_trace_id``),
-and the stages it passes through — admission wait, batcher queue wait,
-device execution, render, the WAL fsync of an upsert ack — each record
+and the stages it passes through — its read off the socket, admission
+wait, batcher queue wait, device execution, render, the WAL fsync of an
+upsert ack, the wake of its coroutine, the write of its reply — each record
 one span against that id.  A span is what a span is: name, start, end
 (``perf_counter_ns``), the span that caused it (``parent``; None for a
 stage of the request itself) and, through the trace it sits on, the
@@ -20,7 +21,10 @@ A stage that a thread runs — ``device``, ``render``, ``wal_fsync``
 the request stages on the host lines of the same file as the device's
 operations.  ``admission`` and ``queue`` are waits measured across threads
 (arrival -> executor slot, enqueue -> drain), not scopes any one thread
-sits in: they stay recorded spans, with a start and an end.
+sits in: they stay recorded spans, with a start and an end.  So do
+:data:`LOOP_STAGES` — ``read``, ``wake``, ``reply`` — which run across the
+event loop's callbacks; what the loop's thread itself was doing meanwhile
+is ``avdb.loop.wait`` / ``avdb.loop.run`` (``obs/loopclock.py``).
 
 Four export surfaces, one recording path:
 
@@ -68,15 +72,26 @@ import time
 
 from annotatedvdb_tpu.utils.profiling import annotation
 
+#: the three stages that close a request's life on the event loop
+#: (``serve/aio.py``): read = the request's head is complete -> its item is
+#: handed back (head parse, the body's socket read, admission, the executor
+#: submit or the batcher enqueue); wake = the work finished (the drain
+#: resolved the futures, the executor half returned) -> the request's
+#: coroutine resumes; reply = the coroutine resumed -> the bytes are handed
+#: to the transport.  The flight summary drops these three first when a
+#: slot has no room (``obs/flight.py``)
+LOOP_STAGES = ("read", "wake", "reply")
+
 #: the fixed stage vocabulary (`avdb_stage_seconds{stage=...}` series):
-#: admission = arrival -> handed to execution (preflight/body read/pool
-#: queue), queue = batcher queue wait, device = engine execution of the
-#: (micro)batch, render = response assembly after the engine answered,
-#: wal_fsync = the durable-ack barrier of an upsert, background = one
-#: background-writer span (flush / compaction group / daemon pass),
-#: total = whole request
-STAGES = ("admission", "queue", "device", "render", "wal_fsync",
-          "background", "total")
+#: admission = arrival -> handed to execution (the executor pool's queue;
+#: exec kinds only: a point read's is inside ``read``), queue = batcher
+#: queue wait, device = engine execution of the (micro)batch, render =
+#: response assembly after the engine answered, wal_fsync = the
+#: durable-ack barrier of an upsert, background = one background-writer
+#: span (flush / compaction group / daemon pass), total = whole request,
+#: to the write of its reply
+STAGES = ("read", "admission", "queue", "device", "render", "wal_fsync",
+          "wake", "reply", "background", "total")
 
 #: per-stage latency histogram edges (seconds): sub-100µs queue waits up
 #: to multi-second background passes
@@ -141,7 +156,8 @@ class RequestTrace:
     concurrently); it becomes an immutable ring record at
     :meth:`TraceRecorder.finish`."""
 
-    __slots__ = ("trace_id", "kind", "t0_ns", "spans", "_subspans")
+    __slots__ = ("trace_id", "kind", "t0_ns", "spans", "_subspans",
+                 "mark_ns", "status")
 
     #: sub-span cap per request: a 4096-interval panel must not grow an
     #: unbounded span list (stages — parent None — are never dropped)
@@ -155,6 +171,13 @@ class RequestTrace:
         #: None = a stage of the request, else the enclosing stage's name
         self.spans: list = []
         self._subspans = 0
+        #: the stamp one wait hands to the next across threads and
+        #: callbacks: set where the work finished (``wake`` starts), moved
+        #: on where the coroutine resumed (``reply`` starts); 0 = never
+        self.mark_ns = 0
+        #: the reply's status, noted where its bytes are built; the trace
+        #: is sealed with it once they are written
+        self.status = 0
 
     def record(self, name: str, start_ns: int, end_ns: int,
                parent: str | None = None) -> None:
@@ -168,7 +191,7 @@ class RequestTrace:
             if self._subspans >= self.MAX_SPANS:
                 return
             self._subspans += 1
-        self.spans.append((name, int(start_ns), int(end_ns), parent))
+        self.spans.append((name, start_ns, end_ns, parent))
 
     def since(self, name: str, start_s: float) -> None:
         """A stage that began at ``time.perf_counter()`` reading
@@ -181,8 +204,12 @@ class RequestTrace:
         """Take over the spans of a stage this request shared with others
         (a microbatch's one engine call serves every co-batched request:
         the continuous-batching reality)."""
-        for span in shared.spans:
-            self.record(*span)
+        for span in shared.spans:  # finished spans: immutable, shared
+            if span[3] is not None:
+                if self._subspans >= self.MAX_SPANS:
+                    continue
+                self._subspans += 1
+            self.spans.append(span)
 
     @property
     def stages(self) -> list:
@@ -344,6 +371,10 @@ class TraceRecorder:
         )
         self.log = log if log is not None else (lambda msg: None)
         self.flight = flight
+        #: the serving loop's clock (``obs/loopclock.py``; the server sets
+        #: it): a slow request's line says how long the loop's longest
+        #: turn was and when it ended
+        self.loop_clock = None
         #: the lock-free ring: slot reservation through the (GIL-atomic)
         #: counter, publication through one list-item assignment of an
         #: immutable tuple — concurrent writers never wait on each other
@@ -376,20 +407,23 @@ class TraceRecorder:
             return
         now_ns = time.perf_counter_ns()
         total = (now_ns - trace.t0_ns) / 1e9
-        stages = tuple(trace.stages)
-        record = (
+        hist = self._hist
+        stages = []
+        for name, start_ns, end_ns, parent in trace.spans:
+            if parent is None:
+                seconds = (end_ns - start_ns) / 1e9
+                stages.append((name, seconds))
+                h = hist.get(name)
+                if h is not None:
+                    h.observe(seconds)
+        stages = tuple(stages)
+        if hist:
+            hist["total"].observe(total)
+        self._ring[next(self._seq) % self.slots] = (
             trace.trace_id, trace.kind, int(status),
             trace.t0_ns, total,
             stages, tuple(trace.spans),
         )
-        self._ring[next(self._seq) % self.slots] = record
-        hist = self._hist
-        if hist:
-            hist["total"].observe(total)
-            for stage, seconds in stages:
-                h = hist.get(stage)
-                if h is not None:
-                    h.observe(seconds)
         if self.slow_s and total >= self.slow_s:
             if self._m_slow is not None:
                 self._m_slow.inc()
@@ -409,6 +443,8 @@ class TraceRecorder:
                 f"status={status} total={total * 1000:.2f}ms {breakdown}"
                 + (f" spans={len(trace.spans) - len(stages)} [{detail}]"
                    if subs else "")
+                + (f" {self.loop_clock.max_turn_note()}"
+                   if self.loop_clock is not None else "")
             )
         if self.flight is not None:
             try:

@@ -52,6 +52,7 @@ import contextlib
 import json
 import mmap
 import os
+import selectors
 import struct
 import threading
 import time
@@ -65,6 +66,7 @@ from annotatedvdb_tpu.export.stream import (
     stream_payload,
 )
 from annotatedvdb_tpu.obs import reqtrace as reqtrace_mod
+from annotatedvdb_tpu.obs.loopclock import PARTS, LoopClock, TimedSelector
 from annotatedvdb_tpu.obs.metrics import MetricsRegistry
 from annotatedvdb_tpu.serve.batcher import QueueFull, batch_annotation
 from annotatedvdb_tpu.serve.engine import (
@@ -255,6 +257,9 @@ class LoopBatcher:
         self._batches = 0
         self._queries = 0
         self._max_depth = 0
+        #: ns the loop's thread spent in ``_drain`` (the loop clock's
+        #: ``drain_s``): two clock reads a drain
+        self.drain_ns = 0
         if registry is not None:
             from annotatedvdb_tpu.serve.batcher import BATCH_FILL_EDGES
 
@@ -325,6 +330,13 @@ class LoopBatcher:
         return fut
 
     def _drain(self) -> None:
+        t0 = time.perf_counter_ns()
+        try:
+            self._drain_pending()
+        finally:
+            self.drain_ns += time.perf_counter_ns() - t0
+
+    def _drain_pending(self) -> None:
         self._drain_soon = False
         if self._timer is not None:
             self._timer.cancel()
@@ -389,7 +401,12 @@ class LoopBatcher:
                 if not fut.done():
                     fut.set_exception(exc)
             return
-        for (fut, _q, _p, _d, _t, _e), result in zip(batch, results):
+        # the work finished: one clock read a drain, shared by its requests
+        # as the start of each one's ``wake``
+        t_done = time.perf_counter_ns()
+        for (fut, _q, _p, _d, trace, _e), result in zip(batch, results):
+            if trace is not None:
+                trace.mark_ns = t_done
             if not fut.done():
                 fut.set_result(result)
         self._batches += 1
@@ -406,8 +423,7 @@ class LoopBatcher:
             "batch_fill": round(
                 self._queries / (self._batches * self.max_batch), 4
             ) if self._batches else 0.0,
-            "queue": {"items": self._queries, "producer_block_s": 0.0,
-                      "consumer_wait_s": 0.0, "max_depth": self._max_depth},
+            "queue": {"items": self._queries, "max_depth": self._max_depth},
         }
 
     def close(self, timeout: float = 5.0) -> None:
@@ -643,6 +659,31 @@ class AioServer:
         # bound once: per-request getattr on the manager is hot-path waste
         self._refresh_due = getattr(ctx.manager, "refresh_due", None)
         self._refresh_inflight = False
+        #: the loop's own account (``obs/loopclock.py``): every turn split
+        #: into waiting and running, always on; ``/stats`` ``loop``, the
+        #: ``avdb_loop_*`` series and a slow request's line read it
+        self.loop_clock = ctx.loop_clock = ctx.reqtrace.loop_clock = \
+            LoopClock()
+        reg = ctx.registry
+        self._m_loop = {
+            "turns": reg.counter(
+                "avdb_loop_turns_total",
+                "turns of the serving event loop (select, then callbacks)"),
+            "max_turn": reg.gauge(
+                "avdb_loop_max_turn_seconds",
+                "longest busy stretch of the serving event loop since start"),
+            **{f"{state}_s": reg.counter(
+                "avdb_loop_seconds_total",
+                "serving event loop time: wait = inside select, busy = "
+                "running callbacks", {"state": state})
+               for state in ("wait", "busy")},
+            **{f"{part}_s": reg.counter(
+                "avdb_loop_busy_seconds_total",
+                "serving event loop busy time by what ran (other = "
+                "asyncio's machinery and the socket callbacks)",
+                {"part": part})
+               for part in (*PARTS, "other")},
+        }
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -651,9 +692,28 @@ class AioServer:
         main thread, SIGTERM/SIGINT) — then drain gracefully.  A bind
         failure raises here (``OSError``, e.g. EADDRINUSE) rather than
         leaving a zombie loop."""
-        asyncio.run(self._main())
+        asyncio.run(self._main(), loop_factory=self._new_loop)
         if self._startup_error is not None:
             raise self._startup_error
+
+    def _new_loop(self) -> asyncio.AbstractEventLoop:
+        """The serving loop: a selector loop whose ``select`` is timed
+        into :attr:`loop_clock` (the constructor's own seam; nothing of
+        the loop is patched)."""
+        return asyncio.SelectorEventLoop(
+            TimedSelector(selectors.DefaultSelector(), self.loop_clock)
+        )
+
+    def _publish_loop_metrics(self) -> None:
+        """Set the ``avdb_loop_*`` series from the clock — at a scrape and
+        on the maintenance tick (a fleet sibling's snapshot), on the
+        loop's thread; nothing is incremented per turn."""
+        stats = self.loop_clock.stats(self.ctx.batcher.drain_ns)
+        for key, metric in self._m_loop.items():
+            if key == "max_turn":
+                metric.set(stats["max_turn_ms_since_start"] / 1000.0)
+            else:
+                metric.inc(max(stats[key] - metric.value, 0.0))
 
     def start_background(self, timeout: float = 30.0) -> None:
         """Run the loop on a daemon thread; returns once the socket is
@@ -761,6 +821,7 @@ class AioServer:
         cannot keep looking healthy from a helper thread."""
         if self._stop is not None and self._stop.is_set():
             return
+        t_tick = time.perf_counter_ns()
         try:
             try:
                 # crash point: fires per maintenance tick; delay = a
@@ -797,10 +858,13 @@ class AioServer:
                 self._maybe_flush_flight()
             with contextlib.suppress(Exception):
                 self._maybe_tick_health()
+            with contextlib.suppress(Exception):
+                self._publish_loop_metrics()
         finally:
             # the next tick is unconditional: whatever one pass hit, the
             # heartbeat/brownout machinery must keep running
             self._loop.call_later(self.TICK_S, self._tick)
+            self.loop_clock.tick_ns += time.perf_counter_ns() - t_tick
 
     #: seconds between fleet-telemetry snapshot publishes
     TELEMETRY_S = 1.0
@@ -911,7 +975,11 @@ class AioServer:
                 except asyncio.LimitOverrunError:
                     await out_q.put(_error(431, "request head too large"))
                     break
-                item, keep = await self._route(reader, writer, head)
+                # the head is complete: the request's ``read`` starts, and
+                # with it the loop clock's ``read`` section
+                item, keep = await self._route(
+                    reader, writer, head, time.perf_counter_ns()
+                )
                 if item is not None:
                     await out_q.put(item)
                 if not keep:
@@ -957,9 +1025,16 @@ class AioServer:
         ~hundreds of futures at once; their bytes should leave in one
         syscall, not hundreds).  A dead client stops the writes but NOT
         the accounting: remaining items are still awaited (admission
-        slots release, executor work completes)."""
+        slots release, executor work completes).
+
+        A request's trace is sealed HERE, after the write that hands its
+        bytes to the transport, so that ``reply`` (and ``total``) end
+        there: ``_emit`` collects the traces of one coalesced write in
+        ``sealing`` and :meth:`_write_out` finishes them together — also
+        when the client is gone, with whatever status their bytes had."""
         dead = False
         out = bytearray()
+        sealing: list = []
         stop = False
         while not stop:
             item = await q.get()
@@ -979,10 +1054,9 @@ class AioServer:
                     if dead:
                         await self._settle(it)
                         continue
-                    await self._emit(writer, it, out)
+                    await self._emit(writer, it, out, sealing)
                     if len(out) > _WRITE_HIGH_WATER:
-                        writer.write(bytes(out))
-                        out.clear()
+                        self._write_out(writer, out, sealing)
                         await writer.drain()
                 except (ConnectionResetError, BrokenPipeError, OSError):
                     # the item whose _emit raised has already settled its
@@ -990,7 +1064,9 @@ class AioServer:
                     # finally) — only LATER items go the settle path
                     dead = True
                     out.clear()
+                    self._seal(sealing)
                 except asyncio.CancelledError:
+                    self._seal(sealing)
                     # cancelled (watchdog/shutdown) with items in hand:
                     # they left the queue, so the handler's teardown
                     # drain cannot see them — settle the LATER ones here
@@ -1000,23 +1076,57 @@ class AioServer:
                         if isinstance(later, tuple) and later[0] == "exec":
                             self._settle_when_done(later[1])
                     raise
-            if out and not dead:
+            if (out or sealing) and not dead:
                 try:
-                    writer.write(bytes(out))
-                    out.clear()
+                    self._write_out(writer, out, sealing)
                     if (writer.transport.get_write_buffer_size()
                             > _WRITE_HIGH_WATER):
                         await writer.drain()
                 except (ConnectionResetError, BrokenPipeError, OSError):
                     dead = True
                     out.clear()
+                    self._seal(sealing)
         if not dead:
             with contextlib.suppress(Exception):
                 await writer.drain()
 
-    async def _emit(self, writer, item, out: bytearray) -> None:
+    def _write_out(self, writer, out: bytearray, sealing: list) -> None:
+        """One coalesced write: hand ``out`` to the transport, then seal
+        the traces whose bytes it held — ``reply`` ends when
+        ``writer.write`` has returned (a write that raises seals them all
+        the same: the attempt is where the reply ended)."""
+        t0 = time.perf_counter_ns()
+        try:
+            if out:
+                writer.write(bytes(out))
+                out.clear()
+        finally:
+            t_written = time.perf_counter_ns()
+            self.loop_clock.reply_ns += t_written - t0
+            self.loop_clock.write_ns += t_written - t0
+            self._seal(sealing, t_written)
+
+    def _seal(self, sealing: list, t_written: int | None = None) -> None:
+        """Finish the collected traces (and empty the list): ``reply`` =
+        the coroutine resumed -> ``t_written``, the status the one noted
+        where the bytes were built."""
+        if not sealing:
+            return
+        if t_written is None:
+            t_written = time.perf_counter_ns()
+        finish = self.ctx.reqtrace.finish
+        for trace in sealing:
+            if trace.mark_ns:
+                trace.record("reply", trace.mark_ns, t_written)
+            finish(trace, trace.status)
+        sealing.clear()
+
+    async def _emit(self, writer, item, out: bytearray,
+                    sealing: list) -> None:
         """Append one response's bytes to the coalescing buffer (or, for
-        a streamed region, flush the buffer and stream directly)."""
+        a streamed region, flush the buffer and stream directly).  A
+        request's trace goes to ``sealing``: the caller finishes it once
+        the buffer is written."""
         if isinstance(item, bytes):
             out += item
             return
@@ -1025,6 +1135,8 @@ class AioServer:
             _k, fut, t0, vid, generation, tid, trace = item
             out += await self._finish_point(fut, t0, vid, generation,
                                             tid, trace)
+            if trace is not None:
+                sealing.append(trace)
             return
         # ("exec", future, kind, t0, tid, trace): buffered bytes or a
         # stream marker
@@ -1037,23 +1149,42 @@ class AioServer:
             # hold its admission slot forever — settle it when it lands
             self._settle_when_done(fut)
             raise
+        t_wake = time.perf_counter_ns()
+        if trace is not None:
+            self._woke(trace, t_wake)
         if isinstance(result, bytes):
-            # the exec trace seals HERE, centrally: the bytes already
-            # know their status, so the work functions never fork on it
-            self.ctx.reqtrace.finish(trace, _status_of(result))
+            # the bytes already know their status, so the work functions
+            # never fork on it; the trace seals with it after the write
             out += _add_trace(result, tid)
+            if trace is not None:
+                trace.status = _status_of(result)
+                sealing.append(trace)
+            self.loop_clock.reply_ns += time.perf_counter_ns() - t_wake
             return
         page = result[1]  # RegionPage or RegionsResult: same stream surface
         try:
-            if out:  # ordering: everything before the stream goes first
-                writer.write(bytes(out))
-                out.clear()
+            self.loop_clock.reply_ns += time.perf_counter_ns() - t_wake
+            # ordering: everything before the stream goes first
+            self._write_out(writer, out, sealing)
             await self._stream_region(writer, page, tid)
             self.ctx.observe(qkind, time.perf_counter() - t0,
                              rows=page.returned)
-            self.ctx.reqtrace.finish(trace, 200)
+            if trace is not None:
+                # a streamed body's reply ends with its last chunk written
+                # and drained
+                trace.status = 200
+                self._seal([trace])
         finally:
             self.ctx.release()
+
+    @staticmethod
+    def _woke(trace, t_wake: int) -> None:
+        """The request's coroutine resumed after ``await fut``: ``wake``
+        = the work finished (``mark_ns``: the drain's one clock read, or
+        the executor half's return) -> now; ``reply`` starts here."""
+        if trace.mark_ns:
+            trace.record("wake", trace.mark_ns, t_wake)
+        trace.mark_ns = t_wake
 
     async def _settle(self, item) -> None:
         """Account for an item that will never reach the wire (the client
@@ -1088,6 +1219,9 @@ class AioServer:
 
     async def _finish_point(self, fut, t0, vid: str, generation: int,
                             tid: str | None = None, trace=None) -> bytes:
+        """The reply's bytes of one point read, once its drain answered.
+        The trace is not sealed here: its ``status`` is noted, and the
+        writer finishes it after the write (``_write_out``)."""
         ctx = self.ctx
         try:
             # no wait_for wrapper (it costs a Task + timer per request):
@@ -1097,28 +1231,37 @@ class AioServer:
             record = await fut
         except DeadlineExceeded as err:
             # the batcher shed it (and counted stage="batcher")
-            ctx.reqtrace.finish(trace, 504)
+            if trace is not None:
+                trace.status = 504
             return _add_trace(_error(504, str(err)), tid)
         except Exception as err:
             ctx.errored("point")
-            ctx.reqtrace.finish(trace, 500)
+            if trace is not None:
+                trace.status = 500
             return _add_trace(
                 _error(500, f"{type(err).__name__}: {err}"), tid
             )
-        t_render = time.perf_counter_ns()
+        # the coroutine resumed: ``wake`` ends, ``render`` and ``reply``
+        # start, and so does the loop clock's ``reply`` section
+        t_wake = time.perf_counter_ns()
         ctx.remember_point(generation, vid, record)
         if record is None:
             ctx.observe("point", time.perf_counter() - t0)
-            ctx.reqtrace.finish(trace, 404)
-            return _add_trace(
-                _error(404, f"variant {vid!r} not in store"), tid
-            )
-        resp = _resp(200, record)
-        ctx.observe("point", time.perf_counter() - t0, rows=1)
+            status = 404
+            resp = _error(404, f"variant {vid!r} not in store")
+        else:
+            status = 200
+            resp = _resp(200, record)
+            ctx.observe("point", time.perf_counter() - t0, rows=1)
+        resp = _add_trace(resp, tid)
+        t_built = time.perf_counter_ns()
         if trace is not None:
-            trace.record("render", t_render, time.perf_counter_ns())
-        ctx.reqtrace.finish(trace, 200)
-        return _add_trace(resp, tid)
+            self._woke(trace, t_wake)
+            if status == 200:
+                trace.record("render", t_wake, t_built)
+            trace.status = status
+        self.loop_clock.reply_ns += t_built - t_wake
+        return resp
 
     # -- routing ------------------------------------------------------------
 
@@ -1147,14 +1290,26 @@ class AioServer:
         keep = (http11 and conn != "close") or conn == "keep-alive"
         return method, target, keep, http11, headers
 
-    async def _route(self, reader, writer, head: bytes):
+    async def _route(self, reader, writer, head: bytes, t_head: int):
         """One parsed request -> (queue item | None, keep_alive).  The
         trace-id echo header splices into prebuilt byte responses HERE
         (one insertion point); deferred items (point/exec tuples) carry
-        the id and the writer splices when their bytes materialize."""
-        item, keep, tid = await self._route_inner(reader, writer, head)
-        if isinstance(item, bytes):
-            item = _add_trace(item, tid)
+        the id and the writer splices when their bytes materialize.
+
+        ``t_head`` (``perf_counter_ns``) is when the head was complete.
+        From there to the item handed back is the request's ``read`` span
+        — recorded here with a start stamped before any trace existed, as
+        ``admission`` is — and, less the body's ``await`` (subtracted where
+        it returns), the loop clock's ``read`` section."""
+        try:
+            item, keep, tid = await self._route_inner(reader, writer, head)
+            if isinstance(item, bytes):
+                item = _add_trace(item, tid)
+        finally:
+            t_item = time.perf_counter_ns()
+            self.loop_clock.read_ns += t_item - t_head
+        if item.__class__ is tuple and item[-1] is not None:
+            item[-1].record("read", t_head, t_item)
         return item, keep
 
     async def _route_inner(self, reader, writer, head: bytes):
@@ -1229,6 +1384,7 @@ class AioServer:
                 status, body = readyz_payload(ctx)
                 return _resp(status, body), keep, tid
             if path == "/metrics":
+                self._publish_loop_metrics()
                 if "fleet" in (url.query or ""):
                     # the fleet view reads sibling snapshot FILES — that
                     # is executor work, never event-loop work
@@ -1325,10 +1481,15 @@ class AioServer:
                 return _error(
                     413, f"body too large (cap {MAX_BODY} bytes)"
                 ), False, tid
+            t_body = time.perf_counter_ns()
             try:
                 body = await reader.readexactly(length) if length else b""
             except asyncio.IncompleteReadError:
                 return None, False, None
+            finally:
+                # a wait across callbacks, not the loop's own work: out of
+                # the ``read`` section ``_route`` adds when this returns
+                self.loop_clock.read_ns -= time.perf_counter_ns() - t_body
             if path == "/variants":
                 if ctx.governor.shed_bulk():
                     ctx.brownout_shed()
@@ -1431,8 +1592,6 @@ class AioServer:
             ctx.reqtrace.finish(trace, 200)
             return _resp(200, payload)
         generation = payload
-        if trace is not None:
-            trace.since("admission", t0)
         try:
             fut = ctx.batcher.submit_future(variant_id, deadline_t,
                                             trace=trace)
@@ -1495,6 +1654,23 @@ class AioServer:
             return head + b"\r\n\r\n" + body
         return _resp(status, body)
 
+    def _submit(self, work, trace, *args):
+        """Run an executor half, ``work(*args, trace)``, on the pool."""
+        return self._loop.run_in_executor(
+            self._pool, self._timed_work, work, trace, args
+        )
+
+    @staticmethod
+    def _timed_work(work, trace, args):
+        """On the executor's thread: the work, then the one clock read its
+        request's ``wake`` starts at (the wait for the event loop to
+        resume the coroutine: the executor hop back)."""
+        try:
+            return work(*args, trace)
+        finally:
+            if trace is not None:
+                trace.mark_ns = time.perf_counter_ns()
+
     def _bulk_item(self, body: bytes, client: str | None = None,
                    max_ids: int | None = None,
                    deadline_t: float | None = None,
@@ -1508,9 +1684,8 @@ class AioServer:
             ctx.rejected("bulk")
             return _error(429, MSG_CAPACITY_BULK, retry_after=1)
         trace = ctx.reqtrace.begin(tid, "bulk") if tid is not None else None
-        fut = self._loop.run_in_executor(
-            self._pool, self._bulk_work, body, t0, client, max_ids,
-            deadline_t, trace
+        fut = self._submit(
+            self._bulk_work, trace, body, t0, client, max_ids, deadline_t
         )
         return ("exec", fut, "bulk", t0, tid, trace)
 
@@ -1594,9 +1769,8 @@ class AioServer:
             return _error(429, MSG_CAPACITY_UPSERT, retry_after=1)
         trace = ctx.reqtrace.begin(tid, "upsert") if tid is not None \
             else None
-        fut = self._loop.run_in_executor(
-            self._pool, self._upsert_work, body, t0, client, max_rows,
-            deadline_t, trace
+        fut = self._submit(
+            self._upsert_work, trace, body, t0, client, max_rows, deadline_t
         )
         return ("exec", fut, "upsert", t0, tid, trace)
 
@@ -1654,9 +1828,9 @@ class AioServer:
             return _error(429, MSG_CAPACITY_REGION, retry_after=1)
         trace = ctx.reqtrace.begin(tid, "regions") if tid is not None \
             else None
-        fut = self._loop.run_in_executor(
-            self._pool, self._regions_work, body, t0, http11, client,
-            max_ids, deadline_t, trace
+        fut = self._submit(
+            self._regions_work, trace, body, t0, http11, client, max_ids,
+            deadline_t
         )
         return ("exec", fut, "regions", t0, tid, trace)
 
@@ -1753,9 +1927,8 @@ class AioServer:
             return _error(429, MSG_CAPACITY_STATS, retry_after=1)
         trace = ctx.reqtrace.begin(tid, "stats") if tid is not None \
             else None
-        fut = self._loop.run_in_executor(
-            self._pool, self._stats_work, body, t0, client, max_ids,
-            deadline_t, trace
+        fut = self._submit(
+            self._stats_work, trace, body, t0, client, max_ids, deadline_t
         )
         return ("exec", fut, "stats", t0, tid, trace)
 
@@ -1828,9 +2001,7 @@ class AioServer:
             return _error(429, MSG_CAPACITY_EXPORT, retry_after=1)
         trace = ctx.reqtrace.begin(tid, "export") if tid is not None \
             else None
-        fut = self._loop.run_in_executor(
-            self._pool, self._export_work, query, t0, deadline_t, trace
-        )
+        fut = self._submit(self._export_work, trace, query, t0, deadline_t)
         return ("exec", fut, "export", t0, tid, trace)
 
     def _export_work(self, query: str, t0: float,
@@ -1877,9 +2048,8 @@ class AioServer:
             return _error(429, MSG_CAPACITY_REGION, retry_after=1)
         trace = ctx.reqtrace.begin(tid, "region") if tid is not None \
             else None
-        fut = self._loop.run_in_executor(
-            self._pool, self._region_work, spec, query, t0, http11,
-            deadline_t, trace
+        fut = self._submit(
+            self._region_work, trace, spec, query, t0, http11, deadline_t
         )
         return ("exec", fut, "region", t0, tid, trace)
 
@@ -1991,7 +2161,12 @@ class AioServer:
         TRUNCATES — close the variants array at a row boundary, append a
         ``"truncated": true`` trailer field, and emit the terminating
         0-chunk — so the client holds valid JSON that SAYS it is partial
-        instead of a connection reset it must guess about."""
+        instead of a connection reset it must guess about.
+
+        What runs here between two ``await``s — framing, a chunk's render,
+        its write — is the loop clock's ``stream`` section."""
+        loop_clock = self.loop_clock
+        t_sync = time.perf_counter_ns()
         head = _STATUS[200]
         if trace_id:
             head += _TRACE_HEADER_B + trace_id.encode("latin-1") + b"\r\n"
@@ -2026,12 +2201,15 @@ class AioServer:
                     writer, (("" if first else ",") + chunk).encode()
                 )
                 first = False
+                loop_clock.stream_ns += time.perf_counter_ns() - t_sync
                 await writer.drain()  # flow control + loop fairness
+                t_sync = time.perf_counter_ns()
         except asyncio.CancelledError:
             # the drain budget expired with this stream still writing:
             # terminate the framing before the cancellation propagates
             # (the writes below are synchronous buffer appends)
             truncated = cancelled = True
+            t_sync = time.perf_counter_ns()
         if clock is not None:  # a panel: its render, observed once
             self.ctx.engine.regions_rendered(clock, streamed=True)
         if truncated:
@@ -2039,6 +2217,7 @@ class AioServer:
         else:
             _write_chunk(writer, page.suffix().encode())
         writer.write(b"0\r\n\r\n")
+        loop_clock.stream_ns += time.perf_counter_ns() - t_sync
         if cancelled:
             raise asyncio.CancelledError
         await writer.drain()

@@ -135,9 +135,12 @@ def stats_payload(ctx) -> str:
     }
     if ctx.engine.residency is not None:
         stats["residency"] = ctx.engine.residency.stats()
-    stats["brownout"] = {
-        "level": ctx.governor.level, "name": ctx.governor.level_name,
-    }
+    stats["brownout"] = ctx.governor.stats()
+    if ctx.loop_clock is not None:
+        # the event loop's own account, computed now (this runs on the
+        # loop); a read starts ``max_turn_ms``'s next window
+        stats["loop"] = ctx.loop_clock.stats(ctx.batcher.drain_ns,
+                                             reset_recent=True)
     if ctx.engine.breaker is not None:
         stats["breaker"] = ctx.engine.breaker.stats()
     if ctx.engine.mesh is not None:
@@ -681,6 +684,10 @@ class ServeContext:
         self.health = health
         self.worker_index = int(worker_index)
         self.started_t = time.time()
+        #: the serving event loop's clock (``obs/loopclock.py``); the
+        #: server that runs the loop sets it, a context without one has
+        #: no ``/stats`` ``loop`` block
+        self.loop_clock = None
         #: the device this process serves from, as JAX reports it
         #: (``/stats``); resolved once
         from annotatedvdb_tpu.utils.runtime import device_summary
@@ -849,14 +856,17 @@ class ServeContext:
         return resilience.deadline_at(header_value, self.default_deadline_s)
 
     def _brownout_event(self, old: int, new: int) -> None:
-        """Brownout ladder transitions land on the flight timeline — the
-        black box's answer to "what was this worker shedding when it
-        died"."""
+        """Brownout ladder transitions go to the server's log, with the
+        two signals the ladder stepped on — whether or not anything else
+        records them, a run that was shed leaves a word of why — and land
+        on the flight timeline: the black box's answer to "what was this
+        worker shedding when it died"."""
+        step = f"level {old}->{new} ({resilience.LEVEL_NAMES[new]})"
+        self.log(f"brownout: {step} "
+                 f"exceedance={self.governor.exceedance:.4f} "
+                 f"depth={self.batcher.depth()}")
         if self.flight is not None:
-            self.flight.event(
-                "brownout",
-                f"level {old}->{new} ({resilience.LEVEL_NAMES[new]})",
-            )
+            self.flight.event("brownout", step)
 
     def fleet_metrics(self) -> str:
         """The ``?fleet=1`` exposition body: this worker's live registry

@@ -228,6 +228,10 @@ class OverloadGovernor:
         self._next_eval = 0.0
         #: guarded by self._lock
         self._last_change = self._clock()
+        #: guarded by self._lock
+        self._steps = 0  # level changes since start, either way
+        #: guarded by self._lock
+        self._max_level = LEVEL_NORMAL
         if registry is not None:
             self._m_level = registry.gauge(
                 "avdb_serve_brownout_level",
@@ -281,6 +285,9 @@ class OverloadGovernor:
                 self._last_change = now
             old = self._level
             changed = level != old
+            if changed:
+                self._steps += 1
+                self._max_level = max(self._max_level, level)
             self._level = level
         if changed:
             if self._m_level is not None:
@@ -298,6 +305,9 @@ class OverloadGovernor:
         level = min(max(int(level), LEVEL_NORMAL), LEVEL_SHED_BULK)
         with self._lock:
             old = self._level
+            if level != old:
+                self._steps += 1
+                self._max_level = max(self._max_level, level)
             self._level = level
             self._last_change = self._clock()
         if self._m_level is not None:
@@ -307,6 +317,17 @@ class OverloadGovernor:
                 self.on_change(old, level)
             except Exception:  # avdb: noqa[AVDB602] -- an observer must never fail the ladder evaluation it watches
                 pass
+
+    def stats(self) -> dict:
+        """The ``/stats`` ``brownout`` block: where the ladder stands, how
+        far up it has been and how often it stepped since start, and the
+        latency signal it steps on."""
+        with self._lock:
+            return {
+                "level": self._level, "name": LEVEL_NAMES[self._level],
+                "max_level": self._max_level, "steps": self._steps,
+                "exceedance": self._exceed_ewma,
+            }
 
     # -- level queries (the front end's contract) ---------------------------
 

@@ -26,20 +26,27 @@ import time
 _TraceAnnotation = None
 
 
-def annotation(name: str, **args):
-    """A ``jax.profiler.TraceAnnotation`` named ``name``: a span on the
-    calling thread's host line of whatever profiler capture is running,
-    ``args`` shown as the event's stats.  The one place the program's
-    spans (load stages, queue waits, request stages, start-up phases)
-    reach the profiler's clock.  jax is imported on first use — modules
+def trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use — modules
     that only time things (``utils.pipeline``, ``obs.reqtrace``) stay
-    importable without it."""
+    importable without jax.  Its ``is_enabled()`` is the profiler's own
+    flag for "a capture is recording": what a span too frequent to create
+    for nothing asks first."""
     global _TraceAnnotation
     if _TraceAnnotation is None:
         from jax.profiler import TraceAnnotation
 
         _TraceAnnotation = TraceAnnotation
-    return _TraceAnnotation(name, **args)
+    return _TraceAnnotation
+
+
+def annotation(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``: a span on the
+    calling thread's host line of whatever profiler capture is running,
+    ``args`` shown as the event's stats.  The one place the program's
+    spans (load stages, queue waits, request stages, start-up phases, the
+    serving loop's turns) reach the profiler's clock."""
+    return trace_annotation()(name, **args)
 
 
 #: seconds per start-up phase of this process (device start, native

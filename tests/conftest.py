@@ -65,6 +65,21 @@ def stop_server(server):
     server.ctx.batcher.close()
 
 
+def ring_records(ctx, trace_id: str, n: int = 1, timeout: float = 5.0):
+    """The span ring's records of ``trace_id`` — polled until ``n`` are
+    there: a trace is sealed after the write that hands its reply to the
+    transport, so a client can hold the reply a moment before the ring
+    holds the record."""
+    import time
+
+    deadline = time.monotonic() + timeout
+    while True:
+        recs = [r for r in ctx.reqtrace.records() if r[0] == trace_id]
+        if len(recs) >= n or time.monotonic() > deadline:
+            return recs
+        time.sleep(0.005)
+
+
 def bulk_envelope(records: list) -> str:
     """The ``POST /variants`` body for ``engine.lookup_many``'s records
     (JSON texts, ``None`` for an absent id) — spelled here once, apart

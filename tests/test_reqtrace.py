@@ -112,10 +112,31 @@ def test_stage_histograms_and_slow_log():
     slow = [ln for ln in lines if "slow request" in ln]
     assert len(slow) == 1
     assert "trace=slowone" in slow[0] and "device=" in slow[0]
+    assert "loop_max_turn" not in slow[0]  # no serving loop behind it
     text = reg.render_prometheus()
     assert 'avdb_stage_seconds_count{stage="device"} 1' in text
     assert 'avdb_stage_seconds_count{stage="total"} 2' in text
     assert "avdb_trace_slow_requests_total 1" in text
+
+
+def test_slow_log_says_whether_the_loop_was_parked():
+    """With the server's loop clock beside it, a slow request's line says
+    how long the loop's longest turn was and when it ended — under this
+    request, or long before it."""
+    from annotatedvdb_tpu.obs.loopclock import LoopClock
+
+    lines: list[str] = []
+    rec = TraceRecorder(slow_ms=5.0, sample=1.0, log=lines.append)
+    rec.loop_clock = clock = LoopClock()
+    clock.max_turn_ns = int(18e6)
+    clock.max_turn_end_ns = clock.t_resume = clock.now()
+    t = rec.begin("parked", "bulk")
+    t.t0_ns -= int(20e6)
+    t.record("wake", t.t0_ns, t.t0_ns + int(18e6))
+    rec.finish(t, 200)
+    (line,) = lines
+    assert "trace=parked" in line and "wake=18.00ms" in line
+    assert "loop_max_turn=18.00ms ended=0.0" in line
 
 
 def test_span_cap_bounds_subspans():
@@ -390,6 +411,28 @@ def test_oversized_payload_drops_stages_not_the_headline(tmp_path):
     ev = decode_ring(path)["events"][0]
     assert ev["trace"] == "big" and ev["ms"] == pytest.approx(1500.0)
     assert "stages" not in ev  # trimmed to fit the fixed slot
+
+
+def test_a_full_slot_drops_the_loops_stages_first_and_says_so(tmp_path):
+    """The three stages of a request's life on the loop are the newest
+    names in the summary: where the fixed slot has no room for them it
+    keeps the others, and says what it cut."""
+    from annotatedvdb_tpu.obs.reqtrace import LOOP_STAGES, STAGES
+
+    path = str(tmp_path / "w0.ring")
+    fr = FlightRecorder(path, slots=4)
+    names = [s for s in STAGES if s not in ("background", "total")]
+    fr.request("roomy", "point", 200, 0.012,
+               [(s, 0.0012) for s in names])
+    fr.request("tight", "regions", 200, 12.345678,
+               [(s, 12.345678) for s in names])
+    fr.close()
+    roomy, tight = decode_ring(path)["events"]
+    assert list(roomy["stages"]) == names and "stages_cut" not in roomy
+    assert list(tight["stages"]) == [s for s in names
+                                     if s not in LOOP_STAGES]
+    assert tight["stages_cut"] == list(LOOP_STAGES)
+    assert tight["ms"] == pytest.approx(12345.678)
 
 
 # ---------------------------------------------------------------------------

@@ -165,7 +165,12 @@ def test_aio_routes_and_metrics(aio_server):
                    "avdb_serve_batches_total"):
         assert metric in body, metric
     status, body, _ = _get(port, "/stats")
-    assert status == 200 and json.loads(body)["batcher"]["queries"] >= 1
+    batcher = json.loads(body)["batcher"]
+    assert status == 200 and batcher["queries"] >= 1
+    # what the loop batcher counts, and no field that can only read 0
+    assert set(batcher) == {"batches", "queries", "batch_fill", "queue"}
+    assert batcher["queue"] == {"items": batcher["queries"],
+                                "max_depth": batcher["queue"]["max_depth"]}
 
 
 def test_aio_429_at_queue_bound(store):

@@ -27,7 +27,7 @@ from annotatedvdb_tpu.store import VariantStore
 from annotatedvdb_tpu.store.memtable import Memtable
 from annotatedvdb_tpu.store.wal import WriteAheadLog
 from annotatedvdb_tpu.types import encode_allele_array
-from conftest import start_server, stop_server
+from conftest import ring_records, start_server, stop_server
 from test_serve import _build_store, _vid
 
 WIDTH = 8
@@ -75,8 +75,8 @@ def _post(port, path, payload, headers=None):
         return err.code, err.read().decode(), dict(err.headers)
 
 
-def _records_for(ctx, tid):
-    return [r for r in ctx.reqtrace.records() if r[0] == tid]
+def _records_for(ctx, tid, n=1):
+    return ring_records(ctx, tid, n)
 
 
 # ---------------------------------------------------------------------------
@@ -138,8 +138,10 @@ def test_stage_breakdown_recorded_per_point_request(store, server):
     assert len(recs) == 1 and recs[0][1] == "point"
     stages = dict(recs[0][5])
     # the queue/device split comes from the batcher drain; the rest
-    # from the front end
-    assert set(stages) >= {"admission", "queue", "device", "render"}
+    # from the front end — whose read covers a point read's admission
+    # (the preflight: microseconds on the loop)
+    assert set(stages) == {"read", "queue", "device", "wake", "render",
+                           "reply"}
     assert all(s >= 0 for s in stages.values())
 
 
@@ -167,7 +169,7 @@ def test_cursor_walk_pages_share_the_trace_id(store, server):
         nxt = json.loads(body).get("next")
         pages += 1
     assert pages >= 2, "walk never continued: the fixture store shrank?"
-    recs = _records_for(ctx, tid)
+    recs = _records_for(ctx, tid, pages)
     assert len(recs) == pages
     for r in recs:
         assert r[1] == "region"
@@ -461,6 +463,46 @@ def test_fleet_view_ignores_torn_snapshot_files(store, tmp_path):
 # lifecycle events -> flight recorder
 
 
+@pytest.mark.parametrize("with_flight", [True, False])
+def test_the_ladder_says_when_it_steps(store, tmp_path, with_flight):
+    """A step of the brownout ladder is one line of the server's log,
+    flight directory or none, and ``/stats`` ``brownout`` keeps how far up
+    it went: a run that was shed leaves a word of why."""
+    store_dir, _truth = store
+    lines: list = []
+    flight = FlightRecorder(str(tmp_path / "w0.ring"), slots=32) \
+        if with_flight else None
+    httpd = start_server(store_dir=store_dir, flight=flight,
+                         log=lines.append)
+    try:
+        ctx = httpd.ctx
+        port = httpd.server_address[1]
+        assert json.loads(_get(port, "/stats")[1])["brownout"] == {
+            "level": 0, "name": "normal", "max_level": 0, "steps": 0,
+            "exceedance": 0.0}
+        ctx.governor.force_level(2)
+        ctx.governor.force_level(1)
+        said = [ln for ln in lines if ln.startswith("brownout: ")]
+        assert said == [
+            "brownout: level 0->2 (cache_first) exceedance=0.0000 depth=0",
+            "brownout: level 2->1 (limit) exceedance=0.0000 depth=0"]
+        assert json.loads(_get(port, "/stats")[1])["brownout"] == {
+            "level": 1, "name": "limit", "max_level": 2, "steps": 2,
+            "exceedance": 0.0}
+        # the ladder's own evaluation steps through the same hook, with
+        # the signal it stepped on
+        for _ in range(200):
+            ctx.governor.note_latency(10.0)
+        ctx.governor._next_eval = 0.0
+        assert ctx.governor.maybe_step() == 2
+        assert lines[-1].startswith(
+            "brownout: level 1->2 (cache_first) exceedance=0.9")
+    finally:
+        stop_server(httpd)
+        if flight is not None:
+            flight.close()
+
+
 def test_brownout_and_breaker_transitions_land_on_the_flight(store,
                                                              tmp_path):
     store_dir, _truth = store
@@ -496,6 +538,7 @@ def test_request_summaries_land_on_the_flight(store, tmp_path):
         vid = _vid(truth[0])
         assert _get(port, f"/variant/{vid}",
                     {"X-Request-Id": "boxed"})[0] == 200
+        assert _records_for(httpd.ctx, "boxed")  # sealed after its write
         flight.flush()  # the serving flush cadence, forced for the test
         reqs = [e for e in decode_ring(ring)["events"]
                 if e["type"] == "request"]
