@@ -253,6 +253,18 @@ def freq_sidecar(info_str: str, n_alts: int) -> list:
     return out
 
 
+#: flagged rows whose FREQ value a load's build stage asked for, by route —
+#: tallied once a chunk (:meth:`VcfChunk.freq_values`), read into the run
+#: record's ``execution.freq`` (``obs/session.py``)
+freq_stats = {"rows": 0, "native_rows": 0, "scalar_rows": 0}
+
+
+def freq_state(base: dict | None = None) -> dict:
+    """:data:`freq_stats` relative to ``base`` (an earlier copy)."""
+    base = base or {}
+    return {k: v - base.get(k, 0) for k, v in freq_stats.items()}
+
+
 @dataclass
 class VcfChunk:
     """One ingest batch: device arrays + host sidecar (aligned by row).
@@ -314,6 +326,23 @@ class VcfChunk:
     #: chunks — consumers fall back to the device/numpy hash.  Over-width
     #: rows still need the host full-string re-hash, same as every engine.
     h_native: np.ndarray | None = None
+
+    def freq_values(self, rows: np.ndarray) -> np.ndarray:
+        """``frequencies`` at ``rows`` (an object array, None where a row
+        holds no value): one native pass where the column offers one
+        (``native/vcf.py`` :class:`FreqColumn`), else a row at a time."""
+        rows = np.asarray(rows, np.intp)
+        at_rows = getattr(self.frequencies, "at_rows", None)
+        if at_rows is None:
+            values = np.fromiter(map(self.frequencies.__getitem__,
+                                     rows.tolist()), object, rows.size)
+            scalar = int(rows.size)
+        else:
+            values, scalar = at_rows(rows)
+        freq_stats["rows"] += int(rows.size)
+        freq_stats["native_rows"] += int(rows.size) - scalar
+        freq_stats["scalar_rows"] += scalar
+        return values
 
 
 class VcfBatchReader:

@@ -1427,28 +1427,22 @@ class TpuVcfLoader:
             with self.timer.stage("build", items=int(sel.size)):
                 payload = []
                 offset = 0
-                for rows in insert_rows:
+                freqs = _freq_slices(chunk, insert_rows)
+                for at, rows in enumerate(insert_rows):
                     k = rows.size
                     j = slice(offset, offset + k)
                     jj = np.arange(offset, offset + k)
                     code = int(batch.chrom[rows[0]])
-                    # a reader that flags its FREQ rows is asked for those
-                    # rows' values only (a FREQ-less slice, the common case,
-                    # for none); any other chunk for a per-row list
-                    if chunk.has_freq is None:
+                    if freqs is None:
                         annotations = {
                             "allele_frequencies": [
                                 chunk.frequencies[i] for i in rows
                             ],
                         }
                     else:
-                        freq = _at_rows(
-                            chunk.has_freq[rows],
-                            lambda jx: chunk.frequencies[int(rows[jx])],
-                        )
                         annotations = (
-                            {} if freq is None
-                            else {"allele_frequencies": freq}
+                            {} if freqs[at] is None
+                            else {"allele_frequencies": freqs[at]}
                         )
                     if display is not None:
                         annotations["display_attributes"] = (
@@ -1541,6 +1535,23 @@ class TpuVcfLoader:
                     int(sel.size) - len(lines)
                 )
         return payload
+
+
+def _freq_slices(chunk: VcfChunk, slices: list) -> list | None:
+    """One sparse FREQ column (or None) a slice of chunk rows: a reader
+    that flags its FREQ rows is asked for those rows' values only, every
+    slice's in one call (a FREQ-less chunk, the common case, for none).
+    None for a chunk without the flags: the caller takes a per-row list."""
+    if chunk.has_freq is None:
+        return None
+    flagged = [np.flatnonzero(chunk.has_freq[rows]) for rows in slices]
+    at = np.concatenate([rows[f] for rows, f in zip(slices, flagged)])
+    if not at.size:
+        return [None] * len(slices)
+    values = np.split(chunk.freq_values(at),
+                      np.cumsum([f.size for f in flagged])[:-1])
+    return [SparseValues(f, v) if f.size else None
+            for f, v in zip(flagged, values)]
 
 
 def _at_rows(flags: np.ndarray, value_at) -> SparseValues | None:

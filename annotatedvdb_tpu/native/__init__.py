@@ -135,6 +135,13 @@ def load():
             c.c_void_p, c.c_void_p,                            # slow bytes, end
             c.c_void_p, c.c_int64,                            # out, cap
         ]
+        lib.avdb_freq_texts.restype = c.c_int64
+        lib.avdb_freq_texts.argtypes = [
+            c.c_char_p, c.c_int64,                             # window, n
+            c.c_void_p, c.c_void_p,                            # info_off, info_len
+            c.c_void_p, c.c_void_p,                            # n_alts, alt_index
+            c.c_void_p, c.c_void_p, c.c_int64,                # status, out, cap
+        ]
         _lib = lib
         return _lib
 

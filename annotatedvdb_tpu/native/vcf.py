@@ -233,6 +233,24 @@ class LazyColumn:
         return f"LazyColumn({list(self)!r})"
 
 
+class FreqColumn(LazyColumn):
+    """A native chunk's ``frequencies``: a row at a time through the scalar
+    route like any :class:`LazyColumn`, and many rows at once through
+    ``at_rows`` — what :meth:`~annotatedvdb_tpu.io.vcf.VcfChunk.freq_values`
+    asks for."""
+
+    __slots__ = ("_rows_fn",)
+
+    def __init__(self, n: int, fn, rows_fn):
+        super().__init__(n, fn)
+        self._rows_fn = rows_fn
+
+    def at_rows(self, rows: np.ndarray) -> tuple[np.ndarray, int]:
+        """``rows``' values as an object array, and how many of them the
+        scalar route gave."""
+        return self._rows_fn(rows)
+
+
 def chunk_from_native(arrays: _Arrays, n: int, window: bytes, base: int,
                       counters: dict, width: int, identity_only: bool,
                       pack_alleles: bool = True,
@@ -245,6 +263,8 @@ def chunk_from_native(arrays: _Arrays, n: int, window: bytes, base: int,
     (``VcfBatchReader.iter_prefetched``).  Sidecar columns are lazy views
     over the immutable window bytes."""
     from annotatedvdb_tpu.io.vcf import VcfChunk, freq_sidecar, parse_info
+    from annotatedvdb_tpu.native import freq as native_freq
+    from annotatedvdb_tpu.store.variant_store import RawJson
 
     batch = VariantBatch(
         chrom=arrays.chrom[:n],
@@ -333,6 +353,33 @@ def chunk_from_native(arrays: _Arrays, n: int, window: bytes, base: int,
             )
         return hit[int(alt_index[i])]
 
+    def freq_rows(rows):
+        # one native pass over the rows' INFO spans; a row it declines
+        # (or every row, without the library) through freq_at
+        rows = np.asarray(rows, np.intp)
+        got = native_freq.freq_texts(
+            window, base + info_off[rows],
+            np.where(has_freq[rows], info_len[rows], 0),
+            n_alts[rows], alt_index[rows],
+        )
+        if got is None:
+            return np.fromiter(map(frequencies.__getitem__, rows.tolist()),
+                               object, rows.size), int(rows.size)
+        status, texts = got
+        values = np.full(rows.size, None, object)
+        values[status == native_freq.WRITTEN] = np.fromiter(
+            map(RawJson, texts), object, len(texts)
+        )
+        declined = np.flatnonzero(status == native_freq.DECLINED)
+        for j in declined.tolist():
+            values[j] = frequencies[int(rows[j])]
+        return values, int(declined.size)
+
+    frequencies = (
+        LazyColumn(n, freq_at) if identity_only
+        else FreqColumn(n, freq_at, freq_rows)
+    )
+
     def ref_snp_at(i):
         # substring rule first, exactly like the Python reader / reference
         # (vcf_parser.py:158-169): an ID containing 'rs' IS the refsnp
@@ -365,7 +412,7 @@ def chunk_from_native(arrays: _Arrays, n: int, window: bytes, base: int,
         is_multi_allelic=arrays.multi[:n].astype(bool),
         # the tokenizer pre-flags FREQ-bearing rows, so FREQ-less rows
         # (the vast majority) skip even the FREQ-token scan
-        frequencies=LazyColumn(n, freq_at),
+        frequencies=frequencies,
         has_freq=has_freq,
         rs_position=LazyColumn(n, lambda i: info_at(i).get("RSPOS")),
         info=LazyColumn(n, lambda i: info_at(i)),

@@ -54,18 +54,21 @@ def config_hash(params: dict) -> str:
 
 def execution_facts(probe_base: dict | None = None,
                     sidecar_base: dict | None = None,
-                    mapping_base: dict | None = None) -> dict:
+                    mapping_base: dict | None = None,
+                    freq_base: dict | None = None) -> dict:
     """Where and how this process ran its load: the device as JAX reports
     it, the annotate kernel that was selected, the transport verdicts, the
     native tokenizer's state, the device membership probes since
-    ``probe_base``, the sidecar writer's rows since ``sidecar_base`` and
-    the mapping file's rows since ``mapping_base`` —
+    ``probe_base``, the sidecar writer's rows since ``sidecar_base``, the
+    mapping file's rows since ``mapping_base`` and the FREQ values' rows
+    since ``freq_base`` —
     the run record's ``execution`` block, so a reader can tell a chip run
     from a CPU run, see which device paths a load reached, and take
     compile seconds apart from the load itself.
     Reports only: nothing here selects, probes or builds."""
     from annotatedvdb_tpu import native
     from annotatedvdb_tpu.io.egress import mapping_state
+    from annotatedvdb_tpu.io.vcf import freq_state
     from annotatedvdb_tpu.models.pipeline import selected_kernel
     from annotatedvdb_tpu.ops.pack import transport_state
     from annotatedvdb_tpu.store.variant_store import (
@@ -96,6 +99,11 @@ def execution_facts(probe_base: dict | None = None,
         # the chunk's columns (native/mapping.py), or through the scalar
         # strings (io/egress.py mapping_lines); tallied once a chunk
         "mapping": mapping_state(mapping_base),
+        # FREQ-flagged rows whose value the build stage asked for, by
+        # route: written by the native pass from the chunk's INFO spans
+        # (native/freq.py), or through io/vcf.py freq_sidecar; tallied
+        # once a chunk
+        "freq": freq_state(freq_base),
     }
 
 
@@ -242,6 +250,9 @@ class ObsSession:
         from annotatedvdb_tpu.io.egress import mapping_stats
 
         self._mapping_base = dict(mapping_stats)
+        from annotatedvdb_tpu.io.vcf import freq_stats
+
+        self._freq_base = dict(freq_stats)
 
     @classmethod
     def from_args(cls, script: str, args, params: dict) -> "ObsSession":
@@ -339,7 +350,7 @@ class ObsSession:
                     # record must still land, without the block
                     execution=(
                         execution_facts(self._probe_base, self._sidecar_base,
-                                        self._mapping_base)
+                                        self._mapping_base, self._freq_base)
                         if error is None else None
                     ),
                 ))

@@ -536,10 +536,11 @@ class SparseValues(NamedTuple):
     """An object column given only where it holds a value — the form
     :meth:`Segment.build` takes beside a per-row list: ``rows`` are
     ascending positions in the build's input order, ``values`` one value
-    each (a None among them is one more row without a value)."""
+    each, a list or an object array (a None among them is one more row
+    without a value)."""
 
     rows: np.ndarray
-    values: list
+    values: list | np.ndarray
 
 
 class Segment:

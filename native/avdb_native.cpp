@@ -610,3 +610,209 @@ int64_t avdb_mapping_lines(
 }
 
 }  // extern "C"
+
+// ---- a FREQ-bearing row's frequency sidecar, from its INFO span ----
+//
+// One row's value is io/vcf.py freq_sidecar(info, n_alts)[alt_index].text:
+//   {"<pop>": {"gmaf": <number>}, ...}
+// over the populations of INFO's last FREQ= entry that hold a value (not
+// "." or "0") at slot alt_index + 1.  A row is written only where these
+// bytes are provably Python's; every other row is declined, and the
+// caller takes it through freq_sidecar itself.
+
+namespace {
+
+// a population name json.dumps renders verbatim between quotes, within
+// io/vcf.py _FREQ_KEY_RE (':' and '|' cannot occur: they cut the name)
+struct FreqKeyLut {
+    uint8_t ok[256] = {};
+    FreqKeyLut() {
+        for (int c = '0'; c <= '9'; ++c) ok[c] = 1;
+        for (int c = 'A'; c <= 'Z'; ++c) ok[c] = 1;
+        for (int c = 'a'; c <= 'z'; ++c) ok[c] = 1;
+        for (const char* c = " _.,/-"; *c; ++c)
+            ok[static_cast<uint8_t>(*c)] = 1;
+    }
+};
+const FreqKeyLut kFreqKey;
+
+inline bool is_digit(char c) { return c >= '0' && c <= '9'; }
+
+// The number Python writes for a FREQ value v: str(int(v)) for an
+// integer, repr(float(v)) for a plain decimal of at most 15 significant
+// digits in [1e-4, 1e16) or of value zero — DBL_DIG is 15, so such a
+// decimal is the shortest string that round-trips, and repr prints it in
+// positional form with its trailing zeros trimmed to one place.  Returns
+// the end of what was written, or nullptr for any other form (exponents,
+// inf/nan, more digits, whitespace, ...), writing nothing then.
+inline uint8_t* put_gmaf(uint8_t* p, const char* v, int len) {
+    int i = 0;
+    bool neg = false;
+    if (len > 0 && (v[0] == '+' || v[0] == '-')) {
+        neg = v[0] == '-';
+        i = 1;
+    }
+    int dot = -1;
+    for (int k = i; k < len; ++k) {
+        if (v[k] == '.' && dot < 0) dot = k;
+        else if (!is_digit(v[k])) return nullptr;
+    }
+    if (dot < 0) {  // an integer: sign and leading zeros fall away
+        if (len == i || len - i > 1000) return nullptr;  // int()'s 4300 cap
+        int b = i;
+        while (b < len && v[b] == '0') ++b;
+        if (b == len) {
+            *p++ = '0';
+            return p;
+        }
+        if (neg) *p++ = '-';
+        return put_bytes(p, v + b, len - b);
+    }
+    int ib = i, ie = dot, fb = dot + 1, fe = len;
+    if (ib == ie && fb == fe) return nullptr;  // a lone sign and dot
+    while (ib < ie && v[ib] == '0') ++ib;      // leading zeros
+    while (fe > fb && v[fe - 1] == '0') --fe;  // trailing zeros
+    if (ib == ie && fe == fb) {                // zero: 0.0 / -0.0
+        if (neg) *p++ = '-';
+        return AVDB_LIT(p, "0.0");
+    }
+    int sig;
+    if (ib < ie) {  // |v| >= 1
+        if (ie - ib > 16) return nullptr;  // >= 1e16: repr takes exponents
+        int last = ie;
+        if (fe == fb)
+            while (v[last - 1] == '0') --last;  // 1200. has 2 digits
+        sig = (last - ib) + (fe - fb);
+    } else {  // |v| < 1
+        int nz = fb;
+        while (v[nz] == '0') ++nz;
+        if (nz - fb > 3) return nullptr;  // < 1e-4: repr takes exponents
+        sig = fe - nz;
+    }
+    if (sig > 15) return nullptr;
+    if (neg) *p++ = '-';
+    if (ib < ie) p = put_bytes(p, v + ib, ie - ib);
+    else *p++ = '0';
+    *p++ = '.';
+    if (fe > fb) return put_bytes(p, v + fb, fe - fb);
+    *p++ = '0';
+    return p;
+}
+
+struct Cut {
+    const char* ptr;
+    int len;
+};
+
+enum FreqOutcome : uint8_t { kFreqNone = 0, kFreqWritten = 1, kFreqDeclined = 2 };
+
+constexpr int kMaxPops = 64;
+
+// One row: writes its text and a newline at p and returns kFreqWritten,
+// or returns kFreqNone / kFreqDeclined and leaves *end at p.
+inline uint8_t freq_row(const char* s, int len, int32_t n_alts,
+                        int32_t alt_index, uint8_t* p, uint8_t** end) {
+    *end = p;
+    if (len <= 0) return kFreqNone;
+    // freq_sidecar scrubs \x2c, \x59 and '#' before it splits: declined
+    if (memchr(s, '\\', len) || memchr(s, '#', len)) return kFreqDeclined;
+    if (alt_index < 0 || alt_index >= n_alts) return kFreqDeclined;
+    // the last ';'-item that starts FREQ= (dict semantics: last wins)
+    const char* raw = nullptr;
+    const char* raw_end = nullptr;
+    for (const char* item = s; item <= s + len;) {
+        const char* semi = static_cast<const char*>(
+            memchr(item, ';', static_cast<size_t>(s + len - item)));
+        const char* stop = semi ? semi : s + len;
+        if (stop - item >= 5 && memcmp(item, "FREQ=", 5) == 0) {
+            raw = item + 5;
+            raw_end = stop;
+        }
+        item = stop + 1;
+    }
+    if (raw == nullptr) return kFreqNone;
+    Cut names[kMaxPops];
+    int n_names = 0;
+    int slot = alt_index + 1;
+    uint8_t* q = p;
+    *q++ = '{';
+    bool any = false;
+    for (const char* pop = raw; pop <= raw_end;) {
+        const char* bar = static_cast<const char*>(
+            memchr(pop, '|', static_cast<size_t>(raw_end - pop)));
+        const char* pop_end = bar ? bar : raw_end;
+        const char* colon = static_cast<const char*>(
+            memchr(pop, ':', static_cast<size_t>(pop_end - pop)));
+        if (colon != nullptr) {  // a part without ':' is no population
+            Cut name{pop, static_cast<int>(colon - pop)};
+            if (name.len == 0 || n_names == kMaxPops) return kFreqDeclined;
+            for (int k = 0; k < name.len; ++k)
+                if (!kFreqKey.ok[static_cast<uint8_t>(name.ptr[k])])
+                    return kFreqDeclined;
+            for (int k = 0; k < n_names; ++k)  // repeated: first slot,
+                if (names[k].len == name.len   // last value — declined
+                    && memcmp(names[k].ptr, name.ptr, name.len) == 0)
+                    return kFreqDeclined;
+            names[n_names++] = name;
+            // the slot-th ','-separated value, if there is one
+            const char* v = colon + 1;
+            for (int k = 0; k < slot && v != nullptr; ++k) {
+                const char* comma = static_cast<const char*>(
+                    memchr(v, ',', static_cast<size_t>(pop_end - v)));
+                v = comma ? comma + 1 : nullptr;
+            }
+            if (v != nullptr) {
+                const char* comma = static_cast<const char*>(
+                    memchr(v, ',', static_cast<size_t>(pop_end - v)));
+                int vlen = static_cast<int>((comma ? comma : pop_end) - v);
+                bool skip = vlen == 1 && (v[0] == '.' || v[0] == '0');
+                if (!skip) {
+                    if (any) q = AVDB_LIT(q, ", ");
+                    *q++ = '"';
+                    q = put_bytes(q, name.ptr, name.len);
+                    q = AVDB_LIT(q, "\": {\"gmaf\": ");
+                    q = put_gmaf(q, v, vlen);
+                    if (q == nullptr) return kFreqDeclined;
+                    *q++ = '}';
+                    any = true;
+                }
+            }
+        }
+        pop = pop_end + 1;
+    }
+    if (!any) return kFreqNone;
+    q = AVDB_LIT(q, "}\n");
+    *end = q;
+    return kFreqWritten;
+}
+
+}  // namespace
+
+extern "C" {
+
+// For each of n rows — its INFO span buf[info_off[i] .. + info_len[i]),
+// its line's alt count and its alt's ordinal among them — sets status[i]
+// to 0 (no value: no FREQ entry, or no population with a value at the
+// row's slot), 1 (written: the text and a newline appended to out) or 2
+// (declined).  Returns the bytes written to out, or -1 if out_cap would
+// not hold them: a row may take 3 + 18 * (info_len + 1) bytes — "{",
+// "}\n", and for each population 17 beside its name and value, which
+// grows by at most a byte (".5" -> 0.5).
+int64_t avdb_freq_texts(
+    const char* buf, int64_t n,
+    const int64_t* info_off, const int32_t* info_len,
+    const int32_t* n_alts, const int32_t* alt_index,
+    uint8_t* status, uint8_t* out, int64_t out_cap) {
+    uint8_t* p = out;
+    uint8_t* const cap = out + out_cap;
+    for (int64_t i = 0; i < n; ++i) {
+        int32_t len = info_len[i];
+        if (len > 0 && cap - p < 3 + 18 * (static_cast<int64_t>(len) + 1))
+            return -1;
+        status[i] = freq_row(len > 0 ? buf + info_off[i] : buf, len,
+                             n_alts[i], alt_index[i], p, &p);
+    }
+    return p - out;
+}
+
+}  // extern "C"
