@@ -93,6 +93,13 @@ LOOP_STAGES = ("read", "wake", "reply")
 STAGES = ("read", "admission", "queue", "device", "render", "wal_fsync",
           "wake", "reply", "background", "total")
 
+#: an upsert's stage beside ``wal_fsync``: the rest of its work under the
+#: memtable lock (the membership check; the segment build, merge and
+#: publish) — two spans around the fsync, one stage.  Only an upsert has
+#: it, so it is not in the vocabulary every request's flight summary
+#: makes room for
+UPSERT_STAGES = ("upsert.apply",)
+
 #: per-stage latency histogram edges (seconds): sub-100µs queue waits up
 #: to multi-second background passes
 STAGE_SECONDS_EDGES = (
@@ -384,7 +391,8 @@ class TraceRecorder:
         self._hist = {}
         self._m_slow = None
         if registry is not None:
-            self._hist = stage_histograms(registry, STAGES)
+            self._hist = stage_histograms(registry,
+                                          STAGES + UPSERT_STAGES)
             self._m_slow = registry.counter(
                 "avdb_trace_slow_requests_total",
                 "requests whose total latency exceeded AVDB_TRACE_SLOW_MS",
@@ -408,15 +416,17 @@ class TraceRecorder:
         now_ns = time.perf_counter_ns()
         total = (now_ns - trace.t0_ns) / 1e9
         hist = self._hist
-        stages = []
+        # a stage's seconds over its spans (a stage split around another,
+        # as an upsert's apply is around its fsync, counts once)
+        summed: dict = {}
         for name, start_ns, end_ns, parent in trace.spans:
             if parent is None:
-                seconds = (end_ns - start_ns) / 1e9
-                stages.append((name, seconds))
-                h = hist.get(name)
-                if h is not None:
-                    h.observe(seconds)
-        stages = tuple(stages)
+                summed[name] = summed.get(name, 0) + end_ns - start_ns
+        stages = tuple((name, ns / 1e9) for name, ns in summed.items())
+        for name, seconds in stages:
+            h = hist.get(name)
+            if h is not None:
+                h.observe(seconds)
         if hist:
             hist["total"].observe(total)
         self._ring[next(self._seq) % self.slots] = (

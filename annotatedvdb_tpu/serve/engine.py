@@ -1007,7 +1007,8 @@ class _LookupClock(_StageClock):
     The tallies are added once per chromosome group (render cache) and
     once per touched segment (renderer route)."""
 
-    __slots__ = ("found", "misses", "batch_rows", "scalar_rows")
+    __slots__ = ("found", "misses", "batch_rows", "scalar_rows",
+                 "overlay_hits")
 
     def __init__(self):
         super().__init__(reqtrace.LOOKUP_STAGES)
@@ -1015,6 +1016,9 @@ class _LookupClock(_StageClock):
         #: distinct rows among them that were not in it
         self.found = 0
         self.misses = 0
+        #: of the ids found: those a memtable overlay segment answered
+        #: (upserted since the last flush), added once a chromosome group
+        self.overlay_hits = 0
         #: of those misses: rows the columnar pass assembled, and rows
         #: that went through the scalar ``_render_row`` (retained host
         #: strings, an over-width length, a group of one miss)
@@ -1143,6 +1147,9 @@ class QueryEngine:
         #: the GIL, read by ``/stats`` (``render_cache``)
         self.render_cache_hits = 0
         self.render_cache_misses = 0
+        #: found ids a memtable overlay segment answered (``/stats``
+        #: ``memtable.read_hits``), added the same way
+        self.memtable_read_hits = 0
         #: of the misses, by renderer route (``/stats`` ``render_batch``):
         #: rows of the columnar pass / rows of the scalar ``_render_row``
         self.render_batch_rows = 0
@@ -1223,6 +1230,7 @@ class QueryEngine:
             self.residency.govern(snap)
         store = snap.store
         width = store.width
+        overlay_from = getattr(store, "overlay_from", None)
         if self.mesh is not None and len(ids) >= self.mesh.bulk_min \
                 and self.mesh.would_dispatch(snap):
             got = self._mesh_lookup_many(snap, parsed, out, clock)
@@ -1273,6 +1281,9 @@ class QueryEngine:
                 )
             with clock.span("lookup.rows", chrom=code, n=len(idxs)):
                 at = np.flatnonzero(found)
+                if overlay_from is not None and at.size:
+                    clock.overlay_hits += int(np.count_nonzero(
+                        gid[at] >= overlay_from.get(code, 0)))
                 texts = self._render_group(
                     shard, code, gid[at].tolist(), snap.generation, clock
                 )
@@ -1289,6 +1300,7 @@ class QueryEngine:
         hits = clock.found - clock.misses
         self.render_cache_hits += hits
         self.render_cache_misses += clock.misses
+        self.memtable_read_hits += clock.overlay_hits
         self.render_batch_rows += clock.batch_rows
         self.render_scalar_rows += clock.scalar_rows
         if self._lookup_hist is not None:

@@ -135,6 +135,13 @@ def stats_payload(ctx) -> str:
     }
     if ctx.engine.residency is not None:
         stats["residency"] = ctx.engine.residency.stats()
+    if hasattr(ctx.manager, "stats"):
+        stats["snapshot"] = ctx.manager.stats()
+    if ctx.memtable is not None:
+        stats["memtable"] = dict(ctx.memtable.stats(),
+                                 read_hits=ctx.engine.memtable_read_hits)
+    if hasattr(ctx.manager, "generations"):
+        stats["overlay"] = {"generations": ctx.manager.generations}
     stats["brownout"] = ctx.governor.stats()
     if ctx.loop_clock is not None:
         # the event loop's own account, computed now (this runs on the

@@ -195,6 +195,11 @@ class ResidencyManager:
         self._generation: int | None = None
         #: guarded by self._lock
         self._entries: dict[int, _Entry] = {}  # id(segment) -> entry
+        #: guarded by self._lock — ``/stats`` ``residency``: generations
+        #: adopted, entries carried into a newer one, uploads landed
+        self.governs = 0
+        self.carried = 0
+        self.uploads = 0
         if registry is not None:
             self._m_resident = registry.gauge(
                 "avdb_serve_resident_bytes",
@@ -224,9 +229,14 @@ class ResidencyManager:
 
     def govern(self, snap) -> None:
         """Adopt the snapshot's segments (idempotent per generation).  A
-        generation swap drops every previous entry — the old snapshot's
-        device caches die with the snapshot object once in-flight readers
-        release it — and marks the new store's segments managed."""
+        segment object the manager already governs carries its entry into
+        the newer generation — resident, warmed, with its device cache and
+        heat: an upsert's overlay generation and a flush's refresh (which
+        keeps unchanged segments, ``VariantStore.load(reuse=...)``) upload
+        and warm nothing again.  Every other entry of the previous
+        generation is dropped — its device cache dies with the snapshot
+        object once in-flight readers release it — and the new segments
+        are marked managed and planned like any others."""
         with self._lock:
             # ordering-aware, not equality: a request still holding a
             # pre-swap snapshot must not re-install a RETIRED generation's
@@ -235,10 +245,13 @@ class ResidencyManager:
             if (self._generation is not None
                     and snap.generation <= self._generation):
                 return
+            known = set(self._entries)
         # candidate scan runs OFF the lock: concurrent touch_window
         # callers must not serialize behind the per-segment bound and
-        # byte-size computation
-        entries: dict[int, _Entry] = {}
+        # byte-size computation.  (segment, code, shard, device, the entry
+        # of a segment not governed yet.)  An entry holds its segment, so
+        # a governed segment's id names no other object
+        found = []
         for code, shard in snap.store.shards.items():
             device = (
                 self.placement.get(code) if self.placement is not None
@@ -246,27 +259,42 @@ class ResidencyManager:
             )
             for seg in shard.segments:
                 seg.residency = "managed"
-                if seg.n >= self.min_rows:
-                    entries[id(seg)] = _Entry(
-                        seg, device_cache_bytes(seg, shard.width),
-                        device=device, code=code, shard=shard,
-                    )
+                if seg.n < self.min_rows:
+                    continue
+                found.append((seg, code, shard, device, None
+                              if id(seg) in known else _Entry(
+                                  seg, device_cache_bytes(seg, shard.width))))
         with self._lock:
             if (self._generation is not None
                     and snap.generation <= self._generation):
                 return  # another thread governed this (or a newer) one
+            entries: dict[int, _Entry] = {}
+            for seg, code, shard, device, fresh in found:
+                e = self._entries.get(id(seg)) or fresh or _Entry(
+                    seg, device_cache_bytes(seg, shard.width))
+                # the newer generation's shard: whose interval index a
+                # later upload of the segment warms
+                e.device, e.code, e.shard = device, code, shard
+                entries[id(seg)] = e
             # a queued upload batch on the uploader thread still holds the
             # displaced _Entry objects and gates on e.resident — a retired
             # generation must never spend transfers/HBM or queue ahead of
-            # the new hot set
-            for e in self._entries.values():
-                e.resident = False
+            # the new hot set.  A carried entry is the same object: it
+            # keeps its state
+            carried = 0
+            for key, e in self._entries.items():
+                if entries.get(key) is e:
+                    carried += 1
+                else:
+                    e.resident = False
             self._entries = entries
             self._generation = snap.generation
+            self.governs += 1
+            self.carried += carried
             candidates = len(self._entries)
         self.log(
             f"residency: governing generation {snap.generation} "
-            f"({candidates} candidate segments, "
+            f"({candidates} candidate segments, {carried} carried, "
             f"budget {self.budget} bytes)"
         )
 
@@ -424,6 +452,7 @@ class ResidencyManager:
                         continue
                     e.seg._device = dev
                     generation = self._generation
+                    self.uploads += 1
                 landed.append((e, generation))
                 if self._m_uploads is not None:
                     self._m_uploads.inc()
@@ -496,6 +525,9 @@ class ResidencyManager:
                     e.nbytes for e in entries if e.resident
                 ),
                 "generation": self._generation,
+                "governs": self.governs,
+                "carried": self.carried,
+                "uploads": self.uploads,
             }
             if self.placement is not None:
                 per_device: dict = {}

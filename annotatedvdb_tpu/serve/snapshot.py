@@ -16,6 +16,10 @@ reference's Postgres snapshot isolation).
   execution — the swap can never tear one mid-read);
 - ``refresh()`` fingerprints ``manifest.json`` (one ``stat``), loads the
   new generation OFF-lock when it changed, then swaps the pin atomically.
+  The load keeps every segment of the pinned store whose files the new
+  manifest lists unchanged (``VariantStore.load(reuse=...)``), with its
+  device cache and warmed programs: a memtable flush reads only the
+  segments it wrote.
   The ``snapshot.swap`` fault point fires between load and swap: a failure
   there must leave the old generation serving, which the fault matrix pins.
 - ``maybe_refresh()`` is the front end's coalesced entry point: at serving
@@ -38,6 +42,7 @@ import time
 from annotatedvdb_tpu.store import VariantStore
 from annotatedvdb_tpu.utils import faults
 from annotatedvdb_tpu.utils.locks import make_lock
+from annotatedvdb_tpu.utils.profiling import annotation
 
 
 def _ttl_from_env() -> float:
@@ -88,6 +93,11 @@ class SnapshotManager:
         self._snap = StoreSnapshot(store, 1, fingerprint)
         #: guarded by self._lock
         self._swaps = 0
+        #: guarded by self._lock — what the swaps' loads took: seconds,
+        #: backing groups kept from the pin and groups read from disk
+        self._reload_s = 0.0
+        self._reused = 0
+        self._loaded = 0
         #: guarded by self._lock
         self._next_check = 0.0  # monotonic deadline of the next free stat
         #: True while a NEW generation is loading (between the changed
@@ -108,6 +118,14 @@ class SnapshotManager:
     def swaps(self) -> int:
         with self._lock:
             return self._swaps
+
+    def stats(self) -> dict:
+        """``/stats`` ``snapshot``: swaps and what their loads took."""
+        with self._lock:
+            return {"swaps": self._swaps,
+                    "reload_s": round(self._reload_s, 6),
+                    "segments_reused": self._reused,
+                    "segments_loaded": self._loaded}
 
     def refresh_due(self) -> bool:
         """Whether the TTL window has lapsed (no stat, no side effects) —
@@ -144,9 +162,12 @@ class SnapshotManager:
         if fingerprint == pinned.fingerprint:
             return False
         self.swapping = True
+        t0 = time.perf_counter()
         try:
             try:
-                store = VariantStore.load(self.store_dir, readonly=True)
+                with annotation("avdb.snapshot.reload"):
+                    store = VariantStore.load(self.store_dir, readonly=True,
+                                              reuse=pinned.store)
             except (OSError, ValueError) as err:  # StoreCorruptError is a ValueError
                 self.log(f"snapshot refresh failed, keeping generation "
                          f"{pinned.generation}: {err}")
@@ -169,9 +190,13 @@ class SnapshotManager:
                 store, self._snap.generation + 1, fingerprint
             )
             self._swaps += 1
+            self._reload_s += time.perf_counter() - t0
+            self._reused += store.load_counts["reused"]
+            self._loaded += store.load_counts["loaded"]
             generation = self._snap.generation
         self.log(f"snapshot swapped to generation {generation} "
-                 f"({store.n} rows)")
+                 f"({store.n} rows; {store.load_counts['reused']} segment "
+                 f"group(s) kept, {store.load_counts['loaded']} read)")
         return True
 
 
@@ -183,7 +208,7 @@ class _OverlayStore:
     machinery loaded rows do.  The Segment objects are shared with the
     base store and the memtable; only the per-shard lists are fresh."""
 
-    __slots__ = ("width", "readonly", "shards")
+    __slots__ = ("width", "readonly", "shards", "overlay_from")
 
     def __init__(self, base_store, mem_segments: dict):
         from annotatedvdb_tpu.store.variant_store import ChromosomeShard
@@ -191,17 +216,22 @@ class _OverlayStore:
         self.width = base_store.width
         self.readonly = True
         shards = {}
+        #: {code: the first global id of the shard that is a memtable row}
+        #: (every global id at or above it was upserted since the flush)
+        self.overlay_from = {}
         for code, bshard in base_store.shards.items():
             sh = ChromosomeShard(code, self.width)
             sh.segments = list(bshard.segments) \
                 + list(mem_segments.get(code, ()))
             shards[code] = sh
+            self.overlay_from[code] = bshard.n
         for code, segs in mem_segments.items():
             if code in shards or not segs:
                 continue
             sh = ChromosomeShard(code, self.width)
             sh.segments = list(segs)
             shards[code] = sh
+            self.overlay_from[code] = 0
         self.shards = shards
 
     @property
@@ -239,6 +269,9 @@ class MemtableSnapshots:
         #: guarded by self._lock — bumps per reset_memtable swap so view
         #: keys from different memtable incarnations can never collide
         self._mt_ver = 0
+        #: guarded by self._lock — overlay generations handed out
+        #: (``/stats`` ``overlay.generations``)
+        self._created = 0
 
     def current(self) -> StoreSnapshot:
         base = self.base.current()
@@ -257,6 +290,7 @@ class MemtableSnapshots:
             if key == self._last_key:  # a racing builder won; take its snap
                 return self._last_snap
             self._gen = max(self._gen + 1, base.generation + 1)
+            self._created += 1
             snap = StoreSnapshot(overlay, self._gen, base.fingerprint)
             self._last_key = key
             self._last_snap = snap
@@ -290,6 +324,14 @@ class MemtableSnapshots:
     @property
     def swaps(self) -> int:
         return self.base.swaps
+
+    @property
+    def generations(self) -> int:
+        with self._lock:
+            return self._created
+
+    def stats(self) -> dict:
+        return self.base.stats() if hasattr(self.base, "stats") else {}
 
     @property
     def swapping(self) -> bool:

@@ -231,6 +231,12 @@ class Memtable:
         #: set, upserts append segments WITHOUT cascade-merging so the
         #: flush plan's segment objects stay identifiable at finalize
         self._flushing = False
+        #: guarded by self._lock — finalized flushes, their seconds (the
+        #: write, the commit and the base refresh) and their rows
+        #: (``/stats`` ``memtable``)
+        self._flushes = 0
+        self._flush_s = 0.0
+        self._flush_rows = 0
         self._m_bytes = self._m_flushes = self._m_wal_bytes = None
         if registry is not None:
             self._m_bytes = registry.gauge(
@@ -272,6 +278,18 @@ class Memtable:
             sum(self._bytes_by_code.values()),  # avdb: noqa[AVDB201] -- helper invoked only under self._lock
         )
 
+    def stats(self) -> dict:
+        """``/stats`` ``memtable``: what it holds now, what its flushes
+        took, and how many WAL frames an fsync made durable."""
+        epoch, _segs, rows, nbytes = self._published
+        with self._lock:
+            return {"rows": rows, "bytes": nbytes, "epoch": epoch,
+                    "flushes": self._flushes,
+                    "flush_s": round(self._flush_s, 6),
+                    "flush_rows": self._flush_rows,
+                    "wal_fsyncs": 0 if self.wal is None
+                    else self.wal.fsyncs}
+
     @property
     def rows(self) -> int:
         with self._lock:
@@ -298,37 +316,42 @@ class Memtable:
         the WAL."""
         built = build_rows(parsed, self.width)
         with self._lock:
-            accepted_idx: list[int] = []
-            keep_by_code: dict[int, np.ndarray] = {}
-            seen: set = set()
-            for code, (idxs, rows, ref, alt, _ann) in built.items():
-                n = len(idxs)
-                found = np.zeros(n, bool)
-                bshard = base_store.shards.get(code) \
-                    if base_store is not None else None
-                if bshard is not None:
-                    f, _gid = bshard.lookup(
-                        rows["pos"], rows["h"], ref, alt,
-                        rows["ref_len"], rows["alt_len"], host_only=True,
-                    )
-                    found |= f
-                mshard = self._shards.get(code)
-                if mshard is not None and mshard.n:
-                    f, _gid = mshard.lookup(
-                        rows["pos"], rows["h"], ref, alt,
-                        rows["ref_len"], rows["alt_len"], host_only=True,
-                    )
-                    found |= f
-                keep = np.zeros(n, bool)
-                for k, i in enumerate(idxs):
-                    ident = (code, parsed[i]["pos"], parsed[i]["ref"],
-                             parsed[i]["alt"])
-                    if found[k] or ident in seen:
-                        continue
-                    seen.add(ident)
-                    keep[k] = True
-                    accepted_idx.append(i)
-                keep_by_code[code] = keep
+            # ``upsert.apply`` is the request's stage for the work under
+            # the lock — the membership check before the WAL frame, the
+            # segment build, merge and publish after it — with the fsync
+            # (``wal_fsync``) left out: two spans of one stage
+            with reqtrace.stage(trace, "upsert.apply"):
+                accepted_idx: list[int] = []
+                keep_by_code: dict[int, np.ndarray] = {}
+                seen: set = set()
+                for code, (idxs, rows, ref, alt, _ann) in built.items():
+                    n = len(idxs)
+                    found = np.zeros(n, bool)
+                    bshard = base_store.shards.get(code) \
+                        if base_store is not None else None
+                    if bshard is not None:
+                        f, _gid = bshard.lookup(
+                            rows["pos"], rows["h"], ref, alt,
+                            rows["ref_len"], rows["alt_len"], host_only=True,
+                        )
+                        found |= f
+                    mshard = self._shards.get(code)
+                    if mshard is not None and mshard.n:
+                        f, _gid = mshard.lookup(
+                            rows["pos"], rows["h"], ref, alt,
+                            rows["ref_len"], rows["alt_len"], host_only=True,
+                        )
+                        found |= f
+                    keep = np.zeros(n, bool)
+                    for k, i in enumerate(idxs):
+                        ident = (code, parsed[i]["pos"], parsed[i]["ref"],
+                                 parsed[i]["alt"])
+                        if found[k] or ident in seen:
+                            continue
+                        seen.add(ident)
+                        keep[k] = True
+                        accepted_idx.append(i)
+                    keep_by_code[code] = keep
             if not accepted_idx:
                 return 0, len(parsed), 0
             wal_bytes = 0
@@ -343,38 +366,41 @@ class Memtable:
                 }, trace=trace)
                 if self._m_wal_bytes is not None:
                     self._m_wal_bytes.inc(wal_bytes)
-            for code, (idxs, rows, ref, alt, ann_cols) in built.items():
-                keep = keep_by_code[code]
-                if not keep.any():
-                    continue
-                seg = Segment.build(
-                    {name: col[keep] for name, col in rows.items()},
-                    ref[keep], alt[keep],
-                    annotations={
-                        col: [v for v, k in zip(vals, keep) if k]
-                        for col, vals in ann_cols.items()
-                    } or None,
-                )
-                shard = self._shards.get(code)
-                if shard is None:
-                    shard = self._shards[code] = ChromosomeShard(
-                        code, self.width
+            with reqtrace.stage(trace, "upsert.apply"):
+                for code, (idxs, rows, ref, alt, ann_cols) in built.items():
+                    keep = keep_by_code[code]
+                    if not keep.any():
+                        continue
+                    seg = Segment.build(
+                        {name: col[keep] for name, col in rows.items()},
+                        ref[keep], alt[keep],
+                        annotations={
+                            col: [v for v, k in zip(vals, keep) if k]
+                            for col, vals in ann_cols.items()
+                        } or None,
                     )
-                shard.append_segment(seg)
-                if not self._flushing:
-                    # cascade-merge like any shard so probe cost stays
-                    # flat; skipped mid-flush (the plan's segment objects
-                    # must survive until finalize removes them)
-                    shard.maintain()
-                self._bytes_by_code[code] = (
-                    self._bytes_by_code.get(code, 0) + self._seg_bytes(seg)
-                )
-            self._epoch += 1
-            if self._first_write_t is None:
-                self._first_write_t = time.monotonic()
-            if self._m_bytes is not None:
-                self._m_bytes.set(sum(self._bytes_by_code.values()))
-            self._publish_locked()
+                    shard = self._shards.get(code)
+                    if shard is None:
+                        shard = self._shards[code] = ChromosomeShard(
+                            code, self.width
+                        )
+                    shard.append_segment(seg)
+                    if not self._flushing:
+                        # cascade-merge, disjoint inserts included, so
+                        # probe cost stays flat; skipped mid-flush (the
+                        # plan's segment objects must survive until
+                        # finalize removes them)
+                        shard.maintain(merge_disjoint=True)
+                    self._bytes_by_code[code] = (
+                        self._bytes_by_code.get(code, 0)
+                        + self._seg_bytes(seg)
+                    )
+                self._epoch += 1
+                if self._first_write_t is None:
+                    self._first_write_t = time.monotonic()
+                if self._m_bytes is not None:
+                    self._m_bytes.set(sum(self._bytes_by_code.values()))
+                self._publish_locked()
             return len(accepted_idx), len(parsed) - len(accepted_idx), \
                 wal_bytes
 
@@ -416,20 +442,19 @@ class Memtable:
     # -- flush ---------------------------------------------------------------
 
     def should_flush(self) -> bool:
-        with self._lock:
-            if self._flushing:
-                return False
-            if not any(sh.n for sh in self._shards.values()):
-                return False
-            if self.flush_bytes and sum(
-                    self._bytes_by_code.values()) >= self.flush_bytes:
-                return True
-            return bool(
-                self.flush_age_s
-                and self._first_write_t is not None
-                and time.monotonic() - self._first_write_t
-                >= self.flush_age_s
-            )
+        """Whether a trigger is due, read WITHOUT the lock, as ``view()``
+        is: the event loop's tick asks, and the write path holds the lock
+        across its WAL fsync.  A stale answer costs at most one ``flush``
+        call that finds a flush in flight or nothing to flush (a noop)."""
+        _epoch, _segs, rows, nbytes = self._published
+        flushing = self._flushing  # avdb: noqa[AVDB201] -- one atomic read; flush() re-checks under the lock
+        first = self._first_write_t  # avdb: noqa[AVDB201] -- one atomic read; a stale value delays or hastens one trigger
+        if flushing or not rows:
+            return False
+        if self.flush_bytes and nbytes >= self.flush_bytes:
+            return True
+        return bool(self.flush_age_s and first is not None
+                    and time.monotonic() - first >= self.flush_age_s)
 
     def flush(self, base_manager=None) -> dict:
         """One flush pass: memtable segments -> ordinary store segments.
@@ -501,25 +526,16 @@ class Memtable:
                     self.store_dir, merged, self.width, log=self.log,
                     fence_epoch=self.fence_epoch,
                 )
-            if result["status"] != "flushed":
-                self.log(f"memtable flush aborted: {result.get('reason')}; "
-                         "rows stay in the memtable (retry on next trigger)")
-                return result
-            # visibility handover: the new generation must be pinned
-            # BEFORE the memtable drops its copy, or reads would lose the
-            # rows for up to one TTL window
-            pinned_current = True
-            if base_manager is not None:
-                try:
-                    base_manager.refresh()
-                    pinned_current = (
-                        base_manager.current().fingerprint
-                        == result["fingerprint"]
-                    )
-                except Exception as err:
-                    self.log(f"memtable flush: snapshot refresh failed "
-                             f"({err}); keeping rows in the memtable")
-                    pinned_current = False
+                if result["status"] != "flushed":
+                    self.log(f"memtable flush aborted: "
+                             f"{result.get('reason')}; rows stay in the "
+                             "memtable (retry on next trigger)")
+                    return result
+                # visibility handover: the new generation must be pinned
+                # BEFORE the memtable drops its copy, or reads would lose
+                # the rows for up to one TTL window (the refresh's
+                # ``avdb.snapshot.reload`` is a child of this span)
+                pinned_current = self._hand_over(base_manager, result)
             if not pinned_current:
                 # the flushed rows are durable on disk but the serving pin
                 # has not caught up (refresh failure, or another writer
@@ -562,6 +578,10 @@ class Memtable:
                 self._m_flushes.inc()
             result["seconds"] = round(time.perf_counter() - t0, 4)
             result["finalized"] = True
+            with self._lock:
+                self._flushes += 1
+                self._flush_s += result["seconds"]
+                self._flush_rows += int(result["rows"])
             self._ledger_record(result)
             self.log(
                 f"memtable flushed {result['rows']} row(s) to "
@@ -575,7 +595,21 @@ class Memtable:
                 self._flushing = False
                 # fold any segments appended mid-flush back into shape
                 for sh in self._shards.values():
-                    sh.maintain()
+                    sh.maintain(merge_disjoint=True)
+
+    def _hand_over(self, base_manager, result: dict) -> bool:
+        """Pin the generation the flush committed; whether the serving
+        pin is now that generation (True without a manager)."""
+        if base_manager is None:
+            return True
+        try:
+            base_manager.refresh()
+            return base_manager.current().fingerprint \
+                == result["fingerprint"]
+        except Exception as err:
+            self.log(f"memtable flush: snapshot refresh failed "
+                     f"({err}); keeping rows in the memtable")
+            return False
 
     def _ledger_record(self, result: dict) -> None:
         """Append the ``{"type": "flush"}`` record (README ledger schema).

@@ -1330,7 +1330,7 @@ class ChromosomeShard:
         self.segments.append(seg)
         self._starts_cache = None
 
-    def maintain(self) -> None:
+    def maintain(self, merge_disjoint: bool = False) -> None:
         """Keep membership-probe cost flat without paying merge copies.
 
         Two-part policy (Postgres analog: append heap pages, defer vacuum,
@@ -1345,11 +1345,17 @@ class ChromosomeShard:
           for no probe savings.  Only when the count passes MAX_SEGMENTS
           does ``_collapse`` concatenate consecutive runs back into
           MERGE_SEGMENT_CAP-sized segments (amortized O(1) copies/row).
+
+        ``merge_disjoint`` (the memtable's shards) merges disjoint tail
+        segments size-tiered too: single-row inserts at scattered positions
+        are disjoint, yet one batch of reads spans them all, so range
+        pruning skips none and every one of them would be probed.
         """
         while (len(self.segments) >= 2
                and self.segments[-2].n <= 2 * self.segments[-1].n
                and self.segments[-2].n <= MERGE_SEGMENT_CAP
-               and self.segments[-2].overlaps(self.segments[-1])):
+               and (merge_disjoint
+                    or self.segments[-2].overlaps(self.segments[-1]))):
             merged = Segment.merge(self.segments[-2], self.segments[-1])
             # single splice AFTER the merge completes: a concurrent reader
             # snapshotting the list (the loader's membership probe) must
@@ -1528,6 +1534,9 @@ class VariantStore:
         # orphan them.  None = fresh store (no on-disk lineage to adopt).
         self._sid_floor: int | None = None
         self._my_sids: set[int] = set()
+        #: backing groups ``load`` took over from a store it was handed
+        #: (``reuse``) and groups it read from disk
+        self.load_counts = {"reused": 0, "loaded": 0}
 
     def shard(self, chrom_code: int) -> ChromosomeShard:
         code = int(chrom_code)
@@ -1914,12 +1923,20 @@ class VariantStore:
         return {"npz": npz_rec, "jsonl": {"bytes": f.nbytes, "crc32": f.crc}}
 
     @classmethod
-    def load(cls, path: str, readonly: bool = False) -> "VariantStore":
+    def load(cls, path: str, readonly: bool = False,
+             reuse: "VariantStore | None" = None) -> "VariantStore":
         """Load a persisted store.  ``readonly=True`` marks the result as a
         pure read replica: ``save`` raises, and ``shard()`` refuses to
         materialize missing shards — a query for an unloaded chromosome can
         never create directories or persist empty shards as a side effect
-        (the serving path's open mode; see ``serve/snapshot.py``)."""
+        (the serving path's open mode; see ``serve/snapshot.py``).
+
+        ``reuse`` is a store loaded earlier from the same directory (the
+        serving pin a refresh replaces): a backing group whose files are
+        unchanged keeps that store's ``Segment`` object — its device cache
+        and warmed probe programs with it — instead of being read again
+        (:meth:`_reusable`).  ``load_counts`` says how many groups were
+        reused and how many read."""
         mpath = os.path.join(path, "manifest.json")
         try:
             with open(mpath) as f:
@@ -1965,11 +1982,18 @@ class VariantStore:
         verify = _verify_mode()
         from annotatedvdb_tpu.types import chromosome_code
 
+        carried = cls._reusable(reuse, path, manifest, verify)
         for label, groups in manifest["shards"].items():
             if fmt == 2:  # v2: flat id list, one file per segment
                 groups = [[sid] for sid in groups]
             shard = store.shard(chromosome_code(label))
             for group in groups:
+                seg = carried.get((label, tuple(group)))
+                if seg is not None:
+                    shard.segments.append(seg)
+                    store.load_counts["reused"] += 1
+                    continue
+                store.load_counts["loaded"] += 1
                 parts = [
                     cls._read_segment(
                         path, label, sid, store.width,
@@ -2001,6 +2025,54 @@ class VariantStore:
         # flip LAST: the loop above materializes shards via store.shard()
         store.readonly = bool(readonly)
         return store
+
+    @staticmethod
+    def _reusable(reuse: "VariantStore | None", path: str, manifest: dict,
+                  verify: str) -> dict:
+        """{(label, group): segment} of ``reuse`` that a load of
+        ``manifest`` may keep instead of reading.  A group is unchanged
+        when the same lineage (``store_uid``) lists the same seg ids under
+        the same chromosome, and every stem of it carries the same
+        write-time integrity record (bytes + crc32) in both manifests,
+        with files of those sizes on disk now.  Every writer (loader
+        ``save``, memtable flush, ``doctor compact``) writes new rows
+        under a FRESH seg id and never touches a manifested file in
+        place, so an unchanged id names unchanged bytes; the record and
+        the sizes catch an id reused after a rollback.  Nothing is reused
+        for a read-write store, a mutated segment, a stem without a
+        record, or under ``AVDB_VERIFY=deep`` (which re-reads every
+        file)."""
+        if (reuse is None or not reuse.readonly or verify == "deep"
+                or reuse._uid != manifest.get("store_uid")
+                or reuse.width != manifest.get("width")):
+            return {}
+        records = manifest.get("integrity") or {}
+        out = {}
+        for code, shard in reuse.shards.items():
+            label = chromosome_label(code)
+            for seg in shard.segments:
+                if seg.dirty or not seg.backing:
+                    continue
+                stems = [f"chr{label}.{sid:06d}" for sid in seg.backing]
+                if all(VariantStore._unchanged(
+                        path, stem, records.get(stem),
+                        reuse._integrity.get(stem)) for stem in stems):
+                    out[(label, tuple(seg.backing))] = seg
+        return out
+
+    @staticmethod
+    def _unchanged(path: str, stem: str, now: dict | None,
+                   then: dict | None) -> bool:
+        if now is None or now != then:
+            return False
+        try:
+            return all(
+                os.path.getsize(os.path.join(path, stem + ext))
+                == now[key]["bytes"]
+                for ext, key in ((".npz", "npz"), (".ann.jsonl", "jsonl"))
+            )
+        except (OSError, KeyError, TypeError):
+            return False
 
     @staticmethod
     def _check_file(fp: str, rec: dict | None, verify: str,

@@ -118,6 +118,10 @@ class WriteAheadLog:
         #: a lower seq are sealed (or pre-restart leftovers awaiting
         #: replay + the next flush's discard)
         self._seq = (existing[-1][0] + 1) if existing else 1
+        #: guarded by self._lock — appended frames whose fsync returned:
+        #: the acks the durable barrier covered (``/stats``
+        #: ``memtable.wal_fsyncs``)
+        self.fsyncs = 0
 
     # -- file naming --------------------------------------------------------
 
@@ -193,6 +197,7 @@ class WriteAheadLog:
             faults.fire("wal.fsync", f, tear_base=pre)
             with reqtrace.stage(trace, "wal_fsync"):
                 tio.fsync(f)
+            self.fsyncs += 1
         return len(frame)
 
     # -- rotation / discard (the flush protocol's WAL half) ------------------

@@ -395,3 +395,83 @@ def test_byte_parity_store_4x_budget(monkeypatch, force_device):
     for spec in ("8:1-200000", "8:100000-400000", "8:1-1000000"):
         assert budgeted.region(spec) == plain.region(spec)
         assert budgeted.region(spec, limit=10) == plain.region(spec, limit=10)
+
+
+def test_govern_carries_a_governed_segments_state_into_a_newer_generation():
+    """A segment object the manager already governs keeps its entry —
+    resident, its device cache, its heat — in a newer generation (a
+    flush's refresh keeps unchanged segments); only a segment it has not
+    seen is planned and uploaded."""
+    store, shard, queries = _build_store(2)
+    manager = ResidencyManager(budget_bytes=1 << 30, upload=True, min_rows=1,
+                               async_upload=False, plan_interval_s=0.0)
+    engine = QueryEngine(
+        StaticSnapshots(store), region_cache_size=0, residency=manager
+    )
+    for q in queries:
+        engine.lookup_many(q)
+    held = dict(manager._entries)
+    assert all(e.resident and e.seg._device is not None
+               for e in held.values())
+    uploads = manager.stats()["uploads"]
+    assert uploads == 2
+    # generation 2: the same two segment objects and one new segment
+    store2 = VariantStore(width=WIDTH)
+    cols, ref, alt, refs, alts, pos = _segment_rows(900_000)
+    store2.shard(8).segments = list(shard.segments) + [
+        Segment.build(cols, ref, alt)]
+    engine2 = QueryEngine(
+        StaticSnapshots(store2, generation=2), region_cache_size=0,
+        residency=manager,
+    )
+    for q in queries:
+        engine2.lookup_many(q)
+    stats = manager.stats()
+    assert (stats["generation"], stats["governs"], stats["carried"]) \
+        == (2, 2, 2)
+    assert stats["uploads"] == uploads  # nothing held went up again
+    for key, e in held.items():
+        assert manager._entries[key] is e and e.resident
+        assert e.shard is store2.shard(8)
+    engine2.lookup_many([f"8:{int(p)}:{r}:{a}"
+                         for p, r, a in zip(pos, refs, alts)])
+    assert manager.stats()["uploads"] == uploads + 1
+
+
+def test_an_overlay_generation_governs_without_an_upload_or_a_warm(
+        monkeypatch):
+    """An upsert makes a new overlay generation over the same base
+    segment objects: reads of it upload, warm and build nothing."""
+    from annotatedvdb_tpu.serve import MemtableSnapshots
+    from annotatedvdb_tpu.store.memtable import Memtable
+
+    store, _shard, queries = _build_store(2)
+    # the memtable's segments stay below the candidates' floor, as a few
+    # thousand upserted rows stay below DEVICE_SEGMENT_MIN
+    manager = ResidencyManager(budget_bytes=1 << 30, upload=True,
+                               min_rows=SEG_ROWS, async_upload=False,
+                               plan_interval_s=0.0)
+    mem = Memtable(width=WIDTH, flush_bytes=0, flush_age_s=0)
+    engine = QueryEngine(MemtableSnapshots(StaticSnapshots(store), mem),
+                         region_cache_size=0, residency=manager)
+    for q in queries:
+        engine.lookup_many(q)
+    warms, index_warms = [], []
+    monkeypatch.setattr(Segment, "warm_device_probe",
+                        lambda self, n, dev: warms.append(n))
+    monkeypatch.setattr(manager, "index_warmer",
+                        lambda *a: index_warms.append(a))
+    before = manager.stats()
+    for k in range(3):
+        assert mem.upsert(store, [{"code": 8, "pos": 7 + k, "ref": "A",
+                                   "alt": "T", "ref_snp": None,
+                                   "ann": None}])[0] == 1
+        got = engine.lookup_many([f"8:{7 + k}:A:T"] + queries[k % 2][:4])
+        assert all(text is not None for text in got)
+    after = manager.stats()
+    assert after["governs"] == before["governs"] + 3
+    assert after["carried"] == before["carried"] + 6
+    assert (after["uploads"], after["resident"]) \
+        == (before["uploads"], before["resident"])
+    assert warms == [] and index_warms == []
+    assert engine.memtable_read_hits == 3

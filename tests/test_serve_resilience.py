@@ -436,9 +436,9 @@ def test_snapshot_manager_reports_swapping_during_generation_load(
     seen = {}
     real_load = VariantStore.load
 
-    def spy(d, readonly=False):
+    def spy(d, readonly=False, **kw):
         seen["during_load"] = manager.swapping
-        return real_load(d, readonly=readonly)
+        return real_load(d, readonly=readonly, **kw)
 
     monkeypatch.setattr(VariantStore, "load", spy)
     assert manager.refresh() is True

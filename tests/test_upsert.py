@@ -7,6 +7,7 @@ store segments."""
 
 import json
 import os
+import threading
 import time
 import urllib.error
 import urllib.request
@@ -639,3 +640,168 @@ def test_undo_still_drops_rows_despite_adoption(tmp_path):
     assert undoer.delete_by_algorithm(9) == 2
     undoer.save(store_dir)
     assert VariantStore.load(store_dir).n == 0
+
+
+# ---------------------------------------------------------------------------
+# a refresh reads only what changed
+
+
+def _pinned_segments(mgr) -> dict:
+    """{(label, backing group): segment object} of the pinned store."""
+    return {
+        (code, tuple(seg.backing)): seg
+        for code, shard in mgr.current().store.shards.items()
+        for seg in shard.segments
+    }
+
+
+def test_refresh_keeps_unchanged_segments_and_reads_new_ones(live):
+    """A flush's refresh keeps the pinned store's Segment objects for the
+    files the new manifest still lists unchanged, and reads only the
+    segment the flush wrote; a compaction that rewrites the group is read
+    anew, whole."""
+    from annotatedvdb_tpu.store.compact import compact_store
+
+    pt, mem, mgr = live["port"], live["mem"], live["mgr"]
+    before = _pinned_segments(mgr)
+    assert _request(pt, "POST", "/variants/upsert",
+                    UPSERT_BODY)[0] == 200
+    assert mem.flush(base_manager=mgr)["finalized"]
+    after = _pinned_segments(mgr)
+    for key, seg in before.items():
+        assert after[key] is seg  # the same object: caches ride along
+    new = [seg for key, seg in after.items() if key not in before]
+    assert len(new) == 1 and new[0].n == 2
+    stats = mgr.stats()
+    assert (stats["swaps"], stats["segments_reused"],
+            stats["segments_loaded"]) == (1, 1, 1)
+    assert stats["reload_s"] > 0
+
+    report = compact_store(live["store_dir"])
+    assert report["status"] == "compacted", report
+    assert mgr.refresh()
+    compacted = _pinned_segments(mgr)
+    assert not set(map(id, compacted.values())) & set(map(id, after.values()))
+    assert mgr.stats()["segments_loaded"] == 2
+    assert mgr.current().store.n == 5
+
+
+def test_refresh_reads_a_group_whose_files_changed_under_the_same_id(tmp_path):
+    """An id reused for other bytes (a rollback, a hand-edited directory)
+    is read again: reuse needs the same integrity record and sizes."""
+    store_dir = str(tmp_path / "store")
+    _seed_store().save(store_dir)
+    mgr = SnapshotManager(store_dir, log=lambda m: None)
+    (seg,) = _pinned_segments(mgr).values()
+    path = os.path.join(store_dir, "manifest.json")
+    with open(path) as f:
+        manifest = json.load(f)
+    (stem,) = manifest["integrity"]
+    manifest["integrity"][stem]["npz"]["crc32"] ^= 1
+    with open(path, "w") as f:
+        json.dump(manifest, f)
+    assert mgr.refresh()
+    (again,) = _pinned_segments(mgr).values()
+    assert again is not seg and again.n == seg.n
+    assert mgr.stats()["segments_reused"] == 0
+    # nothing is carried into a store that may be written
+    rw = VariantStore.load(store_dir, reuse=VariantStore.load(store_dir))
+    assert rw.load_counts == {"reused": 0, "loaded": 1}
+
+
+def test_stats_account_the_write_path(live):
+    pt, mem, mgr = live["port"], live["mem"], live["mgr"]
+    _request(pt, "POST", "/variants/upsert", UPSERT_BODY)
+    _request(pt, "GET", "/variant/3:15:A:G")
+    _request(pt, "GET", "/variant/3:10:A:C")
+    _s, body = _request(pt, "GET", "/stats")
+    stats = json.loads(body)
+    assert stats["memtable"]["rows"] == 2 and stats["memtable"]["epoch"] == 1
+    assert stats["memtable"]["read_hits"] == 1
+    assert stats["overlay"]["generations"] >= 1
+    assert mem.flush(base_manager=mgr)["finalized"]
+    _s, body = _request(pt, "GET", "/stats")
+    stats = json.loads(body)
+    assert (stats["memtable"]["flushes"], stats["memtable"]["flush_rows"],
+            stats["memtable"]["rows"]) == (1, 2, 0)
+    assert stats["memtable"]["flush_s"] > 0
+    assert stats["snapshot"]["segments_reused"] == 1
+    # the upsert's apply is a stage beside its fsync
+    _s, text = _request(pt, "GET", "/metrics")
+    assert b'avdb_stage_seconds_count{stage="upsert.apply"} 1' in text
+    assert b'avdb_stage_seconds_count{stage="wal_fsync"} 1' in text
+
+
+def test_wal_fsyncs_count_the_acknowledged_frames(live, monkeypatch):
+    """``memtable.wal_fsyncs`` moves once an accepted upsert, not for a
+    shadowed one — and not for an ack whose frame was never fsync'd (an
+    append planted without its fsync), which the YCSB D cell's
+    ``fsync_short`` reads as acks the counter falls short of."""
+    pt = live["port"]
+    assert _request(pt, "POST", "/variants/upsert", UPSERT_BODY)[0] == 200
+    assert live["mem"].stats()["wal_fsyncs"] == 1
+    status, body = _request(pt, "POST", "/variants/upsert", UPSERT_BODY)
+    assert status == 200 and json.loads(body)["accepted"] == 0
+    assert live["mem"].stats()["wal_fsyncs"] == 1
+
+    def append_unsynced(self, payload, trace=None):
+        blob = json.dumps(payload).encode()
+        with open(self._path(self._seq), "ab") as f:
+            f.write(blob)
+        return len(blob)
+
+    monkeypatch.setattr(WriteAheadLog, "append", append_unsynced)
+    status, body = _request(pt, "POST", "/variants/upsert",
+                            {"variants": [{"id": "3:35:A:T"}]})
+    assert status == 200 and json.loads(body)["accepted"] == 1
+    assert live["mem"].stats()["wal_fsyncs"] == 1
+
+
+@pytest.mark.parametrize("inserts", [64, 1500])
+def test_memtable_merges_scattered_single_row_inserts(tmp_path, inserts):
+    """Single-row inserts at scattered positions are disjoint, and one
+    batch of reads spans them all: the memtable merges them size-tiered,
+    so a shard holds O(log n) segments, not one an insert, and every row
+    still answers exactly."""
+    rng = np.random.default_rng(inserts)
+    pos = rng.choice(np.arange(100, 10_000_000), inserts, replace=False)
+    mem = Memtable(width=WIDTH, store_dir=str(tmp_path),
+                   wal=WriteAheadLog(str(tmp_path), "serve-w0",
+                                     log=lambda m: None),
+                   log=lambda m: None)
+    for p in pos.tolist():
+        accepted, _shadowed, _b = mem.upsert(
+            None, [{"code": 3, "pos": p, "ref": "A", "alt": "G"}])
+        assert accepted == 1
+    _epoch, segments, rows, _bytes = mem.view()
+    assert rows == inserts
+    assert len(segments[3]) <= 2 * int(np.log2(inserts)) + 1
+    assert mem.stats()["wal_fsyncs"] == inserts
+    ref, ref_len = encode_allele_array(["A"] * inserts, WIDTH)
+    alt, alt_len = encode_allele_array(["G"] * inserts, WIDTH)
+    found, _gid = mem._shards[3].lookup(
+        pos.astype(np.int32), identity_hashes(WIDTH, ref, alt, ref_len,
+                                              alt_len),
+        ref, alt, ref_len, alt_len, host_only=True)
+    assert found.all()
+
+
+def test_should_flush_answers_while_a_write_holds_the_lock(tmp_path):
+    """The event loop's tick asks ``should_flush``; the write path holds
+    the memtable lock across its WAL fsync, so the answer must not wait
+    for it."""
+    store_dir = str(tmp_path / "store")
+    _seed_store().save(store_dir)
+    base = VariantStore.load(store_dir, readonly=True)
+    mem = Memtable(width=WIDTH, store_dir=store_dir, flush_bytes=1,
+                   flush_age_s=0, log=lambda m: None)
+    mem.upsert(base, [{"code": 3, "pos": 15, "ref": "A", "alt": "G",
+                       "ref_snp": None, "ann": None}])
+    with mem._lock:  # a write in its fsync
+        t0 = time.monotonic()
+        answer = []
+        thread = threading.Thread(target=lambda: answer.append(
+            mem.should_flush()))
+        thread.start()
+        thread.join(timeout=5)
+        assert answer == [True] and time.monotonic() - t0 < 5
