@@ -6,10 +6,12 @@ re-hash from the original strings for over-width rows (their device arrays
 are truncated, so the device hash would collide on shared prefixes), then a
 per-chromosome sorted-merge lookup against the shard.
 
-The serving read path (``serve/engine.py``) resolves client-supplied
-``chr:pos:ref:alt`` ids through :func:`identity_hashes` — the numpy twin of
-the same rule — so a query hashes byte-identically to the load that wrote
-the row.
+The serving read path (``serve/engine.py``) and the upsert path
+(``store/memtable.py``) build a group's identity columns from
+client-supplied alleles through :func:`identity_columns`: one native pass
+for ASCII groups, else :func:`identity_hashes` — the numpy twin of the
+same rule — so a query hashes byte-identically to the load that wrote the
+row.
 """
 
 from __future__ import annotations
@@ -17,8 +19,46 @@ from __future__ import annotations
 import numpy as np
 
 from annotatedvdb_tpu.io.vcf import VcfChunk
+from annotatedvdb_tpu.native import identity as native_identity
 from annotatedvdb_tpu.ops.hashing import allele_hash_jit, allele_hash_np
 from annotatedvdb_tpu.store import VariantStore
+from annotatedvdb_tpu.types import encode_allele_array
+
+#: cumulative rows of this process's :func:`identity_columns` calls (the
+#: ``store.variant_store.probe_stats`` pattern; ``/stats`` ``identity``),
+#: by route: ``native_rows`` through the one native pass, ``scalar_rows``
+#: through ``encode_allele_array`` + :func:`identity_hashes`; added once a
+#: group, never a row
+identity_stats = {"rows": 0, "native_rows": 0, "scalar_rows": 0}
+
+
+def identity_columns(refs: list, alts: list, width: int) -> tuple:
+    """``(ref, alt, ref_len, alt_len, h)`` of one group of (ref, alt)
+    allele strings: the ``[N, width]`` uint8 rows and int32 true lengths
+    of :func:`~annotatedvdb_tpu.types.encode_allele_array` for each side,
+    and :func:`identity_hashes` with its over-width override.  An ASCII
+    group with the native library loaded takes one native pass; any other
+    takes those two functions, which stay the definition — the bytes are
+    the same either way."""
+    n = len(refs)
+    identity_stats["rows"] += n
+    ref_text, alt_text = "".join(refs), "".join(alts)
+    if ref_text.isascii() and alt_text.isascii():
+        ref_len = np.fromiter(map(len, refs), np.int32, count=n)
+        alt_len = np.fromiter(map(len, alts), np.int32, count=n)
+        got = native_identity.identity_columns(
+            ref_text.encode("ascii"), ref_len,
+            alt_text.encode("ascii"), alt_len, width,
+        )
+        if got is not None:
+            identity_stats["native_rows"] += n
+            ref, alt, h = got
+            return ref, alt, ref_len, alt_len, h
+    identity_stats["scalar_rows"] += n
+    ref, ref_len = encode_allele_array(refs, width)
+    alt, alt_len = encode_allele_array(alts, width)
+    h = identity_hashes(width, ref, alt, ref_len, alt_len, refs, alts)
+    return ref, alt, ref_len, alt_len, h
 
 
 def identity_hashes(width: int, ref: np.ndarray, alt: np.ndarray,

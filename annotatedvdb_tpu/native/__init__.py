@@ -142,6 +142,18 @@ def load():
             c.c_void_p, c.c_void_p,                            # n_alts, alt_index
             c.c_void_p, c.c_void_p, c.c_int64,                # status, out, cap
         ]
+        # a pass of microseconds on the serving loop's thread keeps the
+        # GIL (PyDLL): released, the loop would queue to take it back
+        # behind whichever thread took it meanwhile
+        lib.avdb_identity_columns = ctypes.PyDLL(
+            lib._name).avdb_identity_columns
+        lib.avdb_identity_columns.restype = c.c_int64
+        lib.avdb_identity_columns.argtypes = [
+            c.c_char_p, c.c_void_p, c.c_int64,                # ref bytes, lens, total
+            c.c_char_p, c.c_void_p, c.c_int64,                # alt bytes, lens, total
+            c.c_int64, c.c_int32, c.c_void_p, c.c_int32,      # n, width, primepow, n
+            c.c_void_p, c.c_void_p, c.c_void_p,               # ref, alt, h
+        ]
         _lib = lib
         return _lib
 

@@ -7,7 +7,7 @@ hierarchical bin index (``find_bin_index`` + the ``bin_index`` ltree column)
 columnar store:
 
 - **point**: ``chr:pos:ref:alt`` resolves through the SAME identity rule
-  the loaders use (``loaders.lookup.identity_hashes``: FNV over the
+  the loaders use (``loaders.lookup.identity_columns``: FNV over the
   width-bounded allele bytes, host-string override for over-width rows),
   then one sorted-merge probe per shard (``ChromosomeShard.lookup``);
 - **bulk**: many thousands of ids per call, grouped per chromosome and
@@ -67,7 +67,7 @@ from collections import OrderedDict
 
 import numpy as np
 
-from annotatedvdb_tpu.loaders.lookup import identity_hashes
+from annotatedvdb_tpu.loaders.lookup import identity_columns
 from annotatedvdb_tpu.obs import reqtrace
 from annotatedvdb_tpu.utils.profiling import annotation
 from annotatedvdb_tpu.ops import intervals as interval_ops
@@ -88,7 +88,6 @@ from annotatedvdb_tpu.types import (
     chromosome_code,
     chromosome_label,
     decode_allele,
-    encode_allele_array,
 )
 from annotatedvdb_tpu.utils import faults
 from annotatedvdb_tpu.utils.locks import make_lock
@@ -1251,15 +1250,12 @@ class QueryEngine:
             if shard is None:
                 continue  # chromosome not loaded: every id misses
             with clock.span("lookup.hash", chrom=code, n=len(idxs)):
-                refs = [parsed[i][2] for i in idxs]
-                alts = [parsed[i][3] for i in idxs]
-                ref, ref_len = encode_allele_array(refs, width)
-                alt, alt_len = encode_allele_array(alts, width)
+                ref, alt, ref_len, alt_len, h = identity_columns(
+                    [parsed[i][2] for i in idxs],
+                    [parsed[i][3] for i in idxs], width,
+                )
                 pos = np.fromiter(
                     (parsed[i][1] for i in idxs), np.int32, count=len(idxs)
-                )
-                h = identity_hashes(
-                    width, ref, alt, ref_len, alt_len, refs, alts
                 )
                 if self.residency is not None:
                     qkey = combined_key(pos, h)
@@ -1382,13 +1378,11 @@ class QueryEngine:
         width = store.width
         n = len(parsed)
         with clock.span("lookup.hash", n=n):
-            refs = [p[2] for p in parsed]
-            alts = [p[3] for p in parsed]
-            ref, ref_len = encode_allele_array(refs, width)
-            alt, alt_len = encode_allele_array(alts, width)
+            ref, alt, ref_len, alt_len, h = identity_columns(
+                [p[2] for p in parsed], [p[3] for p in parsed], width
+            )
             pos = np.fromiter((p[1] for p in parsed), np.int32, count=n)
             chrom = np.fromiter((p[0] for p in parsed), np.int8, count=n)
-            h = identity_hashes(width, ref, alt, ref_len, alt_len, refs, alts)
         with clock.span("lookup.probe", n=n):
             got = self.mesh.bulk_lookup(
                 snap, chrom, pos, h, ref, alt, ref_len, alt_len

@@ -114,6 +114,7 @@ def readyz_payload(ctx) -> tuple[int, str]:
 
 def stats_payload(ctx) -> str:
     """The ``/stats`` body — shared like :func:`healthz_payload`."""
+    from annotatedvdb_tpu.loaders.lookup import identity_stats
     from annotatedvdb_tpu.store.variant_store import device_lookup_state
     from annotatedvdb_tpu.utils.runtime import compile_summary
 
@@ -130,6 +131,7 @@ def stats_payload(ctx) -> str:
                          "misses": ctx.engine.render_cache_misses},
         "render_batch": {"rows": ctx.engine.render_batch_rows,
                          "scalar_rows": ctx.engine.render_scalar_rows},
+        "identity": dict(identity_stats),
         "region_index": ctx.engine.region_index_stats(),
         "region_panels": dict(ctx.engine.region_panels),
     }

@@ -120,16 +120,15 @@ def build_rows(parsed: list[dict], width: int):
     ``parsed`` entries are plain data (``code``/``pos``/``ref``/``alt``/
     ``ref_snp``/``ann``) — the serve layer owns the id grammar, this
     module owns turning rows into store columns exactly as a loader
-    would: the shared identity hash (``loaders.lookup.identity_hashes``),
+    would: the shared identity columns (``loaders.lookup.identity_columns``),
     and the host bin oracle (``oracle.infer_end_location`` +
     ``closed_form_bin``) the loaders' host-fallback path uses, so an
     upserted row is bit-identical to the same row arriving through a VCF
     load.  Returns ``{code: (idxs, rows, ref, alt, ann_cols)}``.
     """
-    from annotatedvdb_tpu.loaders.lookup import identity_hashes
+    from annotatedvdb_tpu.loaders.lookup import identity_columns
     from annotatedvdb_tpu.oracle.annotator import infer_end_location
     from annotatedvdb_tpu.oracle.binindex import closed_form_bin
-    from annotatedvdb_tpu.types import encode_allele_array
 
     by_code: dict[int, list[int]] = {}
     for i, e in enumerate(parsed):
@@ -139,12 +138,10 @@ def build_rows(parsed: list[dict], width: int):
         n = len(idxs)
         refs = [parsed[i]["ref"] for i in idxs]
         alts = [parsed[i]["alt"] for i in idxs]
-        ref, ref_len = encode_allele_array(refs, width)
-        alt, alt_len = encode_allele_array(alts, width)
+        ref, alt, ref_len, alt_len, h = identity_columns(refs, alts, width)
         pos = np.fromiter(
             (parsed[i]["pos"] for i in idxs), np.int32, count=n
         )
-        h = identity_hashes(width, ref, alt, ref_len, alt_len, refs, alts)
         bin_level = np.zeros(n, np.int8)
         leaf_bin = np.zeros(n, np.int32)
         for k in range(n):

@@ -609,6 +609,59 @@ int64_t avdb_mapping_lines(
     return p - out;
 }
 
+// ---- a lookup group's identity columns, from its allele strings ----
+//
+// One pass over n (ref, alt) pairs given as the group's ref strings joined
+// with no padding (ref_bytes, row i's ref_len[i] bytes after row i-1's) and
+// the same for the alts: the columns of types.py encode_allele_array twice
+// and loaders/lookup.py identity_hashes over ASCII strings.  ref and alt
+// ([n, width] each) get the row's bytes truncated at the width and zero
+// padded; h gets fnv_row, or for a row with an allele longer than the
+// width the full-string FNV of vcf_loader.py _fnv32_str (the length bytes,
+// then every ref byte, then every alt byte).  primepow[k] is prime^k for k
+// in [0, pp_n).  Returns 0, or -1 (nothing written) if a length is
+// negative or the lengths do not add up to ref_total / alt_total.
+int64_t avdb_identity_columns(
+    const uint8_t* ref_bytes, const int32_t* ref_len, int64_t ref_total,
+    const uint8_t* alt_bytes, const int32_t* alt_len, int64_t alt_total,
+    int64_t n, int32_t width, const uint32_t* primepow, int32_t pp_n,
+    uint8_t* ref, uint8_t* alt, uint32_t* h) {
+    int64_t rsum = 0, asum = 0;
+    for (int64_t i = 0; i < n; ++i) {
+        if (ref_len[i] < 0 || alt_len[i] < 0) return -1;
+        rsum += ref_len[i];
+        asum += alt_len[i];
+    }
+    if (rsum != ref_total || asum != alt_total) return -1;
+    const uint32_t prime = 16777619u;
+    const uint8_t* rs = ref_bytes;
+    const uint8_t* as = alt_bytes;
+    for (int64_t i = 0; i < n; ++i) {
+        int32_t rl = ref_len[i], al = alt_len[i];
+        uint8_t* rrow = ref + i * width;
+        uint8_t* arow = alt + i * width;
+        int rc = rl < width ? rl : width;
+        int ac = al < width ? al : width;
+        memcpy(rrow, rs, static_cast<size_t>(rc));
+        memset(rrow + rc, 0, static_cast<size_t>(width - rc));
+        memcpy(arow, as, static_cast<size_t>(ac));
+        memset(arow + ac, 0, static_cast<size_t>(width - ac));
+        if (rl > width || al > width) {
+            uint32_t v = 2166136261u;
+            v = (v ^ static_cast<uint32_t>(rl & 0xFF)) * prime;
+            v = (v ^ static_cast<uint32_t>(al & 0xFF)) * prime;
+            for (int32_t k = 0; k < rl; ++k) v = (v ^ rs[k]) * prime;
+            for (int32_t k = 0; k < al; ++k) v = (v ^ as[k]) * prime;
+            h[i] = v;
+        } else {
+            h[i] = fnv_row(rrow, arow, width, rl, al, primepow, pp_n);
+        }
+        rs += rl;
+        as += al;
+    }
+    return 0;
+}
+
 }  // extern "C"
 
 // ---- a FREQ-bearing row's frequency sidecar, from its INFO span ----
