@@ -129,6 +129,15 @@ LOOKUP_STAGES = ("lookup.parse", "lookup.hash", "lookup.probe",
 REGION_STAGES = ("regions.parse", "regions.spans", "regions.rows",
                  "regions.render")
 
+#: a single ``GET /region`` read, split the same way
+#: (``serve.engine.region_serve``): getting the chromosome's base and
+#: delta interval indexes (a cache hit, or a build); locating the rows —
+#: base span, delta span, their merge, the page; rendering the envelope's
+#: text.  Observed by the ENGINE once a read it located (a region-LRU hit
+#: locates nothing); the render only where the engine renders, not a
+#: body streamed on the event loop
+REGION_READ_STAGES = ("region.index", "region.locate", "region.render")
+
 
 def stage_histograms(registry, stages) -> dict:
     """{stage: its ``avdb_stage_seconds`` series} on ``registry``."""

@@ -38,11 +38,14 @@ Rendered region responses sit in a small LRU keyed by store generation
 the next loader commit swaps the generation and naturally invalidates it.
 
 **Batched interval intersection (BITS).**  Region reads — single AND
-batched — resolve through a per-generation :class:`IntervalIndex`: one
-position-sorted, first-wins-deduplicated ``(pos, segment, row)`` view per
-chromosome group, against which every query interval is two sorted-
-endpoint binary searches (``ops/intervals``: the BITS kernel, arXiv
-1208.3407).  :meth:`QueryEngine.regions_serve` answers thousands of
+batched — resolve through an :class:`IntervalIndex`: one position-sorted,
+first-wins-deduplicated ``(pos, segment, row)`` view per chromosome group,
+against which every query interval is two sorted-endpoint binary searches
+(``ops/intervals``: the BITS kernel, arXiv 1208.3407).  A generation's
+view is two parts (:class:`RegionParts`): the base index of the segments
+the store holds, kept with its device copy across the generations live
+inserts make, and a small host delta of everything newer (memtable
+overlay segments), merged per read — byte-identical to one whole index.  :meth:`QueryEngine.regions_serve` answers thousands of
 intervals in ONE device call per touched chromosome group — per-interval
 envelopes byte-identical to N sequential :meth:`QueryEngine.region`
 calls, a count-only mode that never materializes rows (a span width IS
@@ -452,6 +455,9 @@ def _render_columnar(seg, j: np.ndarray, ref_len: np.ndarray,
 #: a region answer that shows no row: (segment index, local row) arrays
 _NO_ROWS = (np.empty(0, np.int32), np.empty(0, np.int64))
 
+#: no index position selected
+_NO_SEL = np.empty(0, np.int64)
+
 #: rows a region answer hands the columnar pass at a time.  A panel's per-row
 #: cost is flat from ~250 to ~1,000 rows a call (PERF.md section 6, PR 33);
 #: a block is also what a streamed body holds rendered, so not larger.
@@ -614,24 +620,89 @@ class RegionPage:
         return self.prefix() + ",".join(self.rows()) + self.suffix()
 
 
+def _identity(seg, j: int) -> tuple:
+    """The identity a first-wins compare uses: lengths and allele bytes."""
+    return (
+        int(seg.cols["ref_len"][j]), int(seg.cols["alt_len"][j]),
+        seg.ref[j].tobytes(), seg.alt[j].tobytes(),
+    )
+
+
+def _sorted_rows(segments, first: int = 0, stop: int | None = None):
+    """``(pos, h, si, jj)`` of every row of ``segments[first:stop]`` — ``si``
+    the row's index in ``segments``, ``jj`` its local row — ordered by
+    (pos, hash, segment age) and first-wins deduplicated EXACTLY as
+    :meth:`QueryEngine._region_rows` resolves a single region.
+
+    The common case (no (pos, hash) collision — loader-deduplicated rows)
+    is three vectorized numpy ops; when collisions exist, the per-row
+    identity walk runs over ONLY the colliding (pos, hash) runs (a
+    singleton row can never be a duplicate), so one shadowed duplicate on
+    a 100M-row chromosome costs a few rows of Python, not a
+    full-chromosome loop."""
+    pos_parts, h_parts, si_parts, jj_parts = [], [], [], []
+    for si in range(first, len(segments) if stop is None else stop):
+        seg = segments[si]
+        if seg.n == 0:
+            continue
+        pos_parts.append(seg.cols["pos"])
+        h_parts.append(seg.cols["h"])
+        si_parts.append(np.full(seg.n, si, np.int32))
+        jj_parts.append(np.arange(seg.n, dtype=np.int64))
+    if not pos_parts:
+        return (np.empty(0, np.int32), np.empty(0, np.uint32),
+                np.empty(0, np.int32), np.empty(0, np.int64))
+    pos = np.concatenate(pos_parts)
+    h = np.concatenate(h_parts)
+    si = np.concatenate(si_parts)
+    jj = np.concatenate(jj_parts)
+    order = np.lexsort((si, h, pos))
+    ps, hs = pos[order], h[order]
+    si_o, jj_o = si[order], jj[order]
+    same = (ps[1:] == ps[:-1]) & (hs[1:] == hs[:-1])
+    if not bool(np.any(same)):
+        # no (pos, hash) collision anywhere: duplicates are impossible
+        # and the sorted view IS the dedup'd view (vectorized path)
+        return ps, hs, si_o, jj_o
+    # collision case: only members of a multi-row (pos, hash) run can be
+    # duplicates — walk those rows (and only those) with the exact
+    # first-wins identity compare of _region_rows
+    run_member = np.zeros(order.shape[0], bool)
+    run_member[1:] |= same
+    run_member[:-1] |= same
+    keep = np.ones(order.shape[0], bool)
+    run_key = None
+    run_seen: list = []  # identities kept for the current (pos, h)
+    for t in np.nonzero(run_member)[0].tolist():
+        key = (int(ps[t]), int(hs[t]))
+        if key != run_key:
+            run_key, run_seen = key, []
+        ident = _identity(segments[int(si_o[t])], int(jj_o[t]))
+        if ident in run_seen:  # shadowed duplicate in a newer segment
+            keep[t] = False
+        else:
+            run_seen.append(ident)
+    return ps[keep], hs[keep], si_o[keep], jj_o[keep]
+
+
 class IntervalIndex:
     """One chromosome group's deduplicated, position-sorted row view —
     the BITS "database" every interval query searches against.
 
-    Built once per (store generation, chromosome): every segment's rows
-    concatenated, ordered by (pos, hash, segment age) and first-wins
+    Built over a run of the shard's segments from the oldest (``segs``:
+    the segment objects it covers, all of them for :meth:`build`'s
+    default), ordered by (pos, hash, segment age) and first-wins
     deduplicated EXACTLY as :meth:`QueryEngine._region_rows` resolves a
-    single region — so a query's ``[lo, hi)`` span over ``pos`` is the
-    region's post-dedup match list verbatim, a span width is the exact
-    region count, and an N-interval panel shares one O(n log n) build
-    instead of paying N per-query dedup passes.  The common case (no
-    cross-segment (pos, hash) collisions — loader-deduplicated stores)
-    builds with three vectorized numpy ops; when collisions exist, the
-    per-row Python identity walk runs over ONLY the colliding (pos, hash)
-    runs (a singleton row can never be a duplicate), so one shadowed
-    duplicate on a 100M-row chromosome costs a few rows of Python, not a
-    full-chromosome loop.  The run-walk is ``_region_rows``'s dedup
-    policy verbatim — the parity suite pins them byte-identical.
+    single region (:func:`_sorted_rows`) — so a query's ``[lo, hi)`` span
+    over ``pos`` is the region's post-dedup match list verbatim, a span
+    width is the exact region count, and an N-interval panel shares one
+    O(n log n) build instead of paying N per-query dedup passes.
+
+    A serving engine keys it by ``segs``, not by store generation: the
+    segments a server holds resident are the BASE of every generation an
+    insert makes on top of them (:class:`RegionParts`), so the index, its
+    device copy and its decoded feature columns (``feats``) outlive the
+    overlay generations.
 
     ``device_pos()`` lazily uploads the sentinel-padded position array
     once per index, so a panel's kernel calls re-use the resident copy
@@ -640,12 +711,16 @@ class IntervalIndex:
     has its copy installed already, after every span program ran against
     it once (``warmed``)."""
 
-    __slots__ = ("pos", "si", "jj", "_dev_pos", "warmed")
+    __slots__ = ("pos", "si", "jj", "segs", "feats", "_dev_pos", "warmed")
 
-    def __init__(self, pos, si, jj):
+    def __init__(self, pos, si, jj, segs: tuple = ()):
         self.pos = pos  # [K] int32, sorted
         self.si = si    # [K] int32 segment index per kept row
         self.jj = jj    # [K] int64 local row per kept row
+        self.segs = segs
+        #: the :class:`StatsColumns` aligned to this index, decoded once
+        #: (:meth:`QueryEngine._stats_features`)
+        self.feats = None
         self._dev_pos = None
         #: every span program a panel can ask for has run against the
         #: installed device copy
@@ -656,58 +731,26 @@ class IntervalIndex:
         return int(self.pos.shape[0])
 
     @classmethod
-    def build(cls, shard) -> "IntervalIndex":
-        pos_parts, h_parts, si_parts, jj_parts = [], [], [], []
-        for si, seg in enumerate(shard.segments):
-            if seg.n == 0:
-                continue
-            pos_parts.append(seg.cols["pos"])
-            h_parts.append(seg.cols["h"])
-            si_parts.append(np.full(seg.n, si, np.int32))
-            jj_parts.append(np.arange(seg.n, dtype=np.int64))
-        if not pos_parts:
-            return cls(np.empty(0, np.int32), np.empty(0, np.int32),
-                       np.empty(0, np.int64))
-        pos = np.concatenate(pos_parts)
-        h = np.concatenate(h_parts)
-        si = np.concatenate(si_parts)
-        jj = np.concatenate(jj_parts)
-        order = np.lexsort((si, h, pos))
-        ps, hs = pos[order], h[order]
-        same = (ps[1:] == ps[:-1]) & (hs[1:] == hs[:-1])
-        if not bool(np.any(same)):
-            # no (pos, hash) collision anywhere: duplicates are impossible
-            # and the sorted view IS the dedup'd view (vectorized path)
-            return cls(np.ascontiguousarray(ps),
-                       np.ascontiguousarray(si[order]),
-                       np.ascontiguousarray(jj[order]))
-        # collision case: only members of a multi-row (pos, hash) run can
-        # be duplicates — walk those rows (and only those) with the exact
-        # first-wins identity compare of _region_rows
-        run_member = np.zeros(order.shape[0], bool)
-        run_member[1:] |= same
-        run_member[:-1] |= same
-        keep = np.ones(order.shape[0], bool)
-        run_key = None
-        run_seen: list = []  # identities kept for the current (pos, h)
-        si_o, jj_o = si[order], jj[order]
-        for t in np.nonzero(run_member)[0].tolist():
-            key = (int(ps[t]), int(hs[t]))
-            if key != run_key:
-                run_key, run_seen = key, []
-            seg = shard.segments[int(si_o[t])]
-            j = int(jj_o[t])
-            ident = (
-                int(seg.cols["ref_len"][j]), int(seg.cols["alt_len"][j]),
-                seg.ref[j].tobytes(), seg.alt[j].tobytes(),
-            )
-            if ident in run_seen:  # shadowed duplicate in a newer segment
-                keep[t] = False
-            else:
-                run_seen.append(ident)
-        return cls(np.ascontiguousarray(ps[keep]),
-                   np.ascontiguousarray(si_o[keep]),
-                   np.ascontiguousarray(jj_o[keep]))
+    def build(cls, shard, stop: int | None = None) -> "IntervalIndex":
+        """The index of ``shard.segments[:stop]`` (all of them by
+        default)."""
+        pos, _h, si, jj = _sorted_rows(shard.segments, 0, stop)
+        return cls(np.ascontiguousarray(pos), np.ascontiguousarray(si),
+                   np.ascontiguousarray(jj), tuple(shard.segments[:stop]))
+
+    def fold(self, delta: "DeltaIndex", segs: tuple) -> "IntervalIndex":
+        """This index with ``delta``'s rows merged in — the index a whole
+        :meth:`build` over ``segs`` gives, in one O(n) pass instead of a
+        sort.  Feature columns decoded on both sides merge the same way."""
+        at = delta.at
+        out = IntervalIndex(np.insert(self.pos, at, delta.pos),
+                            np.insert(self.si, at, delta.si),
+                            np.insert(self.jj, at, delta.jj), segs)
+        if self.feats is not None and delta.feats is not None:
+            out.feats = StatsColumns(*(
+                np.insert(a, at, b) for a, b in
+                zip(self.feats.columns(), delta.feats.columns())))
+        return out
 
     def upload(self):
         """A fresh sentinel-padded device copy of the position array, not
@@ -744,11 +787,134 @@ class IntervalIndex:
         self.warmed = False
 
 
+class DeltaIndex(IntervalIndex):
+    """The rows of a shard that its base :class:`IntervalIndex` does not
+    cover — the segments after the base's: memtable overlay segments and
+    flushed segments not yet folded into a base — as an index of their
+    own, host-only and small (thousands of rows, not millions).
+
+    Built like any index (:func:`_sorted_rows`), then each row's (pos,
+    hash) is searched in the base: a row that repeats an identity the base
+    holds is shadowed (dropped — the base's segments are older, and
+    first-wins is the store's policy), and every other row learns ``at``,
+    the base row it sorts before in (pos, hash, segment age) order.  The
+    base and this index then merge by ``np.insert`` without a compare: a
+    read's rows, a fold into a new base (:meth:`IntervalIndex.fold`)."""
+
+    __slots__ = ("at", "shadowed")
+
+    @classmethod
+    def build(cls, shard, base: IntervalIndex) -> "DeltaIndex":
+        segments = shard.segments
+        pos, h, si, jj = _sorted_rows(segments, len(base.segs))
+        at = np.searchsorted(base.pos, pos, side="left")
+        top = np.searchsorted(base.pos, pos, side="right")
+        keep = np.ones(pos.shape[0], bool)
+        # rows at a position the base holds rows at: place them by hash
+        # among that position's base rows, and drop a repeated identity
+        for t in np.flatnonzero(top > at).tolist():
+            lo, hi = int(at[t]), int(top[t])
+            b_si, b_jj = base.si[lo:hi].tolist(), base.jj[lo:hi].tolist()
+            b_h = [int(segments[s].cols["h"][j]) for s, j in zip(b_si, b_jj)]
+            ht = int(h[t])
+            ident = None
+            for s, j, hb in zip(b_si, b_jj, b_h):
+                if hb != ht:
+                    continue
+                if ident is None:
+                    ident = _identity(segments[int(si[t])], int(jj[t]))
+                if _identity(segments[s], j) == ident:
+                    keep[t] = False
+                    break
+            at[t] = lo + sum(hb <= ht for hb in b_h)
+        index = cls(pos[keep], si[keep], jj[keep], tuple(segments))
+        index.at = at[keep]
+        index.shadowed = int(pos.shape[0] - index.n)
+        return index
+
+    def spans(self, starts, ends) -> tuple:
+        """(lo, hi) per query interval (the BITS search on the host)."""
+        starts, ends = interval_ops.clamped_queries(starts, ends)
+        return (np.searchsorted(self.pos, starts, side="left"),
+                np.searchsorted(self.pos, ends, side="right"))
+
+
+class RegionParts:
+    """A chromosome's rows as a region read sees them in one store
+    generation: the ``base`` index of the segments the server made
+    resident (kept, with its device copy, across the generations inserts
+    make) and the ``delta`` of everything newer (None when there is none:
+    a read-only server's reads take exactly the whole-index route).
+
+    A read's count is the two span widths summed (the delta holds no row
+    the base shadows), its rows are the two spans merged in (pos, hash,
+    segment age) order — byte for byte what one whole index over the
+    shard answers (``tests/test_region_delta_index.py`` pins both)."""
+
+    __slots__ = ("base", "delta", "_whole")
+
+    def __init__(self, base: IntervalIndex, delta: "DeltaIndex | None"):
+        self.base = base
+        self.delta = delta
+        self._whole = None
+
+    def delta_spans(self, starts, ends) -> tuple:
+        """(lo, hi) per query interval in the delta; zeros without one."""
+        if self.delta is None:
+            zero = np.zeros(len(starts), np.int64)
+            return zero, zero
+        return self.delta.spans(starts, ends)
+
+    def rows(self, b_lo: int, b_hi: int, d_lo: int, d_hi: int,
+             limit: int | None = None) -> tuple:
+        """(si, jj) of the rows of base span ``[b_lo, b_hi)`` and delta
+        span ``[d_lo, d_hi)`` — one interval's — merged, the first
+        ``limit`` of them (all by default).  Without a delta row they are
+        slices of the base index: no row is touched before the render."""
+        base, delta = self.base, self.delta
+        if limit is not None:
+            b_hi = min(b_hi, b_lo + limit)
+            d_hi = min(d_hi, d_lo + limit)
+        si, jj = base.si[b_lo:b_hi], base.jj[b_lo:b_hi]
+        if delta is None or d_hi <= d_lo:
+            return si, jj
+        # a delta row of the interval sorts before base row ``at``, which
+        # lies inside the base span (or just past it); a base span cut by
+        # ``limit`` takes the rows past its end at its end, in order
+        at = np.minimum(delta.at[d_lo:d_hi] - b_lo, si.shape[0])
+        si = np.insert(si, at, delta.si[d_lo:d_hi])
+        jj = np.insert(jj, at, delta.jj[d_lo:d_hi])
+        if limit is not None:
+            si, jj = si[:limit], jj[:limit]
+        return si, jj
+
+    def selected(self, b_sel: np.ndarray, d_sel: np.ndarray) -> tuple:
+        """(si, jj) of base rows ``b_sel`` and delta rows ``d_sel``
+        (ascending positions in each index) merged."""
+        si, jj = self.base.si[b_sel], self.base.jj[b_sel]
+        if not d_sel.shape[0]:
+            return si, jj
+        delta = self.delta
+        at = np.searchsorted(b_sel, delta.at[d_sel], side="left")
+        return (np.insert(si, at, delta.si[d_sel]),
+                np.insert(jj, at, delta.jj[d_sel]))
+
+    def whole(self) -> IntervalIndex:
+        """One index over the whole shard (the base itself without a
+        delta; else the two folded, once): for a consumer that walks the
+        index's arrays directly (``export/stream``)."""
+        if self.delta is None:
+            return self.base
+        if self._whole is None:
+            self._whole = self.base.fold(self.delta, self.delta.segs)
+        return self._whole
+
+
 class StatsColumns:
     """One chromosome group's decoded analytics feature columns, aligned
     row-for-row to its :class:`IntervalIndex`.
 
-    The JSONB sidecar is decoded ONCE per (store generation, chromosome)
+    The JSONB sidecar is decoded ONCE per index (held as its ``feats``)
     — ``feature_values`` walks every index row exactly one time — into:
 
     - ``cadd_f``/``rank_f`` float64 (NaN = missing): the exact values the
@@ -760,10 +926,11 @@ class StatsColumns:
       (``ops.stats.STATS_MISSING`` = absent): the stats kernels' inputs.
 
     Because the columns align to the index (position-sorted, first-wins
-    deduplicated, memtable overlay segments included), a BITS span over
-    the index IS a slice of these columns — filters vectorize and the
-    fused stats kernel reduces over them directly.  ``device()`` uploads
-    the sentinel-padded kernel columns once per generation (the
+    deduplicated), a BITS span over the index IS a slice of these columns
+    — filters vectorize and the fused stats kernel reduces over them
+    directly; a base and its delta each have their own, and every number
+    the kernels give is a sum, so the two parts' add up.  ``device()``
+    uploads the sentinel-padded kernel columns once per index (the
     ``IntervalIndex.device_pos`` discipline; same pow2 capacity, so the
     traced program is shared)."""
 
@@ -776,6 +943,11 @@ class StatsColumns:
         self.cadd_fp = cadd_fp
         self.rank_i = rank_i
         self._dev = None
+
+    def columns(self) -> tuple:
+        """The five host columns, in the constructor's order."""
+        return self.cadd_f, self.rank_f, self.af_fp, self.cadd_fp, \
+            self.rank_i
 
     @classmethod
     def build(cls, shard, index: "IntervalIndex") -> "StatsColumns":
@@ -1000,6 +1172,19 @@ class _PanelClock(_StageClock):
         self.scalar_rows = 0
 
 
+class _RegionClock(_StageClock):
+    """One single-region read's sub-stage seconds
+    (``reqtrace.REGION_READ_STAGES``), observed once a read
+    (:meth:`QueryEngine._region_done`); ``delta`` says whether its count or
+    page took a delta row."""
+
+    __slots__ = ("delta",)
+
+    def __init__(self):
+        super().__init__(reqtrace.REGION_READ_STAGES)
+        self.delta = False
+
+
 class _LookupClock(_StageClock):
     """One ``lookup_many`` call's sub-stage seconds
     (``reqtrace.LOOKUP_STAGES``, parent ``device``) and render tallies.
@@ -1046,9 +1231,15 @@ class QueryEngine:
     #: entries x record-size of RSS in a long-lived gc.freeze'd process
     POINT_RENDER_CACHE_BYTES = 64 << 20
 
-    #: retained interval indexes (one per (generation, chromosome); a
-    #: generation swap naturally ages the old entries out of the LRU)
+    #: retained base interval indexes, keyed by the segments each covers
+    #: (the LRU's cap over all chromosomes) ...
     INDEX_CACHE = 64
+    #: ... and a chromosome's cap: the base a fold replaced stays one
+    #: round for the requests that still hold the generation before it
+    BASES_PER_CHROMOSOME = 2
+    #: retained (generation, chromosome) :class:`RegionParts` — a base
+    #: shared by reference and the generation's small delta
+    PARTS_CACHE = 64
     #: byte ceiling on RETAINED device copies of interval indexes (the
     #: BITS kernel's search arrays) AND stats feature columns (the fused
     #: analytics kernel's inputs, ~3x the position bytes per group) —
@@ -1059,13 +1250,6 @@ class QueryEngine:
     #: could pin dozens of chromosome-sized arrays of HBM on a large
     #: store.
     INDEX_DEVICE_BYTES = 256 << 20
-
-    #: retained stats feature-column sets (one per (generation,
-    #: chromosome), the INDEX_CACHE discipline; ~33 bytes/row each).
-    #: Sized like INDEX_CACHE — a human store loads ~24 chromosome
-    #: groups, and a cross-chromosome filtered workload cycling past the
-    #: cap would re-pay the full-chromosome sidecar decode per request
-    STATS_CACHE = 64
 
     def __init__(self, snapshots, registry=None,
                  region_cache_size: int | None = None, residency=None,
@@ -1109,17 +1293,19 @@ class QueryEngine:
         self._cache_lock = make_lock("serve.engine.cache")
         #: guarded by self._cache_lock
         self._region_cache: OrderedDict = OrderedDict()
-        #: guarded by self._cache_lock; (generation, region, filters) ->
-        #: (si, j) int64 arrays of the walk's post-filter matches, so an
-        #: N-page cursor walk scans the region once, not once per page
+        #: (generation, region, filters) -> (si, j) arrays of the walk's
+        #: post-filter matches and whether a delta row is among them, so
+        #: an N-page cursor walk scans the region once, not once per page
+        #: guarded by self._cache_lock
         self._walk_cache: OrderedDict = OrderedDict()
-        #: guarded by self._cache_lock; (generation, code) ->
-        #: :class:`IntervalIndex` (the BITS search database per group)
+        #: (code, ids of the segments it covers) -> base
+        #: :class:`IntervalIndex` (the BITS search database per group; it
+        #: holds the segments, so no id is reused while the entry lives)
+        #: guarded by self._cache_lock
         self._index_cache: OrderedDict = OrderedDict()
         #: guarded by self._cache_lock; (generation, code) ->
-        #: :class:`StatsColumns` (sidecar features decoded ONCE per
-        #: generation — shared by stats kernels and region filters)
-        self._stats_cache: OrderedDict = OrderedDict()
+        #: :class:`RegionParts`
+        self._parts_cache: OrderedDict = OrderedDict()
         #: guarded by self._cache_lock; id(index) -> (index, bytes) for
         #: indexes holding a device copy — the INDEX_DEVICE_BYTES ledger
         self._index_device: OrderedDict = OrderedDict()
@@ -1130,6 +1316,9 @@ class QueryEngine:
         #: be an N-fold memory spike for identical results.  Losers wait
         #: and take the winner's entry from the cache.
         self._index_build_lock = make_lock("serve.engine.index_build")
+        #: the same for a generation's deltas (milliseconds, not seconds:
+        #: never queued behind a whole build)
+        self._delta_build_lock = make_lock("serve.engine.delta_build")
         if registry is not None:
             self._cache_hits = registry.counter(
                 "avdb_query_cache_hits_total",
@@ -1160,17 +1349,38 @@ class QueryEngine:
             ("panels", "intervals", "device_groups", "host_groups",
              "rows_rendered", "rows_batched", "rows_scalar", "streamed",
              "transfers"), 0)
-        #: ``/stats`` ``region_index``: interval indexes built and device
-        #: copies uploaded, counted once each wherever it happened (the
-        #: uploader's thread or, lazily, a request)
+        #: ``/stats`` ``region_index``: whole-chromosome base indexes
+        #: made (``builds``: sorted from the segments, ``base_builds``, or
+        #: folded from a base and the rows a store refresh added,
+        #: ``flush_builds``), of the sorts those a request's thread paid
+        #: (``request_builds``), device copies uploaded, deltas built and
+        #: the rows of each chromosome's newest — counted once each
+        #: wherever it happened (the uploader's or the refresh's thread,
+        #: or lazily a request) — ints under the GIL
         self.index_builds = 0
         self.index_uploads = 0
+        self.base_builds = 0
+        self.flush_builds = 0
+        self.request_builds = 0
+        self.delta_builds = 0
+        #: guarded by self._cache_lock; code -> rows of its newest delta
+        self._delta_rows: dict = {}
+        #: ``/stats`` ``region_scans``: single-region reads the engine
+        #: located (a region-LRU hit locates nothing) and, of those, the
+        #: reads whose count or page took a delta row
+        self.region_scans = {"reads": 0, "delta_reads": 0}
         if residency is not None:
             # a segment the manager uploads has its chromosome's interval
             # index built, uploaded and warmed on the same thread, before
             # the segment counts as resident
             residency.index_warmer = self.warm_region_index
-        self._lookup_hist = self._regions_hist = None
+        # a store refresh (a memtable flush, a loader commit) folds the
+        # segments it adds into the bases it extends before the new
+        # generation is pinned (:meth:`prepare_region_bases`)
+        base_manager = getattr(snapshots, "base", snapshots)
+        if hasattr(base_manager, "add_preparer"):
+            base_manager.add_preparer(self.prepare_region_bases)
+        self._lookup_hist = self._regions_hist = self._region_hist = None
         self._m_render_hits = self._m_render_misses = None
         self._m_batch_rows = self._m_scalar_rows = None
         if registry is not None:
@@ -1179,6 +1389,9 @@ class QueryEngine:
             )
             self._regions_hist = reqtrace.stage_histograms(
                 registry, reqtrace.REGION_STAGES
+            )
+            self._region_hist = reqtrace.stage_histograms(
+                registry, reqtrace.REGION_READ_STAGES
             )
             self._m_render_hits = registry.counter(
                 "avdb_render_cache_hits_total",
@@ -1517,16 +1730,31 @@ class QueryEngine:
             text = self._cache_get(cache_key)
             if text is not None:
                 return "text", text
+        clock = _RegionClock()
         page = self._region_page(
             snap, code, start, end, min_cadd, max_conseq_rank, limit,
-            cursor, host_only,
+            cursor, host_only, clock,
         )
         if stream_threshold is not None and page.returned > stream_threshold:
+            self._region_done(clock, rendered=False)
             return "page", page
-        text = page.assemble()
+        with clock.span("region.render", n=page.returned):
+            text = page.assemble()
+        self._region_done(clock, rendered=True)
         if cache_key is not None:
             self._cache_put(cache_key, text)
         return "text", text
+
+    def _region_done(self, clock: "_RegionClock", rendered: bool) -> None:
+        """One located single-region read's accounts, added once: the
+        ``/stats`` ``region_scans`` tallies and its sub-stages."""
+        self.region_scans["reads"] += 1
+        if clock.delta:
+            self.region_scans["delta_reads"] += 1
+        if self._region_hist is not None:
+            for stage in reqtrace.REGION_READ_STAGES:
+                if rendered or stage != "region.render":
+                    self._region_hist[stage].observe(clock.ns[stage] / 1e9)
 
     def regions_serve(self, specs: list, min_cadd=None, max_conseq_rank=None,
                       limit: int | None = None, tokenize: bool = False,
@@ -1578,9 +1806,11 @@ class QueryEngine:
         n = len(parsed)
         lo = np.zeros(n, np.int64)
         hi = np.zeros(n, np.int64)
+        d_lo = np.zeros(n, np.int64)  # the delta's spans (zero without)
+        d_hi = np.zeros(n, np.int64)
         level = np.zeros(n, np.int64)
         leaf = np.zeros(n, np.int64)
-        indexes: dict[int, IntervalIndex | None] = {}
+        indexes: dict[int, RegionParts | None] = {}
         mesh_spans = None
         if self.mesh is not None and not host_only:
             # ONE sharded stacked-BITS call for the whole panel (every
@@ -1597,15 +1827,15 @@ class QueryEngine:
                         )
                         for code, idxs in by_code.items()
                     },
-                    lambda code: self._interval_index(snap, code),
+                    lambda code: self._base_index(snap, code),
                 )
         for code, idxs in by_code.items():
             t_group = time.perf_counter_ns()
             with clock.span("regions.spans", chrom=code, n=len(idxs)):
                 starts = [parsed[i][1] for i in idxs]
                 ends = [parsed[i][2] for i in idxs]
-                index = indexes[code] = self._interval_index(snap, code)
-                if index is None:
+                parts = indexes[code] = self._region_parts(snap, code)
+                if parts is None:
                     level[idxs], leaf[idxs] = interval_ops.bin_tokens_host(
                         starts, ends
                     )
@@ -1619,9 +1849,10 @@ class QueryEngine:
                     clock.device_groups += 1
                 else:
                     g_lo, g_hi, g_level, g_leaf = self._interval_spans(
-                        index, code, starts, ends, host_only, clock
+                        parts.base, code, starts, ends, host_only, clock
                     )
                 lo[idxs], hi[idxs] = g_lo, g_hi
+                d_lo[idxs], d_hi[idxs] = parts.delta_spans(starts, ends)
                 level[idxs], leaf[idxs] = g_level, g_leaf
             # per-group sub-span onto the request's trace (no-op outside
             # an active trace): a panel's every interval shares the
@@ -1633,60 +1864,46 @@ class QueryEngine:
             )
         with clock.span("regions.rows", n=n):
             pages = self._region_pages(
-                snap, parsed, indexes, lo, hi, level, leaf,
+                snap, parsed, indexes, lo, hi, d_lo, d_hi, level, leaf,
                 min_cadd, max_conseq_rank, limit,
             )
         tokens = None
         if tokenize:
             # the PR-8 envelope now lives in export.tokens — the export
-            # packer shares the exact field list and path renderer
+            # packer shares the exact field list and path renderer; a
+            # span into the whole index is the base's and the delta's
+            # summed (a row before the interval is in one or the other)
             tokens = build_region_tokens(
                 snap.generation,
                 [parsed[i][0] for i in range(n)],
-                level, leaf, lo, hi,
+                level, leaf, lo + d_lo, hi + d_hi,
                 [indexes[parsed[i][0]] is not None for i in range(n)],
             )
         result = RegionsResult(pages, tokens, clock)
         self._regions_done(clock, n, result.returned)
         return result
 
-    def _region_pages(self, snap, parsed, indexes, lo, hi, level, leaf,
-                      min_cadd, max_conseq_rank, limit) -> list:
+    def _region_pages(self, snap, parsed, indexes, lo, hi, d_lo, d_hi,
+                      level, leaf, min_cadd, max_conseq_rank,
+                      limit) -> list:
         """One :class:`RegionPage` per parsed interval, request order:
-        the rows of its span located (and filtered), cut to ``limit``."""
+        the rows of its spans located (and filtered), cut to ``limit``."""
         no_filters = min_cadd is None and max_conseq_rank is None
+        take = None if limit is None else max(int(limit), 0)
         pages = []
         for i, (code, start, end) in enumerate(parsed):
-            index = indexes[code]
+            parts = indexes[code]
             shard = snap.store.shards.get(code)
             label = chromosome_label(code)
-            i_lo, i_hi = int(lo[i]), int(hi[i])
-            span = i_hi - i_lo
-            if index is None:
+            if parts is None:
                 si, jj = _NO_ROWS
                 count = 0
-            elif no_filters:
-                # the index is deduplicated, so the span width IS the
-                # post-filter count — take ONLY the rows that will render
-                # (limit=0 is the pure count-only mode: none), as slices
-                # of the index: no row is touched before the render
-                count = span
-                take = span if limit is None \
-                    else min(max(int(limit), 0), span)
-                si = index.si[i_lo:i_lo + take]
-                jj = index.jj[i_lo:i_lo + take]
             else:
-                # filters vectorize over the cached feature columns —
-                # never a per-row sidecar parse (semantics pinned
-                # byte-identical to the scalar _passes definition)
-                sel = self._filter_span(
-                    snap, code, index, i_lo, i_hi, min_cadd,
+                si, jj, count, _delta = self._span_rows(
+                    shard, parts, int(lo[i]), int(hi[i]), int(d_lo[i]),
+                    int(d_hi[i]), take, no_filters, min_cadd,
                     max_conseq_rank,
                 )
-                count = int(sel.shape[0])
-                if limit is not None:
-                    sel = sel[:max(int(limit), 0)]
-                si, jj = index.si[sel], index.jj[sel]
             pages.append(RegionPage(
                 shard, label, int(level[i]),
                 closed_form_path(label, int(level[i]), int(leaf[i])),
@@ -1694,6 +1911,35 @@ class QueryEngine:
                 f"{label}:{start}-{end}", None, paged=False,
             ))
         return pages
+
+    def _span_rows(self, shard, parts: RegionParts, b_lo: int, b_hi: int,
+                   d_lo: int, d_hi: int, take: int | None,
+                   no_filters: bool, min_cadd, max_conseq_rank) -> tuple:
+        """One interval's answer from its base span ``[b_lo, b_hi)`` and
+        delta span ``[d_lo, d_hi)``: ``(si, jj, count, delta)`` — the
+        first ``take`` matching rows (all of them for None), the
+        post-filter count, and whether a delta row counted."""
+        if no_filters:
+            # the parts are deduplicated, so the span widths ARE the
+            # post-filter count — take ONLY the rows that will render
+            # (take=0 is the pure count-only mode: none)
+            si, jj = parts.rows(b_lo, b_hi, d_lo, d_hi, take)
+            return si, jj, (b_hi - b_lo) + (d_hi - d_lo), d_hi > d_lo
+        # filters vectorize over the cached feature columns — never a
+        # per-row sidecar parse (semantics pinned byte-identical to the
+        # scalar _passes definition)
+        b_sel = self._filter_span(shard, parts.base, b_lo, b_hi, min_cadd,
+                                  max_conseq_rank)
+        d_sel = _NO_SEL if d_hi <= d_lo else self._filter_span(
+            shard, parts.delta, d_lo, d_hi, min_cadd, max_conseq_rank)
+        count = int(b_sel.shape[0] + d_sel.shape[0])
+        delta = d_sel.shape[0] > 0
+        if take is not None:
+            b_sel, d_sel = b_sel[:take], d_sel[:take]
+        si, jj = parts.selected(b_sel, d_sel)
+        if take is not None:
+            si, jj = si[:take], jj[:take]
+        return si, jj, count, delta
 
     @staticmethod
     def panel_clock() -> "_PanelClock":
@@ -1796,8 +2042,8 @@ class QueryEngine:
             t_group = time.perf_counter_ns()
             starts = [parsed[i][1] for i in idxs]
             ends = [parsed[i][2] for i in idxs]
-            index = self._interval_index(snap, code)
-            if index is None:
+            parts = self._region_parts(snap, code)
+            if parts is None:
                 # unloaded/empty chromosome: the zero-row reductions (the
                 # host twin over empty columns keeps every shape exact)
                 empty = np.empty(0, np.int32)
@@ -1808,6 +2054,7 @@ class QueryEngine:
                     empty, empty, starts, ends, windows
                 ) if windows is not None else None
             else:
+                index = parts.base
                 feats = self._stats_features(snap, code, index)
                 panel = self._stats_panel(
                     code, index, feats, starts, ends, host_only
@@ -1815,6 +2062,20 @@ class QueryEngine:
                 wins = self._stats_windows(
                     code, index, feats, starts, ends, windows, host_only
                 ) if windows is not None else None
+                if parts.delta is not None:
+                    # every number of a panel is a sum over its rows: the
+                    # delta's, on the host twin, add to the base's
+                    delta = parts.delta
+                    d_feats = self._stats_features(snap, code, delta)
+                    panel = tuple(a + b for a, b in zip(
+                        panel, stats_ops.stats_panel_host(
+                            delta.pos, d_feats.af_fp, d_feats.cadd_fp,
+                            d_feats.rank_i, starts, ends)))
+                    if wins is not None:
+                        wins = tuple(a + b for a, b in zip(
+                            wins, stats_ops.windowed_stats_host(
+                                delta.pos, d_feats.cadd_fp, starts, ends,
+                                windows)))
             lo, hi, af_l, af_h, c_l, c_h, rk = panel
             for k, i in enumerate(idxs):
                 block = stats_ops.windows_summary(
@@ -1838,46 +2099,33 @@ class QueryEngine:
 
     def _stats_features(self, snap, code: int,
                         index: IntervalIndex) -> StatsColumns:
-        """The (generation, chromosome) feature columns, decoded lazily
-        and LRU-retained — builds coalesce under the index build lock
-        (a decode is a full-column sidecar walk; N concurrent misses
-        must not pay it N times)."""
-        key = (snap.generation, code)
-        with self._cache_lock:
-            feats = self._stats_cache.get(key)
-            if feats is not None:
-                self._stats_cache.move_to_end(key)
-                return feats
-        with self._index_build_lock:
-            with self._cache_lock:
-                feats = self._stats_cache.get(key)
-                if feats is not None:
-                    self._stats_cache.move_to_end(key)
-                    return feats
-            feats = StatsColumns.build(snap.store.shards.get(code), index)
-            evicted: list[StatsColumns] = []
-            with self._cache_lock:
-                self._stats_cache[key] = feats
-                while len(self._stats_cache) > self.STATS_CACHE:
-                    _k, old = self._stats_cache.popitem(last=False)
-                    # the device-byte ledger must not keep an evicted
-                    # column set (and its HBM copies) alive behind the
-                    # cache's back — the _index_cache discipline
-                    if self._index_device.pop(id(old), None) is not None:
-                        evicted.append(old)
-        for old in evicted:
-            old.drop_device()
-        return feats
+        """``index``'s feature columns (``snap``'s chromosome ``code``)."""
+        return self._features(snap.store.shards.get(code), index)
 
-    def _filter_span(self, snap, code: int, index: IntervalIndex,
-                     i_lo: int, i_hi: int, min_cadd, max_conseq_rank):
+    def _features(self, shard, index: IntervalIndex) -> StatsColumns:
+        """``index``'s feature columns, decoded lazily and kept on it (a
+        base's outlive the generations its deltas come and go in) —
+        decodes coalesce under the index's build lock (a decode is a
+        full-column sidecar walk; N concurrent misses must not pay it N
+        times)."""
+        if index.feats is not None:
+            return index.feats
+        lock = self._delta_build_lock if isinstance(index, DeltaIndex) \
+            else self._index_build_lock
+        with lock:
+            if index.feats is None:
+                index.feats = StatsColumns.build(shard, index)
+        return index.feats
+
+    def _filter_span(self, shard, index: IntervalIndex, i_lo: int,
+                     i_hi: int, min_cadd, max_conseq_rank):
         """Index positions of ``[i_lo, i_hi)`` passing the annotation
         filters — one vectorized compare over the cached feature columns
         instead of a JSON decode per row per request.  NaN (missing
         annotation) never satisfies a predicate, exactly like the scalar
         :meth:`_passes` definition (the reference's
         ``WHERE (col->>'x')::numeric`` NULL semantics)."""
-        feats = self._stats_features(snap, code, index)
+        feats = self._features(shard, index)
         keep = np.ones(i_hi - i_lo, bool)
         with np.errstate(invalid="ignore"):  # NaN compares are the point
             if min_cadd is not None:
@@ -1941,7 +2189,7 @@ class QueryEngine:
         position array + feature columns) with their ledger entries —
         one failure path to maintain, not one per kernel."""
         breaker = self.breaker
-        if (not host_only
+        if (not host_only and index.n
                 and n_queries >= self.stats_device_min
                 and (breaker is None or breaker.allow_device(code))):
             try:
@@ -1968,8 +2216,10 @@ class QueryEngine:
 
     def _region_page(self, snap, code, start, end,
                      min_cadd, max_conseq_rank, limit,
-                     cursor: str | None, host_only: bool = False
-                     ) -> RegionPage:
+                     cursor: str | None, host_only: bool = False,
+                     clock: "_RegionClock | None" = None) -> RegionPage:
+        if clock is None:
+            clock = _RegionClock()
         label = chromosome_label(code)
         level, leaf = _region_bin(start, end)
         shard = snap.store.shards.get(code)
@@ -1983,144 +2233,258 @@ class QueryEngine:
                 hit = self._walk_cache.get(wkey)
                 if hit is not None:
                     self._walk_cache.move_to_end(wkey)
-        full_count = None
         if hit is None:
-            si, jj = _NO_ROWS  # (segment index, local row) of each match
-            index = self._interval_index(snap, code)
-            if index is not None:
-                self._touch_region(shard, start, end, 1)
-                # the single-region route rides the SAME interval-index +
-                # BITS-span machinery as the batch API (one query is just
-                # a panel of one); the breaker/host_only fallback is
-                # byte-identical
-                lo, hi, _lvl, _leaf = self._interval_spans(
-                    index, code, [start], [end], host_only
-                )
-                i_lo, i_hi = int(lo[0]), int(hi[0])
-                if min_cadd is not None or max_conseq_rank is not None:
-                    # filters vectorize over the cached feature columns
-                    # (decoded once per generation) — the per-row
-                    # sidecar-parse hot spot is gone; semantics pinned
-                    # byte-identical to the scalar _passes definition
-                    sel = self._filter_span(
-                        snap, code, index, i_lo, i_hi, min_cadd,
-                        max_conseq_rank,
+            with clock.span("region.index", chrom=code):
+                parts = self._region_parts(snap, code)
+        with clock.span("region.locate", chrom=code):
+            count = 0
+            if hit is None:
+                si, jj = _NO_ROWS  # (segment index, local row) of each match
+                took_delta = False
+                if parts is not None:
+                    self._touch_region(shard, start, end, 1)
+                    # the single-region route rides the SAME interval-index
+                    # + BITS-span machinery as the batch API (one query is
+                    # just a panel of one); the breaker/host_only fallback
+                    # is byte-identical
+                    lo, hi, _lvl, _leaf = self._interval_spans(
+                        parts.base, code, [start], [end], host_only
                     )
-                    si, jj = index.si[sel], index.jj[sel]
-                else:
-                    if not paged:
-                        # dedup'd span width IS the count; no filter pass
-                        # and no walk cache to fill — take only the rows
-                        # that will render
-                        full_count = i_hi - i_lo
-                        take = full_count if limit is None \
-                            else min(max(int(limit), 0), full_count)
-                        i_hi = i_lo + take
-                    si, jj = index.si[i_lo:i_hi], index.jj[i_lo:i_hi]
-            if paged:
-                # without this an N-page walk re-runs the full region
-                # scan + filter pass per page (O(N x region) for what the
-                # client sees as keyset pagination); copies, so a cached
-                # walk never pins a stale generation's whole index
-                hit = (si.copy(), jj.copy())
-                with self._cache_lock:
-                    self._walk_cache[wkey] = hit
-                    while len(self._walk_cache) > self.WALK_CACHE:
-                        self._walk_cache.popitem(last=False)
-        if paged:
-            total = int(hit[0].shape[0])
-            ckey = _cursor_key(code, start, end, min_cadd, max_conseq_rank)
-            offset = decode_cursor(cursor, ckey)
-            stop = total if limit is None \
-                else min(offset + max(int(limit), 0), total)
+                    d_lo, d_hi = parts.delta_spans([start], [end])
+                    # unpaged: only the rows that will render; a cursor
+                    # walk takes every match once, for all its pages
+                    si, jj, count, took_delta = self._span_rows(
+                        shard, parts, int(lo[0]), int(hi[0]),
+                        int(d_lo[0]), int(d_hi[0]),
+                        None if paged or limit is None
+                        else max(int(limit), 0),
+                        min_cadd is None and max_conseq_rank is None,
+                        min_cadd, max_conseq_rank,
+                    )
+                if paged:
+                    # without this an N-page walk re-runs the full region
+                    # scan + filter pass per page (O(N x region) for what
+                    # the client sees as keyset pagination); copies, so a
+                    # cached walk never pins a stale generation's whole
+                    # index
+                    hit = (si.copy(), jj.copy(), took_delta)
+                    with self._cache_lock:
+                        self._walk_cache[wkey] = hit
+                        while len(self._walk_cache) > self.WALK_CACHE:
+                            self._walk_cache.popitem(last=False)
+            clock.delta = hit[2] if paged else took_delta
             next_token = None
-            # a page must ADVANCE to mint a continuation (limit=0
-            # count-only pages would otherwise hand back a
-            # self-referential token and loop a cursor-following client
-            # forever)
-            if stop < total and stop > offset:
-                next_token = encode_cursor(snap.generation, stop, ckey)
-            # page sub-span: every page of a cursor walk attributes its
-            # scan to the walking request's trace id (no-op untraced)
-            reqtrace.record_active(f"region.chr{label}", t_page,
-                                   time.perf_counter_ns())
-            return RegionPage(
-                shard, label, level, closed_form_path(label, level, leaf),
-                total, snap.generation, hit[0][offset:stop],
-                hit[1][offset:stop], f"{label}:{start}-{end}",
-                next_token, paged=True,
-            )
-        matched = int(jj.shape[0])
-        stop = matched if limit is None else max(int(limit), 0)
+            if paged:
+                total = count = int(hit[0].shape[0])
+                ckey = _cursor_key(code, start, end, min_cadd,
+                                   max_conseq_rank)
+                offset = decode_cursor(cursor, ckey)
+                stop = total if limit is None \
+                    else min(offset + max(int(limit), 0), total)
+                # a page must ADVANCE to mint a continuation (limit=0
+                # count-only pages would otherwise hand back a
+                # self-referential token and loop a cursor-following
+                # client forever)
+                if stop < total and stop > offset:
+                    next_token = encode_cursor(snap.generation, stop, ckey)
+                si, jj = hit[0][offset:stop], hit[1][offset:stop]
+        # page sub-span: every page of a cursor walk attributes its scan
+        # to the walking request's trace id (no-op untraced)
         reqtrace.record_active(f"region.chr{label}", t_page,
                                time.perf_counter_ns())
         return RegionPage(
             shard, label, level, closed_form_path(label, level, leaf),
-            matched if full_count is None else full_count,
-            snap.generation, si[:stop], jj[:stop],
-            f"{label}:{start}-{end}", None, paged=False,
+            count, snap.generation, si, jj, f"{label}:{start}-{end}",
+            next_token, paged=paged,
         )
 
     # -- interval index (the BITS search database) ---------------------------
 
-    def _interval_index(self, snap, code: int) -> IntervalIndex | None:
-        """The (generation, chromosome) interval index, built lazily and
-        LRU-retained; ``None`` when the chromosome is unloaded or empty.
-        Stale generations age out of the cap like every other
-        generation-keyed cache here — their keys can never be probed
-        again."""
+    def _region_parts(self, snap, code: int) -> RegionParts | None:
+        """The (generation, chromosome) :class:`RegionParts`, made lazily
+        and LRU-retained; ``None`` when the chromosome is unloaded or
+        empty.  The base is the retained one whose segments begin the
+        shard's — sorted here only when none does, a request's build
+        (``request_builds``); the delta of the segments after it is built
+        here once per generation.  Stale generations age out of the cap
+        like every other generation-keyed cache here."""
         shard = snap.store.shards.get(code)
         if shard is None or not shard.n:
             return None
-        return self._index_of(snap.generation, code, shard)
-
-    def _index_of(self, generation: int, code: int, shard) -> IntervalIndex:
-        """``shard``'s interval index under its (generation, chromosome)
-        key: the cached one, or built here — by a request that found none,
-        or ahead of requests by :meth:`warm_region_index`."""
-        key = (generation, code)
+        key = (snap.generation, code)
         with self._cache_lock:
-            index = self._index_cache.get(key)
-            if index is not None:
-                self._index_cache.move_to_end(key)
-                return index
+            parts = self._parts_cache.get(key)
+            if parts is not None:
+                self._parts_cache.move_to_end(key)
+                return parts
+        base = self._base_of(code, shard, request=True)
+        if len(base.segs) == len(shard.segments):
+            parts = RegionParts(base, None)
+        else:
+            with self._delta_build_lock:
+                # double-checked: concurrent readers of a fresh generation
+                # build its delta once
+                with self._cache_lock:
+                    parts = self._parts_cache.get(key)
+                if parts is not None:
+                    return parts
+                delta = DeltaIndex.build(shard, base)
+                self.delta_builds += 1
+                parts = RegionParts(base, delta if delta.n else None)
+                self._install_parts(key, code, parts)
+            return parts
+        self._install_parts(key, code, parts)
+        return parts
+
+    def _install_parts(self, key: tuple, code: int,
+                       parts: RegionParts) -> None:
+        with self._cache_lock:
+            self._parts_cache[key] = parts
+            self._delta_rows[code] = \
+                0 if parts.delta is None else parts.delta.n
+            while len(self._parts_cache) > self.PARTS_CACHE:
+                self._parts_cache.popitem(last=False)
+
+    def _base_index(self, snap, code: int) -> IntervalIndex | None:
+        """The chromosome's base index in ``snap`` (the mesh's stacked
+        search covers the bases; the deltas stay on the host)."""
+        parts = self._region_parts(snap, code)
+        return None if parts is None else parts.base
+
+    def _interval_index(self, snap, code: int) -> IntervalIndex | None:
+        """One index over the whole (generation, chromosome) — the base
+        itself when no delta stands beside it — for a consumer that walks
+        an index's arrays directly (``export/stream``); ``None`` when the
+        chromosome is unloaded or empty.  Beside a delta that is a
+        whole-chromosome fold on the caller's thread, counted as a
+        request's build."""
+        parts = self._region_parts(snap, code)
+        if parts is None:
+            return None
+        if parts.delta is not None and parts._whole is None:
+            self.index_builds += 1
+            self.request_builds += 1
+        return parts.whole()
+
+    def _cached_base(self, code: int, segs) -> IntervalIndex | None:
+        """The retained base whose segments are the longest run that
+        ``segs`` begins with (caller holds ``self._cache_lock``)."""
+        best = None
+        for (c, _ids), base in self._index_cache.items():  # avdb: noqa[AVDB201] -- helper invoked only under self._cache_lock (every call site holds it)
+            k = len(base.segs)
+            if c != code or k > len(segs) \
+                    or (best is not None and k <= len(best.segs)):
+                continue
+            if all(a is b for a, b in zip(base.segs, segs)):
+                best = base
+        if best is not None:
+            self._index_cache.move_to_end(  # avdb: noqa[AVDB201] -- helper invoked only under self._cache_lock
+                (code, tuple(id(seg) for seg in best.segs)))
+        return best
+
+    def _base_of(self, code: int, shard, request: bool) -> IntervalIndex:
+        """``shard``'s base index: the retained one, or built here over
+        the segments the store itself holds (an overlay's memtable
+        segments are the delta's) — by a request that found none, or
+        ahead of requests by :meth:`warm_region_index`."""
+        with self._cache_lock:
+            base = self._cached_base(code, shard.segments)
+        if base is not None:
+            return base
         with self._index_build_lock:
             # double-checked: the winner of the race built it while this
             # thread waited — take the cached entry instead of paying a
             # duplicate full-chromosome sort
             with self._cache_lock:
-                index = self._index_cache.get(key)
-                if index is not None:
-                    self._index_cache.move_to_end(key)
-                    return index
-            index = IntervalIndex.build(shard)
+                base = self._cached_base(code, shard.segments)
+            if base is not None:
+                return base
+            base = IntervalIndex.build(
+                shard, getattr(shard, "overlay_base", None))
             self.index_builds += 1
-            evicted: list[IntervalIndex] = []
+            self.base_builds += 1
+            if request:
+                self.request_builds += 1
+            self._install_base(code, base)
+        return base
+
+    def _install_base(self, code: int, base: IntervalIndex) -> None:
+        """Retain ``base``: the LRU keeps ``INDEX_CACHE`` bases over all
+        chromosomes and ``BASES_PER_CHROMOSOME`` of each; an evicted base
+        leaves the device-byte ledger with its copies (caller holds
+        ``self._index_build_lock``)."""
+        evicted: list = []
+        with self._cache_lock:
+            self._index_cache[(code, tuple(id(seg) for seg in base.segs))] \
+                = base
+            same = [key for key in self._index_cache if key[0] == code]
+            for key in same[:-self.BASES_PER_CHROMOSOME]:
+                evicted.append(self._index_cache.pop(key))
+            while len(self._index_cache) > self.INDEX_CACHE:
+                evicted.append(self._index_cache.popitem(last=False)[1])
+            # the device-byte ledger must not keep an evicted index (and
+            # its HBM copies) alive behind the cache's back
+            held = [obj for old in evicted for obj in (old, old.feats)
+                    if obj is not None
+                    and self._index_device.pop(id(obj), None) is not None]
+        for obj in held:
+            obj.drop_device()
+
+    def prepare_region_bases(self, store) -> None:
+        """Fold the segments a store refresh adds into each retained base
+        they extend, before the refreshed generation is pinned — on the
+        refreshing thread (a memtable flush's, whoever refreshes after a
+        loader commit), never a request's: the rows the refresh added are
+        merged into the base in one O(n) pass (:meth:`IntervalIndex.fold`,
+        no sort), a base with a device copy gets the folded one uploaded
+        and warmed at every span shape, and its feature columns fold too.
+        Until the new generation is pinned, reads keep the old base and a
+        delta that holds the added rows.  A chromosome whose base no
+        retained one begins (a compaction rewrote it) is left to the
+        residency warm or a request."""
+        for code, shard in store.shards.items():
+            segs = shard.segments
             with self._cache_lock:
-                self._index_cache[key] = index
-                while len(self._index_cache) > self.INDEX_CACHE:
-                    _k, old = self._index_cache.popitem(last=False)
-                    # the device-byte ledger must not keep the evicted
-                    # index (and its HBM copy) alive behind the cache's
-                    # back
-                    if self._index_device.pop(id(old), None) is not None:
-                        evicted.append(old)
-        for old in evicted:
-            old.drop_device()
-        return index
+                base = self._cached_base(code, segs)
+            if base is None or len(base.segs) == len(segs):
+                continue
+            with self._index_build_lock, annotation(
+                    "avdb.regions.fold", chrom=code, rows=shard.n):
+                delta = DeltaIndex.build(shard, base)
+                if base.feats is not None:
+                    delta.feats = StatsColumns.build(shard, delta)
+                folded = base.fold(delta, tuple(segs))
+                if base._dev_pos is not None:
+                    dev = folded.upload()
+                    self.index_uploads += 1
+                    if base.warmed:
+                        interval_ops.warm_spans(
+                            dev, self.regions_device_min, self.regions_max
+                        )
+                    folded._dev_pos = dev
+                    folded.warmed = base.warmed
+                if folded.feats is not None and base.feats._dev is not None:
+                    folded.feats.device()
+                self.index_builds += 1
+                self.flush_builds += 1
+                self._install_base(code, folded)
+            self._note_index_device(folded)
+            if folded.feats is not None:
+                self._note_index_device(folded.feats)
 
     def warm_region_index(self, generation: int, code: int, shard) -> None:
-        """Make ``shard``'s interval index ready for panels before any
-        asks: build it, upload its position array, run the span program
-        once at every query shape a panel's group can take
+        """Make ``shard``'s base interval index ready for panels before
+        any asks: build it, upload its position array, run the span
+        program once at every query shape a panel's group can take
         (``interval_ops.span_query_shapes`` of this engine's knobs)
         against that copy, and only then install it.  The residency
         manager's uploader calls this for each segment it has uploaded,
-        before the segment counts as resident — never inside a request.
+        before the segment counts as resident — never inside a request
+        (``generation`` names the governed one; a base outlives it).
         The copy comes out of ``INDEX_DEVICE_BYTES`` like a lazily
         uploaded one."""
-        index = self._index_of(generation, code, shard)
-        if index.warmed:
+        index = self._base_of(code, shard, request=False)
+        if index.warmed or not index.n:
             return
         with annotation("avdb.regions.warm", chrom=code, rows=index.n):
             dev = index._dev_pos
@@ -2137,18 +2501,22 @@ class QueryEngine:
     def region_index_stats(self) -> dict:
         """``/stats`` ``region_index``: of the chromosomes that hold a
         residency candidate segment in the governed generation
-        (``candidates``), how many have their interval index ``built`` and
-        how many have it on the ``device``, uploaded and probed by every
-        warmed shape — a server is ready for region reads when ``device``
-        equals ``candidates``; and the ``builds`` and ``uploads`` this
-        process has made, each counted once."""
-        generation, codes = (
+        (``candidates``), how many have their base interval index
+        ``built`` and how many have it on the ``device``, uploaded and
+        probed by every warmed shape — a server is ready for region reads
+        when ``device`` equals ``candidates``; the builds, uploads and
+        deltas this process has made (``__init__`` says which is which),
+        and ``delta_rows``, the rows of each chromosome's newest delta,
+        summed."""
+        _generation, codes = (
             self.residency.candidate_chromosomes()
             if self.residency is not None else (None, ())
         )
+        shards = self.snapshots.current().store.shards if codes else {}
         with self._cache_lock:
-            held = [self._index_cache.get((generation, code))
-                    for code in codes]
+            held = [self._cached_base(code, shards[code].segments)
+                    if code in shards else None for code in codes]
+            delta_rows = sum(self._delta_rows.values())
         return {
             "candidates": len(held),
             "built": sum(index is not None for index in held),
@@ -2156,6 +2524,11 @@ class QueryEngine:
                           for index in held),
             "builds": self.index_builds,
             "uploads": self.index_uploads,
+            "base_builds": self.base_builds,
+            "flush_builds": self.flush_builds,
+            "request_builds": self.request_builds,
+            "delta_builds": self.delta_builds,
+            "delta_rows": delta_rows,
         }
 
     def _touch_region(self, shard, start: int, end: int, n: int) -> None:
@@ -2202,7 +2575,7 @@ class QueryEngine:
         either way, the serving contract.  A panel's ``clock`` is told
         who answered the group."""
         breaker = self.breaker
-        if (not host_only
+        if (not host_only and index.n
                 and len(starts) >= self.regions_device_min
                 and (breaker is None or breaker.allow_device(code))):
             try:

@@ -134,6 +134,7 @@ def stats_payload(ctx) -> str:
         "identity": dict(identity_stats),
         "region_index": ctx.engine.region_index_stats(),
         "region_panels": dict(ctx.engine.region_panels),
+        "region_scans": dict(ctx.engine.region_scans),
     }
     if ctx.engine.residency is not None:
         stats["residency"] = ctx.engine.residency.stats()

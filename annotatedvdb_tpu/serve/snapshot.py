@@ -15,13 +15,18 @@ reference's Postgres snapshot isolation).
 - ``current()`` hands out the snapshot (queries hold it for their whole
   execution — the swap can never tear one mid-read);
 - ``refresh()`` fingerprints ``manifest.json`` (one ``stat``), loads the
-  new generation OFF-lock when it changed, then swaps the pin atomically.
+  new generation OFF-lock when it changed (one load at a time), then swaps
+  the pin atomically.
   The load keeps every segment of the pinned store whose files the new
   manifest lists unchanged (``VariantStore.load(reuse=...)``), with its
   device cache and warmed programs: a memtable flush reads only the
   segments it wrote.
   The ``snapshot.swap`` fault point fires between load and swap: a failure
   there must leave the old generation serving, which the fault matrix pins.
+  Between the two, each registered preparer (``add_preparer``: the query
+  engine's, which folds the added segments into its interval indexes) gets
+  the loaded store, so what a generation's reads need is made on the
+  refreshing thread before any request can pin it.
 - ``maybe_refresh()`` is the front end's coalesced entry point: at serving
   QPS a per-request ``stat`` is real syscall pressure, so freshness checks
   collapse to one ``stat`` per ``AVDB_SERVE_SNAPSHOT_TTL_MS`` window
@@ -38,6 +43,7 @@ from __future__ import annotations
 import os
 import threading
 import time
+import weakref
 
 from annotatedvdb_tpu.store import VariantStore
 from annotatedvdb_tpu.utils import faults
@@ -87,6 +93,10 @@ class SnapshotManager:
         self.log = log if log is not None else (lambda msg: None)
         self.ttl_s = _ttl_from_env() if ttl_s is None else max(float(ttl_s), 0.0)
         self._lock = make_lock("serve.snapshot.pin")
+        #: held by the one refresh that loads: a second refresh of the
+        #: same manifest would load it again (and prepare a store that is
+        #: never pinned)
+        self._loading = make_lock("serve.snapshot.load")
         fingerprint = _manifest_fingerprint(store_dir)
         store = VariantStore.load(store_dir, readonly=True)
         #: guarded by self._lock
@@ -100,6 +110,9 @@ class SnapshotManager:
         self._loaded = 0
         #: guarded by self._lock
         self._next_check = 0.0  # monotonic deadline of the next free stat
+        #: ``prepare(store)`` callbacks run on a loaded store before its
+        #: pin (weak: a discarded engine is not kept alive by its hook)
+        self._preparers: list = []
         #: True while a NEW generation is loading (between the changed
         #: fingerprint and the pin swap) — the readiness probe reports
         #: not-ready so a fleet router drains traffic off a warming
@@ -113,6 +126,28 @@ class SnapshotManager:
         snapshot object they hold."""
         with self._lock:
             return self._snap
+
+    def add_preparer(self, method) -> None:
+        """Run bound ``method(store)`` on every newly loaded store before
+        it is pinned, on the refreshing thread.  A preparer that raises
+        is logged and the swap goes ahead: it may only make reads of the
+        new generation cheaper, never possible."""
+        with self._lock:
+            self._preparers = [ref for ref in self._preparers
+                               if ref() is not None]
+            self._preparers.append(weakref.WeakMethod(method))
+
+    def _prepare(self, store) -> None:
+        with self._lock:
+            methods = [ref() for ref in self._preparers]
+        for method in methods:
+            if method is None:
+                continue
+            try:
+                method(store)
+            except Exception as err:
+                self.log(f"snapshot refresh: preparing the new generation "
+                         f"failed ({type(err).__name__}: {err})")
 
     @property
     def swaps(self) -> int:
@@ -144,15 +179,26 @@ class SnapshotManager:
             if now < self._next_check:
                 return False
             self._next_check = now + self.ttl_s
-        return self.refresh()
+        return self.refresh(wait=False)
 
-    def refresh(self) -> bool:
+    def refresh(self, wait: bool = True) -> bool:
         """Swap to the on-disk generation if it changed; returns True on a
-        swap.  The expensive load runs OFF-lock (readers keep being served
-        from the old pin); load failures — a commit racing the stat, a torn
-        directory mid-repair — keep the old generation and report False,
-        because a serving process must degrade to stale before it degrades
-        to down."""
+        swap.  The expensive load runs OFF the pin's lock (readers keep
+        being served from the old pin), one refresh at a time: with
+        ``wait`` a caller that must see the result (a flush's hand-over)
+        waits for a refresh in flight, without it returns False at once
+        (the refresh in flight pins what it finds).  Load failures — a
+        commit racing the stat, a torn directory mid-repair — keep the old
+        generation and report False, because a serving process must
+        degrade to stale before it degrades to down."""
+        if not self._loading.acquire(blocking=wait):
+            return False
+        try:
+            return self._refresh()
+        finally:
+            self._loading.release()
+
+    def _refresh(self) -> bool:
         with self._lock:
             pinned = self._snap
         try:
@@ -172,6 +218,7 @@ class SnapshotManager:
                 self.log(f"snapshot refresh failed, keeping generation "
                          f"{pinned.generation}: {err}")
                 return False
+            self._prepare(store)
             # crash point: the new generation is fully loaded, the pin has
             # not moved — a failure here must leave the old generation
             # serving (and readiness recover: the finally clears the flag)
@@ -219,10 +266,14 @@ class _OverlayStore:
         #: {code: the first global id of the shard that is a memtable row}
         #: (every global id at or above it was upserted since the flush)
         self.overlay_from = {}
+        # each shard's ``overlay_base``: how many of its segments the base
+        # store holds (the rest are the memtable's) — where the query
+        # engine's base interval index ends and its delta begins
         for code, bshard in base_store.shards.items():
             sh = ChromosomeShard(code, self.width)
             sh.segments = list(bshard.segments) \
                 + list(mem_segments.get(code, ()))
+            sh.overlay_base = len(bshard.segments)
             shards[code] = sh
             self.overlay_from[code] = bshard.n
         for code, segs in mem_segments.items():
@@ -230,6 +281,7 @@ class _OverlayStore:
                 continue
             sh = ChromosomeShard(code, self.width)
             sh.segments = list(segs)
+            sh.overlay_base = 0
             shards[code] = sh
             self.overlay_from[code] = 0
         self.shards = shards

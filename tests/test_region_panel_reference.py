@@ -316,14 +316,18 @@ def test_after_the_warm_step_no_panel_compiles_builds_or_uploads(
                          residency=_manager(), regions_device_min=1)
     rng = np.random.default_rng([SEED, 3])
     assert engine.region_index_stats() == {
-        "candidates": 0, "built": 0, "device": 0, "builds": 0, "uploads": 0}
+        "candidates": 0, "built": 0, "device": 0, "builds": 0, "uploads": 0,
+        "base_builds": 0, "flush_builds": 0, "request_builds": 0,
+        "delta_builds": 0, "delta_rows": 0}
     # the first panel's windows heat the segments; the uploader (inline
     # here) uploads them and warms their chromosomes' indexes
     first = _panel(rng, 96)
     assert engine.regions_serve(first, limit=LIMIT).assemble() \
         == _reference_body(truth, first, LIMIT)
     assert engine.region_index_stats() == {
-        "candidates": 3, "built": 3, "device": 3, "builds": 3, "uploads": 3}
+        "candidates": 3, "built": 3, "device": 3, "builds": 3, "uploads": 3,
+        "base_builds": 3, "flush_builds": 0, "request_builds": 3,
+        "delta_builds": 0, "delta_rows": 0}
     residency = engine.residency.stats()
     assert residency["resident"] == residency["candidates"] == 6
     programs = runtime.compile_summary()["programs"]
@@ -481,9 +485,12 @@ def test_a_small_panel_is_buffered_and_a_bad_one_counts_nothing(store_dir,
         stats = json.loads(_get(port, "/stats"))
         assert stats["region_panels"]["panels"] == 1
         assert stats["region_panels"]["intervals"] == 12
+        groups = len({s.split(":")[0] for s in specs})
         assert stats["region_index"] == {
             "candidates": 0, "built": 0, "device": 0,
-            "builds": len({s.split(":")[0] for s in specs}), "uploads": 0}
+            "builds": groups, "uploads": 0, "base_builds": groups,
+            "flush_builds": 0, "request_builds": groups,
+            "delta_builds": 0, "delta_rows": 0}
         assert _stage_counts(port)["regions.parse"] == 1
     finally:
         stop_server(server)

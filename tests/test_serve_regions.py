@@ -441,12 +441,12 @@ def test_concurrent_index_builds_deduplicate(served):
     builds = {"n": 0}
     real_build = engine_mod.IntervalIndex.build.__func__
 
-    def slow_build(shard):
+    def slow_build(shard, *args):
         builds["n"] += 1
         import time as _time
 
         _time.sleep(0.05)  # widen the race window
-        return real_build(engine_mod.IntervalIndex, shard)
+        return real_build(engine_mod.IntervalIndex, shard, *args)
 
     engine_mod.IntervalIndex.build = slow_build
     try:

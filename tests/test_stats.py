@@ -500,7 +500,7 @@ def test_stats_device_copies_join_the_device_byte_ledger():
     specs = [f"8:{1000 + 7 * i}-{2000 + 7 * i}" for i in range(4)]
     engine.stats_serve(specs)
     snap = engine.snapshots.current()
-    feats = engine._stats_cache[(snap.generation, 8)]
+    feats = engine._interval_index(snap, 8).feats
     assert feats.device_bytes() > 0
     ledgered = {id(obj) for obj, _b in engine._index_device.values()}
     assert id(feats) in ledgered
